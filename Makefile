@@ -190,9 +190,8 @@ simscale-bench:
 # (docs/PIPELINE.md): deterministic "mode": "simulated" rows over the
 # (stages x microbatches x hop bytes) grid, each cell's verified hop
 # program replayed next to the closed-form step time and stash bound,
-# the 1F1B memory win flagged per row.  Byte-identical across runs —
-# measured gpipe-vs-1f1b A/B rows live in the device-gated pipeline_ab
-# battery (benchmarks.hw_session) instead.
+# the 1F1B memory win flagged per row.  Byte-identical across runs; the
+# gpipe-vs-1f1b A/B is not measured on a chip yet.
 pipe-bench:
 	JAX_PLATFORMS=cpu python -m benchmarks.sim_collectives \
 		--pipe-sweep --pipe-stages 2,4 --pipe-microbatches 2,4,8 \

@@ -13,10 +13,6 @@ Public surface mirrors the reference's `adapcc.py` (reference adapcc.py:6-77):
 reconstruct_topology / set_profile_freq / clear``.
 """
 
-from adapcc_tpu import compat as _compat
-
-_compat.install()
-
 from adapcc_tpu.primitives import (
     ALLREDUCE,
     REDUCE,

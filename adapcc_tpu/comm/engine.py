@@ -38,6 +38,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from adapcc_tpu.primitives import ReduceOp
 from adapcc_tpu.strategy.ir import CommRound, Strategy, Tree
 from adapcc_tpu.comm.mesh import RANKS_AXIS
+from adapcc_tpu.ops.kernel_mode import resolve_interpret
 
 
 #: default KV-stream granularity: one DCN chunk per ~4 MiB of wire payload
@@ -1972,7 +1973,7 @@ class CollectiveEngine:
         )
 
     @staticmethod
-    def _ring_extras(plan) -> Dict[str, Any]:
+    def _ring_extras(plan, interpret) -> Dict[str, Any]:
         """Trace payload for a Pallas-ring dispatch — ONE definition shared
         by allreduce/RS/AG so the three primitives' artifacts cannot
         drift.  ``wire_dtype`` is the EXECUTED codec (from the plan), never
@@ -1983,6 +1984,9 @@ class CollectiveEngine:
             "stage_bytes": plan.stage_bytes,
             "n_tiles": plan.n_tiles,
             "wire_dtype": plan.wire_dtype,
+            # Mosaic kernel (False) or the Pallas interpreter (True): what
+            # ops/kernel_mode.resolve_interpret decided for this dispatch
+            "interpret": bool(interpret),
         }
         if plan.wire_dtype == "off":
             extras["wire_bytes"] = plan.payload_bytes
@@ -1998,14 +2002,25 @@ class CollectiveEngine:
             extras["fused"] = True
         return extras
 
-    def _record_ring(self, primitive: str, plan, stacked: jnp.ndarray) -> None:
+    @staticmethod
+    def _finish_interpreted(out, interpret):
+        """An interpreted Pallas kernel runs as host callbacks that execute
+        JAX ops of their own; left in flight beside the caller's next
+        dispatches they can deadlock jax's CPU client (seen under the
+        six-worker test run: eight callback threads parked in the
+        interpreter's ``store`` while the main thread's next eager op never
+        returns).  The interpreter is never a fast path, so an interpreted
+        dispatch completes before it returns; a Mosaic dispatch stays async."""
+        return jax.block_until_ready(out) if interpret else out
+
+    def _record_ring(self, primitive: str, plan, stacked: jnp.ndarray, interpret) -> None:
         if self.trace is not None:
             suffix = "" if plan.wire_dtype == "off" else f"+{plan.wire_dtype}"
             self.trace.record(
                 primitive,
                 f"pallas_ring[{plan.path}{suffix}]",
                 int(stacked.nbytes),
-                **self._ring_extras(plan),
+                **self._ring_extras(plan, interpret),
             )
 
     def _resolved_wire_dtype(self, wire_dtype: Optional[str]) -> str:
@@ -2197,8 +2212,7 @@ class CollectiveEngine:
             if reroute is None:
                 # the fused path: codec inside the staged Pallas kernels —
                 # compressed tiles on the wire, fp32 accumulation in VMEM
-                if interpret is None:
-                    interpret = jax.devices()[0].platform != "tpu"
+                interpret = resolve_interpret(interpret, "ring_allreduce")
                 world = self.world_size
                 plan = self._ring_plan(
                     stacked, chunk_bytes, rs=True, ag=True,
@@ -2216,10 +2230,12 @@ class CollectiveEngine:
                     "ring_allreduce", stacked.shape, stacked.dtype.name,
                     bool(interpret), plan.path, plan.stage_bytes, wd, block,
                 )
-                out = self._shard_mapped(cache_key, per_shard, 1)(stacked)
+                out = self._finish_interpreted(
+                    self._shard_mapped(cache_key, per_shard, 1)(stacked), interpret
+                )
                 impl = f"pallas_ring[{plan.path}+{wd}]"
                 executed_path, executed_chunk = plan.path, plan.chunk_bytes
-                extras = self._ring_extras(plan)
+                extras = self._ring_extras(plan, interpret)
             else:
                 # the staged kernel was abandoned for this dispatch — say so
                 # once, loudly, and record the executed impl honestly (a
@@ -2234,8 +2250,7 @@ class CollectiveEngine:
                 impl = f"quant_ring[{wd}]"
                 executed_path, executed_chunk = QUANT_PATH, NO_CHUNK
         else:
-            if interpret is None:
-                interpret = jax.devices()[0].platform != "tpu"
+            interpret = resolve_interpret(interpret, "ring_allreduce")
             world = self.world_size
             plan = self._ring_plan(stacked, chunk_bytes, rs=True, ag=True)
 
@@ -2249,10 +2264,12 @@ class CollectiveEngine:
                 "ring_allreduce", stacked.shape, stacked.dtype.name,
                 bool(interpret), plan.path, plan.stage_bytes,
             )
-            out = self._shard_mapped(cache_key, per_shard, 1)(stacked)
+            out = self._finish_interpreted(
+                self._shard_mapped(cache_key, per_shard, 1)(stacked), interpret
+            )
             impl = f"pallas_ring[{plan.path}]"
             executed_path, executed_chunk = plan.path, plan.chunk_bytes
-            extras = self._ring_extras(plan)
+            extras = self._ring_extras(plan, interpret)
         # the executed ALGORITHM rides the trace like wire_dtype: every
         # ring-family branch above is "ring", the latency branch stamped
         # its own name
@@ -2350,8 +2367,7 @@ class CollectiveEngine:
         wd, block = self._ring_wire_args(
             stacked, wire_dtype, quant_block_size, "ring_reduce_scatter"
         )
-        if interpret is None:
-            interpret = jax.devices()[0].platform != "tpu"
+        interpret = resolve_interpret(interpret, "ring_reduce_scatter")
         world = self.world_size
         plan = self._ring_plan(
             stacked, chunk_bytes, rs=True, ag=False,
@@ -2377,8 +2393,10 @@ class CollectiveEngine:
             "ring_rs", stacked.shape, stacked.dtype.name, bool(interpret),
             plan.path, plan.stage_bytes, wd, block,
         )
-        self._record_ring("reduce_scatter", plan, stacked)
-        return self._shard_mapped(key, per_shard, 1)(stacked)
+        self._record_ring("reduce_scatter", plan, stacked, interpret)
+        return self._finish_interpreted(
+            self._shard_mapped(key, per_shard, 1)(stacked), interpret
+        )
 
     def ring_all_gather(
         self,
@@ -2410,8 +2428,7 @@ class CollectiveEngine:
         wd, block = self._ring_wire_args(
             stacked, wire_dtype, quant_block_size, "ring_all_gather"
         )
-        if interpret is None:
-            interpret = jax.devices()[0].platform != "tpu"
+        interpret = resolve_interpret(interpret, "ring_all_gather")
         world = self.world_size
         plan = self._ring_plan(
             stacked, chunk_bytes, rs=False, ag=True,
@@ -2429,8 +2446,10 @@ class CollectiveEngine:
             "ring_ag", stacked.shape, stacked.dtype.name, bool(interpret),
             plan.path, plan.stage_bytes, wd, block,
         )
-        self._record_ring("all_gather", plan, stacked)
-        return self._shard_mapped(key, per_shard, 1)(stacked)
+        self._record_ring("all_gather", plan, stacked, interpret)
+        return self._finish_interpreted(
+            self._shard_mapped(key, per_shard, 1)(stacked), interpret
+        )
 
     def reduce_scatter(
         self,

@@ -24,7 +24,7 @@ Two execution paths share those mechanics, selected per payload by
 - **vmem** — the whole payload is VMEM-resident (input + work + comm slots),
   the right program when everything fits in one ``chunk_bytes`` staging
   budget;
-- **hbm-stream** — the payload lives in HBM (``pltpu.ANY``) and a grid over
+- **hbm-stream** — the payload lives in HBM (``pl.ANY``) and a grid over
   (ring step × tile) streams ``chunk_bytes``-sized tiles through fixed VMEM
   staging: local DMA in → remote RDMA → accumulate → local DMA out, with the
   credit protocol carried across grid steps.  This is the TPU analog of the
@@ -85,6 +85,15 @@ FUSED_WIRE_MODES = ("auto", "on", "off")
 #: "off" is not fused (the plain kernels ship the payload dtype); other
 #: registry codecs reroute to the unfused quantized ppermute ring.
 _FUSED_WIRE_ITEMSIZE = {"bf16": 2, "int8": 1}
+
+
+#: VMEM of one TPU v5e TensorCore (128 MiB; Google Cloud "TPU v5e"
+#: system architecture), the compiler's default scoped limit there, and
+#: the room a ring plan leaves for Mosaic's own temporaries (the fp32
+#: upcast of a narrow accumulate, codec block math) on top of its buffers.
+_VMEM_CAPACITY_BYTES = 128 * 1024 * 1024
+_DEFAULT_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+_COMPILER_STACK_BYTES = 4 * 1024 * 1024
 
 
 def _tile_elems(dtype) -> int:
@@ -163,15 +172,7 @@ def fused_ring_dispatch_reason(
     if mode == "off":
         reason: Optional[str] = f"{FUSED_WIRE_ENV}=off pins the unfused path"
     else:
-        from adapcc_tpu.compat import ring_kernels_supported
-
-        if not ring_kernels_supported():
-            reason = (
-                "ring kernels need a real TPU or the Mosaic TPU interpret "
-                "mode (jax >= 0.5); this build has neither"
-            )
-        else:
-            reason = fused_wire_unsupported_reason(dtype, wire_dtype, block_size)
+        reason = fused_wire_unsupported_reason(dtype, wire_dtype, block_size)
     if reason is not None and mode == "on":
         raise ValueError(
             f"{FUSED_WIRE_ENV}=on but the fused wire path cannot run: {reason}"
@@ -293,6 +294,26 @@ class RingSchedule:
             return 3 * self.padded_bytes + 3 * self.wire_stage_bytes + self.scale_bytes
         return 2 * self.stage_bytes + 3 * self.wire_stage_bytes + self.scale_bytes
 
+    @property
+    def vmem_limit_bytes(self) -> int:
+        """The scoped-VMEM limit the kernel asks Mosaic for: the data
+        buffers (:attr:`vmem_bound_bytes`) plus the compiler's own stack.
+        The compiler's default (16 MiB on v5e) leaves a 4 × 4 MiB fp32
+        stream plan zero margin and refuses the same plan in bf16, whose
+        accumulate upcasts to fp32 (no bf16 VPU on v5e) through compiler
+        temporaries the data-buffer bound cannot see.  Stating the limit
+        from the plan makes the accounting a contract: a plan the chip
+        cannot hold fails here, in Python, not inside Mosaic."""
+        limit = self.vmem_bound_bytes + _COMPILER_STACK_BYTES
+        if limit > _VMEM_CAPACITY_BYTES:
+            raise ValueError(
+                f"ring plan needs {limit} bytes of VMEM "
+                f"({self.path}, stage {self.stage_bytes} B, wire "
+                f"{self.wire_dtype}); the chip has {_VMEM_CAPACITY_BYTES} — "
+                "lower chunk_bytes"
+            )
+        return max(limit, _DEFAULT_SCOPED_VMEM_BYTES)
+
     def to_row(self) -> dict:
         return {
             "ring_path": self.path,
@@ -383,19 +404,25 @@ def plan_ring_schedule(
     steps = (world - 1 if rs else 0) + (world - 1 if ag else 0)
     fused = wire_dtype != "off"
     blk = int(block_size) if fused and wire_dtype == "int8" else 0
+    if blk:
+        # int8 kernels see one codec block per row: staging tiles are whole
+        # fp32 tiles of *block rows* (8 blocks), so a chunk that is not is
+        # zero-padded inside the dispatch (sliced back out, like the stream
+        # path's tile padding — the external chunk layout never moves)
+        sublanes *= blk // _LANES
+    chunk_rows = -(-(chunk // _LANES) // sublanes) * sublanes
     if world == 1 or padded_bytes <= budget:
-        chunk_rows = chunk // _LANES
         wire_stage, scale_slot = (
             _wire_geometry(chunk_rows, wire_dtype, blk) if fused else (0, 0)
         )
         return RingSchedule(
             path="vmem", world=world, steps=steps, chunk_bytes=budget,
-            stage_bytes=chunk * itemsize, n_tiles=1,
-            payload_bytes=int(nelems) * itemsize, padded_bytes=padded_bytes,
+            stage_bytes=chunk_rows * _LANES * itemsize, n_tiles=1,
+            payload_bytes=int(nelems) * itemsize,
+            padded_bytes=world * chunk_rows * _LANES * itemsize,
             dtype=dtype.name, wire_dtype=wire_dtype, block_size=blk,
             wire_stage_bytes=wire_stage, scale_slot_bytes=scale_slot,
         )
-    chunk_rows = chunk // _LANES
     # the staging budget covers what one tile actually keeps in VMEM: the
     # payload row plus, on int8 plans, its amortized fp32 scale bytes (one
     # scale per block_size elements; ceil so block 1024's fraction of a
@@ -668,68 +695,103 @@ def _stream_ring_kernel(
 #   bits and add order are op-identical).
 
 
-def _wire_scales_of(s_tile: jnp.ndarray, n_blocks: int) -> jnp.ndarray:
-    """[s_rows, 128] scale tile → the [n_blocks] fp32 scale vector."""
-    return s_tile.reshape(-1)[:n_blocks]
+#: block rows per lane-dense scale row: one 128×128 transpose moves 128
+#: per-block scales from sublanes (where the lane reduction leaves them)
+#: to lanes (where the side channel ships them densely)
+_SCALE_GROUP = 128
+
+# Kernel-side geometry of a fused tile: ``[n_rows, B]`` fp32 with ONE codec
+# block per row — ``B = block_size`` on int8 plans (the wrapper reshapes the
+# ``[.., rows, 128]`` chunk to ``[.., rows / rows_per_block, block_size]``,
+# a relabeling of the same contiguous elements), ``B = 128`` for bf16.  Per-
+# block scales are then a lane reduction with ``keepdims`` — an ``[n, 1]``
+# column that broadcasts straight back over its row — and every value in
+# the kernel stays 2-D: Mosaic has no layout for a rank-1 fp32 vector (a
+# reshape through one aborts the compiler process, ``layout.h: arr.size()
+# >= layout_rank``; the interpreter accepts it, so only
+# tests/test_chip_compile.py guards this).  All codec math walks the tile
+# in static 128-row groups, so compiler temporaries are one group, not one
+# tile.
 
 
-def _fused_block_scales(vals: jnp.ndarray, rows_per_block: int) -> jnp.ndarray:
-    """Per-block fp32 scales of one ``[R, 128]`` tile — the exact absmax/127
+def _row_groups(n_rows: int):
+    """Static ``(group, first row, rows)`` triples covering ``n_rows``."""
+    return [
+        (g, g * _SCALE_GROUP, min(_SCALE_GROUP, n_rows - g * _SCALE_GROUP))
+        for g in range(-(-n_rows // _SCALE_GROUP))
+    ]
+
+
+def _at(lead, r0: int, n: int):
+    """Index of rows ``[r0, r0 + n)`` of a tile ref, behind an optional
+    leading (slot / chunk) index."""
+    rows = pl.ds(r0, n)
+    return (rows, slice(None)) if lead is None else (lead, rows, slice(None))
+
+
+def _block_scale_col(vals: jnp.ndarray) -> jnp.ndarray:
+    """``[n, B]`` fp32 → ``[n, 1]`` per-block scales — the exact absmax/127
     derivation of ``quant/codec.quantize_int8``."""
-    n_blocks = vals.shape[0] // rows_per_block
-    blocks = vals.reshape(n_blocks, rows_per_block, _LANES)
-    absmax = jnp.max(jnp.abs(blocks), axis=(1, 2))
+    absmax = jnp.max(jnp.abs(vals), axis=1, keepdims=True)
     return jnp.where(absmax > 0, absmax / 127.0, 1.0)
 
 
-def _fused_encode(vals: jnp.ndarray, wire_dtype: str, rows_per_block: int):
-    """Encode one ``[R, 128]`` fp32 tile: returns ``(wire, scales | None)``
-    with the exact ops of ``quant/codec.quantize_int8`` (deterministic
-    rounding) so fused and unfused wire bits can never drift."""
-    if wire_dtype == "bf16":
-        return vals.astype(jnp.bfloat16), None
-    scales = _fused_block_scales(vals, rows_per_block)
-    n_blocks = vals.shape[0] // rows_per_block
-    blocks = vals.reshape(n_blocks, rows_per_block, _LANES)
-    q = jnp.clip(jnp.round(blocks / scales[:, None, None]), -127.0, 127.0)
-    return q.astype(jnp.int8).reshape(vals.shape), scales
+def _pack_scale_row(col: jnp.ndarray) -> jnp.ndarray:
+    """``[n <= 128, 1]`` scale column → one lane-dense ``[1, 128]`` side-
+    channel row (padding scales are 1.0, the all-zero-block convention)."""
+    n = col.shape[0]
+    blk = jnp.broadcast_to(col, (n, _LANES))
+    if n < _SCALE_GROUP:
+        blk = jnp.concatenate(
+            [blk, jnp.ones((_SCALE_GROUP - n, _LANES), jnp.float32)], axis=0
+        )
+    return blk.T[0:1, :]
 
 
-def _fused_requantize(
-    vals: jnp.ndarray, scales: jnp.ndarray, rows_per_block: int
-) -> jnp.ndarray:
-    """Re-derive the int8 codes of already-decoded values against their
-    original (forwarded) scales — exact: ``round((q·s)/s) == q`` for
-    ``|q| <= 127`` in fp32, so the all-gather forwards bits verbatim
-    without carrying the code arrays through HBM."""
-    n_blocks = vals.shape[0] // rows_per_block
-    blocks = vals.reshape(n_blocks, rows_per_block, _LANES)
-    q = jnp.clip(jnp.round(blocks / scales[:, None, None]), -127.0, 127.0)
-    return q.astype(jnp.int8).reshape(vals.shape)
+def _unpack_scale_col(row: jnp.ndarray, n: int) -> jnp.ndarray:
+    """One ``[1, 128]`` side-channel row → the ``[n, 1]`` scale column."""
+    return jnp.broadcast_to(row, (_SCALE_GROUP, _LANES)).T[:n, 0:1]
 
 
-def _fused_decode(
-    wire: jnp.ndarray,
-    scales: Optional[jnp.ndarray],
-    wire_dtype: str,
-    rows_per_block: int,
-) -> jnp.ndarray:
-    """Decode one wire tile back to fp32 (``quant/codec.dequantize_int8``
-    ops, tile-shaped)."""
-    if wire_dtype == "bf16":
-        return wire.astype(jnp.float32)
-    n_blocks = wire.shape[0] // rows_per_block
-    blocks = wire.reshape(n_blocks, rows_per_block, _LANES).astype(jnp.float32)
-    return (blocks * scales[:, None, None]).reshape(wire.shape)
+def _derive_scales(src, lead, scale_dst, n_rows: int) -> None:
+    """Fresh per-block scales of the fp32 tile ``src[lead]`` → the lane-
+    dense scale tile ``scale_dst``."""
+    for g, r0, n in _row_groups(n_rows):
+        scale_dst[g : g + 1, :] = _pack_scale_row(
+            _block_scale_col(src[_at(lead, r0, n)])
+        )
 
 
-def _scales_to_tile(scales: jnp.ndarray, s_rows: int) -> jnp.ndarray:
-    """[n_blocks] scale vector → the [s_rows, 128] side-channel tile
-    (padding scales are 1.0, the all-zero-block convention)."""
-    pad = s_rows * _LANES - scales.shape[0]
-    return jnp.concatenate(
-        [scales, jnp.ones((pad,), jnp.float32)]
-    ).reshape(s_rows, _LANES)
+def _encode(src, lead, scale_src, wire_dst, n_rows: int, int8: bool) -> None:
+    """Encode the fp32 tile ``src[lead]`` into ``wire_dst``: bf16 cast, or
+    int8 codes against the scales already in ``scale_src`` — with fresh
+    scales this IS ``quant/codec.quantize_int8`` (same divide / round /
+    clip ops, so fused and unfused wire bits cannot drift); with forwarded
+    scales it re-derives the codes of already-decoded values exactly
+    (``round((q·s)/s) == q`` for ``|q| <= 127`` in fp32)."""
+    for g, r0, n in _row_groups(n_rows):
+        vals = src[_at(lead, r0, n)]
+        if int8:
+            col = _unpack_scale_col(scale_src[g : g + 1, :], n)
+            wire = jnp.clip(jnp.round(vals / col), -127.0, 127.0).astype(jnp.int8)
+        else:
+            wire = vals.astype(jnp.bfloat16)
+        wire_dst[_at(None, r0, n)] = wire
+
+
+def _decode(
+    wire_src, wire_lead, scale_src, scale_lead, dst, dst_lead,
+    n_rows: int, int8: bool, accumulate: bool,
+) -> None:
+    """Decode the wire tile back to fp32 (``quant/codec.dequantize_int8``
+    ops) and fold it into ``dst[dst_lead]``: add on reduce-scatter hops,
+    adopt on all-gather hops."""
+    for g, r0, n in _row_groups(n_rows):
+        vals = wire_src[_at(wire_lead, r0, n)].astype(jnp.float32)
+        if int8:
+            vals = vals * _unpack_scale_col(scale_src[_at(scale_lead, g, 1)], n)
+        idx = _at(dst_lead, r0, n)
+        dst[idx] = dst[idx] + vals if accumulate else vals
 
 
 def _fused_ring_kernel(
@@ -752,8 +814,6 @@ def _fused_ring_kernel(
     do_reduce_scatter: bool,
     do_all_gather: bool,
     wire_dtype: str,
-    rows_per_block: int,
-    s_rows: int,
 ):
     """VMEM-resident fused ring walk: the ``_ring_kernel`` schedule with
     the wire codec applied per chunk.  ``wire_send``/``comm_w`` carry the
@@ -765,7 +825,7 @@ def _fused_ring_kernel(
     right = (my_id + 1) % world
     left = (my_id + world - 1) % world
     int8 = wire_dtype == "int8"
-    n_blocks = work.shape[1] * _LANES // (rows_per_block * _LANES) if int8 else 0
+    n_rows = work.shape[1]
 
     barrier = pltpu.get_barrier_semaphore()
     pltpu.semaphore_signal(barrier, inc=1, device_id=left)
@@ -790,29 +850,21 @@ def _fused_ring_kernel(
             send_idx = (my_id + world + own - ag) % world
             recv_idx = (my_id + world + own - ag - 1) % world
 
-        vals = work[send_idx]
-        if in_rs or step == n_rs:
-            # RS hops re-encode the moving partial; the first AG hop is
-            # the once-per-reduced-chunk encode that defines the bits
-            wire, scales = _fused_encode(vals, wire_dtype, rows_per_block)
-        else:
-            # later AG hops forward verbatim: stored scales, exact codes
-            scales = (
-                _wire_scales_of(scale_store[send_idx], n_blocks)
-                if int8 else None
-            )
-            wire = (
-                _fused_requantize(vals, scales, rows_per_block)
-                if int8 else vals.astype(jnp.bfloat16)
-            )
-        wire_send[...] = wire
         if int8:
-            scale_send[...] = _scales_to_tile(scales, s_rows)
+            if in_rs or step == n_rs:
+                # RS hops re-encode the moving partial; the first AG hop is
+                # the once-per-reduced-chunk encode that defines the bits
+                _derive_scales(work, send_idx, scale_send, n_rows)
+            else:
+                # later AG hops forward verbatim: stored scales, exact codes
+                scale_send[...] = scale_store[send_idx]
+        _encode(work, send_idx, scale_send, wire_send, n_rows, int8)
         if not in_rs and step == n_rs:
             # the owner adopts its own DECODED chunk: every rank must see
             # the same post-codec value, owner included (quant/ring.py)
-            work[send_idx] = _fused_decode(
-                wire, scales, wire_dtype, rows_per_block
+            _decode(
+                wire_send, None, scale_send, None, work, send_idx,
+                n_rows, int8, accumulate=False,
             )
 
         if step >= 2:
@@ -840,19 +892,13 @@ def _fused_ring_kernel(
             rdma_s.wait()
         rdma_w.wait()  # outbound sent AND left neighbor's arrays landed
 
-        landed_scales = (
-            _wire_scales_of(comm_s[slot], n_blocks) if int8 else None
+        _decode(
+            comm_w, slot, comm_s, slot, work, recv_idx,
+            n_rows, int8, accumulate=in_rs,
         )
-        landed = _fused_decode(
-            comm_w[slot], landed_scales, wire_dtype, rows_per_block
-        )
-        if in_rs:
-            work[recv_idx] = work[recv_idx] + landed
-        else:
-            work[recv_idx] = landed
-            if int8:
-                # bank the forwarded-bit scales for the next AG hop
-                scale_store[recv_idx] = comm_s[slot]
+        if not in_rs and int8:
+            # bank the forwarded-bit scales for the next AG hop
+            scale_store[recv_idx] = comm_s[slot]
 
         pltpu.semaphore_signal(cap_sem, inc=1, device_id=left)
 
@@ -884,11 +930,8 @@ def _fused_stream_ring_kernel(
     do_reduce_scatter: bool,
     do_all_gather: bool,
     n_tiles: int,
-    stage_rows: int,
     total_iters: int,
     wire_dtype: str,
-    rows_per_block: int,
-    s_rows: int,
 ):
     """HBM-streaming fused ring walk: ``_stream_ring_kernel``'s grid and
     credit protocol with the codec in the staging tiles.  Each iteration
@@ -905,7 +948,8 @@ def _fused_stream_ring_kernel(
     right = (my_id + 1) % world
     left = (my_id + world - 1) % world
     int8 = wire_dtype == "int8"
-    n_blocks = stage_rows * _LANES // (rows_per_block * _LANES) if int8 else 0
+    n_rows = send_stage.shape[0]
+    s_rows = scale_send.shape[0] if int8 else 0
 
     n_rs = world - 1 if do_reduce_scatter else 0
 
@@ -933,7 +977,7 @@ def _fused_stream_ring_kernel(
         (my_id + 2 * world + own - ag - 1) % world,
     )
     slot = it % 2
-    rows = pl.ds(tile * stage_rows, stage_rows)
+    rows = pl.ds(tile * n_rows, n_rows)
     srows = pl.ds(tile * s_rows, s_rows)
     # fresh encode on RS hops and the first AG hop (the once-per-reduced-
     # chunk encode); later AG hops re-derive codes against forwarded scales
@@ -955,33 +999,23 @@ def _fused_stream_ring_kernel(
             fwd.start()
             fwd.wait()
 
-    vals = send_stage[...]
-    if int8:
-
         @pl.when(fresh)
         def _derive_fresh_scales():
             # only fresh hops pay the absmax pass; forwarded hops already
             # DMA'd the original scale bits into scale_send above
-            scale_send[...] = _scales_to_tile(
-                _fused_block_scales(vals, rows_per_block), s_rows
-            )
+            _derive_scales(send_stage, None, scale_send, n_rows)
 
-        scales = _wire_scales_of(scale_send[...], n_blocks)
-        # one requantize serves both cases: with fresh scales it IS the
-        # encode (same round/clip ops), with forwarded scales it is exact
-        wire_send[...] = _fused_requantize(vals, scales, rows_per_block)
-    else:
-        scales = None
-        wire_send[...] = vals.astype(jnp.bfloat16)
+    # one encode serves both cases: with fresh scales it IS the quantize
+    # (same round/clip ops), with forwarded scales it is exact
+    _encode(send_stage, None, scale_send, wire_send, n_rows, int8)
 
     @pl.when(jnp.logical_and(jnp.logical_not(in_rs), ag == 0))
     def _adopt_own():
         # the owner adopts its own decoded tile: every rank must end with
         # the same post-codec bits, owner included
-        acc[...] = _fused_decode(
-            wire_send[...],
-            _wire_scales_of(scale_send[...], n_blocks) if int8 else None,
-            wire_dtype, rows_per_block,
+        _decode(
+            wire_send, None, scale_send, None, acc, None,
+            n_rows, int8, accumulate=False,
         )
         own_out = pltpu.make_async_copy(
             acc, out_ref.at[send_idx, rows], local_sem
@@ -1015,10 +1049,6 @@ def _fused_stream_ring_kernel(
         rdma_s.wait()
     rdma_w.wait()  # outbound sent AND left neighbor's arrays landed
 
-    landed_scales = (
-        _wire_scales_of(comm_s[slot], n_blocks) if int8 else None
-    )
-
     @pl.when(in_rs)
     def _reduce():
         acc_in = pltpu.make_async_copy(
@@ -1026,8 +1056,9 @@ def _fused_stream_ring_kernel(
         )
         acc_in.start()
         acc_in.wait()
-        acc[...] = acc[...] + _fused_decode(
-            comm_w[slot], landed_scales, wire_dtype, rows_per_block
+        _decode(
+            comm_w, slot, comm_s, slot, acc, None,
+            n_rows, int8, accumulate=True,
         )
         acc_out = pltpu.make_async_copy(
             acc, out_ref.at[recv_idx, rows], local_sem
@@ -1037,8 +1068,9 @@ def _fused_stream_ring_kernel(
 
     @pl.when(jnp.logical_not(in_rs))
     def _adopt():
-        acc[...] = _fused_decode(
-            comm_w[slot], landed_scales, wire_dtype, rows_per_block
+        _decode(
+            comm_w, slot, comm_s, slot, acc, None,
+            n_rows, int8, accumulate=False,
         )
         adopt = pltpu.make_async_copy(
             acc, out_ref.at[recv_idx, rows], local_sem
@@ -1074,20 +1106,6 @@ def _pad_chunks(flat: jnp.ndarray, world: int):
     return padded.reshape(world, chunk // _LANES, _LANES), chunk
 
 
-def _check_ring_supported() -> None:
-    from adapcc_tpu.compat import ring_kernels_supported
-
-    if not ring_kernels_supported():
-        # the one funnel every ring entry point (and so --zero1-ring,
-        # engine.ring_*, the benchmarks) routes through: fail with guidance
-        # here rather than a cryptic Mosaic/legacy-pallas error deeper in
-        raise RuntimeError(
-            "Pallas ICI ring kernels need a real TPU or the Mosaic TPU "
-            "interpret mode (jax >= 0.5); this build has neither — use the "
-            "XLA collective path instead (e.g. drop --zero1-ring)"
-        )
-
-
 def _check_fused_wire(dtype, wire_dtype: str, block_size: Optional[int]) -> None:
     """Loud reject where fused codec semantics don't apply — running fp32
     silently under a requested codec would invalidate every wire A/B."""
@@ -1108,52 +1126,60 @@ def _run_fused_ring_chunks(
     rs,
     ag,
     interpret,
-    block_size: int,
 ):
     """Dispatch a fused-codec plan on a pre-chunked ``[world, S, 128]``
-    fp32 array (both paths).  The wrappers slice stream-path padding back
-    out, exactly like the unfused dispatch."""
+    fp32 array (both paths).  The kernels see one codec block per row
+    (``[world, S / rows_per_block, block_size]`` on int8 plans — the same
+    contiguous elements, relabeled) with each chunk zero-padded to the
+    plan's whole codec tiles; the padding is sliced back out, exactly like
+    the unfused stream dispatch."""
     wire_dtype = plan.wire_dtype
     int8 = wire_dtype == "int8"
     wire_jnp = jnp.int8 if int8 else jnp.bfloat16
-    rows_per_block = (block_size // _LANES) if int8 else 1
+    width = plan.block_size if int8 else _LANES     # kernel tile width B
+    rows_per_block = width // _LANES
     chunk_rows = chunks.shape[1]
+    stage_rows = plan.stage_bytes // (_LANES * 4)    # fp32 128-lane rows
+    padded_rows = plan.n_tiles * stage_rows
+    if padded_rows != chunk_rows:
+        chunks = jnp.pad(chunks, ((0, 0), (0, padded_rows - chunk_rows), (0, 0)))
+    chunks = chunks.reshape(world, padded_rows // rows_per_block, width)
+    tile_shape = (stage_rows // rows_per_block, width)
+    scale_shape = (_scale_rows(tile_shape[0]), _LANES) if int8 else None
+    kernel_kwargs = dict(
+        world=world,
+        axis_name=axis_name,
+        do_reduce_scatter=rs,
+        do_all_gather=ag,
+        wire_dtype=wire_dtype,
+    )
+    wire_sems = [
+        pltpu.SemaphoreType.DMA((2,)),                        # send codes
+        pltpu.SemaphoreType.DMA((2,)),                        # recv codes
+    ]
+    if int8:
+        wire_sems.extend([
+            pltpu.SemaphoreType.DMA((2,)),                    # send scales
+            pltpu.SemaphoreType.DMA((2,)),                    # recv scales
+        ])
+    wire_sems.append(pltpu.SemaphoreType.REGULAR)             # capacity
+    payload_shape = jax.ShapeDtypeStruct(chunks.shape, chunks.dtype)
+
     if plan.path == "vmem":
-        s_rows = _scale_rows(chunk_rows // rows_per_block) if int8 else 0
-        body = functools.partial(
-            _fused_ring_kernel,
-            world=world,
-            axis_name=axis_name,
-            do_reduce_scatter=rs,
-            do_all_gather=ag,
-            wire_dtype=wire_dtype,
-            rows_per_block=rows_per_block,
-            s_rows=s_rows,
-        )
-        wire_shape = (chunk_rows, _LANES)
-        scale_shape = (s_rows, _LANES)
+        body = functools.partial(_fused_ring_kernel, **kernel_kwargs)
         scratch = [
             pltpu.VMEM(chunks.shape, chunks.dtype),              # work
-            pltpu.VMEM(wire_shape, wire_jnp),                    # wire send
+            pltpu.VMEM(tile_shape, wire_jnp),                    # wire send
         ]
         if int8:
             scratch.append(pltpu.VMEM(scale_shape, jnp.float32))  # scale send
-        scratch.append(pltpu.VMEM((2,) + wire_shape, wire_jnp))   # comm codes
+        scratch.append(pltpu.VMEM((2,) + tile_shape, wire_jnp))   # comm codes
         if int8:
             scratch.extend([
                 pltpu.VMEM((2,) + scale_shape, jnp.float32),      # comm scales
                 pltpu.VMEM((world,) + scale_shape, jnp.float32),  # scale store
             ])
-        scratch.extend([
-            pltpu.SemaphoreType.DMA((2,)),                        # send codes
-            pltpu.SemaphoreType.DMA((2,)),                        # recv codes
-        ])
-        if int8:
-            scratch.extend([
-                pltpu.SemaphoreType.DMA((2,)),                    # send scales
-                pltpu.SemaphoreType.DMA((2,)),                    # recv scales
-            ])
-        scratch.append(pltpu.SemaphoreType.REGULAR)               # capacity
+        scratch.extend(wire_sems)
 
         if int8:
             kernel = body
@@ -1167,104 +1193,84 @@ def _run_fused_ring_chunks(
                     None, send_w, recv_w, None, None, cap_sem,
                 )
 
-        return pl.pallas_call(
+        out = pl.pallas_call(
             kernel,
-            out_shape=jax.ShapeDtypeStruct(chunks.shape, chunks.dtype),
+            out_shape=payload_shape,
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             scratch_shapes=scratch,
             compiler_params=pltpu.CompilerParams(
-                has_side_effects=True, collective_id=0
+                has_side_effects=True, collective_id=0,
+                vmem_limit_bytes=plan.vmem_limit_bytes,
             ),
             interpret=_interpret_params(interpret),
         )(chunks)
-
-    stage_rows = plan.stage_bytes // (_LANES * jnp.dtype(chunks.dtype).itemsize)
-    s_rows = _scale_rows(stage_rows // rows_per_block) if int8 else 0
-    total_iters = plan.steps * plan.n_tiles
-    padded_rows = plan.n_tiles * stage_rows
-    if padded_rows != chunk_rows:
-        chunks = jnp.pad(chunks, ((0, 0), (0, padded_rows - chunk_rows), (0, 0)))
-    body = functools.partial(
-        _fused_stream_ring_kernel,
-        world=world,
-        axis_name=axis_name,
-        do_reduce_scatter=rs,
-        do_all_gather=ag,
-        n_tiles=plan.n_tiles,
-        stage_rows=stage_rows,
-        total_iters=total_iters,
-        wire_dtype=wire_dtype,
-        rows_per_block=rows_per_block,
-        s_rows=s_rows,
-    )
-    tile_shape = (stage_rows, _LANES)
-    scale_shape = (s_rows, _LANES)
-    payload_shape = jax.ShapeDtypeStruct(chunks.shape, chunks.dtype)
-    scratch = [
-        pltpu.VMEM(tile_shape, chunks.dtype),              # fp32 send staging
-        pltpu.VMEM(tile_shape, chunks.dtype),              # fp32 accumulate
-        pltpu.VMEM(tile_shape, wire_jnp),                  # wire send
-    ]
-    if int8:
-        scratch.append(pltpu.VMEM(scale_shape, jnp.float32))  # scale send
-    scratch.append(pltpu.VMEM((2,) + tile_shape, wire_jnp))   # comm codes
-    if int8:
-        scratch.append(
-            pltpu.VMEM((2,) + scale_shape, jnp.float32)       # comm scales
-        )
-    scratch.extend([
-        pltpu.SemaphoreType.DMA(()),                          # local DMAs
-        pltpu.SemaphoreType.DMA((2,)),                        # send codes
-        pltpu.SemaphoreType.DMA((2,)),                        # recv codes
-    ])
-    if int8:
-        scratch.extend([
-            pltpu.SemaphoreType.DMA((2,)),                    # send scales
-            pltpu.SemaphoreType.DMA((2,)),                    # recv scales
-        ])
-    scratch.append(pltpu.SemaphoreType.REGULAR)               # capacity
-    if int8:
-        kernel = body
-        out_shape = (
-            payload_shape,
-            # per-chunk scale store: the AG's forwarded-bit side channel
-            jax.ShapeDtypeStruct(
-                (world, plan.n_tiles * s_rows, _LANES), jnp.float32
-            ),
-        )
-        out_specs = (
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        )
     else:
-        # bf16 needs no scale side channel or store: bind the unused refs
-        # to None so the plan's VMEM accounting matches the allocations
-        def kernel(x_ref, out_ref, send_stage, acc, wire_send, comm_w,
-                   local_sem, send_w, recv_w, cap_sem):
-            return body(
-                x_ref, out_ref, None, send_stage, acc, wire_send, None,
-                comm_w, None, local_sem, send_w, recv_w, None, None,
-                cap_sem,
+        total_iters = plan.steps * plan.n_tiles
+        body = functools.partial(
+            _fused_stream_ring_kernel,
+            n_tiles=plan.n_tiles,
+            total_iters=total_iters,
+            **kernel_kwargs,
+        )
+        scratch = [
+            pltpu.VMEM(tile_shape, chunks.dtype),              # fp32 send staging
+            pltpu.VMEM(tile_shape, chunks.dtype),              # fp32 accumulate
+            pltpu.VMEM(tile_shape, wire_jnp),                  # wire send
+        ]
+        if int8:
+            scratch.append(pltpu.VMEM(scale_shape, jnp.float32))  # scale send
+        scratch.append(pltpu.VMEM((2,) + tile_shape, wire_jnp))   # comm codes
+        if int8:
+            scratch.append(
+                pltpu.VMEM((2,) + scale_shape, jnp.float32)       # comm scales
             )
+        scratch.append(pltpu.SemaphoreType.DMA(()))               # local DMAs
+        scratch.extend(wire_sems)
+        if int8:
+            kernel = body
+            out_shape = (
+                payload_shape,
+                # per-chunk scale store: the AG's forwarded-bit side channel
+                jax.ShapeDtypeStruct(
+                    (world, plan.n_tiles * scale_shape[0], _LANES), jnp.float32
+                ),
+            )
+            out_specs = (
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            )
+        else:
+            # bf16 needs no scale side channel or store: bind the unused
+            # refs to None so the plan's VMEM accounting matches the
+            # allocations
+            def kernel(x_ref, out_ref, send_stage, acc, wire_send, comm_w,
+                       local_sem, send_w, recv_w, cap_sem):
+                return body(
+                    x_ref, out_ref, None, send_stage, acc, wire_send, None,
+                    comm_w, None, local_sem, send_w, recv_w, None, None,
+                    cap_sem,
+                )
 
-        out_shape = payload_shape
-        out_specs = pl.BlockSpec(memory_space=pltpu.ANY)
-    result = pl.pallas_call(
-        kernel,
-        grid=(plan.steps, plan.n_tiles),
-        out_shape=out_shape,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True,
-            collective_id=0,
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-        interpret=_interpret_params(interpret),
-    )(chunks)
-    out = result[0] if int8 else result
+            out_shape = payload_shape
+            out_specs = pl.BlockSpec(memory_space=pl.ANY)
+        result = pl.pallas_call(
+            kernel,
+            grid=(plan.steps, plan.n_tiles),
+            out_shape=out_shape,
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+            compiler_params=pltpu.CompilerParams(
+                has_side_effects=True,
+                collective_id=0,
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=plan.vmem_limit_bytes,
+            ),
+            interpret=_interpret_params(interpret),
+        )(chunks)
+        out = result[0] if int8 else result
+    out = out.reshape(world, padded_rows, _LANES)
     return out[:, :chunk_rows] if padded_rows != chunk_rows else out
 
 
@@ -1284,12 +1290,9 @@ def _run_ring_chunks(
     to the VMEM-resident or HBM-streaming kernel per the planned schedule
     (the fused codec variants when ``wire_dtype`` names one)."""
     if wire_dtype != "off":
-        # codec-semantics reject comes FIRST: it holds on every build,
-        # and a kernel-support RuntimeError must not mask it
         _check_fused_wire(chunks.dtype, wire_dtype, block_size)
         if block_size is None:
             block_size = _default_block_size()
-    _check_ring_supported()
     plan = plan_ring_schedule(
         chunks.size, chunks.dtype, world, chunk_bytes, rs=rs, ag=ag,
         wire_dtype=wire_dtype, block_size=block_size,
@@ -1297,7 +1300,7 @@ def _run_ring_chunks(
     if wire_dtype != "off":
         return _run_fused_ring_chunks(
             chunks, plan, world=world, axis_name=axis_name, rs=rs, ag=ag,
-            interpret=interpret, block_size=int(block_size),
+            interpret=interpret,
         )
     if plan.path == "vmem":
         kernel = functools.partial(
@@ -1320,7 +1323,8 @@ def _run_ring_chunks(
                 pltpu.SemaphoreType.REGULAR,                           # capacity
             ],
             compiler_params=pltpu.CompilerParams(
-                has_side_effects=True, collective_id=0
+                has_side_effects=True, collective_id=0,
+                vmem_limit_bytes=plan.vmem_limit_bytes,
             ),
             interpret=_interpret_params(interpret),
         )(chunks)
@@ -1349,8 +1353,8 @@ def _run_ring_chunks(
         kernel,
         grid=(plan.steps, plan.n_tiles),
         out_shape=jax.ShapeDtypeStruct(chunks.shape, chunks.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM(tile_shape, chunks.dtype),          # send staging
             pltpu.VMEM(tile_shape, chunks.dtype),          # accumulate staging
@@ -1365,6 +1369,7 @@ def _run_ring_chunks(
             collective_id=0,
             # the ring walk is stateful: both grid dims must run in order
             dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=plan.vmem_limit_bytes,
         ),
         interpret=_interpret_params(interpret),
     )(chunks)

@@ -486,7 +486,9 @@ class DDPTrainer:
             from adapcc_tpu.comm.pallas_ring import _tile_elems
 
             align = _tile_elems(jnp.float32)
-            ring_interpret = jax.devices()[0].platform != "tpu"
+            from adapcc_tpu.ops.kernel_mode import resolve_interpret
+
+            ring_interpret = resolve_interpret(None, "zero1_ring")
         else:
             align, ring_interpret = 1, False
         meta = _flatten_meta(state.params, world, align)
@@ -803,11 +805,11 @@ class DDPTrainer:
         """``n_steps`` full-world steps on one batch as ONE compiled dispatch
         (``lax.scan`` inside the shard_map).
 
-        On a remote/tunneled backend every ``step()`` call pays a
-        host→device dispatch round-trip; a scanned multi-step program pays
-        it once, so this is the honest way to measure device-side
-        throughput (bench.py) and the fast way to run tight loops whose
-        active set cannot change mid-scan.  Static full world only — no
+        Every ``step()`` call pays a host→device dispatch; a scanned
+        multi-step program pays it once, so this is the way to take the
+        host out of a device-side throughput reading (bench.py) and the
+        fast way to run tight loops whose active set cannot change
+        mid-scan.  Static full world only — no
         per-step negotiation, relay banking, or GNS capture.  Returns
         ``(final_state, losses [world, n_steps])``.
         """

@@ -189,30 +189,13 @@ def build_launch_plan(
     return plan
 
 
-def apply_platform_env() -> None:
-    """Re-pin ``jax_platforms`` from the env var.
-
-    Site customizations may force-select a platform list at interpreter
-    startup, overriding ``JAX_PLATFORMS`` from the launcher's ``--virtual``
-    env; re-applying it through the config restores the requested backend.
-    Safe no-op once a backend is already initialized with the same platform.
-    """
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        import jax
-
-        jax.config.update("jax_platforms", plat)
-
-
 def maybe_initialize_distributed() -> bool:
     """Join the multi-host world described by the launcher env contract.
 
-    Applies the platform env pin, then reads ``JAX_COORDINATOR_ADDRESS`` +
-    ``ADAPCC_NUM_PROCESSES`` / ``ADAPCC_PROCESS_ID`` and calls
-    ``jax.distributed.initialize``; returns False (after the platform pin)
-    when launched single-host.  Call before first device use.
+    Reads ``JAX_COORDINATOR_ADDRESS`` + ``ADAPCC_NUM_PROCESSES`` /
+    ``ADAPCC_PROCESS_ID`` and calls ``jax.distributed.initialize``; returns
+    False when launched single-host.  Call before first device use.
     """
-    apply_platform_env()
     addr = os.environ.get("JAX_COORDINATOR_ADDRESS")
     num = os.environ.get("ADAPCC_NUM_PROCESSES")
     if not addr or not num or int(num) <= 1:

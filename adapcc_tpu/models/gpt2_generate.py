@@ -176,9 +176,6 @@ def interact(argv: Optional[list] = None) -> None:
     """
     import argparse
 
-    from adapcc_tpu.launch.launcher import apply_platform_env
-
-    apply_platform_env()  # honor JAX_PLATFORMS despite site customizations
 
     ap = argparse.ArgumentParser(description="GPT-2 interactive sampling")
     ap.add_argument("--ckpt", "--checkpoint", dest="ckpt", default=None)

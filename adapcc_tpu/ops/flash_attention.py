@@ -40,6 +40,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from adapcc_tpu.ops.kernel_mode import resolve_interpret
+
 _NEG_INF = -1e30
 
 # Mosaic requires the last two dims of every block shape to be divisible by
@@ -158,12 +160,6 @@ def _dkv_kernel(
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _resolve_interpret(interpret):
-    if interpret is None:
-        return jax.devices()[0].platform != "tpu"
-    return interpret
-
-
 def _block_sizes(T: int, block_q: int, block_k: int):
     bq, bk = min(block_q, T), min(block_k, T)
     if T % bq or T % bk:
@@ -211,7 +207,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
             jax.ShapeDtypeStruct((BH, T, _LSE_LANES), jnp.float32),
         ],
-        interpret=_resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret, "flash_attention"),
     )(q, k, v)
     lse = lse3[:, :, 0]
     return out, (q, k, v, out, lse)
@@ -231,7 +227,7 @@ def _flash_bwd_core(scale, causal, block_q, block_k, interpret, res, do, dlse):
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
-    interp = _resolve_interpret(interpret)
+    interp = resolve_interpret(interpret, "flash_attention")
     # lane-pad the per-row statistics for the kernels' tiled block specs
     lse3 = jnp.broadcast_to(lse[..., None], (BH, T, _LSE_LANES))
     delta3 = jnp.broadcast_to(delta[..., None], (BH, T, _LSE_LANES))
@@ -335,7 +331,8 @@ def flash_attention(
 ) -> jnp.ndarray:
     """Blockwise attention over ``[B, T, H, D]`` tensors (model layout).
 
-    ``interpret=None`` auto-selects the Pallas interpreter off-TPU so the
+    ``interpret=None`` asks :func:`ops.kernel_mode.resolve_interpret`, which
+    records what it decided — the Pallas interpreter off-TPU, so the
     same call works on the virtual CPU pod.  ``scale`` defaults to
     ``1/sqrt(D)``.  ``T`` must divide by the block sizes (clamped to ``T``).
     """

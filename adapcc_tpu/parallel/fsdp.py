@@ -435,9 +435,9 @@ class Zero1Optimizer:
         self.tuner = tuner
         #: the TunedPlan behind an adopted chunk (None = not tuner-chosen)
         self.tuned_plan = None
-        if ring_interpret is None:
-            ring_interpret = jax.devices()[0].platform != "tpu"
-        self.ring_interpret = ring_interpret
+        from adapcc_tpu.ops.kernel_mode import resolve_interpret
+
+        self.ring_interpret = resolve_interpret(ring_interpret, "zero1_ring")
         # gradient-sync wire codec (quant registry; None/"off" = payload
         # dtype, ADAPCC_WIRE_DTYPE overrides — the ring_chunk_bytes
         # precedence).  zero1_train_step applies the codec's wire value to

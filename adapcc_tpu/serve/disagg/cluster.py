@@ -224,9 +224,16 @@ def measure_token_kl(
 
     _, exact = step(layers, first_token, plen)
     _, lossy = step(distorted(layers), first_token, plen)
-    lp_exact = jax.nn.log_softmax(exact[0, 0].astype(jnp.float32))
-    lp_lossy = jax.nn.log_softmax(lossy[0, 0].astype(jnp.float32))
-    kl = jnp.sum(jnp.exp(lp_exact) * (lp_exact - lp_lossy))
+    # float64 on the host: at small widths the KL (quadratic in the logit
+    # shift) sits below fp32's resolution, where the fp32 sum comes out 0 or
+    # negative and the probe would report a lossy wire as lossless
+    def log_softmax64(logits):
+        z = np.asarray(logits[0, 0], np.float64)
+        z = z - z.max()
+        return z - np.log(np.exp(z).sum())
+
+    lp_exact, lp_lossy = log_softmax64(exact), log_softmax64(lossy)
+    kl = np.sum(np.exp(lp_exact) * (lp_exact - lp_lossy))
     return max(float(kl), 0.0)
 
 

@@ -92,10 +92,6 @@ def build_parser() -> argparse.ArgumentParser:
 
 def run(args) -> dict:
     """Serve the trace; returns the summary dict (the printed artifact)."""
-    from adapcc_tpu.launch.launcher import apply_platform_env
-
-    apply_platform_env()  # honor JAX_PLATFORMS despite site customizations
-
     import jax
     import jax.numpy as jnp
 
@@ -254,4 +250,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from adapcc_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

@@ -660,4 +660,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from adapcc_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

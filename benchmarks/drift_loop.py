@@ -56,9 +56,6 @@ from typing import Dict, List, Optional, Sequence
 
 
 def main(argv: Optional[Sequence[str]] = None) -> Dict:
-    from adapcc_tpu.launch.launcher import apply_platform_env
-
-    apply_platform_env()
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--slices", type=int, default=4,

@@ -42,7 +42,6 @@ def main(argv=None) -> int:
 
     from adapcc_tpu.comm.engine import CollectiveEngine
     from adapcc_tpu.comm.mesh import build_world_mesh
-    from adapcc_tpu.compat import ring_kernels_supported
     from adapcc_tpu.strategy.ir import Strategy
     from adapcc_tpu.utils.observability import CollectiveTrace
 
@@ -57,11 +56,8 @@ def main(argv=None) -> int:
         jax.block_until_ready(engine.all_reduce(x))
         jax.block_until_ready(engine.all_gather(x))
         if world >= 2:
-            # the quantized ppermute ring runs on any backend; the fp32
-            # Pallas ring needs a TPU or the Mosaic interpreter
             jax.block_until_ready(engine.ring_allreduce(x, wire_dtype="int8"))
-            if ring_kernels_supported():
-                jax.block_until_ready(engine.ring_allreduce(x))
+            jax.block_until_ready(engine.ring_allreduce(x))
     d = os.path.dirname(out)
     if d:
         os.makedirs(d, exist_ok=True)

@@ -1,0 +1,211 @@
+"""The kernels of the main path, asked of the chip's own compiler.
+
+The TPU compiler is installed in the sandbox and compiles for a chip that is
+described, not attached (``on-chip-measurement`` guide §2, third rehearsal):
+every case here lowers a Pallas kernel with ``interpret=False`` at the shape
+``chip_smoke.py`` runs it, compiles it for a described ``v5e:2x2``, and
+asserts the Mosaic kernel is in the program.  What interpret mode cannot see
+— a rank-1 value Mosaic has no layout for (the fused int8 ring aborted the
+compiler process), a plan over the scoped-VMEM limit (the 32 MiB bf16 ring)
+— fails here, at no chip time.  A compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture and nowhere else:
+only one process may load the TPU library, so a call at import time would
+give the workers of a parallel run different tests to collect.  Every case
+compiles in this process (no child), and all of them live in this one file.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from adapcc_tpu.comm.mesh import RANKS_AXIS
+from adapcc_tpu.comm.pallas_ring import (
+    plan_ring_schedule,
+    ring_all_gather_shard,
+    ring_allreduce_shard,
+    ring_reduce_scatter_shard,
+)
+
+K, M = 1024, 1024 * 1024
+WORLD = 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # such a compile can be written to the persistent cache but never read
+    # back without a chip: keep the cache out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps the library from loading
+        jax.config.update("jax_enable_compilation_cache", True)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices[:WORLD]), (RANKS_AXIS,))
+
+
+def _kernels_in(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+def _compile_on_mesh(mesh, per_shard, shape, dtype):
+    """Compile ``per_shard`` (a ``[1, ...]`` shard → ``[1, ...]``) under
+    shard_map over the described 4-chip mesh."""
+    fn = jax.jit(jax.shard_map(
+        per_shard, mesh=mesh, in_specs=P(RANKS_AXIS), out_specs=P(RANKS_AXIS),
+        check_vma=False,
+    ))
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, P(RANKS_AXIS)))
+    return fn.lower(x).compile()
+
+
+# --- flash attention at the smoke's shape: B × 1,024 × 12 heads × 64 --------
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "fp32"])
+def test_flash_fwd_bwd_compiles_at_gpt2_small_shape(one_chip, dtype):
+    from adapcc_tpu.ops import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128, interpret=False
+        )
+        return jnp.sum(out.astype(jnp.float32))
+
+    x = jax.ShapeDtypeStruct((4, 1024, 12, 64), dtype, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
+    assert _kernels_in(compiled) == 3  # forward, dq, dk/dv
+
+
+# --- the ICI ring at world 4, both paths ------------------------------------
+
+_RING_CASES = [
+    # (id, elements per rank, payload dtype, fused wire codec, expected path)
+    ("fp32-vmem", 64 * K, jnp.float32, "off", "vmem"),
+    ("fp32-stream", 16 * M, jnp.float32, "off", "hbm-stream"),
+    ("bf16-vmem", 64 * K, jnp.bfloat16, "off", "vmem"),
+    # 32 MiB of bf16 per rank needs 17.83M of scoped VMEM against the 16M
+    # default: compiles only because the plan states its own limit
+    ("bf16-stream-32MiB", 16 * M, jnp.bfloat16, "off", "hbm-stream"),
+    ("fused-bf16-vmem", 64 * K, jnp.float32, "bf16", "vmem"),
+    ("fused-bf16-stream", 16 * M, jnp.float32, "bf16", "hbm-stream"),
+    # int8: a rank-1 scale vector anywhere in the kernel aborts the compiler
+    ("fused-int8-vmem", 64 * K, jnp.float32, "int8", "vmem"),
+    ("fused-int8-stream", 16 * M, jnp.float32, "int8", "hbm-stream"),
+]
+
+
+@pytest.mark.parametrize(
+    "nelems,dtype,wire,path",
+    [c[1:] for c in _RING_CASES], ids=[c[0] for c in _RING_CASES],
+)
+def test_ring_allreduce_compiles(mesh4, nelems, dtype, wire, path):
+    plan = plan_ring_schedule(nelems, dtype, WORLD, wire_dtype=wire)
+    assert plan.path == path
+
+    def per_shard(x):
+        return ring_allreduce_shard(
+            x[0], WORLD, RANKS_AXIS, interpret=False, wire_dtype=wire
+        )[None]
+
+    assert _kernels_in(_compile_on_mesh(mesh4, per_shard, (WORLD, nelems), dtype)) == 1
+
+
+@pytest.mark.parametrize("nelems", [64 * K, 16 * M], ids=["vmem", "stream"])
+def test_ring_reduce_scatter_compiles(mesh4, nelems):
+    def per_shard(x):
+        return ring_reduce_scatter_shard(x[0], WORLD, RANKS_AXIS, interpret=False)[None]
+
+    assert _kernels_in(_compile_on_mesh(mesh4, per_shard, (WORLD, nelems), jnp.float32)) == 1
+
+
+@pytest.mark.parametrize("nelems", [16 * K, 4 * M], ids=["vmem", "stream"])
+def test_ring_all_gather_compiles(mesh4, nelems):
+    def per_shard(x):
+        return ring_all_gather_shard(x[0], WORLD, RANKS_AXIS, interpret=False)[None]
+
+    assert _kernels_in(_compile_on_mesh(mesh4, per_shard, (WORLD, nelems), jnp.float32)) == 1
+
+
+# --- the composed programs the old on-chip smoke covered ---------------------
+
+
+def test_flash_ring_attention_block_compiles(mesh4, monkeypatch):
+    """Sequence-parallel ring attention with the flash block kernel: K/V
+    rotate over the ring, every hop runs the Pallas kernel."""
+    import sys
+
+    from adapcc_tpu.parallel.ring_attention import ring_attention_shard
+
+    # ring attention leaves the kernel/interpreter choice to kernel_mode,
+    # which sees this process's CPU backend: steer it here, in the test
+    monkeypatch.setattr(
+        sys.modules["adapcc_tpu.ops.flash_attention"], "resolve_interpret",
+        lambda interpret, site: False,
+    )
+
+    def per_shard(qkv):  # [1, 3, B, T_local, H, D]
+        q, k, v = qkv[0, 0], qkv[0, 1], qkv[0, 2]
+        return ring_attention_shard(
+            q, k, v, axis_name=RANKS_AXIS, causal=True, scale=0.125,
+            block_impl="flash", block_q=128, block_k=128,
+        )[None]
+
+    compiled = _compile_on_mesh(mesh4, per_shard, (WORLD, 3, 2, 256, 12, 64), jnp.bfloat16)
+    assert _kernels_in(compiled) >= 1
+
+
+def test_zero1_ring_step_compiles(mesh4):
+    """The ZeRO-1 step on the Pallas ring data plane: reduce-scatter the
+    gradients, shard-local adam, all-gather the params — two ring kernels."""
+    import optax
+
+    from adapcc_tpu.parallel.fsdp import Zero1Optimizer, zero1_train_step
+
+    params = {"w1": jnp.zeros((256, 1024)), "w2": jnp.zeros((1024, 256))}
+
+    def loss_fn(p, batch):
+        x, y = batch
+        return jnp.mean((jnp.tanh(x @ p["w1"]) @ p["w2"] - y) ** 2)
+
+    opt = Zero1Optimizer(optax.adam(1e-3), mesh4, ring=True, ring_interpret=False)
+    step = zero1_train_step(loss_fn, opt, mesh4)
+    replicated, sharded = NamedSharding(mesh4, P()), NamedSharding(mesh4, P(RANKS_AXIS))
+
+    def on(tree, sharding):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree
+        )
+
+    master, opt_state = jax.eval_shape(opt.init, params)
+    batch = (
+        jax.ShapeDtypeStruct((8 * WORLD, 256), jnp.float32, sharding=sharded),
+        jax.ShapeDtypeStruct((8 * WORLD, 256), jnp.float32, sharding=sharded),
+    )
+    compiled = jax.jit(step).lower(
+        on(params, replicated), on(master, sharded), on(opt_state, sharded), batch
+    ).compile()
+    assert _kernels_in(compiled) >= 2

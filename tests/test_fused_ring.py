@@ -8,8 +8,8 @@ within ``ring_error_bound`` of fp32.
 
 Coverage strategy mirrors tests/test_pallas_ring.py: the planner, support
 funnel, codec helpers, pricing, sweep, tuner-grid, and engine-reroute
-tests run on every build; the kernel executions are gated on
-``ring_kernels_supported()`` (a real TPU or the Mosaic interpret mode).
+tests need no kernel; the kernel executions run under the Mosaic TPU
+interpret mode.
 The always-on section additionally validates the fused *algorithm* —
 per-hop requantize, encode-once, scale forwarding — with a pure-numpy
 ring simulation pinned bit-for-bit against the unfused data plane, so a
@@ -30,19 +30,18 @@ from jax.sharding import PartitionSpec as P
 from adapcc_tpu.comm.mesh import RANKS_AXIS, build_world_mesh
 from adapcc_tpu.comm.pallas_ring import (
     FUSED_WIRE_ENV,
-    _fused_decode,
-    _fused_encode,
-    _fused_requantize,
+    _decode,
+    _derive_scales,
+    _encode,
+    _pack_scale_row,
     _scale_rows,
-    _scales_to_tile,
     _tile_elems,
-    _wire_scales_of,
+    _unpack_scale_col,
     fused_ring_dispatch_reason,
     fused_wire_unsupported_reason,
     plan_ring_schedule,
     resolve_fused_wire,
 )
-from adapcc_tpu.compat import ring_kernels_supported
 from adapcc_tpu.quant import (
     DEFAULT_BLOCK_SIZE,
     dequantize_int8,
@@ -53,13 +52,6 @@ from adapcc_tpu.quant import (
 )
 
 _TILE = _tile_elems(jnp.float32)  # 1024 elems: the fp32 (8, 128) tile
-
-kernels = pytest.mark.skipif(
-    not ring_kernels_supported(),
-    reason="ring kernels need a real TPU or the Mosaic TPU interpret mode "
-    "(jax >= 0.5); this build has neither",
-)
-
 
 @pytest.fixture(scope="module")
 def mesh4():
@@ -174,19 +166,43 @@ def test_fused_wire_env_gate(monkeypatch):
 
 def test_dispatch_reason_matches_build_support(monkeypatch):
     monkeypatch.delenv(FUSED_WIRE_ENV, raising=False)
-    reason = fused_ring_dispatch_reason("float32", "int8")
-    if ring_kernels_supported():
-        assert reason is None
-    else:
-        assert "interpret" in reason
+    assert fused_ring_dispatch_reason("float32", "int8") is None
 
 
 # --------------------------------------------------------------------------- #
 # in-kernel codec helpers: bitwise parity with quant/codec.py
 # --------------------------------------------------------------------------- #
 
-def _tile_of(flat: np.ndarray) -> jnp.ndarray:
-    return jnp.asarray(flat, jnp.float32).reshape(-1, 128)
+def _codec_roundtrip(flat: np.ndarray, wire_dtype: str, block: int = DEFAULT_BLOCK_SIZE):
+    """Drive the kernels' ref-level codec helpers on one tile under the
+    interpreter: ``(wire, scale tile, decoded, wire re-encoded from the
+    decoded values against the same scales)``."""
+    from jax.experimental import pallas as pl
+
+    int8 = wire_dtype == "int8"
+    width = block if int8 else 128
+    x = jnp.asarray(flat, jnp.float32).reshape(-1, width)
+    n_rows = x.shape[0]
+    wire_jnp = jnp.int8 if int8 else jnp.bfloat16
+
+    def kernel(x_ref, wire_ref, scale_ref, back_ref, again_ref):
+        if int8:
+            _derive_scales(x_ref, None, scale_ref, n_rows)
+        _encode(x_ref, None, scale_ref, wire_ref, n_rows, int8)
+        _decode(wire_ref, None, scale_ref, None, back_ref, None,
+                n_rows, int8, accumulate=False)
+        _encode(back_ref, None, scale_ref, again_ref, n_rows, int8)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=(
+            jax.ShapeDtypeStruct(x.shape, wire_jnp),
+            jax.ShapeDtypeStruct((_scale_rows(n_rows), 128), jnp.float32),
+            jax.ShapeDtypeStruct(x.shape, jnp.float32),
+            jax.ShapeDtypeStruct(x.shape, wire_jnp),
+        ),
+        interpret=True,
+    )(x)
 
 
 def test_fused_encode_matches_quantize_int8_bitwise():
@@ -195,15 +211,15 @@ def test_fused_encode_matches_quantize_int8_bitwise():
     registry codec."""
     rng = np.random.default_rng(0)
     flat = rng.normal(size=(4 * _TILE,)).astype(np.float32) * 37.0
-    rows_per_block = DEFAULT_BLOCK_SIZE // 128
-    q_tile, scales = _fused_encode(_tile_of(flat), "int8", rows_per_block)
+    q_tile, scale_tile, back, _ = _codec_roundtrip(flat, "int8")
     q_ref, s_ref = quantize_int8(jnp.asarray(flat), DEFAULT_BLOCK_SIZE)
     np.testing.assert_array_equal(
         np.asarray(q_tile).reshape(-1), np.asarray(q_ref).reshape(-1)
     )
-    np.testing.assert_array_equal(np.asarray(scales), np.asarray(s_ref))
+    np.testing.assert_array_equal(
+        np.asarray(scale_tile).reshape(-1)[: s_ref.shape[0]], np.asarray(s_ref)
+    )
     # decode parity too
-    back = _fused_decode(q_tile, scales, "int8", rows_per_block)
     ref = dequantize_int8(q_ref, s_ref)
     np.testing.assert_array_equal(
         np.asarray(back).reshape(-1), np.asarray(ref)
@@ -216,30 +232,31 @@ def test_fused_requantize_is_exact_on_decoded_values():
     the scales need the side channel."""
     rng = np.random.default_rng(1)
     flat = rng.normal(size=(16 * _TILE,)).astype(np.float32) * 1e3
-    rows_per_block = DEFAULT_BLOCK_SIZE // 128
-    q, scales = _fused_encode(_tile_of(flat), "int8", rows_per_block)
-    decoded = _fused_decode(q, scales, "int8", rows_per_block)
-    again = _fused_requantize(decoded, scales, rows_per_block)
+    q, _, _, again = _codec_roundtrip(flat, "int8")
     np.testing.assert_array_equal(np.asarray(again), np.asarray(q))
 
 
-def test_scale_tile_roundtrip():
-    scales = jnp.asarray(np.random.default_rng(2).uniform(0.1, 9, 13),
-                         jnp.float32)
-    s_rows = _scale_rows(13)
-    tile = _scales_to_tile(scales, s_rows)
-    assert tile.shape == (s_rows, 128)
+@pytest.mark.parametrize("n", [13, 128])
+def test_scale_tile_roundtrip(n):
+    """[n, 1] scale column → lane-dense side-channel row → column: the
+    sublane↔lane move Mosaic can lay out (no rank-1 value anywhere)."""
+    scales = jnp.asarray(
+        np.random.default_rng(2).uniform(0.1, 9, (n, 1)), jnp.float32
+    )
+    row = _pack_scale_row(scales)
+    assert row.shape == (1, 128)
+    np.testing.assert_array_equal(np.asarray(row)[0, :n], np.asarray(scales)[:, 0])
+    np.testing.assert_array_equal(np.asarray(row)[0, n:], 1.0)  # all-zero-block convention
     np.testing.assert_array_equal(
-        np.asarray(_wire_scales_of(tile, 13)), np.asarray(scales)
+        np.asarray(_unpack_scale_col(row, n)), np.asarray(scales)
     )
 
 
 def test_bf16_helpers_are_the_registry_cast():
-    x = _tile_of(np.random.default_rng(3).normal(size=(_TILE,)))
-    wire, scales = _fused_encode(x, "bf16", 1)
-    assert scales is None and wire.dtype == jnp.bfloat16
-    back = _fused_decode(wire, None, "bf16", 1)
-    ref = get_codec("bf16").apply(x)
+    flat = np.random.default_rng(3).normal(size=(_TILE,))
+    wire, _, back, _ = _codec_roundtrip(flat, "bf16")
+    assert wire.dtype == jnp.bfloat16
+    ref = get_codec("bf16").apply(jnp.asarray(flat, jnp.float32).reshape(-1, 128))
     np.testing.assert_array_equal(np.asarray(back), np.asarray(ref))
 
 
@@ -421,7 +438,6 @@ def test_fused_schedule_wire_value_is_the_codec_apply():
 # kernels under the interpreter (race detection on): fused vs unfused vs fp32
 # --------------------------------------------------------------------------- #
 
-@kernels
 @pytest.mark.parametrize("chunk_bytes", [1 << 30, 4096])  # vmem, hbm-stream
 def test_kernel_fused_int8_matches_unfused(mesh4, chunk_bytes):
     """Both paths, vs the unfused ppermute ring on a coinciding chunk
@@ -455,7 +471,6 @@ def test_kernel_fused_int8_matches_unfused(mesh4, chunk_bytes):
             np.testing.assert_array_equal(out[r], out[0])
 
 
-@kernels
 @pytest.mark.parametrize("wire", ["bf16", "int8"])
 def test_kernel_fused_within_ring_error_bound_of_fp32(mesh4, wire):
     from adapcc_tpu.comm.pallas_ring import ring_allreduce_shard
@@ -481,7 +496,6 @@ def test_kernel_fused_within_ring_error_bound_of_fp32(mesh4, wire):
         np.testing.assert_array_equal(got[r], got[0])
 
 
-@kernels
 def test_kernel_fused_bit_identical_across_chunk_sizes(mesh4):
     """Padded-tail regression: a 13-tile (prime) per-rank chunk forces the
     pad/slice path for non-dividing budgets; results stay bit-identical
@@ -508,7 +522,6 @@ def test_kernel_fused_bit_identical_across_chunk_sizes(mesh4):
         np.testing.assert_array_equal(ring(chunk_bytes), reference)
 
 
-@kernels
 def test_kernel_fused_reduce_scatter_and_all_gather(mesh4):
     from adapcc_tpu.comm.pallas_ring import (
         ring_all_gather_shard,
@@ -544,15 +557,16 @@ def test_kernel_fused_reduce_scatter_and_all_gather(mesh4):
         )[None]
 
     gathered = np.asarray(run_shard(ag, mesh4, chunk))
+    # the registry codec as the data plane runs it — compiled: under jit XLA
+    # folds the scale's ``/ 127`` into a multiply by the reciprocal, an ulp
+    # from the eager quotient on some blocks
+    roundtrip = jax.jit(lambda c: get_codec("int8").apply(c, DEFAULT_BLOCK_SIZE))
     for src in range(world):
-        want = np.asarray(
-            get_codec("int8").apply(chunk[src], DEFAULT_BLOCK_SIZE)
-        )
+        want = np.asarray(roundtrip(chunk[src]))
         for r in range(world):
             np.testing.assert_array_equal(gathered[r, src], want)
 
 
-@kernels
 def test_kernel_engine_fused_dispatch_and_trace(mesh4, monkeypatch):
     """Engine end to end on the fused plane: impl names the fused path,
     extras carry the executed wire dtype + shrunken wire bytes."""
@@ -805,7 +819,7 @@ def test_candidates_fused_cells_follow_data_plane_support(monkeypatch):
         c.wire_dtype != "off" and c.path in ("vmem", "hbm-stream")
         for c in cells
     )
-    assert has_fused == ring_kernels_supported()
+    assert has_fused
     # ADAPCC_FUSED_WIRE=off removes them everywhere: a cell must never
     # claim a path the dispatch would not run
     monkeypatch.setenv(FUSED_WIRE_ENV, "off")

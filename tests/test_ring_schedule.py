@@ -172,7 +172,7 @@ def test_engine_trace_records_executed_chunk(mesh8):
     eng = CollectiveEngine(mesh8, strategy, trace=trace)
     stacked = jnp.zeros((8, 64 * _TILE), jnp.float32)
     plan = eng._ring_plan(stacked, None, rs=True, ag=True)
-    eng._record_ring("allreduce", plan, stacked)
+    eng._record_ring("allreduce", plan, stacked, True)
     (ev,) = trace.events()
     assert ev.impl == "pallas_ring[hbm-stream]"
     assert ev.extra["chunk_bytes"] == _TILE_BYTES

@@ -725,18 +725,6 @@ def test_train_ddp_tune_flag_rejects_fsdp():
         main(["--dp-mode", "fsdp", "--tune", "--steps", "1"])
 
 
-def test_hw_session_battery_skips_tuner_convergence_at_world1(tmp_path):
-    from benchmarks.hw_session import run_multichip_phases
-
-    out = str(tmp_path / "hw.jsonl")
-    run_multichip_phases("python", out, world=1)
-    rows = [json.loads(l) for l in open(out)]
-    names = {r["phase"] for r in rows}
-    assert "tuner_convergence" in names
-    row = next(r for r in rows if r["phase"] == "tuner_convergence")
-    assert "skipped" in row and "world=1" in row["skipped"]
-
-
 def test_trainer_step_cell_stays_in_candidate_set_under_zero1_ring(
     mesh8, tmp_path, monkeypatch
 ):
