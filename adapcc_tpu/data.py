@@ -24,6 +24,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from adapcc_tpu.comm.mesh import RANKS_AXIS
+from adapcc_tpu.utils.observability import default_registry
 
 _END = object()
 
@@ -56,6 +57,7 @@ def prefetch_to_device(
         raise ValueError(f"prefetch size must be >= 1, got {size}")
     q: queue.Queue = queue.Queue(maxsize=size)
     stop = threading.Event()  # consumer gone: unblock + stop the producer
+    metrics = default_registry()
 
     def _put(item) -> bool:
         while not stop.is_set():
@@ -68,25 +70,35 @@ def prefetch_to_device(
 
     def produce() -> None:
         try:
-            for batch in it:
-                if stop.is_set():
-                    return
-                if sharding is not None:
-                    batch = jax.device_put(batch, sharding)
-                else:
-                    batch = jax.device_put(batch)
-                if not _put(batch):
+            while not stop.is_set():
+                # the layer's busy time per batch: next(it) materialises the
+                # host batch, device_put hands it to the device (the pass
+                # that finds the iterator exhausted is one more, empty, span)
+                with metrics.span("data.h2d") as live:
+                    batch = next(it, _END)
+                    if batch is not _END:
+                        if sharding is not None:
+                            batch = jax.device_put(batch, sharding)
+                        else:
+                            batch = jax.device_put(batch)
+                        if live:
+                            metrics.incr(
+                                "data.h2d_bytes",
+                                sum(x.nbytes for x in jax.tree_util.tree_leaves(batch)),
+                            )
+                if not _put(batch) or batch is _END:
                     return
         except BaseException as e:  # noqa: BLE001 — re-raised at the consumer
             _put(_PrefetchError(e))
-            return
-        _put(_END)
 
     t = threading.Thread(target=produce, daemon=True, name="adapcc-prefetch")
     t.start()
     try:
         while True:
-            item = q.get()
+            with metrics.span("data.pull") as live:
+                if live:
+                    metrics.sample("data.queue_depth", q.qsize())
+                item = q.get()
             if item is _END:
                 return
             if isinstance(item, _PrefetchError):

@@ -37,6 +37,7 @@ from adapcc_tpu.ddp.bucketing import (
 )
 from adapcc_tpu.primitives import ReduceOp
 from adapcc_tpu.strategy.ir import Strategy
+from adapcc_tpu.utils.observability import default_registry
 
 
 class GradSyncHook:
@@ -94,8 +95,11 @@ class GradSyncHook:
         :class:`~adapcc_tpu.utils.observability.MetricsRegistry`): the
         first traced sync records the bucket plan — count, byte histogram,
         oversized leaves, resolved chunk sizes, and the model-predicted
-        ``exposed_comm_s`` floor — into both.  When absent, an attached
-        communicator's engine trace / metrics registry are used.
+        ``exposed_comm_s`` floor — into both, and every traced sync, on
+        every path, sets the ``grad_sync.bytes`` / ``grad_sync.calls``
+        gauges.  When absent, an attached communicator's engine trace /
+        metrics registry are used, and failing that the process-wide
+        default registry (:attr:`metrics`).
         """
         from adapcc_tpu.ddp.overlap import resolve_overlap_mode
         from adapcc_tpu.quant import get_codec
@@ -121,6 +125,16 @@ class GradSyncHook:
         self._metrics = metrics
         self._plan: Optional[BucketPlan] = None
         self.recorded_buckets: List[tuple] = []  # (size, chunk_bytes) per bucket
+
+    @property
+    def metrics(self) -> Any:
+        """The registry this hook (and the trainer that owns it) records
+        into: the constructor's > the communicator's > the process-wide
+        default."""
+        if self._metrics is not None:
+            return self._metrics
+        attached = getattr(self.communicator, "metrics", None)
+        return attached if attached is not None else default_registry()
 
     def _resolved_mode(self) -> str:
         if self.mode != "auto":
@@ -255,27 +269,20 @@ class GradSyncHook:
         the resolved chunk sizes and the cost model's predicted
         ``exposed_comm_s`` floor for the active overlap schedule — into the
         dispatch trace."""
-        metrics = self._metrics
-        if metrics is None and self.communicator is not None:
-            metrics = getattr(self.communicator, "metrics", None)
+        metrics = self.metrics
         trace = self._trace
         if trace is None and self.communicator is not None:
             trace = getattr(
                 getattr(self.communicator, "engine", None), "trace", None
             )
-        if metrics is None and trace is None:
-            return
-        if metrics is not None:
-            metrics.gauge("bucket_plan.num_buckets", plan.num_buckets)
-            metrics.gauge("bucket_plan.total_bytes", plan.total_bytes)
-            if plan.oversized_leaves:
-                metrics.incr(
-                    "bucket_plan.oversized_leaves", plan.oversized_leaves
-                )
-            for b in plan.bucket_bytes:
-                # the byte histogram rides the timing reservoir: p50/p99
-                # of bucket sizes in the snapshot, O(1) memory
-                metrics.observe("bucket_plan.bucket_bytes", float(b))
+        metrics.gauge("bucket_plan.num_buckets", plan.num_buckets)
+        metrics.gauge("bucket_plan.total_bytes", plan.total_bytes)
+        if plan.oversized_leaves:
+            metrics.incr(
+                "bucket_plan.oversized_leaves", plan.oversized_leaves
+            )
+        for b in plan.bucket_bytes:
+            metrics.sample("bucket_plan.bucket_bytes", b)
         if trace is not None:
             from adapcc_tpu.sim.calibrate import load_or_default
             from adapcc_tpu.sim.cost_model import (
@@ -316,6 +323,15 @@ class GradSyncHook:
             self._record_plan(self._plan, data_plane)
         return self._plan
 
+    def _record_sync(self, nbytes: int, calls: int) -> None:
+        """What one sync hands to collectives (host side, at trace time):
+        bytes at the wire dtype's width, and the collective calls the hook
+        emits — one per leaf on the psum path, one per bucket on the
+        bucketed paths (``overlap="bucket"`` then cuts each into chunks)."""
+        metrics = self.metrics
+        metrics.gauge("grad_sync.bytes", nbytes)
+        metrics.gauge("grad_sync.calls", calls)
+
     def _sync_impl(self, grads: Any, active_mask: Optional[jnp.ndarray]) -> Any:
         import jax as _jax
         from jax import lax as _lax
@@ -333,6 +349,7 @@ class GradSyncHook:
             if data_plane != "psum" and mask is None:
                 mask = jnp.ones((self.strategy.world_size,), dtype=jnp.bool_)
             plan = self._bucket_plan(grads, data_plane)
+            self._record_sync(plan.total_bytes, plan.num_buckets)
             buckets = flatten_to_buckets(plan, grads)
             synced = rolling_bucket_sync(
                 buckets, plan.chunk_bytes, mask,
@@ -341,6 +358,10 @@ class GradSyncHook:
             )
             return unflatten_from_buckets(plan, synced)
         if data_plane == "psum":
+            leaves = _jax.tree_util.tree_leaves(grads)
+            self._record_sync(
+                sum(g.size * g.dtype.itemsize for g in leaves), len(leaves)
+            )
             if active_mask is None:
                 world = self.strategy.world_size
 
@@ -356,6 +377,7 @@ class GradSyncHook:
         if active_mask is None:
             active_mask = jnp.ones((self.strategy.world_size,), dtype=jnp.bool_)
         plan = self._bucket_plan(grads, data_plane)
+        self._record_sync(plan.total_bytes, plan.num_buckets)
         buckets = flatten_to_buckets(plan, grads)
         synced = [
             allreduce_shard(

@@ -451,6 +451,7 @@ class DDPTrainer:
             params=P(), opt_state=opt_spec, step=P(), model_state=P()
         )
 
+    @jax.named_scope("optimizer")  # names the update in the device trace
     def _apply_synced(
         self, state: TrainState, synced: Any, model_state: Any = None
     ) -> TrainState:
@@ -586,14 +587,20 @@ class DDPTrainer:
             loss, grads, new_ms = self._value_and_grad(
                 params, model_state, batch
             )
-            return loss, self.hook.sync(grads, mask), new_ms
+            return loss, self._sync(grads, mask), new_ms
         from adapcc_tpu.ddp.overlap import microbatch_pipelined_sync
 
         vg = jax.value_and_grad(self._loss3, has_aux=True)
         return microbatch_pipelined_sync(
             vg, params, model_state, self._to_microbatches(batch),
-            lambda g: self.hook.sync(g, mask), self.accum_steps,
+            lambda g: self._sync(g, mask), self.accum_steps,
         )
+
+    def _sync(self, grads: Any, mask) -> Any:
+        """The hook's sync under the ``grad_sync`` scope, so the device
+        trace names the operations it emits."""
+        with jax.named_scope("grad_sync"):
+            return self.hook.sync(grads, mask)
 
     def _static_full_step(self, state: TrainState, batch: Any):
         """The static full-world step (no mask, no relay banking): the body
@@ -613,7 +620,7 @@ class DDPTrainer:
 
         pipelined = self.overlap == "microbatch"
 
-        def per_shard(state: TrainState, batch: Any, *extra: Any):
+        def ddp_step(state: TrainState, batch: Any, *extra: Any):
             mask = extra[0] if dynamic_mask else None
             outs = []
             if not pipelined:
@@ -633,20 +640,24 @@ class DDPTrainer:
                 # deferred rides in/out with a sharded [world] leading dim;
                 # strip the per-shard [1] so it matches the grads tree
                 deferred = jax.tree_util.tree_map(lambda d: d[0], extra[-1])
-                synced, new_deferred = self.hook.sync_deferred(grads, deferred, mask)
+                with jax.named_scope("grad_sync"):
+                    synced, new_deferred = self.hook.sync_deferred(
+                        grads, deferred, mask
+                    )
                 outs.append(jax.tree_util.tree_map(lambda d: d[None], new_deferred))
             elif error_feedback:
                 # the residual bank rides like the deferred bank: per-rank,
                 # sharded [world] leading dim, replaced wholesale every step
                 residual = jax.tree_util.tree_map(lambda r: r[0], extra[-1])
-                synced, new_residual = self.hook.sync_error_feedback(
-                    grads, residual, mask
-                )
+                with jax.named_scope("grad_sync"):
+                    synced, new_residual = self.hook.sync_error_feedback(
+                        grads, residual, mask
+                    )
                 outs.append(
                     jax.tree_util.tree_map(lambda r: r[None], new_residual)
                 )
             else:
-                synced = self.hook.sync(grads, mask)
+                synced = self._sync(grads, mask)
             new_state = self._apply_synced(state, synced, new_ms)
             if self.measure_gns:
                 from adapcc_tpu.measure.gns import ddp_grad_sq_norms
@@ -668,7 +679,7 @@ class DDPTrainer:
             + ((P(self.axis_name),) if banked else ())
         )
         fn = jax.shard_map(
-            per_shard,
+            ddp_step,
             mesh=self.mesh,
             in_specs=in_specs,
             out_specs=out_specs,
@@ -696,10 +707,44 @@ class DDPTrainer:
         ``active_mask`` overrides the coordinator's negotiation (workloads
         injecting their own skew signal; requires a dynamic-mask trainer).
         """
+        # the host step index: what the three spans of one step share.
+        # Host-side counter: reading state.step would force a device sync
+        # on every dispatch, serializing the loop
+        idx = self._host_step if step_idx is None else step_idx
+        span = self.hook.metrics.span
+        # three consecutive spans tile the call; no parent span (their sum
+        # is the parent): live only while a profile is being taken
+        # (docs/OBSERVABILITY.md)
+        with span("step.prepare", step=idx):
+            fn, args, active_mask = self._prepare_step(
+                state, batch, idx, active_mask
+            )
+        tuning = self._tuning()
+        with span("step.enqueue", step=idx):
+            # pjit dispatch, and the runtime's wait for output buffers
+            if tuning:
+                import time as _time
+
+                t0 = _time.perf_counter()
+                out = fn(*args)
+                jax.block_until_ready(out)
+                seconds = _time.perf_counter() - t0
+            else:
+                out = fn(*args)
+        with span("step.finish", step=idx):
+            if tuning:
+                self._tune_observe(state, seconds)
+            return self._finish_step(out, batch, active_mask)
+
+    def _prepare_step(
+        self, state: TrainState, batch: Any, idx: int, active_mask
+    ) -> Tuple[Callable, list, Optional[jnp.ndarray]]:
+        """Everything ``step`` does before the compiled call: the program,
+        its arguments, and the step's active mask."""
         self._check_state(state)
         # local binding: an out-of-band supervisor's adopt_strategy may
         # null self._compiled between this resolution and the dispatch
-        # below; the step then finishes on the outgoing program (exactly
+        # in step(); the step then finishes on the outgoing program (exactly
         # like a collective already in flight when an epoch bumps) and the
         # NEXT step picks up the swapped one
         fn = self._compiled
@@ -728,9 +773,6 @@ class DDPTrainer:
                 self._coord_calibrated = comm.calibrate_coordinator(
                     float(grad_bytes)
                 )
-        # host-side counter: reading state.step would force a device sync on
-        # every dispatch, serializing the loop
-        idx = self._host_step if step_idx is None else step_idx
         self._host_step = idx + 1
         if active_mask is not None and not self._dynamic_mask:
             raise ValueError(
@@ -766,16 +808,12 @@ class DDPTrainer:
                     state.params,
                 )
             args.append(self._residual)
-        tuning = self._tuning()
-        if tuning:
-            import time as _time
+        return fn, args, active_mask
 
-            t0 = _time.perf_counter()
-            out = fn(*args)
-            jax.block_until_ready(out)
-            self._tune_observe(state, _time.perf_counter() - t0)
-        else:
-            out = fn(*args)
+    def _finish_step(
+        self, out, batch: Any, active_mask
+    ) -> Tuple[TrainState, jnp.ndarray]:
+        """Everything ``step`` does after the compiled call returns."""
         if not self.bsp:
             *out, self._deferred = out
         elif self.error_feedback:

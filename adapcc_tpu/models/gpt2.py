@@ -277,17 +277,19 @@ class GPT2(nn.Module):
         if return_hidden:
             return x
         # weight-tied LM head
-        logits = x.astype(cfg.dtype) @ wte.embedding.T.astype(cfg.dtype)
-        return logits.astype(jnp.float32)
+        with jax.named_scope("lm_head"):
+            logits = x.astype(cfg.dtype) @ wte.embedding.T.astype(cfg.dtype)
+            return logits.astype(jnp.float32)
 
 
 def lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
     """Next-token cross-entropy over a ``[B, T]`` batch."""
-    logits = logits[:, :-1]
-    targets = tokens[:, 1:]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    with jax.named_scope("loss"):
+        logits = logits[:, :-1]
+        targets = tokens[:, 1:]
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return -jnp.mean(ll)
 
 
 def lm_loss_chunked(

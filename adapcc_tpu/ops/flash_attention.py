@@ -208,6 +208,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((BH, T, _LSE_LANES), jnp.float32),
         ],
         interpret=resolve_interpret(interpret, "flash_attention"),
+        name="flash_fwd",
     )(q, k, v)
     lse = lse3[:, :, 0]
     return out, (q, k, v, out, lse)
@@ -248,6 +249,7 @@ def _flash_bwd_core(scale, causal, block_q, block_k, interpret, res, do, dlse):
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         interpret=interp,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse3, delta3)
 
     dk, dv = pl.pallas_call(
@@ -272,6 +274,7 @@ def _flash_bwd_core(scale, causal, block_q, block_k, interpret, res, do, dlse):
             jax.ShapeDtypeStruct((BH, T, D), v.dtype),
         ],
         interpret=interp,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse3, delta3)
     return dq, dk, dv
 

@@ -294,11 +294,6 @@ def error_feedback_step(
 # host-side codec timing (observability satellite)
 # --------------------------------------------------------------------------- #
 
-#: process-wide default registry for codec timings, created on first use;
-#: ``MetricsRegistry.snapshot()`` exposes p50/p99 over its bounded reservoir
-CODEC_METRICS = None
-
-
 def timed_roundtrip(
     name: str,
     x: jnp.ndarray,
@@ -307,17 +302,15 @@ def timed_roundtrip(
 ) -> jnp.ndarray:
     """Eagerly encode+decode ``x`` through codec ``name``, recording wall
     times as ``quant.<name>.quantize`` / ``quant.<name>.dequantize`` in the
-    metrics registry (module default when none given).  Host-side only —
+    metrics registry (the process-wide default when none given; its
+    ``snapshot()`` exposes p50/p99 over a bounded reservoir).  Host-side only —
     inside a jitted program the codec is fused and has no separable time;
     this is the microbenchmark surface ``make quant-bench`` and the docs
     snippets use."""
-    global CODEC_METRICS
     if registry is None:
-        if CODEC_METRICS is None:
-            from adapcc_tpu.utils.observability import MetricsRegistry
+        from adapcc_tpu.utils.observability import default_registry
 
-            CODEC_METRICS = MetricsRegistry()
-        registry = CODEC_METRICS
+        registry = default_registry()
     codec = get_codec(name)
     flat = jnp.asarray(x).reshape(-1).astype(jnp.float32)
     with registry.timer(f"quant.{name}.quantize"):

@@ -5,8 +5,8 @@ from adapcc_tpu.utils.observability import (
     CollectiveTrace,
     MetricsRegistry,
     ProgressMeter,
+    default_registry,
     parse_track_log,
-    parse_training_log,
     profiler_trace,
 )
 
@@ -15,7 +15,7 @@ __all__ = [
     "CollectiveTrace",
     "MetricsRegistry",
     "ProgressMeter",
+    "default_registry",
     "parse_track_log",
-    "parse_training_log",
     "profiler_trace",
 ]

@@ -6,10 +6,12 @@ relay decisions and per-element progress printed from the native layer
 log/track.txt, AverageMeter/ProgressMeter training meters
 (accuracy_benchmark.py:470-539), and ad-hoc log-scraping post-processors
 (process_log.py, process_gns.py).  This module provides the structured
-versions: the same meters, a metrics registry with JSON export, a collective
-trace that records engine dispatches (the track.txt analog), a
-``jax.profiler`` context for Perfetto traces, and parsers for both trace and
-training logs.
+versions: the same meters, a metrics registry with JSON export (one
+process-wide default, :func:`default_registry`) whose spans lie on the JAX
+profiler's clock and are on exactly while a profile is being taken, a
+collective trace that records engine dispatches (the track.txt analog), a
+``jax.profiler`` context for Perfetto traces, and a parser for the trace's
+dump.  docs/OBSERVABILITY.md lists every span, counter, gauge and sample.
 """
 
 from __future__ import annotations
@@ -17,12 +19,16 @@ from __future__ import annotations
 import contextlib
 import json
 import random
-import re
 import threading
 import time
 from collections import defaultdict, deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
+
+#: every program span's name in a profile starts with this
+SPAN_PREFIX = "adapcc."
 
 
 # --- training meters (accuracy_benchmark.py:470-539) --------------------------
@@ -84,18 +90,61 @@ def nearest_rank_percentile(sorted_samples: Sequence[float], q: float) -> float:
     return sorted_samples[min(rank, len(sorted_samples) - 1)]
 
 
-class MetricsRegistry:
-    """Named counters/gauges/timers with JSON export; thread-safe.
+class _Series:
+    """Running count/total/max of one named series, exactly, plus the
+    bounded reservoir its percentiles are read from."""
 
-    Timings keep running count/total/max exactly, plus a **bounded
-    reservoir** of samples (Vitter's algorithm R, deterministic seed) so
-    :meth:`snapshot` can report p50/p99 with O(1) memory per timing — a
-    long-running trainer recording per-step codec timings must not grow a
-    list without bound, and tail latency (the p99 a straggler policy keys
-    on) is invisible to count/mean/max alone.
+    __slots__ = ("count", "total", "max", "reservoir", "in_session")
+
+    def __init__(self) -> None:
+        self.count = 0
+        self.total = 0.0
+        self.max = float("-inf")
+        self.reservoir: List[float] = []
+        #: recorded under a profiler session, and dropped when the next opens
+        self.in_session = False
+
+    def add(self, v: float, size: int, rng: random.Random) -> None:
+        self.count += 1
+        self.total += v
+        self.max = max(self.max, v)
+        if len(self.reservoir) < size:
+            self.reservoir.append(v)
+        else:
+            j = rng.randrange(self.count)
+            if j < size:
+                self.reservoir[j] = v
+
+    def summary(self, suffix: str = "") -> Dict[str, Any]:
+        res = sorted(self.reservoir)
+        out = {
+            "count": self.count,
+            "mean" + suffix: self.total / self.count,
+            "max" + suffix: self.max,
+            "p50" + suffix: nearest_rank_percentile(res, 0.50),
+            "p99" + suffix: nearest_rank_percentile(res, 0.99),
+        }
+        if suffix:
+            out["total" + suffix] = self.total
+        return out
+
+
+class MetricsRegistry:
+    """Named counters/gauges/timings/samples with JSON export; thread-safe.
+
+    Timings (seconds) and samples (unitless: queue depths, byte sizes) keep
+    running count/total/max exactly, plus a **bounded reservoir** (Vitter's
+    algorithm R, deterministic seed) so :meth:`snapshot` can report p50/p99
+    with O(1) memory per series — a long-running trainer recording per-step
+    timings must not grow a list without bound, and tail latency (the p99 a
+    straggler policy keys on) is invisible to count/mean/max alone.
+
+    :meth:`span` is the tracer: on exactly while a JAX profiler session is
+    live, so an operator turns the spans on by taking a profile and by
+    nothing else (docs/OBSERVABILITY.md).
     """
 
-    #: samples retained per timing for the percentile estimate; above this
+    #: samples retained per series for the percentile estimate; above this
     #: count, reservoir sampling keeps a uniform subset
     RESERVOIR_SIZE = 512
 
@@ -103,10 +152,17 @@ class MetricsRegistry:
         self._lock = threading.Lock()
         self._counters: Dict[str, float] = defaultdict(float)
         self._gauges: Dict[str, float] = {}
-        self._timings: Dict[str, Dict[str, Any]] = {}
+        self._timings: Dict[str, _Series] = {}
+        self._samples: Dict[str, _Series] = {}
         # deterministic reservoir replacement: two identical runs snapshot
         # identical percentiles (the sim-bench byte-stability policy)
         self._rng = random.Random(0x5EED)
+        # a profiler session is told from the next by an off check in
+        # between: ``_closed`` is the generation an off check last saw (read
+        # BEFORE the check, so a thread caught between its check and its
+        # store cannot close a session that opened meanwhile)
+        self._gen = 0
+        self._closed = 0
 
     def incr(self, name: str, by: float = 1.0) -> None:
         with self._lock:
@@ -124,54 +180,88 @@ class MetricsRegistry:
         finally:
             self.observe(name, time.perf_counter() - t0)
 
+    def _add(
+        self, table: Dict[str, _Series], name: str, value: float, in_session: bool
+    ) -> None:
+        with self._lock:
+            series = table.get(name)
+            if series is None:
+                series = table[name] = _Series()
+            series.in_session |= in_session
+            series.add(float(value), self.RESERVOIR_SIZE, self._rng)
+
     def observe(self, name: str, seconds: float) -> None:
         """Record an externally measured duration into the ``name`` timing."""
-        s = float(seconds)
-        with self._lock:
-            t = self._timings.get(name)
-            if t is None:
-                t = self._timings[name] = {
-                    "count": 0, "total": 0.0, "max": s, "reservoir": [],
-                }
-            t["count"] += 1
-            t["total"] += s
-            t["max"] = max(t["max"], s)
-            res = t["reservoir"]
-            if len(res) < self.RESERVOIR_SIZE:
-                res.append(s)
-            else:
-                j = self._rng.randrange(t["count"])
-                if j < self.RESERVOIR_SIZE:
-                    res[j] = s
+        self._add(self._timings, name, seconds, False)
 
-    @staticmethod
-    def _percentile(sorted_samples: List[float], q: float) -> float:
-        """Nearest-rank percentile over the (sorted) reservoir."""
-        return nearest_rank_percentile(sorted_samples, q)
+    def sample(self, name: str, value: float) -> None:
+        """Record one value of the unitless distribution ``name`` (a queue
+        depth, a byte size).  Taken under a profiler session it belongs to
+        that session, like a span."""
+        self._add(self._samples, name, value, self._live())
+
+    def _live(self) -> bool:
+        """Whether a profiler session is live; its first yes after a no
+        opens the session (drops what the one before it recorded)."""
+        gen = self._gen
+        if not TraceAnnotation.is_enabled():
+            self._closed = gen
+            return False
+        if self._closed == self._gen:
+            with self._lock:
+                if self._closed == self._gen:  # else another thread opened it
+                    for table in (self._timings, self._samples):
+                        for name in [n for n, s in table.items() if s.in_session]:
+                            del table[name]
+                    self._gen += 1
+        return True
+
+    @contextlib.contextmanager
+    def span(self, name: str, **meta: Any) -> Iterator[bool]:
+        """Time a block on the profiler's clock: a ``TraceAnnotation`` named
+        ``adapcc.<name>`` carrying ``meta`` (``step=<n>`` is what the spans
+        of one step share), its duration recorded under ``name`` in the
+        timings.  Yields whether it is on.
+
+        On exactly while a JAX profiler session is live
+        (:func:`profiler_trace`, ``jax.profiler.start_trace``, or a capture
+        through ``jax.profiler.start_server``).  Off, it checks that and
+        yields: no clock read, no annotation, no lock.  The first span of a
+        session drops what the session before it recorded (counters, gauges
+        and what was recorded outside any session persist), so a reader
+        after a profile sees that profile's spans and only those.
+        """
+        if not self._live():
+            yield False
+            return
+        t0 = time.perf_counter()
+        try:
+            with TraceAnnotation(SPAN_PREFIX + name, **meta):
+                yield True
+        finally:
+            self._add(self._timings, name, time.perf_counter() - t0, True)
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
-            timings = {}
-            for k, t in self._timings.items():
-                if not t["count"]:
-                    continue
-                res = sorted(t["reservoir"])
-                timings[k] = {
-                    "count": t["count"],
-                    "total_s": t["total"],
-                    "mean_s": t["total"] / t["count"],
-                    "max_s": t["max"],
-                    "p50_s": self._percentile(res, 0.50),
-                    "p99_s": self._percentile(res, 0.99),
-                }
             return {
                 "counters": dict(self._counters),
                 "gauges": dict(self._gauges),
-                "timings": timings,
+                "timings": {k: t.summary("_s") for k, t in self._timings.items()},
+                "samples": {k: t.summary() for k, t in self._samples.items()},
             }
 
     def to_json(self) -> str:
         return json.dumps(self.snapshot(), sort_keys=True)
+
+
+_DEFAULT_REGISTRY = MetricsRegistry()
+
+
+def default_registry() -> MetricsRegistry:
+    """THE process-wide registry: what ``DDPTrainer``, ``GradSyncHook``,
+    the input pipeline and the codec timings record into when handed no
+    registry of their own."""
+    return _DEFAULT_REGISTRY
 
 
 # --- collective dispatch trace (log/track.txt analog) -------------------------
@@ -392,7 +482,12 @@ def parse_track_log(path: str) -> List[TraceEvent]:
 def profiler_trace(log_dir: str) -> Iterator[None]:
     """Capture a ``jax.profiler`` trace (XLA ops, transfers, host activity)
     into ``log_dir`` — the TPU answer to the reference's nsys reports
-    (nccl-perf/tree/report_allreduce.txt, SURVEY.md §5.1)."""
+    (nccl-perf/tree/report_allreduce.txt, SURVEY.md §5.1).
+
+    This is also how an operator turns the program's spans on: inside the
+    context every :meth:`MetricsRegistry.span` is live, lands in the trace
+    as ``adapcc.<name>`` on the device's clock, and is summarised in the
+    registry's timings afterwards (docs/OBSERVABILITY.md)."""
     import jax
 
     jax.profiler.start_trace(log_dir)
@@ -400,30 +495,3 @@ def profiler_trace(log_dir: str) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-# --- training-log post-processors (process_log.py/process_gns.py) -------------
-
-_FLOAT = r"([-+]?\d+(?:\.\d+)?(?:[eE][-+]?\d+)?)"
-
-
-def parse_training_log(
-    path: str, key: str = "loss", pattern: Optional[str] = None
-) -> List[Tuple[int, float]]:
-    """Scrape ``(step, value)`` pairs out of a free-form training log.
-
-    Default pattern matches ``... step <N> ... <key> <float>`` or
-    ``<key>: <float>`` lines (the shapes the reference's process_log.py and
-    process_gns.py scrape); pass ``pattern`` with two groups (step, value)
-    for custom formats.
-    """
-    if pattern is None:
-        pattern = rf"step\s*[:=]?\s*(\d+).*?{re.escape(key)}\s*[:=]?\s*{_FLOAT}"
-    rx = re.compile(pattern)
-    out: List[Tuple[int, float]] = []
-    with open(path) as f:
-        for line in f:
-            m = rx.search(line)
-            if m:
-                out.append((int(m.group(1)), float(m.group(2))))
-    return out

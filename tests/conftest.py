@@ -99,3 +99,51 @@ def mesh2():
     from jax.sharding import Mesh
 
     return Mesh(jax.devices()[:2], ("ranks",))
+
+
+class _Profile:
+    """One JAX profiler session (Python frames off: they only slow the host)
+    as a context manager; afterwards ``spans()`` gives the program's
+    ``adapcc.*`` host events as ``(name, start_ns, duration_ns, stats)``."""
+
+    def __init__(self, log_dir) -> None:
+        self.log_dir = str(log_dir)
+
+    def __enter__(self):
+        import jax
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(self.log_dir, profiler_options=options)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        import jax
+
+        jax.profiler.stop_trace()
+
+    def spans(self):
+        import glob
+        import warnings
+
+        from jax.profiler import ProfileData
+
+        (path,) = glob.glob(self.log_dir + "/plugins/profile/*/*.xplane.pb")
+        with warnings.catch_warnings():
+            # iterating an event's stats warns about the binding's own type
+            warnings.simplefilter("ignore", DeprecationWarning)
+            return sorted(
+                (e.name, int(e.start_ns), int(e.duration_ns), dict(e.stats))
+                for plane in ProfileData.from_file(path).planes
+                if plane.name == "/host:CPU"
+                for line in plane.lines
+                for e in line.events
+                if e.name.startswith("adapcc.")
+            )
+
+
+@pytest.fixture
+def profile(tmp_path):
+    """``with profile("a") as p: ...`` runs the block under a profiler
+    session of its own; the program's spans are live inside it."""
+    return lambda name="prof": _Profile(tmp_path / name)

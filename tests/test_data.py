@@ -112,3 +112,48 @@ def test_prefetch_abandoned_consumer_stops_producer():
     assert not any(
         t.name == "adapcc-prefetch" and t.is_alive() for t in threading.enumerate()
     )
+
+
+# --------------------------------------------------------------------------- #
+# the feed's spans (docs/OBSERVABILITY.md): on under a profile, nothing off
+# --------------------------------------------------------------------------- #
+
+
+def _feed_series(snapshot):
+    return {
+        kind: {k: v for k, v in snapshot[kind].items() if k.startswith("data.")}
+        for kind in ("timings", "samples", "counters")
+    }
+
+
+def test_feed_without_a_profile_records_nothing(mesh8):
+    from adapcc_tpu.utils import default_registry
+
+    before = _feed_series(default_registry().snapshot())
+    assert len(list(device_batches(np.zeros((64, 4), np.float32), 8, mesh=mesh8))) == 8
+    assert _feed_series(default_registry().snapshot()) == before
+
+
+@pytest.mark.parametrize("prefetch", [1, 3])
+def test_profiled_feed_counts_pulls_and_transfers(mesh8, profile, prefetch):
+    from adapcc_tpu.utils import default_registry
+
+    reg = default_registry()
+    packed = np.zeros((64, 4), np.float32)
+    n, batch_bytes = 8, 8 * 4 * 4
+    # an epoch outside any profile first: it records nothing, and its off
+    # checks are what tells this test's session from the one before it
+    assert sum(1 for _ in device_batches(packed, 8, mesh=mesh8, prefetch=prefetch)) == n
+    sent = reg.snapshot()["counters"].get("data.h2d_bytes", 0.0)
+    with profile() as prof:
+        pulled = sum(1 for _ in device_batches(packed, 8, mesh=mesh8, prefetch=prefetch))
+    assert pulled == n
+    snap = reg.snapshot()
+    # one pull and one producer pass per batch, plus the one that finds the end
+    assert snap["timings"]["data.pull"]["count"] == n + 1
+    assert snap["timings"]["data.h2d"]["count"] == n + 1
+    assert snap["counters"]["data.h2d_bytes"] - sent == n * batch_bytes
+    depth = snap["samples"]["data.queue_depth"]
+    assert depth["count"] == n + 1 and 0 <= depth["mean"] <= depth["max"] <= prefetch
+    names = [name for name, *_ in prof.spans()]
+    assert names.count("adapcc.data.pull") == n + 1 and names.count("adapcc.data.h2d") == n + 1

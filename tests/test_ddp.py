@@ -806,3 +806,120 @@ def test_zero1_ring_requires_zero1():
             jax.sharding.Mesh(np.array(jax.devices()[:8]), (RANKS_AXIS,)),
             Strategy.ring(8), zero1_ring=True,
         )
+
+
+# --------------------------------------------------------------------------- #
+# the step's spans and the sync's gauges (docs/OBSERVABILITY.md)
+# --------------------------------------------------------------------------- #
+
+STEP_SPANS = ("step.prepare", "step.enqueue", "step.finish")
+
+
+def _tiny_trainer(mesh, **kw):
+    tx = optax.sgd(0.1)
+    trainer = DDPTrainer(
+        lambda p, b: jnp.mean((b @ p["w"]) ** 2), tx, mesh, Strategy.ring(mesh.devices.size), **kw
+    )
+    state = TrainState.create({"w": jnp.ones((4, 2), jnp.float32)}, tx)
+    batch = jnp.ones((2 * mesh.devices.size, 4), jnp.float32)
+    return trainer, state, batch
+
+
+def _step_timings(trainer):
+    t = trainer.hook.metrics.snapshot()["timings"]
+    return {k: t[k]["count"] for k in STEP_SPANS if k in t}
+
+
+def test_step_without_a_profile_records_no_span(mesh4):
+    from adapcc_tpu.utils import default_registry
+
+    trainer, state, batch = _tiny_trainer(mesh4)
+    assert trainer.hook.metrics is default_registry()
+    before = default_registry().snapshot()["timings"]
+    for _ in range(3):
+        state, _ = trainer.step(state, batch)
+    assert default_registry().snapshot()["timings"] == before
+
+
+def test_profiled_steps_tile_the_call_and_carry_the_step_index(mesh4, profile):
+    import time
+
+    trainer, state, batch = _tiny_trainer(mesh4)
+    state, _ = trainer.step(state, batch)  # compile outside the profile
+    n, host = 5, {}
+    with profile() as prof:
+        for _ in range(n):
+            idx = trainer._host_step
+            t0 = time.perf_counter()
+            state, loss = trainer.step(state, batch)
+            host[idx] = time.perf_counter() - t0
+    assert _step_timings(trainer) == {k: n for k in STEP_SPANS}
+    by_step = {}
+    for name, start, dur, stats in prof.spans():
+        if name.startswith("adapcc.step."):
+            by_step.setdefault(stats["step"], []).append((name[len("adapcc."):], start, dur))
+    assert sorted(by_step) == sorted(host) == list(range(1, n + 1))
+    for idx, spans in by_step.items():
+        spans.sort(key=lambda s: s[1])
+        assert tuple(s[0] for s in spans) == STEP_SPANS  # consecutive, in order
+        for (_, a, da), (_, b, _) in zip(spans, spans[1:]):
+            assert a + da <= b  # flat: no span inside another
+        assert sum(s[2] for s in spans) / 1e9 <= host[idx]
+    # a second session in the same process starts from zero
+    state, _ = trainer.step(state, batch)
+    with profile("again"):
+        for _ in range(2):
+            state, _ = trainer.step(state, batch)
+    assert _step_timings(trainer) == {k: 2 for k in STEP_SPANS}
+
+
+def _sync_gauges(mesh, grads, **hook_kw):
+    from adapcc_tpu.utils import MetricsRegistry
+
+    metrics = MetricsRegistry()
+    world = mesh.devices.size
+    hook = GradSyncHook(Strategy.ring(world), metrics=metrics, **hook_kw)
+    stacked = jax.tree_util.tree_map(lambda g: jnp.stack([g] * world), grads)
+    jax.jit(jax.shard_map(
+        lambda t: hook.sync(jax.tree_util.tree_map(lambda v: v[0], t), None),
+        mesh=mesh, in_specs=(P(RANKS_AXIS),), out_specs=P(), check_vma=False,
+    )).lower(stacked)  # the gauges are set at trace time
+    g = metrics.snapshot()["gauges"]
+    return hook, g["grad_sync.bytes"], g["grad_sync.calls"]
+
+
+@pytest.mark.parametrize("overlap", ["off", "bucket"])
+@pytest.mark.parametrize("use_xla_fastpath", [True, False])
+def test_grad_sync_gauges_on_every_path(mesh4, overlap, use_xla_fastpath, monkeypatch):
+    monkeypatch.delenv("ADAPCC_OVERLAP", raising=False)
+    monkeypatch.delenv("ADAPCC_WIRE_DTYPE", raising=False)
+    grads = {"w": jnp.ones((96, 32), jnp.float32), "b": jnp.ones((32,), jnp.float32),
+             "e": jnp.ones((64, 8), jnp.float32)}
+    param_bytes = sum(g.nbytes for g in grads.values())
+    kw = dict(overlap=overlap, use_xla_fastpath=use_xla_fastpath, bucket_cap_mb=0.004)
+    hook, nbytes, calls = _sync_gauges(mesh4, grads, **kw)
+    assert nbytes == param_bytes
+    if overlap == "off" and use_xla_fastpath:
+        assert hook._plan is None and calls == len(grads)  # one psum per leaf
+    else:
+        assert nbytes == hook._plan.total_bytes and calls == hook._plan.num_buckets > 1
+    # the bf16 wire halves the bytes and leaves the calls
+    _, wire_bytes, wire_calls = _sync_gauges(mesh4, grads, compress="bf16", **kw)
+    assert (wire_bytes, wire_calls) == (param_bytes / 2, calls)
+
+
+def test_the_compiled_step_carries_the_names_the_device_trace_is_read_by(mesh4):
+    """docs/OBSERVABILITY.md, names on the device side: the module, the
+    four scopes and, through the flash path, the three kernels."""
+    from adapcc_tpu.models.gpt2 import GPT2, GPT2Config, lm_loss
+
+    model = GPT2(GPT2Config(vocab_size=64, max_seq=128, n_layer=1, n_head=1, d_model=32, attention="flash"))
+    tokens = jnp.zeros((4, 128), jnp.int32)
+    tx = optax.sgd(0.1)
+    trainer = DDPTrainer(lambda p, b: lm_loss(model.apply(p, b), b), tx, mesh4, Strategy.ring(4))
+    state = TrainState.create(model.init(jax.random.PRNGKey(0), tokens[:1]), tx)
+    text = trainer._build().lower(state, tokens).as_text(debug_info=True)
+    assert "module @jit_ddp_step" in text
+    for name in ('"grad_sync/psum"', '"optimizer/', "jvp(GPT2)/lm_head/", "jvp(loss)/", "transpose(jvp(loss))/",
+                 "/flash_fwd/pallas_call", "/flash_bwd_dq/pallas_call", "/flash_bwd_dkv/pallas_call"):
+        assert name in text, name

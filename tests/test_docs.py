@@ -4,8 +4,8 @@ Every ```python block in docs/PARALLELISM.md, docs/OPERATIONS.md,
 docs/SIMULATION.md, docs/RING.md, docs/QUANT.md, docs/TUNER.md,
 docs/OVERLAP.md, docs/LATENCY.md, docs/ELASTIC.md, docs/ADAPT.md,
 docs/SUPERVISOR.md, docs/HIERARCHY.md, docs/FABRIC.md, docs/RECOVERY.md,
-docs/SERVING.md, docs/COMPILER.md and docs/PIPELINE.md runs verbatim on
-the virtual pod.
+docs/SERVING.md, docs/COMPILER.md, docs/PIPELINE.md and
+docs/OBSERVABILITY.md runs verbatim on the virtual pod.
 A snippet that stops compiling or produces wrong shapes fails here.
 """
 
@@ -34,6 +34,7 @@ _RECOVERY = os.path.join(_DOCS_DIR, "RECOVERY.md")
 _SERVING = os.path.join(_DOCS_DIR, "SERVING.md")
 _COMPILER = os.path.join(_DOCS_DIR, "COMPILER.md")
 _PIPELINE = os.path.join(_DOCS_DIR, "PIPELINE.md")
+_OBSERVABILITY = os.path.join(_DOCS_DIR, "OBSERVABILITY.md")
 
 
 def _blocks(path):
@@ -440,3 +441,28 @@ def test_pipeline_doc_covers_the_contract():
 def test_pipeline_doc_snippet_runs(idx):
     code = _blocks(_PIPELINE)[idx]
     exec(compile(code, f"{_PIPELINE}:block{idx}", "exec"), {})
+
+
+def test_observability_doc_names_everything_the_program_records():
+    """One page, linked from the operations guide, that lists every span,
+    counter, gauge and sample the training path records and every name it
+    puts on the device side."""
+    text = open(_OBSERVABILITY).read()
+    assert "OBSERVABILITY.md" in open(_OPERATIONS).read()
+    for needle in (
+        "default_registry", "profiler_trace", "is_enabled", "start_server",
+        "step.prepare", "step.enqueue", "step.finish", "data.pull",
+        "data.queue_depth", "data.h2d", "data.h2d_bytes", "grad_sync.bytes",
+        "grad_sync.calls", "bucket_plan.bucket_bytes", "jit_ddp_step",
+        "grad_sync", "optimizer", "lm_head", "loss", "flash_fwd",
+        "flash_bwd_dq", "flash_bwd_dkv", "Perfetto", "step_enqueue_ms",
+        "step_host_self_ms", "input_queue_depth", "input_h2d_ms",
+        "grad_sync_bytes_per_step", "grad_sync_calls_per_step",
+    ):
+        assert needle in text, f"OBSERVABILITY.md lost its {needle!r} coverage"
+
+
+@pytest.mark.parametrize("idx", range(len(_blocks(_OBSERVABILITY))))
+def test_observability_doc_snippet_runs(idx):
+    code = _blocks(_OBSERVABILITY)[idx]
+    exec(compile(code, f"{_OBSERVABILITY}:block{idx}", "exec"), {})

@@ -1,4 +1,4 @@
-"""Observability: meters, metrics registry, collective trace, log parsers."""
+"""Observability: meters, metrics registry and its spans, collective trace."""
 
 import json
 
@@ -11,9 +11,10 @@ from adapcc_tpu.utils import (
     CollectiveTrace,
     MetricsRegistry,
     ProgressMeter,
+    default_registry,
     parse_track_log,
-    parse_training_log,
 )
+from adapcc_tpu.utils import observability
 
 
 def test_average_meter():
@@ -74,7 +75,7 @@ def test_metrics_registry_reservoir_is_bounded_and_deterministic():
         return reg
 
     a, b = fill(), fill()
-    assert len(a._timings["t"]["reservoir"]) == MetricsRegistry.RESERVOIR_SIZE
+    assert len(a._timings["t"].reservoir) == MetricsRegistry.RESERVOIR_SIZE
     # deterministic replacement: identical runs snapshot identical stats
     assert a.snapshot() == b.snapshot()
 
@@ -218,19 +219,144 @@ def test_engine_records_dispatches(mesh4):
     assert tr.events()[0].nbytes == 4 * 8 * 4
 
 
-def test_parse_training_log(tmp_path):
-    path = tmp_path / "train.log"
-    path.write_text(
-        "junk line\n"
-        "step 1 loss 0.75 acc 12.0\n"
-        "step: 2  loss: 0.5\n"
-        "epoch done\n"
-        "step 3 loss 2.5e-1\n"
-    )
-    pairs = parse_training_log(str(path))
-    assert pairs == [(1, 0.75), (2, 0.5), (3, 0.25)]
-    accs = parse_training_log(str(path), key="acc")
-    assert accs == [(1, 12.0)]
+def test_sample_is_a_unitless_distribution_beside_the_timings():
+    reg = MetricsRegistry()
+    for v in (1, 2, 2, 7):
+        reg.sample("q.depth", v)
+    snap = reg.snapshot()
+    assert snap["samples"]["q.depth"] == {"count": 4, "mean": 3.0, "max": 7.0, "p50": 2.0, "p99": 7.0}
+    assert snap["timings"] == {}
+    json.loads(reg.to_json())
+
+
+def test_span_off_checks_the_profiler_and_does_nothing_else(monkeypatch):
+    """No profiler session: no clock read, no annotation object, nothing
+    recorded; the block still runs and is told the span is off."""
+
+    class NoAnnotation:
+        def __init__(self, *a, **kw):
+            raise AssertionError("an off span constructed a TraceAnnotation")
+
+        @staticmethod
+        def is_enabled():
+            return False
+
+    def no_clock():
+        raise AssertionError("an off span read the clock")
+
+    monkeypatch.setattr(observability, "TraceAnnotation", NoAnnotation)
+    monkeypatch.setattr(observability.time, "perf_counter", no_clock)
+    reg = MetricsRegistry()
+    ran = []
+    with reg.span("step.prepare", step=3) as live:
+        ran.append(live)
+    assert ran == [False]
+    assert reg.snapshot()["timings"] == {}
+
+
+def test_span_on_lands_in_the_profile_with_its_metadata(profile):
+    import time
+
+    reg = MetricsRegistry()
+    with profile() as prof:
+        t0 = time.perf_counter()
+        for i in range(3):
+            with reg.span("unit.work", step=i) as live:
+                assert live is True
+                time.sleep(0.002)
+        host = time.perf_counter() - t0
+    t = reg.snapshot()["timings"]["unit.work"]
+    assert t["count"] == 3 and 0.006 <= t["total_s"] <= host
+    spans = prof.spans()
+    assert [(n, stats) for n, _, _, stats in spans] == [
+        ("adapcc.unit.work", {"step": i}) for i in range(3)
+    ]
+    # the annotation and the timing are the same interval
+    assert sum(d for _, _, d, _ in spans) / 1e9 == pytest.approx(t["total_s"], rel=0.2)
+    # the session over, the span is off again
+    with reg.span("unit.work") as live:
+        assert live is False
+    assert reg.snapshot()["timings"]["unit.work"]["count"] == 3
+
+
+def test_spans_and_samples_belong_to_their_profiler_session(profile):
+    reg = MetricsRegistry()
+    reg.incr("c")
+    reg.gauge("g", 2.0)
+    reg.observe("outside.timing", 0.5)
+    reg.sample("outside.sample", 9)
+    with profile("a"):
+        for _ in range(4):
+            with reg.span("w"):
+                reg.sample("depth", 1)
+    first = reg.snapshot()
+    assert first["timings"]["w"]["count"] == 4 and first["samples"]["depth"]["count"] == 4
+    with reg.span("w"):  # an off check in between tells the sessions apart
+        pass
+    with profile("b"):
+        with reg.span("v"):
+            pass
+        second = reg.snapshot()
+    # the second session starts from zero; what no session owns persists
+    assert set(second["timings"]) == {"v", "outside.timing"}
+    assert set(second["samples"]) == {"outside.sample"}
+    assert second["counters"] == {"c": 1.0} and second["gauges"] == {"g": 2.0}
+
+
+def test_spans_from_many_threads_lose_no_update(profile):
+    """The feed's producer and the training loop record into one registry
+    at once: every span of every thread is counted, and a session is opened
+    once however many threads see it first."""
+    import sys
+    import threading
+
+    reg = MetricsRegistry()
+    with reg.span("w"):  # off: the session below is a new one
+        pass
+    threads, per_thread = 12, 200
+
+    def work():
+        for i in range(per_thread):
+            with reg.span("w", step=i):
+                reg.sample("depth", i)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with profile():
+            workers = [threading.Thread(target=work) for _ in range(threads)]
+            for t in workers:
+                t.start()
+            for t in workers:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in workers)
+    finally:
+        sys.setswitchinterval(interval)
+    snap = reg.snapshot()
+    assert snap["timings"]["w"]["count"] == threads * per_thread
+    assert snap["samples"]["depth"]["count"] == threads * per_thread
+    assert reg._gen == 1
+
+
+def test_one_default_registry_and_the_codec_timings_use_it():
+    import pathlib
+    import re
+
+    from adapcc_tpu.quant import timed_roundtrip
+
+    reg = default_registry()
+    assert reg is default_registry() and isinstance(reg, MetricsRegistry)
+    before = reg.snapshot()["timings"].get("quant.bf16.quantize", {"count": 0})["count"]
+    timed_roundtrip("bf16", jnp.ones((256,), jnp.float32))
+    assert reg.snapshot()["timings"]["quant.bf16.quantize"]["count"] == before + 1
+    # no second module-level registry anywhere in the package
+    root = pathlib.Path(observability.__file__).resolve().parents[1]
+    owners = [
+        str(p.relative_to(root))
+        for p in root.rglob("*.py")
+        if re.search(r"^\w+\s*(:[^=]+)?=\s*MetricsRegistry\(", p.read_text(), re.M)
+    ]
+    assert owners == ["utils/observability.py"]
 
 
 def test_profiler_trace_writes(tmp_path):

@@ -322,9 +322,11 @@ def test_bucket_plan_observability_metrics(mesh8, monkeypatch):
     assert snap["gauges"]["bucket_plan.num_buckets"] == hook._plan.num_buckets
     assert snap["gauges"]["bucket_plan.total_bytes"] == hook._plan.total_bytes
     assert snap["counters"]["bucket_plan.oversized_leaves"] == 1
-    hist = snap["timings"]["bucket_plan.bucket_bytes"]
+    # bytes are a unitless sample, not a timing in seconds
+    assert "bucket_plan.bucket_bytes" not in snap["timings"]
+    hist = snap["samples"]["bucket_plan.bucket_bytes"]
     assert hist["count"] == hook._plan.num_buckets
-    assert hist["max_s"] == max(hook._plan.bucket_bytes)
+    assert hist["max"] == max(hook._plan.bucket_bytes)
 
 
 # --------------------------------------------------------------------------- #
