@@ -46,9 +46,10 @@ class GPT2Config:
     #: tiles, no attention-matrix HBM traffic.  Training path only (decode
     #: uses the KV cache) and requires dropout == 0.
     attention: str = "xla"
-    #: flash kernel tile edge (block_q == block_k); the VMEM-vs-parallelism
-    #: trade to sweep on hardware (bench.py BENCH_FLASH_BLOCK)
-    flash_block: int = 128
+    #: flash kernel tile edge (block_q == block_k).  None: by shape, from the
+    #: table measured on the chip (ops/flash_attention.TILE_TABLE); an integer
+    #: overrides it (bench.py BENCH_FLASH_BLOCK sweeps one)
+    flash_block: Optional[int] = None
     #: sequence parallelism: when set (a mesh axis name), the model expects
     #: to run INSIDE shard_map with tokens sequence-sharded over that axis —
     #: attention crosses shards via the ring / Ulysses programs

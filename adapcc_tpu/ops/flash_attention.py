@@ -14,13 +14,45 @@ Forward, per query block i (running max ``m``, normalizer ``l``):
     m'     = max(m, rowmax(s_ij))
     p_ij   = exp(s_ij − m')
     l      = l·exp(m − m') + rowsum(p_ij)
-    acc    = acc·exp(m − m') + p_ij v_j
+    acc    = acc·exp(m − m') + p_ij v_j        (p_ij in v's dtype)
     o_i    = acc / l ;  lse_i = m + log l      (saved for backward)
 
 Backward runs two kernels (no atomics needed — each grid program owns its
 output block exclusively): a dq pass gridded over query blocks and a dk/dv
 pass gridded over key blocks, both rebuilding ``p_ij = exp(s_ij − lse_i)``
-from the residuals with ``Δ_i = rowsum(do_i ∘ o_i)``.
+from the residuals with ``Δ_i = rowsum(do_i ∘ o_i)``.  The dk/dv pass works
+on the transposed tile ``s_ji = k_j q_i^T``, so that ``dv += p^T do`` and
+``dk += dS^T q`` are plain products and lse, Δ are rows.
+
+**Which tiles.**  With ``causal=True`` a kernel visits only the tiles the
+mask leaves: query block ``qi`` takes key blocks ``0 … ((qi+1)·bq − 1) // bk``,
+key block ``kj`` takes query blocks from ``(kj·bk) // bq`` up, and the mask is
+applied only on the tiles the diagonal crosses (:func:`visited_tiles` counts
+them: 36 of 64 at T=1,024 with 128-tiles).  Where the code stays small the
+bounds are static: the kernel body is written out once for each grid position
+along the block axis (:func:`_per_program`), because a loop bounded by the
+traced ``program_id`` cost 3.4 times as much for each tile on a v5e; long
+sequences (past ``_STRAIGHT_LINE_ELEMENTS``) take that loop.  ``causal=False``
+(ring attention's off-diagonal shards) visits every tile from one body.
+
+**Which dtype.**  The seven products take their operands in the inputs' own
+dtype and accumulate in fp32: bf16 q/k/v/do go to the MXU as bf16, and ``p``
+and ``dS`` are cast to that dtype for their four products (what the XLA
+attention path does with its softmax); fp32 inputs keep fp32 products.  The
+softmax arithmetic, ``m``, ``l``, ``lse``, ``Δ`` and the accumulators are fp32
+either way.
+
+**Which tile.**  ``block_q`` / ``block_k`` default to the tile
+:data:`TILE_TABLE` holds for ``(T, D, dtype)``, measured on the chip.
+
+**The signature that must hold.**  The benchmark's trace reader
+(``chipbench/trace_reduce.flash_kernel``) tells the kernels apart by their
+operands and results: three Mosaic custom calls an attention; the forward
+takes q, k, v and returns two arrays; both backward kernels take q, k, v, do,
+lse, delta, dq returning one array and dk/dv a tuple of two.  So no
+scalar-prefetch operand, no fused dq+dkv kernel, no split forward (VMEM scratch
+is not an operand); the ``name=`` of each ``pallas_call`` stays
+(tests/test_flash_attention.py guards both).
 
 Used by the GPT-2 flagship model when ``GPT2Config.attention == "flash"``;
 long-context cross-chip attention composes this with the ring/Ulysses
@@ -31,7 +63,7 @@ kernel on its local K/V shard).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -41,127 +73,242 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from adapcc_tpu.ops.kernel_mode import resolve_interpret
+from adapcc_tpu.utils.observability import default_registry
 
 _NEG_INF = -1e30
 
 # Mosaic requires the last two dims of every block shape to be divisible by
 # the (8, 128) tile or equal to the whole array's dims.  A naive ``[BH, T]``
 # logsumexp output with block ``(1, bq)`` violates the sublane rule (the 1),
-# so lse/delta cross every pallas_call boundary lane-padded to
-# ``[BH, T, _LSE_LANES]`` (block ``(1, bq, 8)``: bq % 8 == 0, 8 == minor dim)
-# and are sliced back to ``[BH, T]`` outside the kernels.
+# so the forward kernel's lse and the dq kernel's lse/delta, which they hold as
+# columns, cross the pallas_call boundary lane-padded to ``[BH, T, _LSE_LANES]``
+# (block ``(1, bq, 8)``: bq % 8 == 0, 8 == minor dim) and are sliced back to
+# ``[BH, T]`` outside.  The dk/dv kernel holds them as rows and takes
+# ``[BH, 1, T]`` whole (block ``(1, 1, T)``).
 _LSE_LANES = 8
 
 
-def _causal_mask(s, qi, kj, block_q, block_k):
-    q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    k_pos = kj * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+def _causal_mask(s, qi, kj, block_q, block_k, q_axis=0):
+    """Mask tile ``(qi, kj)`` of the scores; queries run along ``q_axis``."""
+    q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = kj * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     return jnp.where(k_pos <= q_pos, s, _NEG_INF)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    bq, d = q.shape
-    n_k = k_ref.shape[1] // block_k
+def _key_blocks(qi: int, block_q: int, block_k: int) -> Tuple[int, int]:
+    """Key blocks a causal query block ``qi`` needs: ``[0, full)`` lie wholly
+    at or under the diagonal (last column <= first row), ``[full, end)`` are
+    crossed by it."""
+    return (qi * block_q + 1) // block_k, ((qi + 1) * block_q - 1) // block_k + 1
 
-    m = jnp.full((bq,), _NEG_INF, jnp.float32)
-    l = jnp.zeros((bq,), jnp.float32)
-    acc = jnp.zeros((bq, d), jnp.float32)
-    for j in range(n_k):
-        k = k_ref[0, j * block_k : (j + 1) * block_k, :].astype(jnp.float32)
-        v = v_ref[0, j * block_k : (j + 1) * block_k, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if causal:
-            s = _causal_mask(s, qi, j, block_q, block_k)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l = l * alpha + jnp.sum(p, axis=1)
-        acc = acc * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m = m_new
 
-    o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
-    lse_ref[0] = jnp.broadcast_to(
-        (m + jnp.log(l))[:, None], (bq, _LSE_LANES)
+def _query_blocks(kj: int, block_q: int, block_k: int) -> Tuple[int, int]:
+    """Query blocks a causal key block ``kj`` is seen by: ``[start, full)``
+    are crossed by the diagonal, ``[full, n_q)`` lie wholly under it (first
+    row >= last column)."""
+    return (kj * block_k) // block_q, ((kj + 1) * block_k + block_q - 2) // block_q
+
+
+def visited_tiles(T: int, block_q: int, block_k: int, causal: bool) -> int:
+    """``[block_q, block_k]`` tiles of one ``[T, T]`` score plane that each of
+    the three kernels visits: every tile without a mask, and under the causal
+    mask exactly those with an unmasked element."""
+    n_q, n_k = T // block_q, T // block_k
+    if not causal:
+        return n_q * n_k
+    return sum(_key_blocks(qi, block_q, block_k)[1] for qi in range(n_q))
+
+
+#: Score-plane elements (tiles x block_q x block_k) a causal kernel writes out
+#: as straight-line code at most.  Under it every grid position gets its own
+#: copy of the body with static bounds; over it (long sequences: the code
+#: grows with T squared and falls out of instruction memory) the one body takes
+#: the traced ``program_id`` and loops with traced bounds.  Measured on a v5e
+#: (PERF.md §6, PR 25): static wins at T=1,024 and T=2,048 (0.8 and 2.6 M
+#: elements with 512-tiles), the loop at T=4,096 (9.4 M).
+_STRAIGHT_LINE_ELEMENTS = 1 << 22
+
+
+def _per_program(body, n: int, T: int, block_q: int, block_k: int, causal: bool) -> None:
+    """``body(i)`` with ``i`` the grid position along the block axis (of ``n``).
+    Under the causal mask the positions differ in their bounds: each gets its
+    own copy of the body with ``i`` a Python integer, where that keeps the code
+    small enough (every bound and slice is then static and Mosaic schedules a
+    block's tiles as one basic block); else ``i`` is the traced
+    ``pl.program_id``.  Without the mask one body serves them all."""
+    if not causal or n == 1:
+        return body(0)
+    position = pl.program_id(1)
+    if visited_tiles(T, block_q, block_k, True) * block_q * block_k > _STRAIGHT_LINE_ELEMENTS:
+        return body(position)
+    for i in range(n):
+        pl.when(position == i)(functools.partial(body, i))
+
+
+def _tiles(lo, hi, tile, carry):
+    """``carry = tile(j, carry)`` for ``j`` in ``[lo, hi)``: written out when
+    both bounds are Python integers, a ``fori_loop`` when one is traced."""
+    if isinstance(lo, int) and isinstance(hi, int):
+        for j in range(lo, hi):
+            carry = tile(j, carry)
+        return carry
+    return lax.fori_loop(lo, hi, tile, carry)
+
+
+def _dot(a, b, contract):
+    """One MXU product in the operands' own dtype, accumulated in fp32."""
+    return lax.dot_general(
+        a, b, ((contract[:1], contract[1:]), ((), ())),
+        preferred_element_type=jnp.float32,
     )
+
+
+def _block(i, block: int):
+    """The slice of block ``i`` (a Python integer or traced) along T."""
+    return pl.ds(i * block if isinstance(i, int) else pl.multiple_of(i * block, block), block)
+
+
+def _rows(ref, i, block: int):
+    """Block ``i`` of ``block`` rows of a ``[1, T, D]`` ref."""
+    return ref[0, _block(i, block), :]
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k):
+    q = q_ref[0]
+    bq, d = q.shape
+    T = k_ref.shape[1]
+
+    def query_block(qi):
+        def tile(j, carry, masked):
+            m, l, acc = carry
+            k, v = _rows(k_ref, j, block_k), _rows(v_ref, j, block_k)
+            s = _dot(q, k, (1, 1)) * scale
+            if masked:
+                s = _causal_mask(s, qi, j, block_q, block_k)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + _dot(p.astype(v.dtype), v, (1, 0))
+            return m_new, l, acc
+
+        full, end = _key_blocks(qi, block_q, block_k) if causal else (T // block_k,) * 2
+        carry = (
+            jnp.full((bq, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((bq, 1), jnp.float32),
+            jnp.zeros((bq, d), jnp.float32),
+        )
+        carry = _tiles(0, full, functools.partial(tile, masked=False), carry)
+        m, l, acc = _tiles(full, end, functools.partial(tile, masked=True), carry)
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (bq, _LSE_LANES))
+
+    _per_program(query_block, T // block_q, T, block_q, block_k, causal)
 
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     *, scale, causal, block_q, block_k,
 ):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+    q = q_ref[0]
+    do = do_ref[0]
     lse_col = lse_ref[0][:, 0:1]      # [bq, 1] from the lane-padded layout
     delta_col = delta_ref[0][:, 0:1]
     bq, d = q.shape
-    n_k = k_ref.shape[1] // block_k
+    T = k_ref.shape[1]
 
-    dq = jnp.zeros((bq, d), jnp.float32)
-    for j in range(n_k):
-        k = k_ref[0, j * block_k : (j + 1) * block_k, :].astype(jnp.float32)
-        v = v_ref[0, j * block_k : (j + 1) * block_k, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if causal:
-            s = _causal_mask(s, qi, j, block_q, block_k)
-        p = jnp.exp(s - lse_col)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta_col) * scale
-        dq = dq + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    def query_block(qi):
+        def tile(j, dq, masked):
+            k, v = _rows(k_ref, j, block_k), _rows(v_ref, j, block_k)
+            s = _dot(q, k, (1, 1)) * scale
+            if masked:
+                s = _causal_mask(s, qi, j, block_q, block_k)
+            p = jnp.exp(s - lse_col)
+            ds = p * (_dot(do, v, (1, 1)) - delta_col) * scale
+            return dq + _dot(ds.astype(k.dtype), k, (1, 0))
+
+        full, end = _key_blocks(qi, block_q, block_k) if causal else (T // block_k,) * 2
+        dq = _tiles(0, full, functools.partial(tile, masked=False), jnp.zeros((bq, d), jnp.float32))
+        dq = _tiles(full, end, functools.partial(tile, masked=True), dq)
+        dq_ref[0] = dq.astype(dq_ref.dtype)
+
+    _per_program(query_block, T // block_q, T, block_q, block_k, causal)
 
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     *, scale, causal, block_q, block_k,
 ):
-    kj = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
+    k = k_ref[0]
+    v = v_ref[0]
     bk, d = k.shape
-    n_q = q_ref.shape[1] // block_q
+    T = q_ref.shape[1]
 
-    dk = jnp.zeros((bk, d), jnp.float32)
-    dv = jnp.zeros((bk, d), jnp.float32)
-    for i in range(n_q):
-        q = q_ref[0, i * block_q : (i + 1) * block_q, :].astype(jnp.float32)
-        do = do_ref[0, i * block_q : (i + 1) * block_q, :].astype(jnp.float32)
-        lse_col = lse_ref[0, i * block_q : (i + 1) * block_q, 0:1]
-        delta_col = delta_ref[0, i * block_q : (i + 1) * block_q, 0:1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if causal:
-            s = _causal_mask(s, i, kj, block_q, block_k)
-        p = jnp.exp(s - lse_col)
-        dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta_col) * scale
-        dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    def key_block(kj):
+        def tile(i, carry, masked):
+            # the tile transposed, [bk, bq]: all four products then contract
+            # in the MXU's own orientations (no transposed left operand), and
+            # lse and delta are rows
+            dk, dv = carry
+            q, do = _rows(q_ref, i, block_q), _rows(do_ref, i, block_q)
+            lse_row = lse_ref[0, :, _block(i, block_q)]
+            delta_row = delta_ref[0, :, _block(i, block_q)]
+            s = _dot(k, q, (1, 1)) * scale
+            if masked:
+                s = _causal_mask(s, i, kj, block_q, block_k, q_axis=1)
+            p = jnp.exp(s - lse_row)
+            dv = dv + _dot(p.astype(do.dtype), do, (1, 0))
+            ds = p * (_dot(v, do, (1, 1)) - delta_row) * scale
+            dk = dk + _dot(ds.astype(q.dtype), q, (1, 0))
+            return dk, dv
+
+        start, full = _query_blocks(kj, block_q, block_k) if causal else (0, 0)
+        zeros = jnp.zeros((bk, d), jnp.float32)
+        carry = _tiles(start, full, functools.partial(tile, masked=True), (zeros, zeros))
+        dk, dv = _tiles(full, T // block_q, functools.partial(tile, masked=False), carry)
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    _per_program(key_block, T // block_k, T, block_q, block_k, causal)
 
 
-def _block_sizes(T: int, block_q: int, block_k: int):
-    bq, bk = min(block_q, T), min(block_k, T)
+#: The tile by shape, measured on a TPU v5e (PERF.md §6, PR 25): rows of
+#: ``(longest T, widest head, operand bytes) -> (block_q, block_k)``, the first
+#: row that holds the shape wins, the last holds every shape.  Read here and
+#: nowhere else: ``GPT2Config.flash_block = None`` and
+#: ``flash_autotune``'s static default both resolve through
+#: :func:`default_blocks`.
+TILE_TABLE = (
+    ((4096, 64, 2), (512, 512)),   # bf16, measured at T=1,024 (B.H = 144 and 32) and T=4,096
+    ((1024, 64, 4), (512, 512)),   # fp32, measured at T=1,024
+    ((float("inf"),) * 3, (128, 128)),   # not measured: the tile every shape ran before
+)
+
+
+def resolve_block(seq: int, want: int) -> int:
+    """Largest 8-aligned tile <= ``want`` that divides ``seq``; falls back
+    to the full sequence when no aligned divisor exists."""
+    b = min(max(8, want - want % 8), seq)
+    while b >= 8 and seq % b:
+        b -= 8
+    return b if b >= 8 and seq % b == 0 else seq
+
+
+def default_blocks(T: int, D: int, dtype) -> Tuple[int, int]:
+    """``(block_q, block_k)`` for a ``[T, D]`` attention over ``dtype``
+    operands: the table's tile for the shape, cut to a divisor of ``T``."""
+    shape = (T, D, jnp.dtype(dtype).itemsize)
+    want = next(
+        tile for limit, tile in TILE_TABLE if all(x <= m for x, m in zip(shape, limit))
+    )
+    return resolve_block(T, want[0]), resolve_block(T, want[1])
+
+
+def _block_sizes(T: int, D: int, dtype, block_q: Optional[int], block_k: Optional[int]):
+    """The tile the kernels run: the caller's, else the table's."""
+    by_shape = default_blocks(T, D, dtype)
+    bq = by_shape[0] if block_q is None else min(block_q, T)
+    bk = by_shape[1] if block_k is None else min(block_k, T)
     if T % bq or T % bk:
         raise ValueError(f"seq len {T} must divide into blocks ({bq}, {bk})")
     # Mosaic sublane rule: the lane-padded (1, bq, _LSE_LANES) block specs
@@ -177,6 +324,17 @@ def _block_sizes(T: int, block_q: int, block_k: int):
     return bq, bk
 
 
+def _record_tiles(T: int, bq: int, bk: int, causal: bool, kernels: int) -> None:
+    """Trace-time gauges (once per compile, nothing per step): the tiles of
+    one ``[T, T]`` score plane the attention call's kernels visit and would
+    visit without skipping, summed over ``kernels`` of them, and the tile."""
+    metrics = default_registry()
+    metrics.gauge("flash.tiles_visited", kernels * visited_tiles(T, bq, bk, causal))
+    metrics.gauge("flash.tiles_total", kernels * (T // bq) * (T // bk))
+    metrics.gauge("flash.block_q", bq)
+    metrics.gauge("flash.block_k", bk)
+
+
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
 )
@@ -186,14 +344,26 @@ def _flash_bhtd(q, k, v, scale, causal, block_q, block_k, interpret):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
+    _, T, D = q.shape
+    bq, bk = _block_sizes(T, D, q.dtype, block_q, block_k)
+    _record_tiles(T, bq, bk, causal, kernels=1)
+    interp = resolve_interpret(interpret, "flash_attention")
+    out, lse = _fwd_call(q, k, v, scale, causal, bq, bk, interp)
+    return out, (q, k, v, out, lse)
+
+
+# The pallas_calls sit behind jax.jit so that a model's layers, which call them
+# with one shape and one set of parameters, share one traced and lowered kernel:
+# JAX traces a pallas_call's kernel anew at every call site, and a 24-layer
+# step pays 72 of them, twice (PERF.md §6, PR 25: the step's trace).
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _fwd_call(q, k, v, scale, causal, bq, bk, interp):
     BH, T, D = q.shape
-    bq, bk = _block_sizes(T, block_q, block_k)
-    grid = (BH, T // bq)
     out, lse3 = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk
         ),
-        grid=grid,
+        grid=(BH, T // bq),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, T, D), lambda b, i: (b, 0, 0)),
@@ -207,11 +377,10 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
             jax.ShapeDtypeStruct((BH, T, _LSE_LANES), jnp.float32),
         ],
-        interpret=resolve_interpret(interpret, "flash_attention"),
+        interpret=interp,
         name="flash_fwd",
     )(q, k, v)
-    lse = lse3[:, :, 0]
-    return out, (q, k, v, out, lse)
+    return out, lse3[:, :, 0]
 
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, res, do):
@@ -222,14 +391,23 @@ def _flash_bwd_core(scale, causal, block_q, block_k, interpret, res, do, dlse):
     """Shared backward.  An ``lse`` cotangent adds ``dS_ij += p_ij·dlse_i``,
     which folds into the existing kernels as ``delta → delta − dlse`` (the
     bracket is ``p·(dp − delta)``) — no kernel change needed."""
+    q = res[0]
+    _, T, D = q.shape
+    bq, bk = _block_sizes(T, D, q.dtype, block_q, block_k)
+    # a backward pass closes an attention call: its forward kernel and these two
+    _record_tiles(T, bq, bk, causal, kernels=3)
+    interp = resolve_interpret(interpret, "flash_attention")
+    return _bwd_call(res, do, dlse, scale, causal, bq, bk, interp)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp):
     q, k, v, out, lse = res
     BH, T, D = q.shape
-    bq, bk = _block_sizes(T, block_q, block_k)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
-    interp = resolve_interpret(interpret, "flash_attention")
-    # lane-pad the per-row statistics for the kernels' tiled block specs
+    # lane-pad the per-row statistics for the dq kernel's tiled block specs
     lse3 = jnp.broadcast_to(lse[..., None], (BH, T, _LSE_LANES))
     delta3 = jnp.broadcast_to(delta[..., None], (BH, T, _LSE_LANES))
 
@@ -262,8 +440,8 @@ def _flash_bwd_core(scale, causal, block_q, block_k, interpret, res, do, dlse):
             pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, T, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, T, _LSE_LANES), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, T, _LSE_LANES), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, T), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, T), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
@@ -275,7 +453,7 @@ def _flash_bwd_core(scale, causal, block_q, block_k, interpret, res, do, dlse):
         ],
         interpret=interp,
         name="flash_bwd_dkv",
-    )(q, k, v, do, lse3, delta3)
+    )(q, k, v, do, lse[:, None, :], delta[:, None, :])
     return dq, dk, dv
 
 
@@ -328,8 +506,8 @@ def flash_attention(
     v: jnp.ndarray,
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Blockwise attention over ``[B, T, H, D]`` tensors (model layout).
@@ -337,7 +515,9 @@ def flash_attention(
     ``interpret=None`` asks :func:`ops.kernel_mode.resolve_interpret`, which
     records what it decided — the Pallas interpreter off-TPU, so the
     same call works on the virtual CPU pod.  ``scale`` defaults to
-    ``1/sqrt(D)``.  ``T`` must divide by the block sizes (clamped to ``T``).
+    ``1/sqrt(D)``.  ``block_q`` / ``block_k`` default to the tile the table
+    holds for ``(T, D, dtype)`` (:func:`default_blocks`); given, ``T`` must
+    divide by them (clamped to ``T``).
     """
     out, (B, T, H, D) = _bthd_call(
         _flash_bhtd, q, k, v, causal, scale, block_q, block_k, interpret
@@ -351,8 +531,8 @@ def flash_attention_with_lse(
     v: jnp.ndarray,
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> "tuple[jnp.ndarray, jnp.ndarray]":
     """Blockwise attention returning ``(out [B,T,H,D], lse [B,H,T])``.

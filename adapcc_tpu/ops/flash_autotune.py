@@ -1,9 +1,13 @@
 """Flash-attention tile autotuning.
 
-Round 4 found the best flash block empirically (256 beat 128 by ~11% on the
-v5e flagship shape) via a *manual* battery A/B; the default was then pinned
-statically (VERDICT r4, "What's weak" #3).  This module makes that sweep a
-first-class, cached measurement: for a given (seq, d_head, dtype) it times a
+The static tile is not kept here: :data:`adapcc_tpu.ops.flash_attention.TILE_TABLE`
+holds it by ``(T, D, dtype)``, measured on a TPU v5e in PR 25 (PERF.md §6 has
+the tiles tried and their times: 512 beat 256 beat 128 at T=1,024 and at
+T=4,096), and the kernels, ``GPT2Config.flash_block = None`` and
+:data:`DEFAULT_BLOCK` all read that one table.  Round 4's "256 beat 128 by
+~11% at T=512" was a manual A/B on kernels that visited every tile; on today's
+kernels the tiles are level at T=512 (same readings).  This module is the
+sweep that refreshes the table: for a given (seq, d_head, dtype) it times a
 short jitted forward+backward of the real kernel at each candidate tile and
 returns the fastest.
 
@@ -24,22 +28,16 @@ import sys
 import time
 from typing import Dict, Optional, Sequence, Tuple
 
-#: measured-best static default (round-4 battery, v5e, T=512)
-DEFAULT_BLOCK = 256
+from adapcc_tpu.ops.flash_attention import default_blocks, resolve_block  # noqa: F401 — re-exported
+
+#: the static default at the flagship shape (T=1,024, head size 64, bf16),
+#: read from the one table the kernels themselves resolve through
+DEFAULT_BLOCK = default_blocks(1024, 64, "bfloat16")[0]
 
 #: candidate tile edges swept by the autotuner
 CANDIDATES = (128, 256, 512)
 
 _cache: Dict[Tuple, Tuple[int, Dict[int, float]]] = {}
-
-
-def resolve_block(seq: int, want: int) -> int:
-    """Largest 8-aligned tile <= ``want`` that divides ``seq``; falls back
-    to the full sequence when no aligned divisor exists."""
-    b = min(max(8, want - want % 8), seq)
-    while b >= 8 and seq % b:
-        b -= 8
-    return b if b >= 8 and seq % b == 0 else seq
 
 
 def autotune_flash_block(
@@ -79,7 +77,7 @@ def autotune_flash_block(
             resolved.append(r)
     if platform != "tpu" or len(resolved) == 1:
         # interpreter timings are meaningless for Mosaic tile choice
-        best = resolve_block(seq, DEFAULT_BLOCK)
+        best = default_blocks(seq, d_head, dtype)[0]
         _cache[key] = (best, {})
         return best
 

@@ -41,8 +41,8 @@ def ring_attention_shard(
     causal: bool = True,
     scale: Optional[float] = None,
     block_impl: str = "dense",
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ) -> jnp.ndarray:
     """Per-shard ring attention, for use inside ``shard_map``.
 
@@ -112,8 +112,8 @@ def _ring_flash_shard(
     axis_name: str,
     causal: bool,
     scale: Optional[float],
-    block_q: int,
-    block_k: int,
+    block_q: Optional[int],
+    block_k: Optional[int],
 ) -> jnp.ndarray:
     """Flash-ring: each ring step runs the blockwise Pallas kernel on the
     K/V block currently held, then merges via log-sum-exp using the
@@ -188,8 +188,8 @@ def ring_attention(
     causal: bool = True,
     scale: Optional[float] = None,
     block_impl: str = "dense",
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ) -> jnp.ndarray:
     """Global-view convenience wrapper: ``q/k/v [B, T, H, D]`` with ``T``
     divisible by the mesh axis size; shards the sequence dim, runs the ring,

@@ -40,8 +40,8 @@ def ulysses_attention_shard(
     causal: bool = True,
     scale: Optional[float] = None,
     block_impl: str = "dense",
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ) -> jnp.ndarray:
     """Per-shard Ulysses attention, for use inside ``shard_map``.
 

@@ -87,7 +87,7 @@ def test_flash_autotune_resolution_and_cpu_skip(monkeypatch):
     assert fa.resolve_block(384, 256) == 192
     assert fa.resolve_block(300, 256) == 300  # no aligned divisor: full seq
     best = fa.autotune_flash_block(512)
-    assert best == fa.resolve_block(512, fa.DEFAULT_BLOCK)
+    assert best == fa.resolve_block(512, fa.DEFAULT_BLOCK) == fa.default_blocks(512, 64, "bfloat16")[0]
     assert fa.last_timings(512) == {}  # swept-off marker, not None
     # cached: a second call must not re-enter the sweep
     assert fa.autotune_flash_block(512) == best
@@ -98,9 +98,14 @@ def test_bench_flash_block_auto_env(monkeypatch):
 
     monkeypatch.setenv("BENCH_FLASH_BLOCK", "auto")
     monkeypatch.setitem(bench._RESULT, "flash_autotune", None)
+    import jax.numpy as jnp
+
+    from adapcc_tpu.ops.flash_attention import default_blocks
+
     b = bench.flash_block_for(512)
-    assert b == 256  # cpu skip path resolves the static default
-    assert bench._RESULT["flash_autotune"]["best"] == 256
+    # the cpu skip path resolves the static default: the one table's tile
+    assert b == default_blocks(512, 64, jnp.bfloat16)[0]
+    assert bench._RESULT["flash_autotune"]["best"] == b
 
 
 def test_bench_rejects_bad_opt_moments_env():

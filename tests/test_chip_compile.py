@@ -85,19 +85,31 @@ def _compile_on_mesh(mesh, per_shard, shape, dtype):
 # --- flash attention at the smoke's shape: B × 1,024 × 12 heads × 64 --------
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "fp32"])
-def test_flash_fwd_bwd_compiles_at_gpt2_small_shape(one_chip, dtype):
+def _flash_step(shape, dtype, one_chip, block):
     from adapcc_tpu.ops import flash_attention
 
     def loss(q, k, v):
         out = flash_attention(
-            q, k, v, causal=True, block_q=128, block_k=128, interpret=False
+            q, k, v, causal=True, block_q=block, block_k=block, interpret=False
         )
         return jnp.sum(out.astype(jnp.float32))
 
-    x = jax.ShapeDtypeStruct((4, 1024, 12, 64), dtype, sharding=one_chip)
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
-    assert _kernels_in(compiled) == 3  # forward, dq, dk/dv
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
+
+
+@pytest.mark.parametrize("block", [None, 128], ids=["by-shape", "128"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "fp32"])
+def test_flash_fwd_bwd_compiles_at_gpt2_small_shape(one_chip, dtype, block):
+    """The table's tile and the old 128: at T=1,024 every grid position has
+    its own straight-line body (bf16 operands reach Mosaic transposed too)."""
+    assert _kernels_in(_flash_step((4, 1024, 12, 64), dtype, one_chip, block)) == 3  # forward, dq, dk/dv
+
+
+def test_flash_fwd_bwd_compiles_at_a_long_shard(one_chip):
+    """T=4,096 (a ring shard): past ``_STRAIGHT_LINE_ELEMENTS`` the bodies
+    loop with bounds from the traced ``program_id`` and slice K/V by it."""
+    assert _kernels_in(_flash_step((1, 4096, 12, 64), jnp.bfloat16, one_chip, None)) == 3
 
 
 # --- the ICI ring at world 4, both paths ------------------------------------

@@ -921,5 +921,6 @@ def test_the_compiled_step_carries_the_names_the_device_trace_is_read_by(mesh4):
     text = trainer._build().lower(state, tokens).as_text(debug_info=True)
     assert "module @jit_ddp_step" in text
     for name in ('"grad_sync/psum"', '"optimizer/', "jvp(GPT2)/lm_head/", "jvp(loss)/", "transpose(jvp(loss))/",
-                 "/flash_fwd/pallas_call", "/flash_bwd_dq/pallas_call", "/flash_bwd_dkv/pallas_call"):
+                 # the kernels sit in jitted calls that the layers share: named at the call, and inside it
+                 "attn/jit(_fwd_call)", "attn/jit(_bwd_call)", '"flash_fwd/', '"flash_bwd_dq/', '"flash_bwd_dkv/'):
         assert name in text, name

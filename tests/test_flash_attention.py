@@ -7,15 +7,23 @@ must match it; the Pallas interpreter runs on the CPU pod (Mosaic lowering
 is covered separately by tests/test_tpu_smoke.py).
 """
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from adapcc_tpu.ops import flash_attention
+from adapcc_tpu.ops import flash_attention, flash_attention_with_lse
+from adapcc_tpu.ops.flash_attention import TILE_TABLE, default_blocks, visited_tiles
+from adapcc_tpu.utils.observability import default_registry
+
+#: the module (the package's attribute of that name is the function)
+flash_module = sys.modules["adapcc_tpu.ops.flash_attention"]
 
 
-def _dense_attention(q, k, v, causal=True, scale=None):
+def _dense_attention_lse(q, k, v, causal=True, scale=None):
+    """The fp32 oracle with its logsumexp ``[B, H, T]``."""
     B, T, H, D = q.shape
     if scale is None:
         scale = 1.0 / np.sqrt(D)
@@ -23,8 +31,13 @@ def _dense_attention(q, k, v, causal=True, scale=None):
     if causal:
         mask = jnp.tril(jnp.ones((T, T), dtype=bool))
         att = jnp.where(mask[None, None], att, -1e30)
-    p = jax.nn.softmax(att, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(q.dtype)
+    lse = jax.nn.logsumexp(att, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(att - lse[..., None]), v.astype(jnp.float32))
+    return out, lse
+
+
+def _dense_attention(q, k, v, causal=True, scale=None):
+    return _dense_attention_lse(q, k, v, causal, scale)[0].astype(q.dtype)
 
 
 def _qkv(T=128, B=2, H=2, D=16, dtype=jnp.float32, seed=0):
@@ -146,3 +159,229 @@ def test_unaligned_block_raises_clearly():
     q4, k4, v4 = _qkv(T=4)
     out = flash_attention(q4, k4, v4, block_q=4, block_k=4)
     assert out.shape == q4.shape
+
+
+# --- PR 25: only the causal tiles, in the inputs' own dtype, tile by shape ----
+
+
+#: (block_q, block_k) at T=64: equal, bq > bk, bq < bk, one block
+_BLOCKS = [(16, 16), (32, 16), (16, 32), (64, 64)]
+
+
+@pytest.fixture(params=["static", "traced"])
+def program_index(request, monkeypatch):
+    """Both ways a causal kernel learns its grid position: a Python integer
+    for each position (short sequences), or the traced ``program_id`` once
+    the written-out code would pass ``_STRAIGHT_LINE_ELEMENTS``."""
+    def forget():   # the jitted kernel calls cache their trace by shape, not by the threshold
+        flash_module._fwd_call.clear_cache()
+        flash_module._bwd_call.clear_cache()
+
+    if request.param == "traced":
+        monkeypatch.setattr(flash_module, "_STRAIGHT_LINE_ELEMENTS", 0)
+    forget()
+    yield request.param
+    forget()
+
+
+@pytest.mark.parametrize("with_lse", [False, True], ids=["out", "out+lse"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("blocks", _BLOCKS, ids=lambda b: f"{b[0]}x{b[1]}")
+def test_forward_and_grads_match_oracle_at_every_block_shape(blocks, causal, with_lse, program_index):
+    """fp32 inputs keep fp32 products: forward within 2e-5, the three
+    gradients within 5e-5, with and without an ``lse`` cotangent (the ring's
+    ``delta - dlse`` path), whichever of the two blocks is the larger."""
+    bq, bk = blocks
+    q, k, v = _qkv(T=64)
+    rng = np.random.default_rng(11)
+    do = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
+    dl = jnp.asarray(rng.normal(size=(q.shape[0], q.shape[2], q.shape[1])), jnp.float32)
+
+    def flash(q, k, v):
+        if not with_lse:
+            return flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk), None
+        return flash_attention_with_lse(q, k, v, causal=causal, block_q=bq, block_k=bk)
+
+    def loss_of(fn):
+        def loss(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.vdot(out, do) + (jnp.vdot(lse, dl) if with_lse else 0.0)
+        return loss
+
+    out, lse = flash(q, k, v)
+    ref_out, ref_lse = _dense_attention_lse(q, k, v, causal)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out), atol=2e-5)
+    if with_lse:
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), atol=2e-5)
+    gf = jax.grad(loss_of(flash), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(loss_of(lambda q, k, v: _dense_attention_lse(q, k, v, causal)), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("q k v".split(), gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, err_msg=f"d{name} mismatch")
+
+
+@pytest.mark.parametrize("blocks", [(32, 32), (64, 32), (32, 64)], ids=lambda b: f"{b[0]}x{b[1]}")
+def test_bfloat16_head_size_64_close_to_fp32_oracle(blocks, program_index):
+    """bf16 inputs at GPT-2's head size: bf16 operands, fp32 accumulation,
+    forward and gradients against the fp32 oracle on the same rounded
+    inputs, within the file's bf16 tolerance."""
+    bq, bk = blocks
+    q, k, v = _qkv(T=64, B=1, D=64, dtype=jnp.bfloat16)
+    do = jnp.asarray(np.random.default_rng(5).normal(size=q.shape), jnp.float32)
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+
+    def flash_loss(q, k, v):
+        return jnp.vdot(f32(flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)), do)
+
+    def dense_loss(q, k, v):
+        return jnp.vdot(_dense_attention(q, k, v), do)
+
+    out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(f32(out)), np.asarray(_dense_attention(f32(q), f32(k), f32(v))), atol=0.05
+    )
+    gf = jax.grad(flash_loss, argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(dense_loss, argnums=(0, 1, 2))(f32(q), f32(k), f32(v))
+    for name, a, b in zip("q k v".split(), gf, gd):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(f32(a)), np.asarray(b), atol=0.05, err_msg=f"d{name}")
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation under ``jaxpr``, in order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
+
+
+def _eqns(jaxpr):
+    """Every equation under ``jaxpr``, loop and branch bodies included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _grad_jaxpr(dtype, **kw):
+    q, k, v = _qkv(T=64, B=1, D=64, dtype=dtype)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, **kw).astype(jnp.float32))
+
+    return jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr
+
+
+def test_three_kernels_with_the_signature_the_trace_reader_tells_them_by():
+    """``chipbench/trace_reduce.flash_kernel`` knows the kernels by operands
+    and results (forward 3 -> 2, dq 6 -> 1, dk/dv 6 -> 2), three custom calls
+    an attention: no scalar prefetch, no fused or split kernel may change it."""
+    calls = _pallas_calls(_grad_jaxpr(jnp.bfloat16, block_q=32, block_k=32))
+    assert [(c.params["name"], len(c.invars), len(c.outvars)) for c in calls] == [
+        ("flash_fwd", 3, 2), ("flash_bwd_dq", 6, 1), ("flash_bwd_dkv", 6, 2),
+    ]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "fp32"])
+def test_every_product_takes_the_inputs_dtype_and_accumulates_in_fp32(dtype, program_index):
+    """bf16 inputs reach every product of the three kernels (2 in the
+    forward's loop body, 3 in dq's, 4 in dk/dv's, each body traced once for
+    the masked and once for the unmasked range) as bf16; fp32 inputs keep
+    fp32 products."""
+    for call in _pallas_calls(_grad_jaxpr(dtype, block_q=32, block_k=16)):
+        names = [e.primitive.name for e in _eqns(call.params["jaxpr"])]
+        assert ("while" in names) == (program_index == "traced"), call.params["name"]
+        dots = [e for e in _eqns(call.params["jaxpr"]) if e.primitive.name == "dot_general"]
+        assert dots, call.params["name"]
+        for dot in dots:
+            assert {v.aval.dtype for v in dot.invars} == {jnp.dtype(dtype)}, call.params["name"]
+            assert dot.outvars[0].aval.dtype == jnp.float32
+
+
+def _brute_force_tiles(T, bq, bk, causal):
+    allowed = np.tril(np.ones((T, T), bool)) if causal else np.ones((T, T), bool)
+    return int(allowed.reshape(T // bq, bq, T // bk, bk).any(axis=(1, 3)).sum())
+
+
+@pytest.mark.parametrize(
+    "T,bq,bk",
+    [(1024, 128, 128), (1024, 256, 256), (1024, 512, 128), (1024, 128, 512), (1024, 1024, 1024),
+     (64, 16, 16), (64, 32, 16), (64, 16, 32), (64, 8, 64), (96, 24, 32), (96, 32, 24), (8, 8, 8)],
+)
+def test_visited_tiles_is_the_count_of_tiles_with_an_unmasked_element(T, bq, bk):
+    assert visited_tiles(T, bq, bk, True) == _brute_force_tiles(T, bq, bk, True)
+    assert visited_tiles(T, bq, bk, False) == (T // bq) * (T // bk)
+
+
+def test_causal_visits_36_of_64_tiles_at_t1024_with_128_tiles():
+    assert visited_tiles(1024, 128, 128, True) == 36
+    assert visited_tiles(1024, 128, 128, False) == 64
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("blocks", [(16, 16), (32, 16), (16, 32)], ids=lambda b: f"{b[0]}x{b[1]}")
+def test_tile_gauges_follow_the_trace(blocks, causal):
+    """Trace time records the tiles the call's kernels visit: one kernel's
+    after a forward pass, all three's after a backward pass."""
+    bq, bk = blocks
+    q, k, v = _qkv(T=64, B=1, H=1)
+    gauges = lambda: default_registry().snapshot()["gauges"]  # noqa: E731
+    one = visited_tiles(64, bq, bk, causal)
+
+    jax.make_jaxpr(lambda q, k, v: flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk))(q, k, v)
+    g = gauges()
+    assert (g["flash.tiles_visited"], g["flash.tiles_total"]) == (one, (64 // bq) * (64 // bk))
+    assert (g["flash.block_q"], g["flash.block_k"]) == (bq, bk)
+
+    jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)),
+        argnums=(0, 1, 2),
+    ))(q, k, v)
+    g = gauges()
+    assert (g["flash.tiles_visited"], g["flash.tiles_total"]) == (3 * one, 3 * (64 // bq) * (64 // bk))
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_loop_bounds_visit_exactly_the_counted_tiles(causal):
+    """The kernels' own loop bounds, run as Python integers, cover each
+    counted tile once and no other, from the query side and the key side."""
+    from adapcc_tpu.ops.flash_attention import _key_blocks, _query_blocks
+
+    for T, bq, bk in [(64, 16, 16), (64, 32, 16), (64, 16, 32), (96, 24, 32), (96, 32, 24)]:
+        n_q, n_k = T // bq, T // bk
+        allowed = np.tril(np.ones((T, T), bool)).reshape(n_q, bq, n_k, bk)
+        want = {(i, j) for i in range(n_q) for j in range(n_k) if allowed[i, :, j].any() or not causal}
+        whole = {(i, j) for i in range(n_q) for j in range(n_k) if allowed[i, :, j].all()}
+        if not causal:
+            assert visited_tiles(T, bq, bk, False) == len(want)
+            continue
+        by_q = {(i, j) for i in range(n_q) for j in range(_key_blocks(i, bq, bk)[1])}
+        by_k = {(i, j) for j in range(n_k) for i in range(_query_blocks(j, bq, bk)[0], n_q)}
+        assert by_q == want and by_k == want, (T, bq, bk)
+        # the unmasked ranges hold only tiles the diagonal does not cross
+        assert {(i, j) for i in range(n_q) for j in range(_key_blocks(i, bq, bk)[0])} == whole
+        assert {(i, j) for j in range(n_k) for i in range(_query_blocks(j, bq, bk)[1], n_q)} == whole
+
+
+def test_tile_resolves_by_shape_through_one_table():
+    """``GPT2Config.flash_block`` (None by default), ``flash_autotune`` and a
+    bare ``flash_attention`` call all read ``TILE_TABLE``."""
+    from adapcc_tpu.models.gpt2 import GPT2Config
+    from adapcc_tpu.ops import flash_autotune
+
+    assert GPT2Config().flash_block is None
+    cells = default_blocks(1024, 64, jnp.bfloat16)
+    assert cells == next(tile for _, tile in TILE_TABLE)           # the first row is the cells' shape
+    assert flash_autotune.DEFAULT_BLOCK == cells[0]
+    assert default_blocks(384, 64, jnp.bfloat16) == tuple(
+        flash_autotune.resolve_block(384, b) for b in cells        # cut to a divisor of T
+    )
+    assert default_blocks(1 << 20, 256, "float64") == TILE_TABLE[-1][1]     # the last row holds every shape
+    q, k, v = _qkv(T=64, B=1, H=1, dtype=jnp.bfloat16)
+    jax.make_jaxpr(flash_attention)(q, k, v)
+    g = default_registry().snapshot()["gauges"]
+    assert (g["flash.block_q"], g["flash.block_k"]) == default_blocks(64, 16, jnp.bfloat16)
