@@ -1,23 +1,40 @@
-"""Mixture-of-Experts with expert parallelism.
+"""Mixture-of-Experts: one dropless expert layer, told which experts it holds.
 
 The reference benchmarks a fastmoe ``FMoETransformerMLP`` whose all-to-all
 dispatch is done by fastmoe/NCCL — *not* by AdapCC, whose ALLTOALL primitive
 was an unimplemented stub (SURVEY §2.3; models/moe/train_moe.py:20-41).
-Here EP is native: capacity-based top-k routing with one-hot dispatch/combine
-einsums over a stacked expert axis.  Sharding that axis over an ``experts``
-mesh axis makes XLA lower the dispatch einsums to ICI all-to-alls — the
-TPU-idiomatic form of the fastmoe shuffle; the explicit
-``CollectiveEngine.all_to_all`` covers the manual path.
+
+:func:`routed_experts` is the layer.  The router (the caller's: softmax here,
+sigmoid in :mod:`adapcc_tpu.models.trinity`) scores every token against ALL
+experts; the layer holds ``held`` of them, experts ``offset … offset + held``,
+as stacked weights.  It sorts the ``tokens × top_k`` assignments by expert,
+gathers the rows that fall to its own experts, runs each projection as ONE
+grouped product (``jax.lax.ragged_dot``: on a TPU XLA lowers it to a Mosaic
+kernel that visits only the tiles the group sizes fill), and gathers the
+weighted results back to their tokens.  Shapes are static by a bound no
+routing can exceed, ``tokens × min(top_k, held)`` rows: nothing is dropped and
+there is no capacity.  On one chip there is no exchange; what absent experts
+would have added is simply not there.
+
+:class:`MoEMLP` (the toy switch MLP of ``workloads/train_moe.py``) holds all
+its experts and runs through the same layer; across chips
+:mod:`adapcc_tpu.parallel.expert` reuses the same sort and the same grouped
+product around its all-to-all, whose fixed buffers are the one place a
+capacity remains.
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
+from typing import Callable, Dict, NamedTuple, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from adapcc_tpu.utils.observability import default_registry
 
 
 @dataclass(frozen=True)
@@ -26,6 +43,9 @@ class MoEConfig:
     d_model: int = 256
     d_hidden: int = 1024
     top_k: int = 2
+    #: size of the all-to-all's per-(rank, expert) buffers in
+    #: :mod:`adapcc_tpu.parallel.expert`; a single device drops nothing and
+    #: does not read it
     capacity_factor: float = 1.25
     dtype: jnp.dtype = jnp.bfloat16
     #: ST-MoE router z-loss: penalizes large router logits (mean logsumexp²
@@ -43,8 +63,201 @@ class MoEConfig:
         return MoEConfig(num_experts=4, d_model=32, d_hidden=64, top_k=2)
 
 
+# --------------------------------------------------------------------------- #
+# the expert layer
+# --------------------------------------------------------------------------- #
+
+
+class Assignments(NamedTuple):
+    """The ``tokens × top_k`` assignments, sorted by held expert.
+
+    ``order [M]``: the assignment (``token · top_k + choice``) in sorted row
+    ``m``; rows past ``sum(sizes)`` hold assignments of experts not held.
+    ``slot [N, k]``: the sorted row of each assignment, cut to ``[0, M)``.
+    ``here [N, k]``: whether the assignment's expert is held.
+    ``sizes [held]``: rows of each held expert, in order."""
+
+    order: jnp.ndarray
+    slot: jnp.ndarray
+    here: jnp.ndarray
+    sizes: jnp.ndarray
+
+
+def assignment_bound(tokens: int, top_k: int, held: int) -> int:
+    """Rows the held experts can be given at most: a token picks ``top_k``
+    different experts, so at most ``min(top_k, held)`` of them are held."""
+    return tokens * min(top_k, held)
+
+
+def held_assignments(ids: jnp.ndarray, offset: int, held: int) -> Assignments:
+    """Sort ``ids [N, k]`` (expert of each assignment, over ALL experts) by
+    held expert ``offset … offset + held``; the others sort to the end."""
+    n, k = ids.shape
+    bound = assignment_bound(n, k, held)
+    local = ids.reshape(n * k).astype(jnp.int32) - offset
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held)
+    order = jnp.argsort(key, stable=True)
+    slot = jnp.argsort(order)          # the inverse permutation, without a scatter
+    sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :], axis=0)
+    return Assignments(
+        order[:bound].astype(jnp.int32),
+        jnp.minimum(slot, bound - 1).reshape(n, k).astype(jnp.int32),
+        here.reshape(n, k),
+        sizes.astype(jnp.int32),
+    )
+
+
+def _rows_to_tokens(rows, sent: Assignments, weights):
+    """``y[n] = sum_j weights[n, j] · rows[slot[n, j]]`` over the held
+    assignments of token ``n``, accumulated in float32."""
+    picked = jnp.take(rows, sent.slot.reshape(-1), axis=0).reshape(*sent.slot.shape, rows.shape[-1])
+    # select, never multiply by zero: rows of no expert may hold anything.
+    # Gather, select, scale and sum fuse into one pass over the picked rows
+    # (a dot here would have them written out in float32 first)
+    picked = jnp.where(sent.here[..., None], picked, 0).astype(jnp.float32)
+    return jnp.sum(weights.astype(jnp.float32)[..., None] * picked, axis=1)
+
+
+@jax.custom_vjp
+def dispatch(x, sent: Assignments):
+    """``x [N, D]`` → the sorted rows ``[M, D]``: row ``m`` is the token of
+    assignment ``order[m]``.  Its transpose is a gather too (every held
+    assignment has one row), so the backward pass scatters nothing."""
+    return jnp.take(x, sent.order // sent.slot.shape[1], axis=0)
+
+
+def _dispatch_fwd(x, sent):
+    return dispatch(x, sent), sent
+
+
+def _dispatch_bwd(sent, d_rows):
+    dx = _rows_to_tokens(d_rows, sent, jnp.ones(sent.slot.shape, jnp.float32))
+    return dx.astype(d_rows.dtype), None
+
+
+dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine(rows, weights, sent: Assignments):
+    """The sorted rows' results ``[M, D]`` back to their tokens, each times
+    its assignment's weight (``weights [N, k]``, float32): ``[N, D]`` float32.
+    Rows past ``sum(sizes)`` are never read into the result, and get no
+    cotangent."""
+    return _rows_to_tokens(rows, sent, weights)
+
+
+def _combine_fwd(rows, weights, sent):
+    return combine(rows, weights, sent), (rows, weights, sent)
+
+
+def _combine_bwd(res, dy):
+    rows, weights, sent = res
+    k = sent.slot.shape[1]
+    filled = jnp.arange(rows.shape[0]) < jnp.sum(sent.sizes)
+    dy_rows = jnp.take(dy, sent.order // k, axis=0)                      # [M, D] float32
+    w_rows = jnp.take(weights.reshape(-1), sent.order).astype(jnp.float32)
+    d_rows = jnp.where(filled[:, None], w_rows[:, None] * dy_rows, 0.0)
+    dw_rows = jnp.sum(rows.astype(jnp.float32) * dy_rows, axis=-1)       # [M]
+    dw = jnp.where(sent.here, jnp.take(dw_rows, sent.slot.reshape(-1)).reshape(sent.slot.shape), 0.0)
+    return d_rows.astype(rows.dtype), dw.astype(weights.dtype), None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def grouped_ffn(rows, sizes, stacked: Dict[str, jnp.ndarray], act: Callable, dtype):
+    """Every held expert's MLP over its run of ``rows``: ``act(x W1) W2``,
+    gated by ``x W3`` where the stack has one.  One grouped product for each
+    projection; rows past ``sum(sizes)`` belong to no expert and come out
+    undefined (the caller masks them)."""
+    product = functools.partial(jax.lax.ragged_dot, group_sizes=sizes)
+    rows = rows.astype(dtype)
+    h = act(product(rows, stacked["w1"].astype(dtype)))
+    if "w3" in stacked:
+        h = h * product(rows, stacked["w3"].astype(dtype))
+    return product(h, stacked["w2"].astype(dtype))
+
+
+def routed_experts(
+    x: jnp.ndarray,
+    ids: jnp.ndarray,
+    weights: jnp.ndarray,
+    stacked: Dict[str, jnp.ndarray],
+    *,
+    offset: int = 0,
+    act: Callable = nn.gelu,
+    dtype=jnp.bfloat16,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The held experts' part of a routed layer.
+
+    ``x [N, D]`` tokens; ``ids [N, k]`` the experts each token chose among
+    ALL experts and ``weights [N, k]`` their float32 weights (applied to the
+    expert's output); ``stacked`` the held experts' weights (``w1 [held, D,
+    H]``, ``w2 [held, H, D]``, optionally the gate's ``w3``), experts
+    ``offset … offset + held``.  Returns ``(y [N, D] float32, sizes [held])``:
+    the sum over each token's held assignments, and how many assignments
+    each held expert was given.  No assignment of a held expert is dropped.
+
+    The rows' intermediates are recomputed in the backward pass: they are
+    sized by the bound (``tokens × top_k`` rows), eight times what a balanced
+    router fills at 16 experts of 128.
+    """
+    held = stacked["w1"].shape[0]
+    metrics = default_registry()
+    metrics.gauge("moe.experts_held", held)
+    metrics.gauge("moe.assignment_bound", assignment_bound(x.shape[0], ids.shape[1], held))
+
+    @jax.checkpoint
+    def experts(x, weights, stacked):
+        sent = held_assignments(ids, offset, held)
+        out = grouped_ffn(dispatch(x, sent), sent.sizes, stacked, act, dtype)
+        return combine(out, weights, sent), sent.sizes
+
+    with jax.named_scope("moe_experts"):
+        return experts(x, weights.astype(jnp.float32), stacked)
+
+
+def record_routing(sizes, dropped=0, metrics=None) -> None:
+    """Per-step samples from what a compiled step returned beside its loss:
+    ``sizes [layers, held]`` assignments of each held expert in each expert
+    layer.  ``moe.assignments_here`` and ``moe.load_max_over_mean`` (fullest
+    held expert ÷ mean) once for each layer; ``moe.dropped`` counts what an
+    exchange's buffers could not take (0 on one chip, by construction)."""
+    metrics = metrics or default_registry()
+    for layer in np.atleast_2d(np.asarray(sizes, np.float64)):
+        metrics.sample("moe.assignments_here", float(layer.sum()))
+        if layer.sum() > 0:
+            metrics.sample("moe.load_max_over_mean", float(layer.max() / layer.mean()))
+    metrics.incr("moe.dropped", float(np.sum(np.asarray(dropped))))
+
+
+# --------------------------------------------------------------------------- #
+# the toy switch MLP
+# --------------------------------------------------------------------------- #
+
+
+def softmax_router(logits: jnp.ndarray, top_k: int, z_coef: float, mean=lambda x: x):
+    """``(ids [N, k], weights [N, k], aux)`` of a softmax router: the top-k
+    probabilities as weights, the switch load-balancing loss (times
+    ``num_experts``) plus the optional z-loss.  ``mean`` averages the
+    per-shard statistics across shards where tokens are sharded."""
+    num_experts = logits.shape[-1]
+    probs = jax.nn.softmax(logits, axis=-1)
+    me = mean(jnp.mean(probs, axis=0))
+    ce = mean(jnp.mean(jax.nn.one_hot(jnp.argmax(probs, axis=-1), num_experts), axis=0))
+    aux = num_experts * jnp.sum(me * ce)
+    if z_coef:
+        z = jax.nn.logsumexp(logits, axis=-1)
+        aux = aux + z_coef * mean(jnp.mean(z**2))
+    weights, ids = jax.lax.top_k(probs, top_k)
+    return ids, weights, aux
+
+
 class MoEMLP(nn.Module):
-    """Top-k routed expert MLP (switch-style dispatch, static capacity)."""
+    """Top-k routed expert MLP (softmax router, GELU experts), every expert
+    held here."""
 
     cfg: MoEConfig
 
@@ -53,59 +266,20 @@ class MoEMLP(nn.Module):
         """``x [B, T, D]`` → ``(y [B, T, D], aux_loss scalar)``."""
         cfg = self.cfg
         B, T, D = x.shape
-        n_tokens = B * T
-        capacity = int(np.ceil(cfg.capacity_factor * cfg.top_k * n_tokens / cfg.num_experts))
-        tokens = x.reshape(n_tokens, D)
+        tokens = x.reshape(B * T, D)
 
         # routing (fp32 for a stable softmax)
-        gate_logits = nn.Dense(cfg.num_experts, dtype=jnp.float32, name="router")(
-            tokens.astype(jnp.float32)
-        )
-        gate_probs = jax.nn.softmax(gate_logits, axis=-1)
-
-        # load-balancing auxiliary loss (switch-transformer form)
-        me = jnp.mean(gate_probs, axis=0)
-        ce = jnp.mean(
-            jax.nn.one_hot(jnp.argmax(gate_probs, axis=-1), cfg.num_experts), axis=0
-        )
-        aux_loss = cfg.num_experts * jnp.sum(me * ce)
-        if cfg.router_z_coef:
-            z = jax.nn.logsumexp(gate_logits, axis=-1)  # [tokens]
-            aux_loss = aux_loss + cfg.router_z_coef * jnp.mean(z**2)
-
-        # top-k dispatch with per-expert positional capacity
-        combine = jnp.zeros((n_tokens, cfg.num_experts, capacity), dtype=jnp.float32)
-        remaining = gate_probs
-        used = jnp.zeros((cfg.num_experts,), dtype=jnp.int32)
-        for _ in range(cfg.top_k):
-            choice = jnp.argmax(remaining, axis=-1)                    # [tokens]
-            prob = jnp.take_along_axis(remaining, choice[:, None], 1)[:, 0]
-            onehot = jax.nn.one_hot(choice, cfg.num_experts, dtype=jnp.int32)
-            # position of each token within its chosen expert's buffer
-            pos_in_expert = (jnp.cumsum(onehot, axis=0) - onehot) + used[None, :]
-            pos = jnp.sum(onehot * pos_in_expert, axis=-1)             # [tokens]
-            keep = pos < capacity
-            combine = combine + (
-                (prob * keep)[:, None, None]
-                * jax.nn.one_hot(choice, cfg.num_experts)[:, :, None]
-                * jax.nn.one_hot(pos, capacity)[:, None, :]
+        with jax.named_scope("moe_route"):
+            gate_logits = nn.Dense(cfg.num_experts, dtype=jnp.float32, name="router")(
+                tokens.astype(jnp.float32)
             )
-            used = used + jnp.sum(onehot * keep[:, None], axis=0)
-            remaining = remaining * (1.0 - jax.nn.one_hot(choice, cfg.num_experts))
+            ids, weights, aux_loss = softmax_router(gate_logits, cfg.top_k, cfg.router_z_coef)
 
-        dispatch = (combine > 0).astype(cfg.dtype)                     # [tokens, E, C]
-
-        # expert computation over the stacked expert axis; sharding this axis
-        # over an "experts" mesh axis yields all-to-all dispatch under pjit
         w1 = self.param(
             "w1", nn.initializers.normal(0.02), (cfg.num_experts, D, cfg.d_hidden)
         )
         w2 = self.param(
             "w2", nn.initializers.normal(0.02), (cfg.num_experts, cfg.d_hidden, D)
         )
-        expert_in = jnp.einsum("nec,nd->ecd", dispatch, tokens.astype(cfg.dtype))
-        h = nn.gelu(jnp.einsum("ecd,edh->ech", expert_in, w1.astype(cfg.dtype)))
-        expert_out = jnp.einsum("ech,ehd->ecd", h, w2.astype(cfg.dtype))
-        y = jnp.einsum("nec,ecd->nd", combine.astype(cfg.dtype), expert_out)
-
+        y, _ = routed_experts(tokens, ids, weights, {"w1": w1, "w2": w2}, dtype=cfg.dtype)
         return y.reshape(B, T, D).astype(x.dtype), aux_loss

@@ -35,6 +35,21 @@ traced ``program_id`` cost 3.4 times as much for each tile on a v5e; long
 sequences (past ``_STRAIGHT_LINE_ELEMENTS``) take that loop.  ``causal=False``
 (ring attention's off-diagonal shards) visits every tile from one body.
 
+**A window.**  ``window=W`` (with ``causal=True``) lets query ``t`` see key
+``s`` only where ``0 <= t - s < W``: a band under the diagonal.  A query
+block then has a *first* key block as well as a last, and the mask is applied
+on both edges of the band (:func:`_key_span`, :func:`_query_span`).  The
+band's shape repeats from block to block, so with square tiles the positions
+whose bounds differ only by their own index share ONE written-out body
+(bounds static relative to the traced position): at T=8,192, W=2,048 and
+512-tiles that is four leading bodies plus one, 15 tiles of code, where one
+body for each of the 16 positions would be 70.
+
+**Grouped KV heads.**  ``k`` and ``v`` may carry fewer heads than ``q``
+(``H_q = G * H_kv``): query head ``h`` reads KV head ``h // G`` through the
+K/V block index, nothing is repeated in HBM, and the dk/dv kernel writes one
+fp32 partial for each query head that the wrapper sums over the group.
+
 **Which dtype.**  The seven products take their operands in the inputs' own
 dtype and accumulate in fp32: bf16 q/k/v/do go to the MXU as bf16, and ``p``
 and ``dS`` are cast to that dtype for their four products (what the XLA
@@ -52,7 +67,8 @@ takes q, k, v and returns two arrays; both backward kernels take q, k, v, do,
 lse, delta, dq returning one array and dk/dv a tuple of two.  So no
 scalar-prefetch operand, no fused dq+dkv kernel, no split forward (VMEM scratch
 is not an operand); the ``name=`` of each ``pallas_call`` stays
-(tests/test_flash_attention.py guards both).
+(tests/test_flash_attention.py guards both).  A window and grouped heads
+change neither: the window is a static bound, the group an index map.
 
 Used by the GPT-2 flagship model when ``GPT2Config.attention == "flash"``;
 long-context cross-chip attention composes this with the ring/Ulysses
@@ -95,6 +111,41 @@ def _causal_mask(s, qi, kj, block_q, block_k, q_axis=0):
     return jnp.where(k_pos <= q_pos, s, _NEG_INF)
 
 
+def _band_mask(s, offset, window: int, q_axis=0):
+    """Mask a tile whose first query lies ``offset`` positions after its
+    first key: key ``s`` is seen by query ``t`` where ``0 <= t - s < window``
+    (both edges of the band at once)."""
+    ahead = (
+        offset
+        + lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+        - lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    )
+    return jnp.where((ahead >= 0) & (ahead < window), s, _NEG_INF)
+
+
+def _mask(s, base, q_rel, k_rel, block_q, block_k, window, q_axis=0):
+    """Mask the tile of query block ``base + q_rel`` and key block
+    ``base + k_rel`` (``base`` is 0, or the traced position of a body that
+    several positions share: then the tiles are square and it cancels)."""
+    if window is None:
+        return _causal_mask(s, _at(base, q_rel), _at(base, k_rel), block_q, block_k, q_axis)
+    return _band_mask(s, q_rel * block_q - k_rel * block_k, window, q_axis)
+
+
+def _at(base, rel):
+    """Block ``base + rel``; ``base`` is the integer 0 except in a body that
+    several positions share."""
+    return rel if isinstance(base, int) and base == 0 else base + rel
+
+
+def _least(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.minimum(a, b)
+
+
+def _most(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.maximum(a, b)
+
+
 def _key_blocks(qi: int, block_q: int, block_k: int) -> Tuple[int, int]:
     """Key blocks a causal query block ``qi`` needs: ``[0, full)`` lie wholly
     at or under the diagonal (last column <= first row), ``[full, end)`` are
@@ -109,14 +160,45 @@ def _query_blocks(kj: int, block_q: int, block_k: int) -> Tuple[int, int]:
     return (kj * block_k) // block_q, ((kj + 1) * block_k + block_q - 2) // block_q
 
 
-def visited_tiles(T: int, block_q: int, block_k: int, causal: bool) -> int:
+def _key_span(qi, block_q: int, block_k: int, window: Optional[int]):
+    """``(lo, a, b, end)`` for causal query block ``qi`` (an integer or
+    traced): key blocks ``[lo, a)`` and ``[b, end)`` need the mask (the
+    window's far edge; the diagonal, and both where the window is narrower
+    than a tile), ``[a, b)`` lie wholly inside the band."""
+    full, end = _key_blocks(qi, block_q, block_k)
+    if window is None:
+        return 0, 0, full, end
+    first_row = qi * block_q
+    lo = _most(first_row - window + 1, 0) // block_k
+    a = _most(first_row + block_q - window + block_k - 1, 0) // block_k
+    return lo, a, _most(full, a), end
+
+
+def _query_span(kj, block_q: int, block_k: int, window: Optional[int], n_q: int):
+    """``(start, a, b, end)`` for causal key block ``kj``: query blocks
+    ``[start, a)`` and ``[b, end)`` need the mask, ``[a, b)`` see the whole
+    key block."""
+    start, full = _query_blocks(kj, block_q, block_k)
+    if window is None:
+        return start, full, n_q, n_q
+    first_col = kj * block_k
+    end = _least((first_col + block_k + window - 2) // block_q + 1, n_q)
+    a = _least(full, end)
+    return start, a, _least(_most((first_col + window) // block_q, a), end), end
+
+
+def visited_tiles(
+    T: int, block_q: int, block_k: int, causal: bool, window: Optional[int] = None
+) -> int:
     """``[block_q, block_k]`` tiles of one ``[T, T]`` score plane that each of
     the three kernels visits: every tile without a mask, and under the causal
-    mask exactly those with an unmasked element."""
+    mask (and the window, where one is given) exactly those with an unmasked
+    element."""
     n_q, n_k = T // block_q, T // block_k
     if not causal:
         return n_q * n_k
-    return sum(_key_blocks(qi, block_q, block_k)[1] for qi in range(n_q))
+    spans = (_key_span(qi, block_q, block_k, window) for qi in range(n_q))
+    return sum(end - lo for lo, _, _, end in spans)
 
 
 #: Score-plane elements (tiles x block_q x block_k) a causal kernel writes out
@@ -129,20 +211,47 @@ def visited_tiles(T: int, block_q: int, block_k: int, causal: bool) -> int:
 _STRAIGHT_LINE_ELEMENTS = 1 << 22
 
 
-def _per_program(body, n: int, T: int, block_q: int, block_k: int, causal: bool) -> None:
-    """``body(i)`` with ``i`` the grid position along the block axis (of ``n``).
-    Under the causal mask the positions differ in their bounds: each gets its
-    own copy of the body with ``i`` a Python integer, where that keeps the code
-    small enough (every bound and slice is then static and Mosaic schedules a
-    block's tiles as one basic block); else ``i`` is the traced
-    ``pl.program_id``.  Without the mask one body serves them all."""
-    if not causal or n == 1:
-        return body(0)
-    position = pl.program_id(1)
-    if visited_tiles(T, block_q, block_k, True) * block_q * block_k > _STRAIGHT_LINE_ELEMENTS:
-        return body(position)
+def _bodies(n: int, span, share: bool):
+    """The written-out bodies of ``n`` grid positions: ``(first, last,
+    bounds)`` runs, ``bounds`` those of ``first``.  A run longer than one
+    holds consecutive positions whose bounds differ only by the position's
+    own index (the interior of a window's band, square tiles): they share one
+    body.  Without ``share`` every position is its own run."""
+    runs = []
     for i in range(n):
-        pl.when(position == i)(functools.partial(body, i))
+        bounds = span(i)
+        rel = tuple(x - i for x in bounds)
+        if share and runs and runs[-1][3] == rel:
+            runs[-1][1] = i
+        else:
+            runs.append([i, i, bounds, rel])
+    return [(first, last, bounds) for first, last, bounds, _ in runs]
+
+
+def _per_program(body, n: int, span, block_q: int, block_k: int, causal: bool, window) -> None:
+    """``body(rel, bounds, base)`` for the grid position ``base + rel`` along
+    the block axis (of ``n``), ``bounds = span(position)`` counted from
+    ``base``.  Under the causal mask the positions differ in their bounds:
+    each gets its own copy of the body with the position a Python integer
+    (``base`` 0), where that keeps the code small enough (every bound and
+    slice is then static and Mosaic schedules a block's tiles as one basic
+    block); the positions inside a window's band share one copy, ``base`` the
+    traced position and the bounds static from it.  Else ``rel`` is the traced
+    ``pl.program_id`` and the bounds are traced.  Without the mask one body
+    serves them all."""
+    if not causal or n == 1:
+        return body(0, span(0), 0)
+    position = pl.program_id(1)
+    runs = _bodies(n, span, share=window is not None and block_q == block_k)
+    written = sum(bounds[3] - bounds[0] for _, _, bounds in runs)
+    if written * block_q * block_k > _STRAIGHT_LINE_ELEMENTS:
+        return body(position, span(position), 0)
+    for first, last, bounds in runs:
+        if first == last:
+            pl.when(position == first)(functools.partial(body, first, bounds, 0))
+        else:
+            shared = functools.partial(body, 0, tuple(x - first for x in bounds), position)
+            pl.when((position >= first) & (position <= last))(shared)
 
 
 def _tiles(lo, hi, tile, carry):
@@ -153,6 +262,14 @@ def _tiles(lo, hi, tile, carry):
             carry = tile(j, carry)
         return carry
     return lax.fori_loop(lo, hi, tile, carry)
+
+
+def _band(bounds, tile, carry):
+    """The three runs of a span: masked, unmasked, masked."""
+    lo, a, b, end = bounds
+    carry = _tiles(lo, a, functools.partial(tile, masked=True), carry)
+    carry = _tiles(a, b, functools.partial(tile, masked=False), carry)
+    return _tiles(b, end, functools.partial(tile, masked=True), carry)
 
 
 def _dot(a, b, contract):
@@ -173,18 +290,27 @@ def _rows(ref, i, block: int):
     return ref[0, _block(i, block), :]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k):
+def _held(base, r, first, band):
+    """Where block ``base + r`` lies in a ref that holds the whole sequence
+    (``band`` None) or only ``band`` blocks from block ``first`` on, counted
+    from ``base`` like ``r``."""
+    return _at(base, r) if band is None else r - first
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k, window, seq, band):
     q = q_ref[0]
     bq, d = q.shape
-    T = k_ref.shape[1]
+    T = seq
+    n_k = T // block_k
 
-    def query_block(qi):
-        def tile(j, carry, masked):
+    def query_block(rel, bounds, base):
+        def tile(r, carry, masked):
             m, l, acc = carry
-            k, v = _rows(k_ref, j, block_k), _rows(v_ref, j, block_k)
+            at = _held(base, r, bounds[0], band)
+            k, v = _rows(k_ref, at, block_k), _rows(v_ref, at, block_k)
             s = _dot(q, k, (1, 1)) * scale
             if masked:
-                s = _causal_mask(s, qi, j, block_q, block_k)
+                s = _mask(s, base, rel, r, block_q, block_k, window)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)
@@ -192,84 +318,94 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, 
             acc = acc * alpha + _dot(p.astype(v.dtype), v, (1, 0))
             return m_new, l, acc
 
-        full, end = _key_blocks(qi, block_q, block_k) if causal else (T // block_k,) * 2
         carry = (
             jnp.full((bq, 1), _NEG_INF, jnp.float32),
             jnp.zeros((bq, 1), jnp.float32),
             jnp.zeros((bq, d), jnp.float32),
         )
-        carry = _tiles(0, full, functools.partial(tile, masked=False), carry)
-        m, l, acc = _tiles(full, end, functools.partial(tile, masked=True), carry)
+        m, l, acc = _band(bounds, tile, carry)
         o_ref[0] = (acc / l).astype(o_ref.dtype)
         lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (bq, _LSE_LANES))
 
-    _per_program(query_block, T // block_q, T, block_q, block_k, causal)
+    def span(qi):
+        return _key_span(qi, block_q, block_k, window) if causal else (0, 0, n_k, n_k)
+
+    _per_program(query_block, T // block_q, span, block_q, block_k, causal, window)
 
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-    *, scale, causal, block_q, block_k,
+    *, scale, causal, block_q, block_k, window, seq, band,
 ):
     q = q_ref[0]
     do = do_ref[0]
     lse_col = lse_ref[0][:, 0:1]      # [bq, 1] from the lane-padded layout
     delta_col = delta_ref[0][:, 0:1]
     bq, d = q.shape
-    T = k_ref.shape[1]
+    T = seq
+    n_k = T // block_k
 
-    def query_block(qi):
-        def tile(j, dq, masked):
-            k, v = _rows(k_ref, j, block_k), _rows(v_ref, j, block_k)
+    def query_block(rel, bounds, base):
+        def tile(r, dq, masked):
+            at = _held(base, r, bounds[0], band)
+            k, v = _rows(k_ref, at, block_k), _rows(v_ref, at, block_k)
             s = _dot(q, k, (1, 1)) * scale
             if masked:
-                s = _causal_mask(s, qi, j, block_q, block_k)
+                s = _mask(s, base, rel, r, block_q, block_k, window)
             p = jnp.exp(s - lse_col)
             ds = p * (_dot(do, v, (1, 1)) - delta_col) * scale
             return dq + _dot(ds.astype(k.dtype), k, (1, 0))
 
-        full, end = _key_blocks(qi, block_q, block_k) if causal else (T // block_k,) * 2
-        dq = _tiles(0, full, functools.partial(tile, masked=False), jnp.zeros((bq, d), jnp.float32))
-        dq = _tiles(full, end, functools.partial(tile, masked=True), dq)
+        dq = _band(bounds, tile, jnp.zeros((bq, d), jnp.float32))
         dq_ref[0] = dq.astype(dq_ref.dtype)
 
-    _per_program(query_block, T // block_q, T, block_q, block_k, causal)
+    def span(qi):
+        return _key_span(qi, block_q, block_k, window) if causal else (0, 0, n_k, n_k)
+
+    _per_program(query_block, T // block_q, span, block_q, block_k, causal, window)
 
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    *, scale, causal, block_q, block_k,
+    *, scale, causal, block_q, block_k, window, seq, band,
 ):
     k = k_ref[0]
     v = v_ref[0]
     bk, d = k.shape
-    T = q_ref.shape[1]
+    T = seq
+    n_q = T // block_q
 
-    def key_block(kj):
-        def tile(i, carry, masked):
+    def key_block(rel, bounds, base):
+        # a band of queries starts at this key block, or as late as fits
+        first = None if band is None else 0 if not isinstance(base, int) else _least(rel, n_q - band)
+
+        def tile(r, carry, masked):
             # the tile transposed, [bk, bq]: all four products then contract
             # in the MXU's own orientations (no transposed left operand), and
             # lse and delta are rows
             dk, dv = carry
+            i = _held(base, r, first, band)
             q, do = _rows(q_ref, i, block_q), _rows(do_ref, i, block_q)
             lse_row = lse_ref[0, :, _block(i, block_q)]
             delta_row = delta_ref[0, :, _block(i, block_q)]
             s = _dot(k, q, (1, 1)) * scale
             if masked:
-                s = _causal_mask(s, i, kj, block_q, block_k, q_axis=1)
+                s = _mask(s, base, r, rel, block_q, block_k, window, q_axis=1)
             p = jnp.exp(s - lse_row)
             dv = dv + _dot(p.astype(do.dtype), do, (1, 0))
             ds = p * (_dot(v, do, (1, 1)) - delta_row) * scale
             dk = dk + _dot(ds.astype(q.dtype), q, (1, 0))
             return dk, dv
 
-        start, full = _query_blocks(kj, block_q, block_k) if causal else (0, 0)
         zeros = jnp.zeros((bk, d), jnp.float32)
-        carry = _tiles(start, full, functools.partial(tile, masked=True), (zeros, zeros))
-        dk, dv = _tiles(full, T // block_q, functools.partial(tile, masked=False), carry)
+        dk, dv = _band(bounds, tile, (zeros, zeros))
         dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv.astype(dv_ref.dtype)
 
-    _per_program(key_block, T // block_k, T, block_q, block_k, causal)
+    def span(kj):
+        return _query_span(kj, block_q, block_k, window, n_q) if causal else (0, 0, n_q, n_q)
+
+    _per_program(key_block, T // block_k, span, block_q, block_k, causal, window)
 
 
 #: The tile by shape, measured on a TPU v5e (PERF.md §6, PR 25): rows of
@@ -281,6 +417,7 @@ def _dkv_kernel(
 TILE_TABLE = (
     ((4096, 64, 2), (512, 512)),   # bf16, measured at T=1,024 (B.H = 144 and 32) and T=4,096
     ((1024, 64, 4), (512, 512)),   # fp32, measured at T=1,024
+    ((8192, 128, 2), (512, 512)),  # bf16, head size 128 at T=8,192, window 2,048 and none (PERF.md §6, PR 26)
     ((float("inf"),) * 3, (128, 128)),   # not measured: the tile every shape ran before
 )
 
@@ -324,31 +461,77 @@ def _block_sizes(T: int, D: int, dtype, block_q: Optional[int], block_k: Optiona
     return bq, bk
 
 
-def _record_tiles(T: int, bq: int, bk: int, causal: bool, kernels: int) -> None:
+def _record_tiles(T: int, bq: int, bk: int, causal: bool, kernels: int, window, groups: int) -> None:
     """Trace-time gauges (once per compile, nothing per step): the tiles of
     one ``[T, T]`` score plane the attention call's kernels visit and would
-    visit without skipping, summed over ``kernels`` of them, and the tile."""
+    visit without skipping, summed over ``kernels`` of them, the tile, the
+    window (0: none) and the query heads that share a KV head."""
     metrics = default_registry()
-    metrics.gauge("flash.tiles_visited", kernels * visited_tiles(T, bq, bk, causal))
+    metrics.gauge("flash.tiles_visited", kernels * visited_tiles(T, bq, bk, causal, window))
     metrics.gauge("flash.tiles_total", kernels * (T // bq) * (T // bk))
     metrics.gauge("flash.block_q", bq)
     metrics.gauge("flash.block_k", bk)
+    metrics.gauge("flash.window", window or 0)
+    metrics.gauge("flash.kv_groups", groups)
+
+
+#: Bytes of whole-sequence operands (K and V, or q and do, double-buffered)
+#: past which a kernel asks Mosaic for more than its default 16 MiB of scoped
+#: VMEM; under it no compiler parameter is passed at all (GPT-2's shapes).
+_VMEM_ASK_OVER = 6 << 20
+_VMEM_LIMIT = 96 << 20
+
+
+def _compiler_params(T: int, D: int, dtype) -> dict:
+    resident = 2 * 2 * T * D * jnp.dtype(dtype).itemsize
+    if resident <= _VMEM_ASK_OVER:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)}
+
+
+def _band_blocks(T: int, bq: int, bk: int, window) -> Optional[int]:
+    """Blocks of K and V a query block's band spans (and of q and do a key
+    block's), where only those need be resident: a window, square tiles (the
+    band then starts a fixed number of blocks before the diagonal) and a band
+    shorter than the sequence.  None: the whole sequence is resident."""
+    if window is None or bq != bk:
+        return None
+    blocks = (window - 1 + bk - 1) // bk + 1
+    return blocks if blocks < T // bk else None
+
+
+def _resident(T: int, D: int, band: Optional[int], block: int, index, first):
+    """The spec of a ``[·, T, D]`` operand held whole, or (``band`` blocks)
+    from block ``first(i)`` of grid position ``i`` on: element-indexed, the
+    offset a multiple of the block so that Mosaic can prove it aligned."""
+    if band is None:
+        return pl.BlockSpec((1, T, D), lambda b, i: (index(b), 0, 0))
+    return pl.BlockSpec(
+        (pl.Element(1), pl.Element(band * block), pl.Element(D)),
+        lambda b, i: (index(b), first(i) * block, 0),
+    )
+
+
+def _kv_index(groups: int):
+    """Query head ``b`` of ``[B·H_q]`` reads KV head ``b // groups`` of
+    ``[B·H_kv]`` (``H_q = groups · H_kv``, heads minor)."""
+    return (lambda b: b) if groups == 1 else (lambda b: b // groups)
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
 )
-def _flash_bhtd(q, k, v, scale, causal, block_q, block_k, interpret):
-    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret)
+def _flash_bhtd(q, k, v, scale, causal, block_q, block_k, interpret, window):
+    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window)
     return out
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window):
     _, T, D = q.shape
     bq, bk = _block_sizes(T, D, q.dtype, block_q, block_k)
-    _record_tiles(T, bq, bk, causal, kernels=1)
+    _record_tiles(T, bq, bk, causal, 1, window, q.shape[0] // k.shape[0])
     interp = resolve_interpret(interpret, "flash_attention")
-    out, lse = _fwd_call(q, k, v, scale, causal, bq, bk, interp)
+    out, lse = _fwd_call(q, k, v, scale, causal, bq, bk, interp, window)
     return out, (q, k, v, out, lse)
 
 
@@ -356,19 +539,20 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
 # with one shape and one set of parameters, share one traced and lowered kernel:
 # JAX traces a pallas_call's kernel anew at every call site, and a 24-layer
 # step pays 72 of them, twice (PERF.md §6, PR 25: the step's trace).
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
-def _fwd_call(q, k, v, scale, causal, bq, bk, interp):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def _fwd_call(q, k, v, scale, causal, bq, bk, interp, window):
     BH, T, D = q.shape
+    kv = _kv_index(BH // k.shape[0])
+    band = _band_blocks(T, bq, bk, window)
+    ahead = 0 if band is None else band - 1     # the band starts this many blocks before the diagonal
+    held = _resident(T, D, band, bk, kv, lambda i: jnp.maximum(i - ahead, 0))
     out, lse3 = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk
+            _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk, window=window,
+            seq=T, band=band,
         ),
         grid=(BH, T // bq),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, T, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, T, D), lambda b, i: (b, 0, 0)),
-        ],
+        in_specs=[pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)), held, held],
         out_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, bq, _LSE_LANES), lambda b, i: (b, i, 0)),
@@ -379,31 +563,34 @@ def _fwd_call(q, k, v, scale, causal, bq, bk, interp):
         ],
         interpret=interp,
         name="flash_fwd",
+        **_compiler_params(T if band is None else band * bk, D, k.dtype),
     )(q, k, v)
     return out, lse3[:, :, 0]
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, do):
-    return _flash_bwd_core(scale, causal, block_q, block_k, interpret, res, do, None)
+def _flash_bwd(scale, causal, block_q, block_k, interpret, window, res, do):
+    return _flash_bwd_core(scale, causal, block_q, block_k, interpret, window, res, do, None)
 
 
-def _flash_bwd_core(scale, causal, block_q, block_k, interpret, res, do, dlse):
+def _flash_bwd_core(scale, causal, block_q, block_k, interpret, window, res, do, dlse):
     """Shared backward.  An ``lse`` cotangent adds ``dS_ij += p_ij·dlse_i``,
     which folds into the existing kernels as ``delta → delta − dlse`` (the
     bracket is ``p·(dp − delta)``) — no kernel change needed."""
-    q = res[0]
+    q, k = res[0], res[1]
     _, T, D = q.shape
     bq, bk = _block_sizes(T, D, q.dtype, block_q, block_k)
     # a backward pass closes an attention call: its forward kernel and these two
-    _record_tiles(T, bq, bk, causal, kernels=3)
+    _record_tiles(T, bq, bk, causal, 3, window, q.shape[0] // k.shape[0])
     interp = resolve_interpret(interpret, "flash_attention")
-    return _bwd_call(res, do, dlse, scale, causal, bq, bk, interp)
+    return _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
-def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window):
     q, k, v, out, lse = res
     BH, T, D = q.shape
+    groups = BH // k.shape[0]
+    kv = _kv_index(groups)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
@@ -411,15 +598,20 @@ def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp):
     lse3 = jnp.broadcast_to(lse[..., None], (BH, T, _LSE_LANES))
     delta3 = jnp.broadcast_to(delta[..., None], (BH, T, _LSE_LANES))
 
+    band = _band_blocks(T, bq, bk, window)
+    rows = T if band is None else band * bk
+    ahead = 0 if band is None else band - 1
+    held = _resident(T, D, band, bk, kv, lambda i: jnp.maximum(i - ahead, 0))
     dq = pl.pallas_call(
         functools.partial(
-            _dq_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk
+            _dq_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk, window=window,
+            seq=T, band=band,
         ),
         grid=(BH, T // bq),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, T, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, T, D), lambda b, i: (b, 0, 0)),
+            held,
+            held,
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, bq, _LSE_LANES), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, bq, _LSE_LANES), lambda b, i: (b, i, 0)),
@@ -428,74 +620,100 @@ def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp):
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         interpret=interp,
         name="flash_bwd_dq",
+        **_compiler_params(rows, D, k.dtype),
     )(q, k, v, do, lse3, delta3)
 
+    # grouped heads: each query head writes its own fp32 share of dk and dv
+    # (a grid program owns its output block), summed over the group below
+    part = jnp.float32 if groups > 1 else None
+    last = 0 if band is None else T // bq - band    # the last block a band of queries can start at
+    seen = _resident(T, D, band, bq, lambda b: b, lambda j: jnp.minimum(j, last))
+    if band is None:
+        stat = pl.BlockSpec((1, 1, T), lambda b, j: (b, 0, 0))
+    else:
+        stat = pl.BlockSpec(
+            (pl.Element(1), pl.Element(1), pl.Element(rows)),
+            lambda b, j: (b, 0, jnp.minimum(j, last) * bq),
+        )
     dk, dv = pl.pallas_call(
         functools.partial(
-            _dkv_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk
+            _dkv_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk, window=window,
+            seq=T, band=band,
         ),
         grid=(BH, T // bk),
         in_specs=[
-            pl.BlockSpec((1, T, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, T, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1, T), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1, T), lambda b, j: (b, 0, 0)),
+            seen,
+            pl.BlockSpec((1, bk, D), lambda b, j: (kv(b), j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, j: (kv(b), j, 0)),
+            seen,
+            stat,
+            stat,
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, T, D), v.dtype),
+            jax.ShapeDtypeStruct((BH, T, D), part or k.dtype),
+            jax.ShapeDtypeStruct((BH, T, D), part or v.dtype),
         ],
         interpret=interp,
         name="flash_bwd_dkv",
+        **_compiler_params(rows, D, q.dtype),
     )(q, k, v, do, lse[:, None, :], delta[:, None, :])
+    if groups > 1:
+        dk = dk.reshape(BH // groups, groups, T, D).sum(axis=1).astype(k.dtype)
+        dv = dv.reshape(BH // groups, groups, T, D).sum(axis=1).astype(v.dtype)
     return dq, dk, dv
 
 
 _flash_bhtd.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bhtd_lse(q, k, v, scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_bhtd_lse(q, k, v, scale, causal, block_q, block_k, interpret, window):
     """Like :func:`_flash_bhtd` but also returns the per-row logsumexp —
     the merge statistic blockwise consumers (ring attention) need.  Both
     outputs are differentiable: the ``lse`` cotangent lowers to the same
     backward kernels via ``delta − dlse``."""
-    out, (_, _, _, _, lse) = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret)
+    out, (_, _, _, _, lse) = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window)
     return out, lse
 
 
-def _flash_fwd_lse(q, k, v, scale, causal, block_q, block_k, interpret):
-    out, res = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret)
+def _flash_fwd_lse(q, k, v, scale, causal, block_q, block_k, interpret, window):
+    out, res = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window)
     return (out, res[4]), res
 
 
-def _flash_bwd_lse(scale, causal, block_q, block_k, interpret, res, cts):
+def _flash_bwd_lse(scale, causal, block_q, block_k, interpret, window, res, cts):
     do, dlse = cts
-    return _flash_bwd_core(scale, causal, block_q, block_k, interpret, res, do, dlse)
+    return _flash_bwd_core(scale, causal, block_q, block_k, interpret, window, res, do, dlse)
 
 
 _flash_bhtd_lse.defvjp(_flash_fwd_lse, _flash_bwd_lse)
 
 
-def _bthd_call(kernel_entry, q, k, v, causal, scale, block_q, block_k, interpret):
+def _bthd_call(kernel_entry, q, k, v, causal, scale, block_q, block_k, interpret, window=None):
     """Shared model-layout plumbing for the public wrappers: validate,
     default the scale, run ``kernel_entry`` on ``[B·H, T, D]`` tensors, and
     return its raw outputs plus the dims needed to restore the layout."""
     B, T, H, D = q.shape
-    if k.shape != q.shape or v.shape != q.shape:
-        raise ValueError(f"q/k/v shapes differ: {q.shape} {k.shape} {v.shape}")
+    Hkv = k.shape[2] if k.ndim == 4 else 0
+    if k.shape != v.shape or k.shape != (B, T, Hkv, D) or Hkv == 0 or H % Hkv:
+        raise ValueError(
+            f"q/k/v shapes differ: {q.shape} {k.shape} {v.shape} (k and v may "
+            "only carry fewer heads than q, a whole number of query heads to each)"
+        )
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"window={window} needs causal=True and at least one position")
+        window = None if window >= T else int(window)   # a band over the whole triangle
     if scale is None:
         scale = float(1.0 / np.sqrt(D))
-    to_bhtd = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, T, D)  # noqa: E731
+    to_bhtd = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, T, D)  # noqa: E731
     raw = kernel_entry(
         to_bhtd(q), to_bhtd(k), to_bhtd(v),
-        scale, causal, block_q, block_k, interpret,
+        scale, causal, block_q, block_k, interpret, window,
     )
     return raw, (B, T, H, D)
 
@@ -509,6 +727,7 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Blockwise attention over ``[B, T, H, D]`` tensors (model layout).
 
@@ -517,10 +736,13 @@ def flash_attention(
     same call works on the virtual CPU pod.  ``scale`` defaults to
     ``1/sqrt(D)``.  ``block_q`` / ``block_k`` default to the tile the table
     holds for ``(T, D, dtype)`` (:func:`default_blocks`); given, ``T`` must
-    divide by them (clamped to ``T``).
+    divide by them (clamped to ``T``).  ``window=W`` (causal only) lets a
+    query see the ``W`` keys up to and including its own position; ``k`` and
+    ``v`` may be ``[B, T, H_kv, D]`` with ``H`` a multiple of ``H_kv``
+    (query head ``h`` reads KV head ``h // (H // H_kv)``).
     """
     out, (B, T, H, D) = _bthd_call(
-        _flash_bhtd, q, k, v, causal, scale, block_q, block_k, interpret
+        _flash_bhtd, q, k, v, causal, scale, block_q, block_k, interpret, window
     )
     return out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 

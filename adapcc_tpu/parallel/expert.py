@@ -4,10 +4,16 @@ The reference's ALLTOALL primitive is an unimplemented stub — its MoE
 workload delegates the shuffle to fastmoe/NCCL (SURVEY §2.3,
 models/moe/train_moe.py:20-41).  Here the all-to-all is native:
 each rank owns ``E / world`` experts and a token shard; routing happens
-locally, per-expert buffers are exchanged with ``lax.all_to_all`` over ICI,
-experts run on their home rank, and a second all-to-all brings results back
-for the weighted combine.  Capacity is static per (rank, expert) so every
-shape is fixed under jit.
+locally over all experts, per-expert buffers are exchanged with
+``lax.all_to_all`` over ICI, experts run on their home rank, and a second
+all-to-all brings results back for the weighted combine.
+
+The sort of assignments by expert, the grouped product and the weighted
+gather back are :mod:`adapcc_tpu.models.moe`'s, the same layer a single
+device runs.  What this module adds is the exchange, and with it the one
+capacity left: an all-to-all moves buffers of a fixed size, so each (rank,
+expert) pair gets ``moe_capacity`` rows and a rank's assignments past that
+are dropped before they are sent.
 """
 
 from __future__ import annotations
@@ -21,7 +27,14 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 
-from adapcc_tpu.models.moe import MoEConfig
+from adapcc_tpu.models.moe import (
+    MoEConfig,
+    combine,
+    dispatch,
+    grouped_ffn,
+    held_assignments,
+    softmax_router,
+)
 
 
 def moe_capacity(cfg: MoEConfig, n_loc: int) -> int:
@@ -62,58 +75,40 @@ def _moe_shard(
     E = cfg.num_experts
     e_loc = w1.shape[0]
 
-    # --- local routing (fp32 softmax) ------------------------------------
+    # --- local routing over all experts (fp32 softmax) --------------------
     logits = x.astype(jnp.float32) @ router_kernel + router_bias
-    probs = jax.nn.softmax(logits, axis=-1)  # [n_loc, E]
+    ids, weights, aux_loss = softmax_router(
+        logits, cfg.top_k, cfg.router_z_coef, mean=partial(lax.pmean, axis_name=axis_name)
+    )
 
-    me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(jax.nn.one_hot(jnp.argmax(probs, -1), E), axis=0)
-    aux_loss = E * jnp.sum(lax.pmean(me, axis_name) * lax.pmean(ce, axis_name))
-    if cfg.router_z_coef:
-        # ST-MoE router z-loss, globally token-averaged (matches MoEMLP)
-        z = jax.nn.logsumexp(logits, axis=-1)
-        aux_loss = aux_loss + cfg.router_z_coef * lax.pmean(
-            jnp.mean(z**2), axis_name
-        )
-
-    # top-k dispatch with per-rank positional capacity
-    combine = jnp.zeros((n_loc, E, capacity), jnp.float32)
-    remaining = probs
-    used = jnp.zeros((E,), jnp.int32)
-    for _ in range(cfg.top_k):
-        choice = jnp.argmax(remaining, axis=-1)
-        prob = jnp.take_along_axis(remaining, choice[:, None], 1)[:, 0]
-        onehot = jax.nn.one_hot(choice, E, dtype=jnp.int32)
-        pos_in_expert = (jnp.cumsum(onehot, axis=0) - onehot) + used[None, :]
-        pos = jnp.sum(onehot * pos_in_expert, axis=-1)
-        keep = pos < capacity
-        combine = combine + (
-            (prob * keep)[:, None, None]
-            * jax.nn.one_hot(choice, E)[:, :, None]
-            * jax.nn.one_hot(pos, capacity)[:, None, :]
-        )
-        used = used + jnp.sum(onehot * keep[:, None], axis=0)
-        remaining = remaining * (1.0 - jax.nn.one_hot(choice, E))
-    dispatch = (combine > 0).astype(cfg.dtype)  # [n_loc, E, C]
-
-    # --- dispatch all-to-all --------------------------------------------
-    # my tokens' contributions to all E experts, grouped by owner rank
-    expert_in = jnp.einsum("nec,nd->ecd", dispatch, x.astype(cfg.dtype))
-    expert_in = expert_in.reshape(world, e_loc, capacity, D)
+    # --- my assignments, sorted by expert, into the exchange's buffers ----
+    sent = held_assignments(ids, 0, E)
+    rows = dispatch(x.astype(cfg.dtype), sent)               # [n_loc * k, D]
+    ends = jnp.cumsum(sent.sizes)
+    starts = ends - sent.sizes                               # first row of each expert
+    place = jnp.arange(capacity)[None, :]
+    taken = place < sent.sizes[:, None]                      # [E, capacity]
+    source = jnp.minimum(starts[:, None] + place, rows.shape[0] - 1)
+    picked = jnp.take(rows, source.reshape(-1), axis=0).reshape(E, capacity, D)
+    expert_in = jnp.where(taken[..., None], picked, 0)
     # exchange: afterwards axis 0 indexes the *source* rank and the local
     # expert slice is mine
-    recv = a2a(expert_in)
+    recv = a2a(expert_in.reshape(world, e_loc, capacity, D))
 
-    # --- my experts run on everyone's tokens ----------------------------
-    flat = recv.transpose(1, 0, 2, 3).reshape(e_loc, world * capacity, D)
-    h = jax.nn.gelu(jnp.einsum("ecd,edh->ech", flat, w1.astype(cfg.dtype)))
-    out = jnp.einsum("ech,ehd->ecd", h, w2.astype(cfg.dtype))
+    # --- my experts run on everyone's tokens: the one grouped product -----
+    flat = recv.transpose(1, 0, 2, 3).reshape(e_loc * world * capacity, D)
+    full = jnp.full((e_loc,), world * capacity, jnp.int32)
+    out = grouped_ffn(flat, full, {"w1": w1, "w2": w2}, jax.nn.gelu, cfg.dtype)
     out = out.reshape(e_loc, world, capacity, D).transpose(1, 0, 2, 3)
 
-    # --- return all-to-all + weighted combine ---------------------------
-    back = a2a(out)
-    expert_out = back.reshape(E, capacity, D)
-    y = jnp.einsum("nec,ecd->nd", combine.astype(cfg.dtype), expert_out)
+    # --- return all-to-all + weighted combine ------------------------------
+    expert_out = a2a(out).reshape(E * capacity, D)
+    row = jnp.arange(rows.shape[0])
+    expert_of_row = jnp.minimum(jnp.searchsorted(ends, row, side="right"), E - 1)
+    place_of_row = row - starts[expert_of_row]
+    kept = place_of_row < capacity                            # else dropped before the exchange
+    back = jnp.take(expert_out, expert_of_row * capacity + jnp.minimum(place_of_row, capacity - 1), axis=0)
+    y = combine(jnp.where(kept[:, None], back, 0), weights, sent)
     return y.astype(x.dtype), aux_loss
 
 

@@ -140,10 +140,10 @@ def _ring_flash_shard(
     qf, kf, vf = to_bhtd(q), to_bhtd(k), to_bhtd(v)
 
     def full_block(qf, kb, vb):
-        return _flash_bhtd_lse(qf, kb, vb, scale, False, block_q, block_k, None)
+        return _flash_bhtd_lse(qf, kb, vb, scale, False, block_q, block_k, None, None)
 
     def diag_block(qf, kb, vb):
-        return _flash_bhtd_lse(qf, kb, vb, scale, True, block_q, block_k, None)
+        return _flash_bhtd_lse(qf, kb, vb, scale, True, block_q, block_k, None, None)
 
     def skip_block(qf, kb, vb):
         return jnp.zeros_like(qf), jnp.full((B * H, Tl), _NEG_INF, jnp.float32)
