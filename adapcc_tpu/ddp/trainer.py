@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from flax import struct
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from adapcc_tpu.comm.mesh import RANKS_AXIS
 from adapcc_tpu.ddp.hook import GradSyncHook
@@ -376,7 +376,9 @@ class DDPTrainer:
         """Build the trainer's state: replicated optax state normally, the
         ZeRO-1 flat master + sharded optimizer state when ``zero1=True``."""
         if not self.zero1:
-            return TrainState.create(params, self.tx, model_state=model_state)
+            return self._on_mesh(
+                TrainState.create(params, self.tx, model_state=model_state)
+            )
         from adapcc_tpu.parallel.fsdp import Zero1Optimizer
 
         opt = self._zero1_opt = Zero1Optimizer(
@@ -399,12 +401,29 @@ class DDPTrainer:
             # granularity so the step program and the optimizer execute the
             # same ring plan
             self.zero1_ring_chunk_bytes = opt.ring_chunk_bytes
-        return TrainState(
+        return self._on_mesh(TrainState(
             params=params,
             opt_state=(master, opt_state),
             step=jnp.zeros((), jnp.int32),
             model_state=model_state,
-        )
+        ))
+
+    def _on_mesh(self, state: TrainState) -> TrainState:
+        """Commit what was made from nothing (the step counter, the
+        optimizer's count, a caller's fresh ``model_state``, plain params) to
+        the mesh, replicated, as the step's own outputs are.  Left
+        uncommitted they type the first call's arguments differently from
+        every later call's, and the step is traced and compiled a second
+        time on its second call.  The ZeRO-1 pair is sharded by its maker."""
+        replicated = NamedSharding(self.mesh, P())
+
+        def place(x):
+            return x if getattr(x, "committed", False) else jax.device_put(x, replicated)
+
+        if self.zero1:
+            rest = jax.tree_util.tree_map(place, state.replace(opt_state=()))
+            return rest.replace(opt_state=state.opt_state)
+        return jax.tree_util.tree_map(place, state)
 
     def checkpoint_extra(self, extra: Optional[dict] = None) -> dict:
         """``TrainCheckpointState.extra`` payload for this trainer's state.

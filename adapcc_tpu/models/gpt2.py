@@ -284,13 +284,28 @@ class GPT2(nn.Module):
 
 
 def lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
-    """Next-token cross-entropy over a ``[B, T]`` batch."""
+    """Next-token cross-entropy over a ``[B, T]`` batch: the mean over rows of
+    ``logsumexp(row) - row[target]``.
+
+    Written as a log-sum-exp and a masked sum against an ``iota`` so that the
+    compiled step sweeps the ``[B, T-1, vocab]`` logits once forward (both
+    reductions in one fusion) and once backward (``(exp(x - lse) - onehot) / N``
+    written straight in the head's dtype), and keeps ``lse [B, T-1]`` between
+    them.  The textbook ``take_along_axis(log_softmax(x), targets)`` is the
+    same arithmetic, and on a TPU it compiles to three sweeps: the whole fp32
+    log-softmax is written to HBM (2.47 GB at 12 x 1,023 x 50,257) for a gather
+    to pick ``B * (T-1)`` numbers out of it, and the gather's one-hot cotangent
+    is summed over the vocabulary to a constant (at Trinity's 8,191 x 25,024 it
+    is scattered by a loop of 195 trips instead, 14 ms a step).  Do not fold it
+    back: ``tests/test_chip_compile.py`` holds the compiled program to this
+    form, PERF.md section 6 (PR 28) has the chip's numbers."""
     with jax.named_scope("loss"):
         logits = logits[:, :-1]
         targets = tokens[:, 1:]
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return -jnp.mean(ll)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        vocab_ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+        picked = jnp.sum(jnp.where(vocab_ids == targets[..., None], logits, 0), axis=-1)
+        return jnp.mean(lse - picked)
 
 
 def lm_loss_chunked(

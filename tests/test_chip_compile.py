@@ -18,6 +18,7 @@ compiles in this process (no child), and all of them live in this one file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +81,24 @@ def _compile_on_mesh(mesh, per_shard, shape, dtype):
     ))
     x = jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, P(RANKS_AXIS)))
     return fn.lower(x).compile()
+
+
+def _shapes_on(tree, sharding):
+    """``tree``'s leaves as ``ShapeDtypeStruct``s placed by ``sharding``."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree
+    )
+
+
+def _flash_through_mosaic(monkeypatch):
+    """Callers that leave the kernel/interpreter choice to ``kernel_mode``
+    would get this process's CPU answer: steer it here, in the test."""
+    import sys
+
+    monkeypatch.setattr(
+        sys.modules["adapcc_tpu.ops.flash_attention"], "resolve_interpret",
+        lambda interpret, site: False,
+    )
 
 
 # --- flash attention at the smoke's shape: B × 1,024 × 12 heads × 64 --------
@@ -162,22 +181,80 @@ def test_ring_all_gather_compiles(mesh4, nelems):
     assert _kernels_in(_compile_on_mesh(mesh4, per_shard, (WORLD, nelems), jnp.float32)) == 1
 
 
+# --- the training loss over GPT-2 small's logits: 12 x 1,023 x 50,257 --------
+
+_LOGITS = r"\[(?:12,1023|12276),50257\]"
+
+
+def _entry_fusions(text):
+    """``(name, kind, output shapes, operand shapes, fused body)`` of every
+    fusion in the compiled module's entry computation."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+    entry = bodies[re.search(r"^ENTRY (%[\w.\-]+)", text, re.M).group(1)]
+    shapes = {}
+    for line in entry:
+        inst = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.*?) ([a-z][a-z\-]*)\((.*)", line)
+        if not inst:
+            continue
+        out, opcode, rest = inst.group(2), inst.group(3), inst.group(4)
+        shapes[inst.group(1)] = out
+        if opcode == "fusion":
+            operands = re.findall(r"%[\w.\-]+", rest.split("), kind=")[0])
+            yield (
+                inst.group(1), re.search(r"kind=(\w+)", rest).group(1), out,
+                [shapes.get(o, "") for o in operands],
+                "\n".join(bodies[re.search(r"calls=(%[\w.\-]+)", rest).group(1)]),
+            )
+
+
+def test_the_loss_sweeps_the_logits_once_forward_and_once_backward(one_chip, monkeypatch):
+    """``lm_loss`` as the chip's compiler sees it behind GPT-2 small's head:
+    the textbook form (``log_softmax`` then ``take_along_axis``) writes the
+    whole fp32 log-softmax (2.47 GB) for a gather to read 12,276 numbers of,
+    and sums the gather's one-hot cotangent over the vocabulary to a
+    constant: three sweeps and 5.49 GB of temporaries where this asks for
+    two and under 3.3."""
+    from adapcc_tpu.models.gpt2 import GPT2, GPT2Config, lm_loss
+
+    _flash_through_mosaic(monkeypatch)
+    model = GPT2(GPT2Config(n_layer=2, attention="flash"))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    tokens = jax.ShapeDtypeStruct((12, 1024), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        jax.value_and_grad(lambda p, b: lm_loss(model.apply(p, b), b))
+    ).lower(_shapes_on(params, one_chip), tokens).compile()
+
+    fusions = list(_entry_fusions(compiled.as_text()))
+    assert len(fusions) > 20 and any(re.search("bf16" + _LOGITS, out) for _, _, out, _, _ in fusions)
+    # nothing vocabulary-sized is written in fp32
+    assert not [name for name, _, out, _, _ in fusions if re.search("f32" + _LOGITS, out)]
+    # no pass builds a vocabulary-sized one-hot from an iota alone and reduces it
+    assert not [
+        name for name, _, _, operands, body in fusions
+        if re.search(_LOGITS + r"\S* iota\(", body) and not any(re.search(_LOGITS, o) for o in operands)
+    ]
+    # elementwise passes over the logits: the forward's two reductions in one, the gradient
+    sweeps = [name for name, kind, _, _, body in fusions if kind == "kLoop" and re.search(_LOGITS, body)]
+    assert len(sweeps) <= 2, sweeps
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.3e9
+
+
 # --- the composed programs the old on-chip smoke covered ---------------------
 
 
 def test_flash_ring_attention_block_compiles(mesh4, monkeypatch):
     """Sequence-parallel ring attention with the flash block kernel: K/V
     rotate over the ring, every hop runs the Pallas kernel."""
-    import sys
-
     from adapcc_tpu.parallel.ring_attention import ring_attention_shard
 
-    # ring attention leaves the kernel/interpreter choice to kernel_mode,
-    # which sees this process's CPU backend: steer it here, in the test
-    monkeypatch.setattr(
-        sys.modules["adapcc_tpu.ops.flash_attention"], "resolve_interpret",
-        lambda interpret, site: False,
-    )
+    _flash_through_mosaic(monkeypatch)
 
     def per_shard(qkv):  # [1, 3, B, T_local, H, D]
         q, k, v = qkv[0, 0], qkv[0, 1], qkv[0, 2]
@@ -207,17 +284,12 @@ def test_zero1_ring_step_compiles(mesh4):
     step = zero1_train_step(loss_fn, opt, mesh4)
     replicated, sharded = NamedSharding(mesh4, P()), NamedSharding(mesh4, P(RANKS_AXIS))
 
-    def on(tree, sharding):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree
-        )
-
     master, opt_state = jax.eval_shape(opt.init, params)
     batch = (
         jax.ShapeDtypeStruct((8 * WORLD, 256), jnp.float32, sharding=sharded),
         jax.ShapeDtypeStruct((8 * WORLD, 256), jnp.float32, sharding=sharded),
     )
     compiled = jax.jit(step).lower(
-        on(params, replicated), on(master, sharded), on(opt_state, sharded), batch
+        _shapes_on(params, replicated), _shapes_on(master, sharded), _shapes_on(opt_state, sharded), batch
     ).compile()
     assert _kernels_in(compiled) >= 2

@@ -648,6 +648,35 @@ def _bn_net_and_loss():
     return net, loss_fn
 
 
+@pytest.mark.parametrize("kind", ["plain", "stateful", "zero1"])
+def test_the_step_is_traced_once_over_its_first_calls(mesh4, kind):
+    """``init_state`` commits what it makes from nothing (the step counter,
+    the optimizer's count, a caller's fresh ``model_state``) to the mesh, as
+    the step's own outputs are: left uncommitted they typed the first call's
+    arguments differently from the second's, and every run traced and
+    compiled its step twice."""
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(8, 12)), jnp.float32)
+    y = jnp.asarray(rng.integers(0, 4, size=(8,)))
+    if kind == "stateful":
+        net, loss_fn = _bn_net_and_loss()
+        v0 = net.init(jax.random.PRNGKey(0), x[:1], train=True)
+        tr = DDPTrainer(loss_fn, optax.adam(1e-2), mesh4, Strategy.ring(4), stateful_loss=True)
+        state = tr.init_state(v0["params"], model_state=v0["batch_stats"])
+    else:
+        params = {"w": jnp.asarray(rng.normal(size=(12, 4)), jnp.float32)}
+
+        def loss_fn(p, b):
+            return jnp.mean((b[0] @ p["w"] - jax.nn.one_hot(b[1], 4)) ** 2)
+
+        tr = DDPTrainer(loss_fn, optax.adam(1e-2), mesh4, Strategy.ring(4), zero1=kind == "zero1")
+        state = tr.init_state(params)
+    for _ in range(3):
+        state, loss = tr.step(state, (x, y))
+    assert np.isfinite(np.asarray(loss)).all()
+    assert tr._compiled._cache_size() == 1
+
+
 def test_stateful_loss_syncbn_stats_update(mesh4):
     """SyncBN under the adaptive DDP step (reference torchvision-BN DDP,
     main_elastic.py:243-244): batch_stats ride TrainState.model_state,

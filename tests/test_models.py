@@ -23,6 +23,78 @@ def test_gpt2_forward_and_loss():
     assert np.isfinite(float(loss))
 
 
+def _textbook_loss(logits, tokens):
+    """The next-token loss as the textbook writes it, in float32: what
+    ``lm_loss`` has to equal whatever form it is compiled from."""
+    logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32), axis=-1)
+    return -jnp.mean(jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)[..., 0])
+
+
+_LOSS_CASES = {
+    # id: (rows, T, vocabulary, logits rounded to bfloat16, a very large logit)
+    "fp32": (3, 16, 256, False, False),
+    "bf16-rounded": (3, 16, 256, True, False),
+    "vocab-not-a-multiple-of-128": (2, 16, 257, True, False),
+    "T-2": (4, 2, 130, False, False),
+    "T-not-a-multiple-of-8": (2, 13, 384, True, False),
+    "very-large-logit": (3, 8, 130, False, True),
+}
+
+
+@pytest.mark.parametrize("case", list(_LOSS_CASES))
+def test_lm_loss_is_the_textbook_loss_in_value_and_gradient(case):
+    rows, T, vocab, rounded, large = _LOSS_CASES[case]
+    rng = np.random.default_rng(len(case))
+    logits = rng.normal(scale=4.0, size=(rows, T, vocab)).astype(np.float32)
+    tokens = rng.integers(0, vocab, (rows, T)).astype(np.int32)
+    if large:
+        # exp() of it overflows float32: on the target in one row, off it in another
+        logits[0, 2, tokens[0, 3]] = 3.0e4
+        logits[1, 2, (tokens[1, 3] + 1) % vocab] = 3.0e4
+    logits = jnp.asarray(logits)
+    if rounded:  # what the bf16 head hands over, cast to float32
+        logits = logits.astype(jnp.bfloat16).astype(jnp.float32)
+    tokens = jnp.asarray(tokens)
+    got, got_grad = jax.jit(jax.value_and_grad(lm_loss))(logits, tokens)
+    want, want_grad = jax.jit(jax.value_and_grad(_textbook_loss))(logits, tokens)
+    assert got.dtype == jnp.float32 and np.isfinite(float(got))
+    assert float(got) == pytest.approx(float(want), rel=2e-6)
+    assert got_grad.shape == logits.shape and np.isfinite(np.asarray(got_grad)).all()
+    np.testing.assert_allclose(np.asarray(got_grad), np.asarray(want_grad), rtol=2e-5, atol=1e-9)
+    assert not np.any(np.asarray(got_grad[:, -1]))  # the last position predicts nothing
+
+
+def test_trinity_dense_loss_is_the_textbook_loss_of_its_logits():
+    from adapcc_tpu.models.trinity import Trinity, TrinityConfig, initial_model_state, stateful_loss
+
+    cfg = TrinityConfig.tiny()
+    model = Trinity(cfg)
+    tokens = jnp.asarray(np.random.default_rng(3).integers(0, cfg.vocab_size, (2, 24)), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens)
+    loss_fn = stateful_loss(model, "dense")
+    (got, _), got_grad = jax.value_and_grad(loss_fn, has_aux=True)(params, initial_model_state(cfg), tokens)
+    want, want_grad = jax.value_and_grad(lambda p: _textbook_loss(model.apply(p, tokens)[0], tokens))(params)
+    assert float(got) == pytest.approx(float(want), rel=2e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(got_grad), jax.tree_util.tree_leaves(want_grad)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-7)
+
+
+def test_pipeline_last_stage_loss_is_the_textbook_loss_of_its_logits():
+    from adapcc_tpu.pipe.partition import composed_loss, partition_gpt2, split_params
+
+    cfg = GPT2Config.tiny()
+    model = GPT2(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (3, 13), 0, cfg.vocab_size)
+    params = model.init(jax.random.PRNGKey(0), tokens)
+    part = partition_gpt2(cfg, 2)
+    # every stage in order; the last applies the head and lm_loss
+    got, got_grad = jax.value_and_grad(lambda p: composed_loss(cfg, part, split_params(p, part), tokens))(params)
+    want, want_grad = jax.value_and_grad(lambda p: _textbook_loss(model.apply(p, tokens), tokens))(params)
+    assert float(got) == pytest.approx(float(want), rel=2e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(got_grad), jax.tree_util.tree_leaves(want_grad)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-7)
+
+
 @pytest.mark.slow
 def test_gpt2_gradients_nonzero():
     cfg = GPT2Config.tiny()
