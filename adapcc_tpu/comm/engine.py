@@ -221,8 +221,8 @@ def _merged_env_disabled() -> bool:
     """``ADAPCC_MERGE_ROUNDS=0`` disables round merging everywhere — the A/B
     knob for measuring the merged executor against sequential per-tree
     chains on hardware (flat and two-level paths share it).  Unknown values
-    raise: a typo silently enabling the default would invalidate the A/B
-    (same policy as bench.py's BENCH_REMAT validation)."""
+    raise: a typo silently enabling the default would invalidate the
+    A/B."""
     import os
 
     val = os.environ.get("ADAPCC_MERGE_ROUNDS", "1").strip().lower()

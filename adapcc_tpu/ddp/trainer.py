@@ -864,10 +864,10 @@ class DDPTrainer:
 
         Every ``step()`` call pays a host→device dispatch; a scanned
         multi-step program pays it once, so this is the way to take the
-        host out of a device-side throughput reading (bench.py) and the
-        fast way to run tight loops whose active set cannot change
-        mid-scan.  Static full world only — no
-        per-step negotiation, relay banking, or GNS capture.  Returns
+        host out of a device-side throughput reading and the fast way to
+        run tight loops whose active set cannot change mid-scan.  Static
+        full world only — no per-step negotiation, relay banking, or GNS
+        capture.  Returns
         ``(final_state, losses [world, n_steps])``.
         """
         if self._dynamic_mask or not self.bsp or self.measure_gns:

@@ -48,7 +48,7 @@ class GPT2Config:
     attention: str = "xla"
     #: flash kernel tile edge (block_q == block_k).  None: by shape, from the
     #: table measured on the chip (ops/flash_attention.TILE_TABLE); an integer
-    #: overrides it (bench.py BENCH_FLASH_BLOCK sweeps one)
+    #: overrides it (tests at toy shapes pass small tiles)
     flash_block: Optional[int] = None
     #: sequence parallelism: when set (a mesh axis name), the model expects
     #: to run INSIDE shard_map with tokens sequence-sharded over that axis —

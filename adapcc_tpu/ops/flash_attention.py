@@ -411,8 +411,8 @@ def _dkv_kernel(
 #: The tile by shape, measured on a TPU v5e (PERF.md §6, PR 25): rows of
 #: ``(longest T, widest head, operand bytes) -> (block_q, block_k)``, the first
 #: row that holds the shape wins, the last holds every shape.  Read here and
-#: nowhere else: ``GPT2Config.flash_block = None`` and
-#: ``flash_autotune``'s static default both resolve through
+#: nowhere else: a config's ``flash_block = None`` (``GPT2Config``,
+#: ``TrinityConfig``) and a bare kernel call both resolve through
 #: :func:`default_blocks`.
 TILE_TABLE = (
     ((4096, 64, 2), (512, 512)),   # bf16, measured at T=1,024 (B.H = 144 and 32) and T=4,096

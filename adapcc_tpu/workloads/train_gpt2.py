@@ -418,9 +418,9 @@ def run(args, report: Optional[dict] = None) -> Tuple[float, float]:
             # feeds from the async device_batches prefetcher, and a donating
             # step racing the prefetch thread's device_put deadlocks the
             # XLA CPU collective rendezvous (verified on the 8-device pod:
-            # only some ranks join, 40 s timeout, SIGABRT).  Donated
-            # steady-state throughput is measured by bench.py, which uses a
-            # static batch and can donate safely.
+            # only some ranks join, 40 s timeout, SIGABRT).  Whether that
+            # still holds is ROADMAP S1's to find out, with the tests that
+            # keep the old state; until then this loop does not donate.
         )
     state = (
         trainer.init_state(params) if trainer is not None

@@ -97,9 +97,16 @@ WORKER = textwrap.dedent(
         request_serializer=lambda b: b,
         response_deserializer=lambda b: b,
     )
+    # The "training step" is step_s of progress in slices of 10 ms.  A sleep
+    # counts on while the process is stopped, so one sleep(step_s) under a
+    # SIGSTOP duty cycle only runs to the end of the stop it falls into
+    # (0.1 s -> 0.2 s at slowdown 4); slices advance while the process
+    # runs, as work does (0.1 s -> 0.4 s, measured with the injector alone).
+    slices = max(1, round(step_s / 0.01))
     while True:
         t0 = time.monotonic()
-        time.sleep(step_s)          # the "training step": SIGSTOP stretches it
+        for _ in range(slices):
+            time.sleep(step_s / slices)
         dt = time.monotonic() - t0  # self-reported step walltime
         try:
             beat(cont_request(max(1, int(dt * 1e6)), rank), timeout=2.0)
@@ -662,10 +669,12 @@ def test_chaos_drill_sigstop_straggler_demoted_then_promoted(tmp_path):
         config=LivenessConfig(timeout_s=3.0, period_s=0.25, grace=2),
     )
     procs = _spawn_workers(tmp_path, srv.port, world, step_s=0.1)
-    # slow from t≈1 s to t≈5 s at slowdown 4 (stopped 75% of each window)
+    # slow from t≈1 s to t≈9 s at slowdown 4 (stopped 75% of each window):
+    # a step of 0.1 s takes 0.4 s, twice what slow_factor=2.0 asks for, and
+    # the rule's median of 16 reports needs 8 of them, 3.2 s, to turn
     plan = FaultPlan(
         [FaultEvent(step=1, kind="slow", rank=1, slowdown=4.0),
-         FaultEvent(step=5, kind="recover", rank=1)],
+         FaultEvent(step=9, kind="recover", rank=1)],
         world=world,
         label="drill-sigstop",
     )

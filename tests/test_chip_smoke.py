@@ -123,17 +123,3 @@ def test_compile_cache_defaults_to_a_fixed_path_in_the_checkout(monkeypatch):
     assert compile_cache.enable_compile_cache() == want
     assert compile_cache.enable_compile_cache() == want  # no pid, no time, no tempdir
     assert calls == [("jax_compilation_cache_dir", want)] * 2
-
-
-def test_unknown_device_kind_is_an_error(monkeypatch):
-    import bench
-
-    class _Dev:
-        device_kind = "TPU v9 hyper"
-
-    monkeypatch.delenv("BENCH_PEAK_TFLOPS", raising=False)
-    monkeypatch.setattr(jax, "devices", lambda: [_Dev()])
-    with pytest.raises(ValueError, match="TPU v9 hyper"):
-        bench.chip_peak_tflops()
-    with pytest.raises(ValueError, match="not in bench.py's peak table"):
-        bench.chip_hbm_gbps()

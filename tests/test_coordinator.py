@@ -44,8 +44,20 @@ def fast_logic(world, **kw):
     return CoordinatorLogic(world, **kw)
 
 
+def patient_logic(world, **kw):
+    """For rounds in which every rank arrives: no clock may decide them.  At
+    the nominal cost constants rent-or-buy buys a partial collective some
+    10 ms after the second arrival, and threads (or gRPC channels) on a host
+    shared with other test workers do not land that close.  Here the
+    collective is priced at a minute, so the leader waits 15 s and more."""
+    return fast_logic(
+        world, relay_threshold=60.0, accumulated_size=60.0,
+        accumulated_bandwidth=float(world), **kw
+    )
+
+
 def test_all_arrive_full_active_list():
-    logic = fast_logic(4)
+    logic = patient_logic(4)
     out = run_workers(4, lambda r: logic.hook_arrive(step=0, rank=r))
     for r, active in out.items():
         assert sorted(active) == [0, 1, 2, 3]
@@ -96,7 +108,7 @@ def test_sole_leader_escapes_after_fault_timeout():
 
 
 def test_controller_barrier_all_alive():
-    logic = fast_logic(3)
+    logic = patient_logic(3)
     # hook phase freezes the active list first
     run_workers(3, lambda r: logic.hook_arrive(step=5, rank=r))
     out = run_workers(3, lambda r: logic.controller_arrive(step=5, rank=r))
@@ -115,7 +127,7 @@ def test_controller_fault_timeout_returns_alive_subset():
 
 
 def test_steps_are_independent():
-    logic = fast_logic(2)
+    logic = patient_logic(2)
     run_workers(2, lambda r: logic.hook_arrive(step=0, rank=r))
     out = run_workers(2, lambda r: logic.hook_arrive(step=1, rank=r))
     assert sorted(out[0]) == [0, 1]
@@ -130,7 +142,7 @@ def test_steps_are_independent():
 
 @pytest.fixture
 def server():
-    logic = fast_logic(3)
+    logic = patient_logic(3)
     srv = CoordinatorServer(3, port=0, logic=logic).start()
     yield srv
     srv.stop()

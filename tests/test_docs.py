@@ -58,7 +58,7 @@ def test_operations_doc_covers_the_contract():
         "ip_table.txt", "topo_detect_<r>.xml", "logical_graph.xml",
         "strategy.xml", "reconstruct_topology", "chip_smoke.py", "--chips 4",
         "JAX_COMPILATION_CACHE_DIR",
-        "BENCH_FLASH_BLOCK", "--entry_point", "--dry-run",
+        "chipbench/run.py", "--entry_point", "--dry-run",
         "ADAPCC_DISAGG", "ADAPCC_KV_WIRE_DTYPE", "ADAPCC_KV_KL_BOUND",
         "ADAPCC_PIPE_SCHEDULE", "ADAPCC_IR_OPT",
     ):

@@ -201,7 +201,7 @@ def test_merged_reduce_and_broadcast_oracles(mesh8):
 
 def test_merge_rounds_env_knob_validated(monkeypatch):
     """A typo'd ADAPCC_MERGE_ROUNDS must raise, not silently run the
-    default executor and invalidate the A/B (BENCH_REMAT policy)."""
+    default executor and invalidate the A/B."""
     import pytest
 
     from adapcc_tpu.comm.engine import _merged_env_disabled

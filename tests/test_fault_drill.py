@@ -34,10 +34,15 @@ from adapcc_tpu.strategy.ir import Strategy
 
 def _controller_round(logic, step, ranks):
     """Per-rank controller heartbeats in threads (each blocks on the
-    barrier/timeout); returns {rank: (active, status)}."""
+    barrier/timeout); returns {rank: (active, status)}.  The threads
+    heartbeat together: every rank's deadline runs from its own arrival, so
+    on a loaded host a thread started late would outlive the first one's
+    deadline and the ranks would disagree on who is alive."""
     results = {}
+    together = threading.Barrier(len(ranks))
 
     def arrive(r):
+        together.wait(timeout=60)
         results[r] = logic.controller_arrive(step=step, rank=r)
 
     threads = [threading.Thread(target=arrive, args=(r,)) for r in ranks]
@@ -68,8 +73,14 @@ def test_fault_drill_heartbeat_to_masked_step_to_restart(mesh8, tmp_path):
     state = TrainState.create(params, tx)
 
     # -- phase 1: healthy steps under coordinator negotiation ---------------
+    # Nobody is late here, so no deadline may decide a round: eight threads
+    # on a host shared with five other test workers do not arrive inside
+    # milliseconds of each other.  The collective is priced at a minute, so
+    # the leader waits 15 s and more before it buys a partial one, and the
+    # relay and fault deadlines are a minute too.
     logic = CoordinatorLogic(
-        world, relay_threshold=0.05, time_slot=0.01, fault_timeout=0.3
+        world, relay_threshold=60.0, time_slot=0.01, fault_timeout=60.0,
+        accumulated_size=60.0, accumulated_bandwidth=float(world),
     )
     for step_idx in range(2):
         hook_threads = [
@@ -93,8 +104,10 @@ def test_fault_drill_heartbeat_to_masked_step_to_restart(mesh8, tmp_path):
         assert np.isfinite(np.asarray(loss)).all()
 
     # -- phase 2: rank 5 dies mid-training; heartbeat timeout fires ---------
+    # the short heartbeat deadline only here, where a rank is really silent
     dead = 5
     survivors = [r for r in range(world) if r != dead]
+    logic.heartbeat_timeout = 1.0
     out = _controller_round(logic, 2, survivors)
     alive_sets = {tuple(sorted(a)) for a, _ in out.values()}
     statuses = {s for _, s in out.values()}

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from adapcc_tpu.ops import flash_attention, flash_attention_with_lse
-from adapcc_tpu.ops.flash_attention import TILE_TABLE, default_blocks, visited_tiles
+from adapcc_tpu.ops.flash_attention import TILE_TABLE, default_blocks, resolve_block, visited_tiles
 from adapcc_tpu.utils.observability import default_registry
 
 #: the module (the package's attribute of that name is the function)
@@ -368,17 +368,15 @@ def test_loop_bounds_visit_exactly_the_counted_tiles(causal):
 
 
 def test_tile_resolves_by_shape_through_one_table():
-    """``GPT2Config.flash_block`` (None by default), ``flash_autotune`` and a
-    bare ``flash_attention`` call all read ``TILE_TABLE``."""
+    """``GPT2Config.flash_block`` (None by default) and a bare
+    ``flash_attention`` call both read ``TILE_TABLE``."""
     from adapcc_tpu.models.gpt2 import GPT2Config
-    from adapcc_tpu.ops import flash_autotune
 
     assert GPT2Config().flash_block is None
     cells = default_blocks(1024, 64, jnp.bfloat16)
     assert cells == next(tile for _, tile in TILE_TABLE)           # the first row is the cells' shape
-    assert flash_autotune.DEFAULT_BLOCK == cells[0]
     assert default_blocks(384, 64, jnp.bfloat16) == tuple(
-        flash_autotune.resolve_block(384, b) for b in cells        # cut to a divisor of T
+        resolve_block(384, b) for b in cells                       # cut to a divisor of T
     )
     assert default_blocks(1 << 20, 256, "float64") == TILE_TABLE[-1][1]     # the last row holds every shape
     q, k, v = _qkv(T=64, B=1, H=1, dtype=jnp.bfloat16)
