@@ -13,8 +13,12 @@ grouped product (``jax.lax.ragged_dot``: on a TPU XLA lowers it to a Mosaic
 kernel that visits only the tiles the group sizes fill), and gathers the
 weighted results back to their tokens.  Shapes are static by a bound no
 routing can exceed, ``tokens × min(top_k, held)`` rows: nothing is dropped and
-there is no capacity.  On one chip there is no exchange; what absent experts
-would have added is simply not there.
+there is no capacity.  A layer that holds a share of the experts and is told
+how many there are in all sizes its rows by twice its balanced share
+(:func:`short_rows`) whenever the assignments it counted fit them, and by the
+bound when they do not: the choice is made on the device, step by step.  On
+one chip there is no exchange; what absent experts would have added is simply
+not there.
 
 :class:`MoEMLP` (the toy switch MLP of ``workloads/train_moe.py``) holds all
 its experts and runs through the same layer; across chips
@@ -27,7 +31,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Callable, Dict, NamedTuple, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -87,6 +91,23 @@ def assignment_bound(tokens: int, top_k: int, held: int) -> int:
     """Rows the held experts can be given at most: a token picks ``top_k``
     different experts, so at most ``min(top_k, held)`` of them are held."""
     return tokens * min(top_k, held)
+
+
+#: the short rows are this many times the held experts' balanced share of the
+#: assignments (``tokens × top_k × held ÷ num_experts``), in whole row tiles
+SHORT_ROWS_OVER_SHARE = 2
+ROW_TILE = 128
+
+
+def short_rows(tokens: int, top_k: int, held: int, num_experts: Optional[int]) -> int:
+    """Rows that take the held experts' assignments of any routing near
+    balance; the bound itself where that is no fewer (every expert held, or
+    a caller that does not say how many there are)."""
+    bound = assignment_bound(tokens, top_k, held)
+    if num_experts is None:
+        return bound
+    share = -(-tokens * top_k * held // num_experts)
+    return min(bound, -(-SHORT_ROWS_OVER_SHARE * share // ROW_TILE) * ROW_TILE)
 
 
 def held_assignments(ids: jnp.ndarray, offset: int, held: int) -> Assignments:
@@ -180,6 +201,67 @@ def grouped_ffn(rows, sizes, stacked: Dict[str, jnp.ndarray], act: Callable, dty
     return product(h, stacked["w2"].astype(dtype))
 
 
+def _cut(sent: Assignments, rows: int) -> Assignments:
+    """``sent`` with its sorted list cut to ``rows``: the same assignments
+    where ``sum(sizes) <= rows``."""
+    return Assignments(sent.order[:rows], jnp.minimum(sent.slot, rows - 1), sent.here, sent.sizes)
+
+
+def _experts(x, weights, stacked, sent: Assignments, act: Callable, dtype):
+    out = grouped_ffn(dispatch(x, sent), sent.sizes, stacked, act, dtype)
+    return combine(out, weights, sent)
+
+
+def _two_paths(sent: Assignments, short: int, path: Callable):
+    """What the layer's ``lax.cond`` takes first: whether the assignments
+    fit ``short`` rows, ``path(sent cut to them)`` and ``path(sent)``, each
+    with a barrier behind its results.  The barrier keeps a branch whole.
+    Without it the compiler moves what both branches end with out of them
+    (the combine's masked sum, which then reads the picked rows, ``[tokens,
+    top_k, D]``, back from memory) and what reads their results into them
+    (the widening of the weights' gradients, which then leave a branch in
+    float32 and stay that wide until the optimizer has them)."""
+    def whole(sent):
+        return lambda *a: jax.lax.optimization_barrier(path(sent)(*a))
+
+    return jnp.sum(sent.sizes) <= short, whole(_cut(sent, short)), whole(sent)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _short_or_bound(x, weights, stacked, sent: Assignments, short: int, act: Callable, dtype):
+    """:func:`_experts` over ``short`` rows where the assignments fit them,
+    over all of ``sent``'s where they do not.  Differentiating a ``cond``
+    would have each branch write zeros for the other's residuals, the
+    bound-sized ones too: so the forward pass keeps the inputs alone and the
+    backward pass chooses again, recomputing inside the branch it takes
+    (what ``jax.checkpoint`` does for the layer of one path)."""
+    forward = lambda sent: lambda *a: _experts(*a, sent, act, dtype)  # noqa: E731
+    return jax.lax.cond(*_two_paths(sent, short, forward), x, weights, stacked)
+
+
+def _short_or_bound_fwd(x, weights, stacked, sent, short, act, dtype):
+    return _short_or_bound(x, weights, stacked, sent, short, act, dtype), (x, weights, stacked, sent)
+
+
+def _short_or_bound_bwd(short, act, dtype, res, dy):
+    x, weights, stacked, sent = res
+
+    def backward(sent):
+        def run(dy, x, weights, stacked):
+            # down to the stacked weights as the products read them: their
+            # gradients leave the branch in that dtype, as the products give them
+            low = jax.tree_util.tree_map(lambda w: w.astype(dtype), stacked)
+            return jax.vjp(lambda *a: _experts(*a, sent, act, dtype), x, weights, low)[1](dy)
+
+        return run
+
+    dx, dweights, dlow = jax.lax.cond(*_two_paths(sent, short, backward), dy, x, weights, stacked)
+    return dx, dweights, jax.tree_util.tree_map(lambda g, w: g.astype(w.dtype), dlow, stacked), None
+
+
+_short_or_bound.defvjp(_short_or_bound_fwd, _short_or_bound_bwd)
+
+
 def routed_experts(
     x: jnp.ndarray,
     ids: jnp.ndarray,
@@ -187,47 +269,66 @@ def routed_experts(
     stacked: Dict[str, jnp.ndarray],
     *,
     offset: int = 0,
+    num_experts: Optional[int] = None,
     act: Callable = nn.gelu,
     dtype=jnp.bfloat16,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The held experts' part of a routed layer.
 
     ``x [N, D]`` tokens; ``ids [N, k]`` the experts each token chose among
-    ALL experts and ``weights [N, k]`` their float32 weights (applied to the
-    expert's output); ``stacked`` the held experts' weights (``w1 [held, D,
-    H]``, ``w2 [held, H, D]``, optionally the gate's ``w3``), experts
-    ``offset … offset + held``.  Returns ``(y [N, D] float32, sizes [held])``:
-    the sum over each token's held assignments, and how many assignments
-    each held expert was given.  No assignment of a held expert is dropped.
+    ALL ``num_experts`` experts and ``weights [N, k]`` their float32 weights
+    (applied to the expert's output); ``stacked`` the held experts' weights
+    (``w1 [held, D, H]``, ``w2 [held, H, D]``, optionally the gate's ``w3``),
+    experts ``offset … offset + held``.  Returns ``(y [N, D] float32, sizes
+    [held])``: the sum over each token's held assignments, and how many
+    assignments each held expert was given.  No assignment of a held expert
+    is dropped.
 
-    The rows' intermediates are recomputed in the backward pass: they are
-    sized by the bound (``tokens × top_k`` rows), eight times what a balanced
-    router fills at 16 experts of 128.
+    The rows' intermediates are recomputed in the backward pass.  They are
+    sized by the bound (``tokens × min(top_k, held)`` rows), eight times what
+    a balanced router fills at 16 experts of 128: where ``num_experts`` says
+    the held experts are a share, a step whose assignments fit
+    :func:`short_rows` runs over that many rows instead, forward and backward
+    (one ``conditional`` each); any other step, and a layer whose short rows
+    would reach the bound, runs over the bound.
     """
     held = stacked["w1"].shape[0]
+    bound = assignment_bound(x.shape[0], ids.shape[1], held)
+    short = short_rows(x.shape[0], ids.shape[1], held, num_experts)
     metrics = default_registry()
     metrics.gauge("moe.experts_held", held)
-    metrics.gauge("moe.assignment_bound", assignment_bound(x.shape[0], ids.shape[1], held))
+    metrics.gauge("moe.assignment_bound", bound)
+    metrics.gauge("moe.short_rows", short)
+    weights = weights.astype(jnp.float32)
 
     @jax.checkpoint
-    def experts(x, weights, stacked):
+    def one_path(x, weights, stacked):
         sent = held_assignments(ids, offset, held)
-        out = grouped_ffn(dispatch(x, sent), sent.sizes, stacked, act, dtype)
-        return combine(out, weights, sent), sent.sizes
+        return _experts(x, weights, stacked, sent, act, dtype), sent.sizes
+
+    def two_paths(x, weights, stacked):
+        sent = held_assignments(ids, offset, held)
+        return _short_or_bound(x, weights, stacked, sent, short, act, dtype), sent.sizes
 
     with jax.named_scope("moe_experts"):
-        return experts(x, weights.astype(jnp.float32), stacked)
+        return (one_path if short == bound else two_paths)(x, weights, stacked)
 
 
 def record_routing(sizes, dropped=0, metrics=None) -> None:
     """Per-step samples from what a compiled step returned beside its loss:
     ``sizes [layers, held]`` assignments of each held expert in each expert
-    layer.  ``moe.assignments_here`` and ``moe.load_max_over_mean`` (fullest
-    held expert ÷ mean) once for each layer; ``moe.dropped`` counts what an
-    exchange's buffers could not take (0 on one chip, by construction)."""
+    layer.  ``moe.assignments_here``, ``moe.load_max_over_mean`` (fullest
+    held expert ÷ mean) and ``moe.rows_fit`` (1.0 where the layer's
+    assignments fit its short rows, the gauge ``moe.short_rows`` that the
+    layer left when it was traced; no sample before that) once for each
+    layer; ``moe.dropped`` counts what an exchange's buffers could not take
+    (0 on one chip, by construction)."""
     metrics = metrics or default_registry()
+    short = metrics.snapshot()["gauges"].get("moe.short_rows")
     for layer in np.atleast_2d(np.asarray(sizes, np.float64)):
         metrics.sample("moe.assignments_here", float(layer.sum()))
+        if short is not None:
+            metrics.sample("moe.rows_fit", float(layer.sum() <= short))
         if layer.sum() > 0:
             metrics.sample("moe.load_max_over_mean", float(layer.max() / layer.mean()))
     metrics.incr("moe.dropped", float(np.sum(np.asarray(dropped))))

@@ -246,7 +246,8 @@ class SparseExperts(nn.Module):
             "w2": self.param("experts_w2", init, (held, width, d)),
         }
         routed, sizes = routed_experts(
-            tokens, ids, weights, stacked, offset=cfg.expert_offset, act=nn.silu, dtype=cfg.dtype
+            tokens, ids, weights, stacked, offset=cfg.expert_offset, num_experts=E, act=nn.silu,
+            dtype=cfg.dtype,
         )
         return shared + routed.reshape(B, T, d).astype(x.dtype), sizes
 

@@ -186,9 +186,8 @@ def test_ring_all_gather_compiles(mesh4, nelems):
 _LOGITS = r"\[(?:12,1023|12276),50257\]"
 
 
-def _entry_fusions(text):
-    """``(name, kind, output shapes, operand shapes, fused body)`` of every
-    fusion in the compiled module's entry computation."""
+def _computations(text):
+    """``{name: its instruction lines}`` of every computation of a compiled module."""
     bodies, name = {}, None
     for line in text.splitlines():
         head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
@@ -197,6 +196,13 @@ def _entry_fusions(text):
             bodies[name] = []
         elif name is not None:
             bodies[name].append(line)
+    return bodies
+
+
+def _entry_fusions(text):
+    """``(name, kind, output shapes, operand shapes, fused body)`` of every
+    fusion in the compiled module's entry computation."""
+    bodies = _computations(text)
     entry = bodies[re.search(r"^ENTRY (%[\w.\-]+)", text, re.M).group(1)]
     shapes = {}
     for line in entry:
@@ -244,6 +250,62 @@ def test_the_loss_sweeps_the_logits_once_forward_and_once_backward(one_chip, mon
     sweeps = [name for name, kind, _, _, body in fusions if kind == "kLoop" and re.search(_LOGITS, body)]
     assert len(sweeps) <= 2, sweeps
     assert compiled.memory_analysis().temp_size_in_bytes < 3.3e9
+
+
+# --- the expert layer at Trinity-Mini's share: 8,192 tokens, 16 of 128 experts --
+
+
+def test_the_expert_layer_writes_short_rows_when_the_assignments_fit_them(one_chip):
+    """``routed_experts`` forward and backward at cell 3's shapes (8,192
+    tokens of 2,048, top-8, 16 of 128 experts of 1,024, bf16): the layer told
+    how many experts there are chooses twice, and the branches it takes when
+    the assignments fit 16,384 rows write nothing with the bound's 65,536
+    rows: not the sorted rows, nor a grouped product's input or output, nor
+    an elementwise pass over them.  What stays is the gather back to
+    ``[tokens x top_k, D]``, once forward and once backward: it reads a row
+    for every assignment whatever the rows are sized by (ROADMAP S9).  And
+    the two paths together ask for no more temporaries than the bound's path
+    alone (the branches share them)."""
+    from adapcc_tpu.models.moe import assignment_bound, routed_experts, short_rows
+
+    n, k, experts, held, d, h = 8192, 8, 128, 16, 2048, 1024
+    assert (short_rows(n, k, held, experts), assignment_bound(n, k, held)) == (16384, 65536)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    stacked = {"w1": shape((held, d, h), jnp.float32), "w3": shape((held, d, h), jnp.float32),
+               "w2": shape((held, h, d), jnp.float32)}
+
+    def compiled(num_experts):
+        def loss(x, weights, stacked, ids, mix):
+            y, _ = routed_experts(x, ids, weights, stacked, num_experts=num_experts, act=jax.nn.silu)
+            return jnp.sum(y * mix)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            shape((n, d), jnp.bfloat16), shape((n, k), jnp.float32), stacked, shape((n, k), jnp.int32),
+            shape((n, d), jnp.float32),
+        ).compile()
+
+    one_path, two_paths = compiled(None), compiled(experts)
+    assert " conditional(" not in one_path.as_text()
+    bodies = _computations(two_paths.as_text())
+    chosen = [
+        re.search(r"branch_computations=\{([^}]*)\}", line).group(1).split(", ")
+        for lines in bodies.values() for line in lines if " conditional(" in line
+    ]
+    assert len(chosen) == 2 and all(len(branches) == 2 for branches in chosen)   # forward, backward
+    for bound_branch, short_branch in chosen:          # index 0: the predicate is false
+        wide = lambda lines: [  # noqa: E731
+            line for line in lines
+            if re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \(?(?:bf16|f32)\[65536,(?:1024|2048)\]", line)
+        ]
+        assert len(wide(bodies[bound_branch])) >= 4
+        left = wide(bodies[short_branch])
+        assert len(left) <= 1 and all("/gather\"" in line and "bf16[65536,2048]" in line for line in left), left
+        assert any(re.search(r"= bf16\[16384,2048\]", line) for line in bodies[short_branch])
+    temporaries = lambda c: c.memory_analysis().temp_size_in_bytes  # noqa: E731
+    assert temporaries(two_paths) <= temporaries(one_path)
 
 
 # --- the composed programs the old on-chip smoke covered ---------------------
