@@ -1,0 +1,306 @@
+"""Kimi-Linear's block (``model_type`` ``kimi_linear``): Kimi Delta Attention
+(KDA) layers three to one with latent attention that carries no positions,
+sigmoid-routed sparse experts beside a shared expert after a leading dense
+layer.
+
+Written from the published ``config.json``
+(https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct/blob/main/config.json)
+and the layer equations docs/KIMI_LINEAR.md states; the fields of
+:class:`KimiLinearConfig` are that file's keys (its ``linear_attn_config``
+group flattened).  The two mixers share no code with each other: KDA is a
+recurrence over a ``[128, 128]`` state a head (:mod:`adapcc_tpu.ops.kda`), the
+latent layer a causal softmax whose scores run over 192 channels and whose
+values over 128 (:mod:`adapcc_tpu.ops.flash_attention`).  Norm, gated MLP,
+the expert layer with its share, the remat table and the training loss are
+:mod:`adapcc_tpu.models.trinity`'s: the router is Trinity's to the letter
+(sigmoid scores, a bias for the choice only, top-k of one group,
+renormalised, scaled), so a chip holds ``experts_held`` of the
+``num_experts`` from ``expert_offset`` on, as there.
+
+The model returns ``(logits, sizes)`` as ``Trinity`` does, so
+``trinity.stateful_loss`` and ``trinity.initial_model_state`` serve it.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any, Dict, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from adapcc_tpu.models.trinity import _REMAT, GatedMLP, RMSNorm, SparseExperts, _dense
+from adapcc_tpu.utils.observability import default_registry
+
+#: ``l2norm(x) = x * rsqrt(sum(x^2) + L2_EPS)`` over a head
+L2_EPS = 1e-6
+
+
+@dataclass(frozen=True)
+class KimiLinearConfig:
+    vocab_size: int = 163840
+    hidden_size: int = 2304
+    intermediate_size: int = 9216          # the leading dense layers' FFN
+    moe_intermediate_size: int = 1024      # every expert's, the shared one's too
+    num_hidden_layers: int = 27
+    first_k_dense_replace: int = 1
+    #: ``linear_attn_config``: the layers of each kind, numbered from 1 as published
+    kda_layers: Tuple[int, ...] = (1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19, 21, 22, 23, 25, 26)
+    full_attn_layers: Tuple[int, ...] = (4, 8, 12, 16, 20, 24, 27)
+    linear_attn_num_heads: int = 32        # ``linear_attn_config.num_heads``
+    linear_attn_head_dim: int = 128        # ``linear_attn_config.head_dim``; the low-rank gates' inner size too
+    short_conv_kernel_size: int = 4
+    num_attention_heads: int = 32          # the latent layers'
+    kv_lora_rank: int = 512
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64             # carried, never rotated: ``mla_use_nope``
+    v_head_dim: int = 128
+    mla_use_nope: bool = True
+    num_experts: int = 256
+    num_experts_per_token: int = 8
+    num_shared_experts: int = 1
+    moe_router_activation_func: str = "sigmoid"
+    moe_renormalize: bool = True
+    routed_scaling_factor: float = 2.446
+    num_expert_group: int = 1
+    topk_group: int = 1
+    rms_norm_eps: float = 1e-5
+    tie_word_embeddings: bool = False
+    hidden_act: str = "silu"
+    #: routed experts held here, ``expert_offset … expert_offset + experts_held``
+    #: of ``num_experts``; None holds them all
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    dtype: jnp.dtype = jnp.bfloat16
+    #: recomputation of a layer in the backward pass: "none", "dots", "full"
+    remat: str = "none"
+
+    def __post_init__(self):
+        if (
+            self.moe_router_activation_func != "sigmoid" or self.hidden_act != "silu" or self.tie_word_embeddings
+            or self.num_shared_experts != 1 or self.num_expert_group != 1 or self.topk_group != 1
+            or self.q_lora_rank is not None or not self.mla_use_nope
+        ):
+            raise ValueError(
+                "only the published kimi_linear settings are implemented: sigmoid scores in one group, "
+                "silu, one shared expert, an untied head, no query rank, no rotation"
+            )
+        if self.remat not in _REMAT:
+            raise ValueError(f"remat {self.remat!r} not in {sorted(_REMAT)}")
+        self.kinds   # every layer is of one kind
+        if not 0 <= self.expert_offset <= self.num_experts - self.held:
+            raise ValueError(f"experts {self.expert_offset}+{self.held} of {self.num_experts}")
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """``"kda"`` or ``"mla"`` for each layer run here: the first
+        ``num_hidden_layers`` of the published lists."""
+        kinds = []
+        for i in range(1, self.num_hidden_layers + 1):
+            if (i in self.kda_layers) == (i in self.full_attn_layers):
+                raise ValueError(f"layer {i} must be in exactly one of kda_layers and full_attn_layers")
+            kinds.append("kda" if i in self.kda_layers else "mla")
+        return tuple(kinds)
+
+    # what models/trinity.py's shared modules read, under its key names
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None else int(self.experts_held)
+
+    @property
+    def num_experts_per_tok(self) -> int:
+        return self.num_experts_per_token
+
+    @property
+    def route_norm(self) -> bool:
+        return self.moe_renormalize
+
+    @property
+    def route_scale(self) -> float:
+        return self.routed_scaling_factor
+
+    @property
+    def num_dense_layers(self) -> int:
+        return self.first_k_dense_replace
+
+    @staticmethod
+    def from_config(config: Dict[str, Any], **program) -> "KimiLinearConfig":
+        """From a ``config.json``-shaped mapping (keys that are no field are
+        passed over), ``program`` the fields that are the program's own."""
+        names = set(KimiLinearConfig.__dataclass_fields__)
+        group = config.get("linear_attn_config", {})
+        fields = {k: v for k, v in config.items() if k in names}
+        fields.update(
+            kda_layers=tuple(group.get("kda_layers", ())), full_attn_layers=tuple(group.get("full_attn_layers", ())),
+            linear_attn_num_heads=group["num_heads"], linear_attn_head_dim=group["head_dim"],
+            short_conv_kernel_size=group["short_conv_kernel_size"],
+        )
+        fields.update(program)
+        return KimiLinearConfig(**fields)
+
+    @staticmethod
+    def tiny(**over) -> "KimiLinearConfig":
+        """Test-sized: a dense KDA layer, a KDA and a latent expert layer and
+        a KDA one, 8 experts top-2 beside a shared one; the kernels run (in
+        the interpreter off the chip)."""
+        base = dict(
+            vocab_size=256, hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
+            num_hidden_layers=4, kda_layers=(1, 2, 4), full_attn_layers=(3,),
+            linear_attn_num_heads=2, linear_attn_head_dim=16, num_attention_heads=2,
+            kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            num_experts=8, num_experts_per_token=2, routed_scaling_factor=1.5, dtype=jnp.float32,
+        )
+        base.update(over)
+        return KimiLinearConfig(**base)
+
+
+def l2norm(x):
+    x32 = x.astype(jnp.float32)
+    return (x32 * jax.lax.rsqrt(jnp.sum(jnp.square(x32), axis=-1, keepdims=True) + L2_EPS)).astype(x.dtype)
+
+
+def short_conv(x, taps):
+    """A causal depthwise convolution over time: ``y_t = sum_j taps[j] *
+    x_{t - (K - 1) + j}`` for ``x [B, T, C]``, ``taps [K, C]``, zeros before
+    the sequence, no bias; summed in float32."""
+    K, T = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+    y = sum(taps[j].astype(jnp.float32) * padded[:, j:j + T] for j in range(K))
+    return y.astype(x.dtype)
+
+
+def taps_init(key, shape, dtype=jnp.float32):
+    """A short convolution's taps ``[K, channels]``: uniform(-1/sqrt(K), 1/sqrt(K))."""
+    bound = shape[0] ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def a_log_init(key, shape, dtype=jnp.float32):
+    """``A_log = log(uniform(1, 16))``: a head forgets 1 to 16 times as fast as its gate says."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def dt_bias_init(key, shape, dtype=jnp.float32):
+    """``softplus(dt_bias)`` log-uniform in [0.001, 0.1], so that a step's
+    decay ``exp(-exp(A_log) softplus(dt_bias))`` starts between 0.2 and 0.999:
+    neither forgetting all nor nothing."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, jnp.log(0.001), jnp.log(0.1)))
+    return dt + jnp.log(-jnp.expm1(-dt))       # softplus^-1
+
+
+class KDAMixer(nn.Module):
+    """Kimi Delta Attention: q, k, v behind a short convolution, a per-channel
+    decay and a write strength from the token, the gated delta rule, a gated
+    normed output."""
+
+    cfg: KimiLinearConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from adapcc_tpu.ops.kda import kda
+
+        cfg = self.cfg
+        B, T, _ = x.shape
+        H, D, K = cfg.linear_attn_num_heads, cfg.linear_attn_head_dim, cfg.short_conv_kernel_size
+
+        def mixed(name):
+            y = _dense(H * D, cfg, f"{name}_proj")(x)
+            return nn.silu(short_conv(y, self.param(f"{name}_conv", taps_init, (K, H * D)))).reshape(B, T, H, D)
+
+        q, k, v = l2norm(mixed("q")), l2norm(mixed("k")), mixed("v")
+        with jax.named_scope("kda_gate"):
+            a_log = self.param("A_log", a_log_init, (H,))
+            dt_bias = self.param("dt_bias", dt_bias_init, (H * D,))
+            f = _dense(H * D, cfg, "f_b_proj")(_dense(D, cfg, "f_a_proj")(x))
+            g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(
+                f.astype(jnp.float32) + dt_bias
+            ).reshape(B, T, H, D)
+            beta = jax.nn.sigmoid(_dense(H, cfg, "b_proj")(x).astype(jnp.float32))
+        with jax.named_scope("kda_scan"):
+            o = kda(q, k, v, g, beta)
+        o = RMSNorm(cfg.rms_norm_eps, name="o_norm")(o)     # one weight of head_dim for every head
+        gate = _dense(H * D, cfg, "g_b_proj")(_dense(D, cfg, "g_a_proj")(x))
+        return _dense(cfg.hidden_size, cfg, "o_proj")(o.reshape(B, T, H * D) * jax.nn.sigmoid(gate))
+
+
+class MLAMixer(nn.Module):
+    """Latent attention without positions: keys and values come up from a
+    normed latent of ``kv_lora_rank``; ``qk_rope_head_dim`` more key channels
+    come straight from the token, the same for every head and never rotated."""
+
+    cfg: KimiLinearConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        B, T, _ = x.shape
+        H, nope, pe, dv = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        q = _dense(H * (nope + pe), cfg, "q_proj")(x).reshape(B, T, H, nope + pe)
+        latent, k_pe = jnp.split(_dense(cfg.kv_lora_rank + pe, cfg, "kv_a_proj_with_mqa")(x), [cfg.kv_lora_rank], axis=-1)
+        up = _dense(H * (nope + dv), cfg, "kv_b_proj")(RMSNorm(cfg.rms_norm_eps, name="kv_a_layernorm")(latent))
+        k_nope, v = jnp.split(up.reshape(B, T, H, nope + dv), [nope], axis=-1)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe[:, :, None, :], (B, T, H, pe))], axis=-1)
+        metrics = default_registry()
+        metrics.gauge("mla.qk_dim", nope + pe)
+        metrics.gauge("mla.v_dim", dv)
+        from adapcc_tpu.ops import flash_attention
+
+        with jax.named_scope("mla_attn"):
+            o = flash_attention(q, k, v, causal=True)
+        return _dense(cfg.hidden_size, cfg, "o_proj")(o.reshape(B, T, H * dv))
+
+
+class Block(nn.Module):
+    """One layer, two norms: ``h += mixer(norm(h))``, then ``h += ffn(norm(h))``."""
+
+    cfg: KimiLinearConfig
+    kind: str
+    sparse: bool
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.cfg
+        mixer = KDAMixer if self.kind == "kda" else MLAMixer
+        h = h + mixer(cfg, name="self_attn")(RMSNorm(cfg.rms_norm_eps, name="input_layernorm")(h))
+        x = RMSNorm(cfg.rms_norm_eps, name="post_attention_layernorm")(h)
+        if self.sparse:
+            m, sizes = SparseExperts(cfg, name="mlp")(x)
+        else:
+            m, sizes = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(x), None
+        return h + m, sizes
+
+
+class KimiLinear(nn.Module):
+    cfg: KimiLinearConfig
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray, return_hidden: bool = False):
+        """``tokens [B, T]`` → ``(logits [B, T, vocab] float32, sizes [expert
+        layers, experts_held] int32)``; with ``return_hidden`` the final
+        norm's output stands in for the logits.  No positions anywhere: the
+        KDA layers carry the order."""
+        cfg = self.cfg
+        h = nn.Embed(
+            cfg.vocab_size, cfg.hidden_size, embedding_init=nn.initializers.normal(0.02),
+            dtype=cfg.dtype, name="embed_tokens",
+        )(tokens)
+        metrics = default_registry()
+        metrics.gauge("model.layers_kda", cfg.kinds.count("kda"))
+        metrics.gauge("model.layers_mla", cfg.kinds.count("mla"))
+        policy = _REMAT[cfg.remat]
+        block = Block if policy is False else nn.remat(Block, policy=policy)
+        sizes = []
+        for i, kind in enumerate(cfg.kinds):
+            h, given = block(cfg, kind, i >= cfg.first_k_dense_replace, name=f"layers_{i}")(h)
+            if given is not None:
+                sizes.append(given)
+        h = RMSNorm(cfg.rms_norm_eps, name="norm")(h)
+        sizes = jnp.stack(sizes) if sizes else jnp.zeros((0, cfg.held), jnp.int32)
+        head = self.param("lm_head", nn.initializers.normal(0.02), (cfg.vocab_size, cfg.hidden_size))
+        if return_hidden:
+            return h, sizes
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum("btd,vd->btv", h.astype(cfg.dtype), head.astype(cfg.dtype))
+        return logits.astype(jnp.float32), sizes

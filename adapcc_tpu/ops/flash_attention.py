@@ -50,6 +50,12 @@ body for each of the 16 positions would be 70.
 K/V block index, nothing is repeated in HBM, and the dk/dv kernel writes one
 fp32 partial for each query head that the wrapper sums over the group.
 
+**A head size of its own for v.**  ``v`` may be ``[B, T, H_kv, D_v]`` with
+``D_v != D`` (latent attention: scores over 192 channels, values over 128):
+the two score products run at ``D``, the three value products at ``D_v``,
+``o`` and ``dv`` come out ``D_v`` wide.  With ``D_v == D`` the kernels lower
+to what they lowered to before (tests/test_chip_compile.py holds the text).
+
 **Which dtype.**  The seven products take their operands in the inputs' own
 dtype and accumulate in fp32: bf16 q/k/v/do go to the MXU as bf16, and ``p``
 and ``dS`` are cast to that dtype for their four products (what the XLA
@@ -299,7 +305,7 @@ def _held(base, r, first, band):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k, window, seq, band):
     q = q_ref[0]
-    bq, d = q.shape
+    bq = q.shape[0]
     T = seq
     n_k = T // block_k
 
@@ -321,7 +327,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, 
         carry = (
             jnp.full((bq, 1), _NEG_INF, jnp.float32),
             jnp.zeros((bq, 1), jnp.float32),
-            jnp.zeros((bq, d), jnp.float32),
+            jnp.zeros((bq, v_ref.shape[-1]), jnp.float32),
         )
         m, l, acc = _band(bounds, tile, carry)
         o_ref[0] = (acc / l).astype(o_ref.dtype)
@@ -371,7 +377,6 @@ def _dkv_kernel(
 ):
     k = k_ref[0]
     v = v_ref[0]
-    bk, d = k.shape
     T = seq
     n_q = T // block_q
 
@@ -397,8 +402,8 @@ def _dkv_kernel(
             dk = dk + _dot(ds.astype(q.dtype), q, (1, 0))
             return dk, dv
 
-        zeros = jnp.zeros((bk, d), jnp.float32)
-        dk, dv = _band(bounds, tile, (zeros, zeros))
+        zeros = jnp.zeros(k.shape, jnp.float32)
+        dk, dv = _band(bounds, tile, (zeros, zeros if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32)))
         dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -418,6 +423,7 @@ TILE_TABLE = (
     ((4096, 64, 2), (512, 512)),   # bf16, measured at T=1,024 (B.H = 144 and 32) and T=4,096
     ((1024, 64, 4), (512, 512)),   # fp32, measured at T=1,024
     ((8192, 128, 2), (512, 512)),  # bf16, head size 128 at T=8,192, window 2,048 and none (PERF.md §6, PR 26)
+    ((8192, 192, 2), (512, 512)),  # bf16, q/k of 192 over v of 128 at T=8,192 (PERF.md §6, PR 32)
     ((float("inf"),) * 3, (128, 128)),   # not measured: the tile every shape ran before
 )
 
@@ -482,8 +488,8 @@ _VMEM_ASK_OVER = 6 << 20
 _VMEM_LIMIT = 96 << 20
 
 
-def _compiler_params(T: int, D: int, dtype) -> dict:
-    resident = 2 * 2 * T * D * jnp.dtype(dtype).itemsize
+def _compiler_params(T: int, D: int, Dv: int, dtype) -> dict:
+    resident = 2 * T * (D + Dv) * jnp.dtype(dtype).itemsize
     if resident <= _VMEM_ASK_OVER:
         return {}
     return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)}
@@ -542,28 +548,29 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window):
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
 def _fwd_call(q, k, v, scale, causal, bq, bk, interp, window):
     BH, T, D = q.shape
+    Dv = v.shape[-1]
     kv = _kv_index(BH // k.shape[0])
     band = _band_blocks(T, bq, bk, window)
     ahead = 0 if band is None else band - 1     # the band starts this many blocks before the diagonal
-    held = _resident(T, D, band, bk, kv, lambda i: jnp.maximum(i - ahead, 0))
+    held = lambda d: _resident(T, d, band, bk, kv, lambda i: jnp.maximum(i - ahead, 0))  # noqa: E731
     out, lse3 = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk, window=window,
             seq=T, band=band,
         ),
         grid=(BH, T // bq),
-        in_specs=[pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)), held, held],
+        in_specs=[pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)), held(D), held(Dv)],
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, bq, _LSE_LANES), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, T, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, T, _LSE_LANES), jnp.float32),
         ],
         interpret=interp,
         name="flash_fwd",
-        **_compiler_params(T if band is None else band * bk, D, k.dtype),
+        **_compiler_params(T if band is None else band * bk, D, Dv, k.dtype),
     )(q, k, v)
     return out, lse3[:, :, 0]
 
@@ -589,6 +596,7 @@ def _flash_bwd_core(scale, causal, block_q, block_k, interpret, window, res, do,
 def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window):
     q, k, v, out, lse = res
     BH, T, D = q.shape
+    Dv = v.shape[-1]
     groups = BH // k.shape[0]
     kv = _kv_index(groups)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
@@ -601,7 +609,7 @@ def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window):
     band = _band_blocks(T, bq, bk, window)
     rows = T if band is None else band * bk
     ahead = 0 if band is None else band - 1
-    held = _resident(T, D, band, bk, kv, lambda i: jnp.maximum(i - ahead, 0))
+    held = lambda d: _resident(T, d, band, bk, kv, lambda i: jnp.maximum(i - ahead, 0))  # noqa: E731
     dq = pl.pallas_call(
         functools.partial(
             _dq_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk, window=window,
@@ -610,9 +618,9 @@ def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window):
         grid=(BH, T // bq),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-            held,
-            held,
-            pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
+            held(D),
+            held(Dv),
+            pl.BlockSpec((1, bq, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, bq, _LSE_LANES), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, bq, _LSE_LANES), lambda b, i: (b, i, 0)),
         ],
@@ -620,14 +628,14 @@ def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window):
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         interpret=interp,
         name="flash_bwd_dq",
-        **_compiler_params(rows, D, k.dtype),
+        **_compiler_params(rows, D, Dv, k.dtype),
     )(q, k, v, do, lse3, delta3)
 
     # grouped heads: each query head writes its own fp32 share of dk and dv
     # (a grid program owns its output block), summed over the group below
     part = jnp.float32 if groups > 1 else None
     last = 0 if band is None else T // bq - band    # the last block a band of queries can start at
-    seen = _resident(T, D, band, bq, lambda b: b, lambda j: jnp.minimum(j, last))
+    seen = lambda d: _resident(T, d, band, bq, lambda b: b, lambda j: jnp.minimum(j, last))  # noqa: E731
     if band is None:
         stat = pl.BlockSpec((1, 1, T), lambda b, j: (b, 0, 0))
     else:
@@ -642,28 +650,28 @@ def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window):
         ),
         grid=(BH, T // bk),
         in_specs=[
-            seen,
+            seen(D),
             pl.BlockSpec((1, bk, D), lambda b, j: (kv(b), j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j: (kv(b), j, 0)),
-            seen,
+            pl.BlockSpec((1, bk, Dv), lambda b, j: (kv(b), j, 0)),
+            seen(Dv),
             stat,
             stat,
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, j: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, T, D), part or k.dtype),
-            jax.ShapeDtypeStruct((BH, T, D), part or v.dtype),
+            jax.ShapeDtypeStruct((BH, T, Dv), part or v.dtype),
         ],
         interpret=interp,
         name="flash_bwd_dkv",
-        **_compiler_params(rows, D, q.dtype),
+        **_compiler_params(rows, D, Dv, q.dtype),
     )(q, k, v, do, lse[:, None, :], delta[:, None, :])
     if groups > 1:
         dk = dk.reshape(BH // groups, groups, T, D).sum(axis=1).astype(k.dtype)
-        dv = dv.reshape(BH // groups, groups, T, D).sum(axis=1).astype(v.dtype)
+        dv = dv.reshape(BH // groups, groups, T, Dv).sum(axis=1).astype(v.dtype)
     return dq, dk, dv
 
 
@@ -699,10 +707,11 @@ def _bthd_call(kernel_entry, q, k, v, causal, scale, block_q, block_k, interpret
     return its raw outputs plus the dims needed to restore the layout."""
     B, T, H, D = q.shape
     Hkv = k.shape[2] if k.ndim == 4 else 0
-    if k.shape != v.shape or k.shape != (B, T, Hkv, D) or Hkv == 0 or H % Hkv:
+    if k.shape[:-1] != v.shape[:-1] or k.shape != (B, T, Hkv, D) or Hkv == 0 or H % Hkv:
         raise ValueError(
             f"q/k/v shapes differ: {q.shape} {k.shape} {v.shape} (k and v may "
-            "only carry fewer heads than q, a whole number of query heads to each)"
+            "only carry fewer heads than q, a whole number of query heads to each; "
+            "v alone may have a head size of its own)"
         )
     if window is not None:
         if not causal or window < 1:
@@ -710,12 +719,12 @@ def _bthd_call(kernel_entry, q, k, v, causal, scale, block_q, block_k, interpret
         window = None if window >= T else int(window)   # a band over the whole triangle
     if scale is None:
         scale = float(1.0 / np.sqrt(D))
-    to_bhtd = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, T, D)  # noqa: E731
+    to_bhtd = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, T, x.shape[-1])  # noqa: E731
     raw = kernel_entry(
         to_bhtd(q), to_bhtd(k), to_bhtd(v),
         scale, causal, block_q, block_k, interpret, window,
     )
-    return raw, (B, T, H, D)
+    return raw, (B, T, H, v.shape[-1])
 
 
 def flash_attention(

@@ -1,0 +1,486 @@
+"""Kimi Delta Attention's recurrence as chunked Pallas TPU kernels.
+
+For one head, with keys ``k_t`` and queries ``q_t`` of ``d_k`` channels,
+values ``v_t`` of ``d_v``, a per-channel log-decay ``g_t <= 0`` and a write
+strength ``beta_t`` in (0, 1), the state ``S`` (``[d_k, d_v]``, zero at the
+start) follows the gated delta rule
+
+    S_t = (I - beta_t k_t k_t^T) diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t * scale
+
+(docs/KIMI_LINEAR.md).  A step at a time that is ``T`` dependent rank-one
+updates; here a **chunk** of ``C`` steps is taken at once.  With ``G`` the
+log-decay summed from the chunk's first step (inclusive), ``kb = beta k`` and
+
+    Phi(x, y)[r, i] = sum_c x[r, c] y[i, c] exp(G[r, c] - G[i, c])     (i <= r)
+
+the chunk that starts from state ``S`` gives
+
+    A  = Phi(k, kb) strictly under the diagonal        [C, C]
+    U  = (I + A)^-1 (v - (k exp G) S)                  [C, d_v]  what each step writes
+    o  = scale ((q exp G) S + Phi(q, kb) U)
+    S' = diag(exp G_C) S + (kb exp(G_C - G))^T U       the next chunk's state
+
+**The decay is per channel**, so ``Phi`` is not one product: ``exp(G_r) *
+exp(-G_i)`` overflows where a channel forgets fast (64 steps at ``g = -20``
+would be ``exp(1280)``).  A chunk is cut into sub-blocks of ``_SUB = 8`` rows.
+Between sub-blocks the two operands are decayed to the row block's first row
+(both exponents are then <= 0) and multiplied on the MXU; inside a sub-block
+the exponent ``G_r - G_i`` is formed per pair, one column of the block at a
+time on the VPU.  Nothing is clamped: a product that underflows is a term
+that is zero in float32.
+
+``(I + A)^-1`` is built from products alone: the sub-blocks on the diagonal
+by ``(I - L)(I + L^2)(I + L^4)`` (``L^8 = 0``), then the blocks under them by
+the same identity over the ``C / _SUB`` block rows; a grid step's chunks go
+through those products together.
+
+**What is float32.**  ``G``, every exponential, ``A``, the inverse, ``U`` and
+the state stay float32 and the products that build the inverse and ``U`` run
+at full precision.  The other products take their operands in the inputs'
+dtype (bfloat16 in a bf16 model) and accumulate in float32, as the flash
+kernels do.
+
+**Backward.**  The forward kernel keeps the state at the start of every grid
+step (``[B H, T / block, d_v, d_k]`` float32: 64 KB each).  The backward kernel
+walks the grid steps from the last to the first; in each it first recomputes
+the chunks' ``Phi(q, kb)``, inverses, ``U`` and states forward (into VMEM
+scratch, never HBM), then walks its chunks backward with the state's
+cotangent carried in scratch.  The equations are in docs/KIMI_LINEAR.md.
+
+The chunk follows the shape (:func:`chunk_plan`); there is nothing to tune
+from outside.  The state is held transposed, ``[d_v, d_k]``, so that the
+per-channel decay scales lanes.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from adapcc_tpu.ops.kernel_mode import resolve_interpret
+from adapcc_tpu.utils.observability import default_registry
+
+_SUB = 8       # rows of a sub-block: pairs inside one are formed on the VPU
+_CHUNK = 64    # rows of a chunk: eight sub-blocks, one solve
+_BLOCK = 512   # rows of a grid step at most: eight chunks behind one DMA
+_NEG = -1e30
+
+_NN = ((1,), (0,))
+_NT = ((1,), (1,))
+_TN = ((0,), (0,))
+
+
+def chunk_plan(T: int) -> Tuple[int, int, int]:
+    """``(chunk, chunks per grid step, padded T)`` for a sequence of ``T``
+    steps: chunks of 64 (a short sequence: what holds it, in sub-blocks), as
+    many to a grid step as divide the padded length, eight at most."""
+    chunk = min(_CHUNK, -(-T // _SUB) * _SUB)
+    padded = -(-T // chunk) * chunk
+    n = padded // chunk
+    per = max(p for p in (8, 4, 2, 1) if p * chunk <= _BLOCK and n % p == 0)
+    return chunk, per, padded
+
+
+class _Geometry(NamedTuple):
+    """Index planes of a ``[C, C]`` chunk, made once a kernel."""
+
+    row: jnp.ndarray        # [C, C] row index
+    col: jnp.ndarray        # [C, C] column index
+    rel: jnp.ndarray        # [C, C] column minus the first column of the row's sub-block
+    eye: jnp.ndarray        # [C, C] float32 identity
+    same: jnp.ndarray       # [C, C] row and column in one sub-block
+    rowmod: jnp.ndarray     # [C, 1] row index inside its sub-block
+    rowid: jnp.ndarray      # [C, 1] row index
+
+
+def _geometry(C: int) -> _Geometry:
+    row = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    start = (row // _SUB) * _SUB
+    rowid = lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    return _Geometry(
+        row=row, col=col, rel=col - start, eye=(row == col).astype(jnp.float32),
+        same=(col // _SUB) == (row // _SUB), rowmod=rowid % _SUB, rowid=rowid,
+    )
+
+
+def _hi(a, b, dims):
+    """A float32 product at full precision (the inverse and what it solves);
+    of ``[P, ·, ·]`` operands, one product for each ``P``."""
+    batch = ((0,), (0,)) if a.ndim == 3 else ((), ())
+    dims = tuple(tuple(d + a.ndim - 2 for d in side) for side in dims)
+    return lax.dot_general(
+        a, b, (dims, batch), precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32
+    )
+
+
+def _mx(a, b, dims, dtype):
+    """An MXU product with operands in the inputs' dtype, float32 out."""
+    precision = lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32 else None
+    return lax.dot_general(
+        a.astype(dtype), b.astype(dtype), (dims, ((), ())), precision=precision,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _block_row(x, j: int):
+    """Row ``j`` of every sub-block of ``x [C, d]``, repeated over its block."""
+    C, d = x.shape
+    x3 = x.reshape(C // _SUB, _SUB, d)
+    return jnp.broadcast_to(x3[:, j:j + 1, :], x3.shape).reshape(C, d)
+
+
+def _rows(x, a: int):
+    return x[a * _SUB:(a + 1) * _SUB]
+
+
+def _stack_rows(parts, like):
+    """Row blocks 1.. of a ``[C, ·]`` array from ``parts``; block 0 is zero."""
+    return jnp.concatenate([jnp.zeros_like(parts[0])] + parts, axis=0) if parts else jnp.zeros_like(like)
+
+
+def _scores(xs, y, G, geo: _Geometry, dtype):
+    """``Phi(x, y)`` for each ``x`` of ``xs``: ``[C, C]``, zero above the diagonal."""
+    C = G.shape[0]
+    lead = jnp.exp(G - _block_row(G, 0))             # to the row's sub-block's first row: <= 1
+    offs = [[] for _ in xs]
+    for a in range(1, C // _SUB):
+        yd = y * jnp.exp(jnp.minimum(G[a * _SUB:a * _SUB + 1] - G, 0.0))   # rows before block a: <= 1
+        for parts, x in zip(offs, xs):
+            parts.append(_mx(_rows(x * lead, a), yd, _NT, dtype))
+    # a row block's product holds every column: keep those before the block
+    outs = [jnp.where(geo.rel < 0, _stack_rows(parts, geo.eye), 0.0) for parts in offs]
+    for j in range(_SUB):
+        e = jnp.exp(jnp.where(geo.rowmod >= j, G - _block_row(G, j), _NEG))
+        t = _block_row(y, j) * e
+        outs = [
+            jnp.where(geo.rel == j, jnp.sum(x * t, axis=1, keepdims=True), out)
+            for x, out in zip(xs, outs)
+        ]
+    return outs
+
+
+def _rows_back(Ds, y, G, geo: _Geometry, dtype):
+    """``Psi(D, y)[r, c] = sum_{i <= r} D[r, i] y[i, c] exp(G[r, c] - G[i, c])``
+    for each lower-triangular ``D`` of ``Ds``: the cotangent of ``Phi``'s row
+    operand."""
+    C = G.shape[0]
+    lead = jnp.exp(G - _block_row(G, 0))
+    offs = [[] for _ in Ds]
+    for a in range(1, C // _SUB):
+        yd = y * jnp.exp(jnp.minimum(G[a * _SUB:a * _SUB + 1] - G, 0.0))
+        before = lax.broadcasted_iota(jnp.int32, (_SUB, C), 1) < a * _SUB
+        for parts, D in zip(offs, Ds):
+            parts.append(_rows(lead, a) * _mx(jnp.where(before, _rows(D, a), 0.0), yd, _NN, dtype))
+    outs = [_stack_rows(parts, G) for parts in offs]
+    for j in range(_SUB):
+        e = jnp.exp(jnp.where(geo.rowmod >= j, G - _block_row(G, j), _NEG))
+        t = _block_row(y, j) * e
+        outs = [
+            out + jnp.sum(jnp.where(geo.rel == j, D, 0.0), axis=1, keepdims=True) * t
+            for D, out in zip(Ds, outs)
+        ]
+    return outs
+
+
+def _cols_back(pairs, G, geo: _Geometry, dtype):
+    """``sum over (Dt, x) of sum_{r >= i} Dt[i, r] x[r, c] exp(G[r, c] - G[i, c])``:
+    the cotangent of ``Phi``'s column operand, each ``Dt`` the transpose of a
+    lower-triangular cotangent."""
+    C = G.shape[0]
+    lead = jnp.exp(G - _block_row(G, 0))
+    out = jnp.zeros_like(G)
+    for a in range(1, C // _SUB):
+        w = jnp.exp(jnp.minimum(G[a * _SUB:a * _SUB + 1] - G, 0.0))
+        pick = (geo.col // _SUB == a) & (geo.row < a * _SUB)
+        for Dt, x in pairs:
+            out = out + w * _mx(jnp.where(pick, Dt, 0.0), x * lead, _NN, dtype)
+    for j in range(_SUB):
+        e = jnp.exp(jnp.where(geo.rowmod <= j, _block_row(G, j) - G, _NEG))
+        for Dt, x in pairs:
+            picked = jnp.sum(jnp.where(geo.rel == j, Dt, 0.0), axis=1, keepdims=True)
+            out = out + picked * _block_row(x, j) * e
+    return out
+
+
+def inverse(A, geo: _Geometry):
+    """``(I + A)^-1`` for ``A [C, C]`` strictly lower-triangular, or for each
+    of ``[P, C, C]``: a grid step's chunks go through the ten dependent
+    products together, so that one chunk's product fills the MXU while
+    another's drains (alone, a chunk's chain waits out each product's
+    latency: 60% of the forward kernel's time, PERF.md section 6, PR 32)."""
+    n = A.shape[-1] // _SUB
+    L = jnp.where(geo.same, A, 0.0)
+    inv, power = geo.eye - L, L
+    for _ in range(int(math.log2(_SUB)) - 1):        # (I - L)(I + L^2)(I + L^4): L^8 = 0
+        power = _hi(power, power, _NN)
+        inv = _hi(inv, geo.eye + power, _NN)
+    if n == 1:
+        return inv
+    N = _hi(inv, A - L, _NN)                          # block-strictly-lower: N^n = 0
+    out, power = geo.eye - N, N
+    for _ in range(math.ceil(math.log2(n)) - 1):
+        power = _hi(power, power, _NN)
+        out = _hi(out, geo.eye + power, _NN)
+    return _hi(out, inv, _NN)
+
+
+def _decayed(q, k, kb, G):
+    last = G[-1:]
+    eG = jnp.exp(G)
+    return eG, last, q * eG, k * eG, kb * jnp.exp(last - G)
+
+
+def chunk_scores(q, k, kb, G, geo: _Geometry, dtype):
+    """What of a chunk needs no state: ``(A, Phi(q, kb))``."""
+    Bq, Akk = _scores((q, k), kb, G, geo, dtype)
+    return jnp.where(geo.row > geo.col, Akk, 0.0), Bq
+
+
+def chunk_state(q, k, kb, v, G, St, Minv, Bq, dtype, scale: float):
+    """One chunk from state ``St [d_v, d_k]`` with its inverse and ``Phi(q,
+    kb)`` in hand: ``(o, U, next state)``, float32 arrays in and out."""
+    _, last, qd, kd, kt = _decayed(q, k, kb, G)
+    U = _hi(Minv, v - _mx(kd, St, _NT, dtype), _NN)
+    o = scale * (_mx(qd, St, _NT, dtype) + _mx(Bq, U, _NN, dtype))
+    return o, U, St * jnp.exp(last) + _mx(U, kt, _TN, dtype)
+
+
+def chunk_backward(q, k, kb, G, St, U, Minv, Bq, do, dSt, geo: _Geometry, dtype, scale: float):
+    """The chunk's cotangents ``(dq, dk, dkb, dv, dG, dSt_in)`` from ``do`` and
+    the cotangent ``dSt`` of the state it handed on."""
+    eG, last, qd, kd, kt = _decayed(q, k, kb, G)
+    elast = jnp.exp(last)
+    dos = scale * do
+    lower, upper = geo.row >= geo.col, geo.col >= geo.row
+    dqd = _mx(dos, St, _NN, dtype)
+    dBq = jnp.where(lower, _mx(dos, U, _NT, dtype), 0.0)
+    dBqT = jnp.where(upper, _mx(U, dos, _NT, dtype), 0.0)
+    dU = _mx(Bq, dos, _TN, dtype) + _mx(kt, dSt, _NT, dtype)
+    dkt = _mx(U, dSt, _NN, dtype)
+    dR = _hi(Minv, dU, _TN)
+    dA = jnp.where(geo.row > geo.col, -_mx(dR, U, _NT, dtype), 0.0)
+    dAT = jnp.where(geo.col > geo.row, -_mx(U, dR, _NT, dtype), 0.0)
+    dkd = -_mx(dR, St, _NN, dtype)
+    dSt_in = _mx(dos, qd, _TN, dtype) - _mx(dR, kd, _TN, dtype) + dSt * elast
+    Pq, Pk = _rows_back((dBq, dA), kb, G, geo, dtype)
+    Pt = _cols_back(((dAT, k), (dBqT, q)), G, geo, dtype)
+    dlast = jnp.sum(dSt * St, axis=0, keepdims=True) * elast + jnp.sum(dkt * kt, axis=0, keepdims=True)
+    dG = dqd * qd + dkd * kd - dkt * kt + q * Pq + k * Pk - kb * Pt
+    dG = dG + jnp.where(geo.rowid == G.shape[0] - 1, dlast, 0.0)
+    return dqd * eG + Pq, dkd * eG + Pk, dkt * jnp.exp(last - G) + Pt, dR, dG, dSt_in
+
+
+def _chunks(per: int, body, carry):
+    """``carry = body(c, carry)`` over a grid step's chunks, written out: the
+    scheduler then fills one chunk's waits with the next one's work."""
+    return body(0, carry) if per == 1 else lax.fori_loop(0, per, body, carry, unroll=True)
+
+
+def _at(c, chunk: int):
+    return pl.ds(c * chunk if isinstance(c, int) else pl.multiple_of(c * chunk, chunk), chunk)
+
+
+def _load(refs, c, chunk: int):
+    return [ref[0, _at(c, chunk), :].astype(jnp.float32) for ref in refs]
+
+
+def _solve_chunks(refs, invs, bqs, geo: _Geometry, chunk: int, per: int, dtype) -> None:
+    """Every chunk's ``Phi(q, kb)`` into ``bqs`` and ``(I + A)^-1`` into
+    ``invs`` (scratch ``[per, C, C]``): the scores a chunk at a time, the
+    inverses together."""
+
+    def scores(c, carry):
+        invs[c], bqs[c] = chunk_scores(*_load(refs, c, chunk), geo, dtype)
+        return carry
+
+    _chunks(per, scores, 0)
+    invs[...] = inverse(invs[...], geo)
+
+
+def _fwd_kernel(q_ref, k_ref, kb_ref, v_ref, g_ref, o_ref, start_ref, state, invs, bqs, *, chunk, per, scale):
+    geo = _geometry(chunk)
+    dtype = q_ref.dtype
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    start_ref[0, 0] = state[...]
+    _solve_chunks((q_ref, k_ref, kb_ref, g_ref), invs, bqs, geo, chunk, per, dtype)
+
+    def one(c, St):
+        q, k, kb, v, G = _load((q_ref, k_ref, kb_ref, v_ref, g_ref), c, chunk)
+        o, _, St = chunk_state(q, k, kb, v, G, St, invs[c], bqs[c], dtype, scale)
+        o_ref[0, _at(c, chunk), :] = o.astype(o_ref.dtype)
+        return St
+
+    state[...] = _chunks(per, one, state[...])
+
+
+def _bwd_kernel(
+    q_ref, k_ref, kb_ref, v_ref, g_ref, do_ref, start_ref,
+    dq_ref, dk_ref, dkb_ref, dv_ref, dg_ref,
+    dstate, states, us, invs, bqs, *, chunk, per, scale,
+):
+    geo = _geometry(chunk)
+    dtype = q_ref.dtype
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    _solve_chunks((q_ref, k_ref, kb_ref, g_ref), invs, bqs, geo, chunk, per, dtype)
+
+    def again(c, St):
+        q, k, kb, v, G = _load((q_ref, k_ref, kb_ref, v_ref, g_ref), c, chunk)
+        states[c] = St
+        _, us[c], St = chunk_state(q, k, kb, v, G, St, invs[c], bqs[c], dtype, scale)
+        return St
+
+    _chunks(per, again, start_ref[0, 0])
+
+    def back(i, dSt):
+        c = per - 1 - i
+        q, k, kb, G, do = _load((q_ref, k_ref, kb_ref, g_ref, do_ref), c, chunk)
+        dq, dk, dkb, dv, dG, dSt = chunk_backward(
+            q, k, kb, G, states[c], us[c], invs[c], bqs[c], do, dSt, geo, dtype, scale
+        )
+        at = _at(c, chunk)
+        dq_ref[0, at, :] = dq.astype(dq_ref.dtype)
+        dk_ref[0, at, :] = dk.astype(dk_ref.dtype)
+        dkb_ref[0, at, :] = dkb.astype(dkb_ref.dtype)
+        dv_ref[0, at, :] = dv.astype(dv_ref.dtype)
+        dg_ref[0, at, :] = dG
+        return dSt
+
+    dstate[...] = _chunks(per, back, dstate[...])
+
+
+def _params():
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
+# behind jax.jit, as the flash kernels are: a model's layers share one traced kernel
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _fwd_call(q, k, kb, v, G, scale, chunk, per, interp):
+    BH, T, dk = q.shape
+    dv = v.shape[-1]
+    rows = chunk * per
+    steps = T // rows
+    wide = lambda d: pl.BlockSpec((1, rows, d), lambda b, i: (b, i, 0))  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, chunk=chunk, per=per, scale=scale),
+        grid=(BH, steps),
+        in_specs=[wide(dk), wide(dk), wide(dk), wide(dv), wide(dk)],
+        out_specs=[wide(dv), pl.BlockSpec((1, 1, dv, dk), lambda b, i: (b, i, 0, 0))],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, T, dv), v.dtype),
+            jax.ShapeDtypeStruct((BH, steps, dv, dk), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((dv, dk), jnp.float32),
+            pltpu.VMEM((per, chunk, chunk), jnp.float32),
+            pltpu.VMEM((per, chunk, chunk), jnp.float32),
+        ],
+        compiler_params=_params(),
+        interpret=interp,
+        name="kda_fwd",
+    )(q, k, kb, v, G)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
+def _bwd_call(q, k, kb, v, G, starts, do, scale, chunk, per, interp):
+    BH, T, dk = q.shape
+    dv = v.shape[-1]
+    rows = chunk * per
+    steps = T // rows
+    wide = lambda d: pl.BlockSpec((1, rows, d), lambda b, i: (b, steps - 1 - i, 0))  # noqa: E731
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, chunk=chunk, per=per, scale=scale),
+        grid=(BH, steps),
+        in_specs=[
+            wide(dk), wide(dk), wide(dk), wide(dv), wide(dk), wide(dv),
+            pl.BlockSpec((1, 1, dv, dk), lambda b, i: (b, steps - 1 - i, 0, 0)),
+        ],
+        out_specs=[wide(dk), wide(dk), wide(dk), wide(dv), wide(dk)],
+        out_shape=[like(q), like(k), like(kb), like(v), like(G)],
+        scratch_shapes=[
+            pltpu.VMEM((dv, dk), jnp.float32),
+            pltpu.VMEM((per, dv, dk), jnp.float32),
+            pltpu.VMEM((per, chunk, dv), jnp.float32),
+            pltpu.VMEM((per, chunk, chunk), jnp.float32),
+            pltpu.VMEM((per, chunk, chunk), jnp.float32),
+        ],
+        compiler_params=_params(),
+        interpret=interp,
+        name="kda_bwd",
+    )(q, k, kb, v, G, do, starts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _kda_chunked(q, k, kb, v, G, scale, chunk, per, interpret):
+    return _kda_fwd(q, k, kb, v, G, scale, chunk, per, interpret)[0]
+
+
+def _kda_fwd(q, k, kb, v, G, scale, chunk, per, interpret):
+    interp = resolve_interpret(interpret, "kda")
+    o, starts = _fwd_call(q, k, kb, v, G, scale, chunk, per, interp)
+    return o, (q, k, kb, v, G, starts)
+
+
+def _kda_bwd(scale, chunk, per, interpret, res, do):
+    q, k, kb, v, G, starts = res
+    interp = resolve_interpret(interpret, "kda")
+    return tuple(_bwd_call(q, k, kb, v, G, starts, do, scale, chunk, per, interp))
+
+
+_kda_chunked.defvjp(_kda_fwd, _kda_bwd)
+
+
+def kda(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    g: jnp.ndarray,
+    beta: jnp.ndarray,
+    scale: Optional[float] = None,
+    interpret: Optional[bool] = None,
+) -> jnp.ndarray:
+    """The gated delta rule over ``q, k [B, T, H, d_k]``, ``v [B, T, H, d_v]``,
+    the log-decay ``g [B, T, H, d_k]`` (``<= 0``) and ``beta [B, T, H]``, from a
+    zero state: ``o [B, T, H, d_v]`` in ``v``'s dtype.  ``g`` and ``beta`` are
+    taken in float32 whatever they come in; ``scale`` defaults to
+    ``1 / sqrt(d_k)``.  Differentiable in all five.  ``interpret=None`` asks
+    :func:`ops.kernel_mode.resolve_interpret` (site ``"kda"``)."""
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    if k.shape != q.shape or v.shape[:3] != q.shape[:3] or g.shape != q.shape or beta.shape != q.shape[:3]:
+        raise ValueError(f"kda shapes: q {q.shape} k {k.shape} v {v.shape} g {g.shape} beta {beta.shape}")
+    if scale is None:
+        scale = float(1.0 / math.sqrt(dk))
+    chunk, per, padded = chunk_plan(T)
+    metrics = default_registry()
+    metrics.gauge("kda.chunk", chunk)
+    metrics.gauge("kda.tiles", B * H * (padded // chunk))
+    metrics.gauge("kda.state_bytes", B * H * dk * dv * 4)
+
+    def heads(x):   # [B, T, H, d] -> [B H, padded T, d]; a padded step writes and forgets nothing
+        x = x.transpose(0, 2, 1, 3).reshape(B * H, T, x.shape[-1])
+        return jnp.pad(x, ((0, 0), (0, padded - T), (0, 0)))
+
+    kb = (k.astype(jnp.float32) * beta.astype(jnp.float32)[..., None]).astype(k.dtype)
+    G = jnp.cumsum(heads(g.astype(jnp.float32)).reshape(B * H, padded // chunk, chunk, dk), axis=2)
+    o = _kda_chunked(
+        heads(q), heads(k), heads(kb), heads(v), G.reshape(B * H, padded, dk), scale, chunk, per, interpret
+    )
+    return o[:, :T].reshape(B, H, T, dv).transpose(0, 2, 1, 3)

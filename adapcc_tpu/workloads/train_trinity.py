@@ -75,8 +75,12 @@ def build_trainer(cfg, tx, mesh, loss: str = "dense", donate_state: bool = True)
     return trainer, model
 
 
-def run(args, report: Optional[dict] = None) -> Tuple[float, float]:
-    """Train; returns (first epoch's mean loss, last epoch's)."""
+def train(args, cfg, build, banner: str, report: Optional[dict] = None) -> Tuple[float, float]:
+    """The loop of an expert model's entry point (this one's and
+    ``train_kimi_linear``'s): ``build(cfg, tx, mesh, loss)`` gives ``(trainer,
+    model)``; ``args`` carries ``vocab``, ``seq``, ``batch``, ``corpus_tokens``,
+    ``epochs``, ``lr``, ``world``, ``loss``.  Returns (first epoch's mean
+    loss, last epoch's)."""
     from adapcc_tpu.launch import maybe_initialize_distributed
 
     maybe_initialize_distributed()
@@ -88,34 +92,24 @@ def run(args, report: Optional[dict] = None) -> Tuple[float, float]:
     from adapcc_tpu.comm.mesh import build_world_mesh
     from adapcc_tpu.data import device_batches
     from adapcc_tpu.models.moe import record_routing
-    from adapcc_tpu.models.trinity import TrinityConfig, initial_model_state
+    from adapcc_tpu.models.trinity import initial_model_state
     from adapcc_tpu.utils.observability import default_registry
 
     mesh = build_world_mesh(args.world)
     world = int(mesh.devices.size)
     if args.batch % world:
         raise ValueError(f"--batch {args.batch} must divide by world {world}")
-    cfg = TrinityConfig(
-        vocab_size=args.vocab, hidden_size=args.hidden, intermediate_size=args.dense_width,
-        moe_intermediate_size=args.expert_width, num_hidden_layers=args.layers,
-        num_dense_layers=args.dense_layers, num_attention_heads=args.heads,
-        num_key_value_heads=args.kv_heads, head_dim=args.head_dim, sliding_window=args.window,
-        global_attn_every_n_layers=args.global_every, num_experts=args.experts,
-        num_experts_per_tok=args.top_k, route_scale=args.route_scale,
-        experts_held=args.experts_held, expert_offset=args.expert_offset,
-        dtype=jnp.dtype(args.dtype), attention=args.attn, remat=args.remat,
-    )
     rows = pack_sequences(markov_corpus(args.corpus_tokens, args.vocab, seed=0), args.seq)
     if len(rows) < args.batch:
         raise ValueError(f"corpus too small: {len(rows)} rows of {args.seq} for a batch of {args.batch}")
 
     tx = optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(args.lr, weight_decay=0.01))
-    trainer, model = build_trainer(cfg, tx, mesh, args.loss)
+    trainer, model = build(cfg, tx, mesh, args.loss)
     params = model.init(jax.random.PRNGKey(0), jnp.asarray(rows[:1]))
     state = trainer.init_state(params, initial_model_state(cfg))
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params))
     print(
-        f"trinity: {n_params / 1e6:.2f} M parameters, layers {list(cfg.kinds)}, "
+        f"{banner}: {n_params / 1e6:.2f} M parameters, layers {list(cfg.kinds)}, "
         f"experts {cfg.expert_offset}..{cfg.expert_offset + cfg.held} of {cfg.num_experts} held, world {world}"
     )
 
@@ -139,6 +133,25 @@ def run(args, report: Optional[dict] = None) -> Tuple[float, float]:
     if report is not None:
         report.update(trainer=trainer, state=state, losses=means)
     return means[0], means[-1]
+
+
+def run(args, report: Optional[dict] = None) -> Tuple[float, float]:
+    """Train; returns (first epoch's mean loss, last epoch's)."""
+    import jax.numpy as jnp
+
+    from adapcc_tpu.models.trinity import TrinityConfig
+
+    cfg = TrinityConfig(
+        vocab_size=args.vocab, hidden_size=args.hidden, intermediate_size=args.dense_width,
+        moe_intermediate_size=args.expert_width, num_hidden_layers=args.layers,
+        num_dense_layers=args.dense_layers, num_attention_heads=args.heads,
+        num_key_value_heads=args.kv_heads, head_dim=args.head_dim, sliding_window=args.window,
+        global_attn_every_n_layers=args.global_every, num_experts=args.experts,
+        num_experts_per_tok=args.top_k, route_scale=args.route_scale,
+        experts_held=args.experts_held, expert_offset=args.expert_offset,
+        dtype=jnp.dtype(args.dtype), attention=args.attn, remat=args.remat,
+    )
+    return train(args, cfg, build_trainer, "trinity", report)
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:

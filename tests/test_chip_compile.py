@@ -308,6 +308,177 @@ def test_the_expert_layer_writes_short_rows_when_the_assignments_fit_them(one_ch
     assert temporaries(two_paths) <= temporaries(one_path)
 
 
+# --- what cells 1-3 lower to stays what it was; the hybrid cell's kernels compile --
+
+
+#: sha256 of the lowered text, made from ``git archive`` of 3f0513b (the parent of PR 32) by these same functions
+_PARENT_LOWERED = {
+    "flash-cell1": "d6ef39f9ef10c6ff09bb99ef7a39eee183941bbd5361316660308c8e9c6173ac",
+    "flash-cell2": "07e34fc7769e246ae1704f53f9261a71f0295aa67a92581d99e451c1112e807f",
+    "flash-cell3-window": "f1a9d5c89a04985f0c37ecce26269183150d1510e8f48e67338db9aafc5b05cd",
+    "flash-cell3-full": "ea69f198c0f04d6c1b42b8e6798789c95097b56d4f7ceb8bd29e914c6c48581d",
+    "experts-cell3": "c13c04dad2a0fb4e8e8d6c336ea23dec01ad1e8bd5c1c4893c82a43f75e70bf7",
+}
+
+_FLASH_CELLS = {
+    "flash-cell1": ((12, 1024, 12, 64), (12, 1024, 12, 64), None),      # gpt2-small-train
+    "flash-cell2": ((2, 1024, 16, 64), (2, 1024, 16, 64), None),        # gpt2-medium-ddp4, a chip's rows
+    "flash-cell3-window": ((1, 8192, 32, 128), (1, 8192, 4, 128), 2048),   # trinity-mini-ep8-train, sliding layers
+    "flash-cell3-full": ((1, 8192, 32, 128), (1, 8192, 4, 128), None),     # and the full layer
+}
+
+
+def _without_source_lines(lower):
+    """``lower()`` with no Python frames in the MLIR locations: a Mosaic
+    kernel's serialized body then holds no file path and no line number, and
+    the text says what is computed and nothing of where it was written."""
+    limit = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        return lower().as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+
+
+def _lowered_text(case, one_chip) -> str:
+    """Forward and backward of the flash kernels at a cell's shape, or of
+    cell 3's expert layer, as lowered for the described chip."""
+    import functools
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    if case in _FLASH_CELLS:
+        from adapcc_tpu.ops import flash_attention
+
+        q_dims, kv_dims, window = _FLASH_CELLS[case]
+
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, causal=True, window=window, interpret=False).astype(jnp.float32))
+
+        step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+        args = (shape(q_dims, jnp.bfloat16), shape(kv_dims, jnp.bfloat16), shape(kv_dims, jnp.bfloat16))
+    else:
+        from adapcc_tpu.models.moe import routed_experts
+
+        n, k, experts, held, d, h = 8192, 8, 128, 16, 2048, 1024
+
+        def loss(x, weights, stacked, ids):
+            y, sizes = routed_experts(
+                x, ids, weights, stacked, num_experts=experts, act=jax.nn.silu, dtype=jnp.bfloat16
+            )
+            return jnp.sum(y.astype(jnp.float32)), sizes
+
+        step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
+        stacked = {"w1": shape((held, d, h), jnp.float32), "w3": shape((held, d, h), jnp.float32),
+                   "w2": shape((held, h, d), jnp.float32)}
+        args = (shape((n, d), jnp.bfloat16), shape((n, k), jnp.float32), stacked, shape((n, k), jnp.int32))
+    return _without_source_lines(functools.partial(step.lower, *args))
+
+
+@pytest.mark.parametrize("case", sorted(_PARENT_LOWERED))
+def test_cells_1_to_3_lower_to_the_text_they_lowered_to_before_the_hybrid_model(one_chip, case):
+    """PR 32 gave the flash kernels a head size of its own for v and added a
+    model beside Trinity's: at the three accepted cells' shapes the kernels'
+    lowered text (Mosaic bodies included) and the expert layer's are the
+    parent's, character for character, so those cells compute what they
+    computed.  A change that means to alter them replaces the digest and says
+    so; the digests are of text without source locations, so moving code
+    inside a file does not."""
+    import hashlib
+
+    assert hashlib.sha256(_lowered_text(case, one_chip).encode()).hexdigest() == _PARENT_LOWERED[case]
+
+
+def _kimi_cell():
+    import json
+    from pathlib import Path
+
+    from adapcc_tpu.models.kimi_linear import KimiLinearConfig
+
+    config = json.loads((Path(__file__).resolve().parents[1] / "chipbench/configs/kimi-linear-ep32.json").read_text())
+    program = config["assumed"]["program"]
+    cfg = KimiLinearConfig.from_config(
+        config, experts_held=config["num_experts_held"], remat=program["remat"], dtype=jnp.dtype(program["activations"]),
+    )
+    return config, cfg
+
+
+def _mixers_through_mosaic(monkeypatch):
+    import sys
+
+    _flash_through_mosaic(monkeypatch)
+    monkeypatch.setattr(sys.modules["adapcc_tpu.ops.kda"], "resolve_interpret", lambda interpret, site: False)
+
+
+def test_the_kda_scan_and_the_latent_attention_compile_at_the_hybrid_cells_shapes(one_chip):
+    """``kimi-linear-ep32-train``'s two mixers' kernels, forward and backward,
+    through Mosaic: the chunked scan over 32 heads of 128 at T = 8,192 (two
+    kernels; the backward one holds eight chunks' inverses, writes and states
+    in scratch), and the flash kernels with scores over 192 channels and
+    values over 128 (three)."""
+    from adapcc_tpu.ops import flash_attention
+    from adapcc_tpu.ops.kda import kda
+
+    _, cfg = _kimi_cell()
+    T, H, D = 8192, cfg.linear_attn_num_heads, cfg.linear_attn_head_dim
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def scan(q, k, v, g, beta):
+        return jnp.sum(kda(q, k, v, g, beta, interpret=False).astype(jnp.float32))
+
+    wide = shape((1, T, H, D))
+    compiled = jax.jit(jax.value_and_grad(scan, argnums=(0, 1, 2, 3, 4))).lower(
+        wide, wide, wide, shape((1, T, H, D), jnp.float32), shape((1, T, H), jnp.float32)
+    ).compile()
+    assert _kernels_in(compiled) == 2 and "%kda_fwd" in compiled.as_text() and "%kda_bwd" in compiled.as_text()
+
+    def latent(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, interpret=False).astype(jnp.float32))
+
+    heads, dqk = cfg.num_attention_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    compiled = jax.jit(jax.value_and_grad(latent, argnums=(0, 1, 2))).lower(
+        shape((1, T, heads, dqk)), shape((1, T, heads, dqk)), shape((1, T, heads, cfg.v_head_dim))
+    ).compile()
+    assert _kernels_in(compiled) == 3
+    assert re.search(r"%flash_bwd_dkv[\w.]* = \(bf16\[32,8192,192\]\S*, bf16\[32,8192,128\]", compiled.as_text())
+
+
+def test_the_hybrid_cells_step_fits_the_chip(topo, monkeypatch):
+    """The whole donating step of ``kimi-linear-ep32-train`` (602 M float32
+    parameters with AdamW's moments, one row of 8,192 tokens, the loss and
+    remat the configuration file states) compiled for the described chip:
+    state and temporaries leave 5% of its 16 GiB free, and the five kernels
+    are in the program under their own names (the device trace is read by
+    them: chipbench/trace_hybrid_lm.py)."""
+    import optax
+
+    from adapcc_tpu.ddp.trainer import TrainState
+    from adapcc_tpu.models.trinity import initial_model_state
+    from adapcc_tpu.workloads.train_kimi_linear import build_trainer
+
+    _mixers_through_mosaic(monkeypatch)
+    config, cfg = _kimi_cell()
+    mesh = Mesh(np.array(topo.devices[:1]), (RANKS_AXIS,))
+    tx = optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(1e-6, weight_decay=0.01))
+    program = config["assumed"]["program"]
+    trainer, model = build_trainer(cfg, tx, mesh, loss=program["loss"], donate_state=program["donate_state"])
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 64), jnp.int32))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)) == 602_434_432
+    state = jax.eval_shape(lambda p: TrainState.create(p, tx, model_state=initial_model_state(cfg)), params)
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=NamedSharding(mesh, P(RANKS_AXIS)))
+    compiled = trainer._build().lower(_shapes_on(state, NamedSharding(mesh, P())), tokens).compile()
+    text = compiled.as_text()
+    names = {name: len(re.findall(rf"^\s*%{name}[\w.]* = ", text, re.M)) for name in (
+        "kda_fwd", "kda_bwd", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+    )}
+    assert names == {"kda_fwd": 4, "kda_bwd": 4, "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 0.95 * 16 * 2**30
+
+
 # --- the composed programs the old on-chip smoke covered ---------------------
 
 

@@ -1,0 +1,242 @@
+"""Kimi-Linear's block (``adapcc_tpu/models/kimi_linear.py``) and its KDA
+kernel (``adapcc_tpu/ops/kda.py``) at a small size on the CPU, the kernels in
+the Pallas interpreter.
+
+The chunked scan against the recurrence a step at a time
+(``chipbench/reference/kimi_linear_ref.kda_recurrence``), forward and all five
+gradients, at lengths that are no whole number of chunks and with decays from
+almost none to almost all; the latent mixer against the head-at-a-time form;
+the model's logits, loss and every gradient leaf against the plain reference
+on seeded weights; the shares' routed parts plus the shared expert once add up
+to the uncut layer; the workload trains through ``DDPTrainer.step``.
+"""
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from adapcc_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig, MLAMixer, l2norm, short_conv
+from adapcc_tpu.models.moe import routed_experts
+from adapcc_tpu.models.trinity import initial_model_state, stateful_loss
+from adapcc_tpu.ops.kda import chunk_plan, kda
+from adapcc_tpu.utils.observability import default_registry
+from chipbench import weights_hybrid_lm
+from chipbench.reference import kimi_linear_ref, trinity_ref
+
+CFG = KimiLinearConfig.tiny()
+PROD = kimi_linear_ref._product("float32")
+
+
+def file_config(cfg: KimiLinearConfig = CFG, **over) -> dict:
+    """The configuration as the benchmark's file states it (``config.json`` keys)."""
+    flat = ("kda_layers", "full_attn_layers", "linear_attn_num_heads", "linear_attn_head_dim", "short_conv_kernel_size")
+    out = {
+        f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)
+        if f.name not in flat + ("dtype", "remat", "experts_held")
+    }
+    out["linear_attn_config"] = {
+        "kda_layers": list(cfg.kda_layers), "full_attn_layers": list(cfg.full_attn_layers),
+        "num_heads": cfg.linear_attn_num_heads, "head_dim": cfg.linear_attn_head_dim,
+        "short_conv_kernel_size": cfg.short_conv_kernel_size,
+    }
+    out.update(num_experts_held=cfg.held)
+    out.update(over)
+    return out
+
+
+@pytest.fixture(scope="module")
+def params():
+    return weights_hybrid_lm.make_params(5, file_config())
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return jnp.asarray(np.random.default_rng(0).integers(0, CFG.vocab_size, (2, 40)), jnp.int32)
+
+
+# --- the kernel --------------------------------------------------------------
+
+
+def scan_inputs(T, seed, decay, B=1, H=2, dk=16, dv=8):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q, k = (l2norm(jax.random.normal(key, (B, T, H, dk))) for key in ks[:2])
+    v = jax.random.normal(ks[2], (B, T, H, dv))
+    lo, hi = {"near-one": (1e-5, 1e-3), "near-zero": (5.0, 40.0), "every-rate": (1e-4, 30.0)}[decay]
+    g = -jnp.exp(jax.random.uniform(ks[3], (B, T, H, dk), minval=math.log(lo), maxval=math.log(hi)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, T, H)))
+    return (q, k, v, g, beta), jax.random.normal(ks[5], (B, T, H, dv))
+
+
+def recurrence(q, k, v, g, beta, scale):
+    return jnp.stack([kimi_linear_ref.kda_recurrence(*(x[b] for x in (q, k, v, g, beta)), scale, PROD) for b in range(q.shape[0])])
+
+
+@pytest.mark.parametrize("decay", ["near-one", "near-zero", "every-rate"])
+@pytest.mark.parametrize("T", [40, 100, 200], ids=["under-a-chunk", "a-chunk-and-a-part", "four-chunks-less-a-part"])
+def test_the_chunked_scan_is_the_recurrence_forward_and_in_all_five_gradients(T, decay):
+    args, mix = scan_inputs(T, T, decay)
+    scale = 0.25
+    got = kda(*args, scale=scale)
+    want = recurrence(*args, scale)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+    grads = jax.grad(lambda *a: jnp.sum(kda(*a, scale=scale) * mix), argnums=(0, 1, 2, 3, 4))(*args)
+    wants = jax.grad(lambda *a: jnp.sum(recurrence(*a, scale) * mix), argnums=(0, 1, 2, 3, 4))(*args)
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), grads, wants):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, err_msg=f"d{name}")
+
+
+def test_the_chunk_follows_the_shape_and_the_scan_leaves_its_gauges():
+    assert chunk_plan(8192) == (64, 8, 8192)          # the cell: 128 chunks, eight to a grid step
+    assert chunk_plan(200) == (64, 4, 256) and chunk_plan(100) == (64, 2, 128) and chunk_plan(40) == (40, 1, 40)
+    args, _ = scan_inputs(100, 0, "every-rate")
+    kda(*args)
+    gauges = default_registry().snapshot()["gauges"]
+    assert (gauges["kda.chunk"], gauges["kda.tiles"], gauges["kda.state_bytes"]) == (64, 2 * 2, 2 * 16 * 8 * 4)
+    with pytest.raises(ValueError, match="kda shapes"):
+        kda(args[0], args[1], args[2], args[3][..., :4], args[4])
+
+
+def test_a_batch_of_rows_scans_each_from_a_zero_state():
+    args, _ = scan_inputs(70, 3, "every-rate", B=2)
+    both = kda(*args)
+    for b in range(2):
+        alone = kda(*(x[b:b + 1] for x in args))
+        np.testing.assert_allclose(np.asarray(both[b:b + 1]), np.asarray(alone), atol=1e-6)
+
+
+# --- the two mixers ----------------------------------------------------------
+
+
+@pytest.mark.parametrize("T", [40, 256])
+def test_the_latent_mixer_is_the_head_at_a_time_form(params, T):
+    """Scores over 16 + 8 channels (8 of them the same for every head), values
+    over 16; a row inside one tile of the kernel and a row over two."""
+    p = params["params"]["layers_2"]["self_attn"]
+    x = jnp.asarray(np.random.default_rng(3).normal(size=(2, T, 32)), jnp.float32)
+    got = MLAMixer(CFG).apply({"params": p}, x)
+    want = jnp.stack([kimi_linear_ref.mla_mixer(row, p, file_config(), PROD) for row in x])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    gauges = default_registry().snapshot()["gauges"]
+    assert (gauges["mla.qk_dim"], gauges["mla.v_dim"]) == (24, 16)
+
+
+def test_the_short_convolution_is_causal_and_depthwise():
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(1, 9, 3)), jnp.float32)
+    taps = jnp.asarray(np.random.default_rng(5).normal(size=(4, 3)), jnp.float32)
+    y = np.asarray(short_conv(x, taps))
+    for t in range(9):
+        want = sum(np.asarray(taps)[j] * np.asarray(x)[0, t - 3 + j] for j in range(4) if t - 3 + j >= 0)
+        np.testing.assert_allclose(y[0, t], want, atol=1e-6)
+    np.testing.assert_allclose(y[0], np.asarray(kimi_linear_ref.short_conv(x[0], taps)), atol=1e-6)
+
+
+# --- the model ---------------------------------------------------------------
+
+
+def test_the_weight_maker_makes_the_tree_the_model_reads(params):
+    shapes = jax.eval_shape(KimiLinear(CFG).init, jax.random.PRNGKey(0), jnp.zeros((1, 40), jnp.int32))
+    assert jax.tree_util.tree_structure(shapes) == jax.tree_util.tree_structure(params)
+    for want, got in zip(jax.tree_util.tree_leaves(shapes), jax.tree_util.tree_leaves(params)):
+        assert want.shape == got.shape and got.dtype == jnp.float32
+    assert CFG.kinds == ("kda", "kda", "mla", "kda") == weights_hybrid_lm.layer_kinds(file_config())
+    scan = params["params"]["layers_0"]["self_attn"]
+    rate = np.exp(np.asarray(scan["A_log"]))[:, None] * np.asarray(jax.nn.softplus(scan["dt_bias"])).reshape(2, 16)
+    assert 0.15 < np.exp(-rate).min() and np.exp(-rate).max() < 0.9995     # a step forgets neither all nor nothing
+    assert np.abs(np.asarray(scan["q_conv"])).max() <= 0.5
+
+
+def test_logits_match_the_plain_reference(params, tokens):
+    logits, sizes = KimiLinear(CFG).apply(params, tokens)
+    want = jnp.stack([kimi_linear_ref.logits_fn(params, row, file_config()) for row in tokens])
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want), atol=5e-6)
+    assert sizes.shape == (3, 8) and sizes.sum(axis=1).tolist() == [2 * 40 * 2] * 3
+    gauges = default_registry().snapshot()["gauges"]
+    assert (gauges["model.layers_kda"], gauges["model.layers_mla"]) == (3, 1)
+
+
+@pytest.mark.parametrize("loss", ["dense", "chunked"])
+def test_loss_and_every_gradient_leaf_match_the_plain_reference(params, tokens, loss):
+    model = KimiLinear(CFG)
+    (value, state), grads = jax.value_and_grad(stateful_loss(model, loss, block=64), has_aux=True)(
+        params, initial_model_state(CFG), tokens
+    )
+    want, want_grads = kimi_linear_ref.loss_and_grads(params, tokens, file_config())
+    assert float(value) == pytest.approx(float(want), rel=1e-6)
+    assert state["moe_sizes"].shape == (3, 8)
+    for (path, got), ref in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree_util.tree_leaves(want_grads)):
+        scale = float(jnp.max(jnp.abs(ref)))
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(ref), atol=1e-6 + 5e-4 * scale, err_msg=jax.tree_util.keystr(path)
+        )
+    assert not np.any(np.asarray(grads["params"]["layers_1"]["mlp"]["expert_bias"]))
+
+
+def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(params):
+    """What each of four chips computes for its two experts (``expert_offset``
+    0, 2, 4, 6), plus what they all compute alike (the shared expert) counted
+    once, is the uncut reference's expert FFN; and a share through the model
+    is the reference given that share."""
+    p = params["params"]["layers_1"]["mlp"]
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(96, 32)), jnp.float32)
+    keys = kimi_linear_ref.router_keys(file_config())
+    whole = trinity_ref.sparse_ffn(x, p, keys, PROD)
+    shared = p["shared_experts"]
+    total = trinity_ref.gated_mlp(
+        x, shared["gate_proj"]["kernel"], shared["up_proj"]["kernel"], shared["down_proj"]["kernel"], PROD
+    )
+    ids, weights = trinity_ref.route(x, p, keys, PROD)
+    given = 0
+    for offset in range(0, 8, 2):
+        stacked = {k: p[f"experts_{k}"][offset:offset + 2] for k in ("w1", "w3", "w2")}
+        part, sizes = routed_experts(
+            x, ids, weights, stacked, offset=offset, num_experts=8, act=jax.nn.silu, dtype=jnp.float32
+        )
+        total, given = total + part, given + int(sizes.sum())
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole), atol=2e-6)
+    assert given == 96 * 2
+    held = dataclasses.replace(CFG, experts_held=2, expert_offset=4)
+    cut = jax.tree_util.tree_map(lambda a: a, params)
+    for name in ("layers_1", "layers_2", "layers_3"):
+        for k in ("experts_w1", "experts_w3", "experts_w2"):
+            cut["params"][name]["mlp"][k] = params["params"][name]["mlp"][k][4:6]
+    toks = jnp.asarray(np.random.default_rng(2).integers(0, 256, (1, 40)), jnp.int32)
+    logits, sizes = KimiLinear(held).apply(cut, toks)
+    want = kimi_linear_ref.logits_fn(cut, toks[0], file_config(held, expert_offset=4))
+    np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(want), atol=5e-6)
+    assert sizes.shape == (3, 2)
+
+
+def test_the_config_reads_config_json_and_refuses_what_it_does_not_implement():
+    import json
+    from pathlib import Path
+
+    body = json.loads((Path(__file__).resolve().parents[1] / "chipbench/configs/kimi-linear-ep32.json").read_text())
+    cfg = KimiLinearConfig.from_config(body, experts_held=body["num_experts_held"])
+    assert cfg.kinds == ("kda", "kda", "kda", "mla", "kda") and (cfg.held, cfg.num_experts) == (8, 256)
+    assert (cfg.hidden_size, cfg.linear_attn_head_dim, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) == (2304, 128, 192)
+    assert KimiLinearConfig().kinds.count("mla") == 7 and KimiLinearConfig().kinds[3] == "mla"
+    with pytest.raises(ValueError, match="sigmoid"):
+        KimiLinearConfig.tiny(moe_router_activation_func="softmax")
+    with pytest.raises(ValueError, match="exactly one"):
+        KimiLinearConfig.tiny(num_hidden_layers=5)
+    with pytest.raises(ValueError, match="experts"):
+        KimiLinearConfig.tiny(experts_held=4, expert_offset=6)
+
+
+def test_the_workload_trains_through_ddptrainer_and_hands_the_counts_out(capsys):
+    from adapcc_tpu.workloads.train_kimi_linear import build_parser, run
+
+    report = {}
+    first, last = run(build_parser().parse_args(
+        ["--epochs", "3", "--world", "2", "--experts-held", "4", "--expert-offset", "2"]
+    ), report)
+    assert last < first - 0.5, (first, last)
+    out = capsys.readouterr().out
+    assert "experts 2..6 of 8 held" in out and "'mla'" in out and "assignments here" in out
+    sizes = np.asarray(report["state"].model_state["moe_sizes"])
+    assert sizes.shape == (3, 4) and sizes.sum() > 0
+    assert report["trainer"].donate_state is True
