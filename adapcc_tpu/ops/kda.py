@@ -41,16 +41,37 @@ at full precision.  The other products take their operands in the inputs'
 dtype (bfloat16 in a bf16 model) and accumulate in float32, as the flash
 kernels do.
 
-**Backward.**  The forward kernel keeps the state at the start of every grid
-step (``[B H, T / block, d_v, d_k]`` float32: 64 KB each).  The backward kernel
-walks the grid steps from the last to the first; in each it first recomputes
-the chunks' ``Phi(q, kb)``, inverses, ``U`` and states forward (into VMEM
-scratch, never HBM), then walks its chunks backward with the state's
-cotangent carried in scratch.  The equations are in docs/KIMI_LINEAR.md.
+**The kernels read and write the model's own arrays.**  ``q, k, g [B, T, H,
+d_k]``, ``v [B, T, H, d_v]`` and ``beta [B, T, H]`` go into the two
+``pallas_call``s as they are, and ``o``, ``dq``, ``dk``, ``dv``, ``dg``,
+``dbeta`` come out in the same shapes: XLA adds no transpose, copy or sum
+around them (``tests/test_chip_compile.py`` holds that).  On the chip such an
+array's tile is heads x channels of one step, so a block of ``rows`` steps is
+``[rows H, d]`` in memory and a head's chunk is every ``H``-th row of it
+(:class:`_Rows`).  The grid is ``(B, T / rows, H / 2)``: a block of rows over
+every head stays in VMEM while the grid walks its heads two at a time (two
+bfloat16 rows share a 32-bit word, and only words are read ``H`` rows apart).
+Formed in VMEM, where a chunk is first loaded: ``G``, by six shifted adds
+down the chunk's rows, and ``kb = beta k`` in float32, a head's ``beta`` picked
+out of the ``[rows, H]`` block.  On the way back: ``dg`` by the same adds up
+the rows, ``beta dkb`` folded into ``dk``, and ``dbeta = sum_c dkb k`` written
+into the head's column of its block.  Through Mosaic ``d_k`` and ``d_v`` are
+multiples of 128 (a row is whole lane tiles) and bfloat16 heads come in pairs;
+the interpreter takes any shape.  Where ``T`` is no whole number of chunks the
+five inputs are padded along ``T``, the one copy left (gauge
+``kda.padded_rows``).
 
-The chunk follows the shape (:func:`chunk_plan`); there is nothing to tune
-from outside.  The state is held transposed, ``[d_v, d_k]``, so that the
-per-channel decay scales lanes.
+**Backward.**  The forward kernel keeps the state at the start of every grid
+step (``[B H, T / rows, d_v, d_k]`` float32: 64 KB each).  The backward kernel
+walks the grid steps from the last to the first; in each it first recomputes
+the chunks' ``G``, ``kb``, ``Phi(q, kb)``, inverses, ``U`` and states forward
+(into VMEM scratch, never HBM), then walks its chunks backward with the
+state's cotangent carried in scratch.  The equations are in
+docs/KIMI_LINEAR.md.
+
+The chunk and the rows of a grid step follow the shape (:func:`chunk_plan`);
+there is nothing to tune from outside.  The state is held transposed, ``[d_v,
+d_k]``, so that the per-channel decay scales lanes.
 """
 
 from __future__ import annotations
@@ -71,6 +92,8 @@ from adapcc_tpu.utils.observability import default_registry
 _SUB = 8       # rows of a sub-block: pairs inside one are formed on the VPU
 _CHUNK = 64    # rows of a chunk: eight sub-blocks, one solve
 _BLOCK = 512   # rows of a grid step at most: eight chunks behind one DMA
+_SLAB = 1 << 20  # elements of a grid step's rows over every head at most: the backward kernel holds nine such blocks, twice
+_LANES = 128   # through Mosaic a head's channels are whole lane tiles
 _NEG = -1e30
 
 _NN = ((1,), (0,))
@@ -78,15 +101,17 @@ _NT = ((1,), (1,))
 _TN = ((0,), (0,))
 
 
-def chunk_plan(T: int) -> Tuple[int, int, int]:
+def chunk_plan(T: int, width: int = 0) -> Tuple[int, int, int]:
     """``(chunk, chunks per grid step, padded T)`` for a sequence of ``T``
-    steps: chunks of 64 (a short sequence: what holds it, in sub-blocks), as
-    many to a grid step as divide the padded length, eight at most."""
+    steps whose every step is ``width`` elements over all heads: chunks of 64
+    (a short sequence: what holds it, in sub-blocks), as many to a grid step
+    as divide the padded length, eight at most, and no more than keep a block
+    of rows within ``_SLAB`` elements."""
     chunk = min(_CHUNK, -(-T // _SUB) * _SUB)
     padded = -(-T // chunk) * chunk
     n = padded // chunk
-    per = max(p for p in (8, 4, 2, 1) if p * chunk <= _BLOCK and n % p == 0)
-    return chunk, per, padded
+    fits = lambda p: p * chunk <= _BLOCK and n % p == 0 and p * chunk * width <= _SLAB  # noqa: E731
+    return chunk, max(p for p in (8, 4, 2, 1) if p == 1 or fits(p)), padded
 
 
 class _Geometry(NamedTuple):
@@ -120,6 +145,22 @@ def _hi(a, b, dims):
     return lax.dot_general(
         a, b, (dims, batch), precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32
     )
+
+
+def _running_sum(x, reverse: bool = False):
+    """``x [C, d]`` summed along its rows from the first (from the last:
+    ``reverse``) up to and with each row, in float32 on the VPU: ``log2 C``
+    shifted adds, each row taking the partial sum that ends ``s`` rows off."""
+    C = x.shape[0]
+    row = lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    s = 1
+    while s < C:
+        if reverse:
+            x = x + jnp.where(row < C - s, pltpu.roll(x, C - s, 0), 0.0)
+        else:
+            x = x + jnp.where(row >= s, pltpu.roll(x, s, 0), 0.0)
+        s *= 2
+    return x
 
 
 def _mx(a, b, dims, dtype):
@@ -285,163 +326,276 @@ def _chunks(per: int, body, carry):
     return body(0, carry) if per == 1 else lax.fori_loop(0, per, body, carry, unroll=True)
 
 
+def _heads(group: int, body) -> None:
+    """``body(j)`` for each head of a grid step's group, written out as the
+    chunk loops are: two heads' state chains are independent, and the
+    scheduler runs one in the other's waits (as a loop the kernel keeps the
+    scan took 7% longer on the chip; PERF.md section 6, PR 33)."""
+    for j in range(group):
+        body(j)
+
+
 def _at(c, chunk: int):
     return pl.ds(c * chunk if isinstance(c, int) else pl.multiple_of(c * chunk, chunk), chunk)
 
 
-def _load(refs, c, chunk: int):
-    return [ref[0, _at(c, chunk), :].astype(jnp.float32) for ref in refs]
+class _Rows(NamedTuple):
+    """How a grid step's rows lie in its blocks.  A block is the model's own
+    ``[rows, H, d]``; in memory a tile is heads x channels of one step, so a
+    head's chunk is every ``H``-th row of ``[rows H, d]``.  ``mosaic``: the
+    kernel runs through Mosaic, which reads and writes rows ``H`` apart as
+    32-bit words only: two bfloat16 rows share a sublane of words (the even
+    row the low halves), so a pair of heads is read as words and a head
+    written into its half.  The interpreter indexes the head."""
+
+    H: int          # heads
+    group: int      # heads a grid step walks: a packed pair, or one
+    per: int        # chunks of each
+    chunk: int
+    mosaic: bool
+
+    @classmethod
+    def plan(cls, H: int, chunk: int, per: int, interp) -> "_Rows":
+        return cls(H=H, group=2 - H % 2, per=per, chunk=chunk, mosaic=not interp)
+
+    def _words(self, ref, h, c):
+        """Head ``h``'s chunk ``c`` as rows of 32-bit words ``H`` rows apart:
+        ``(view, index, bits a bfloat16 head sits above the word's low end or
+        None)``."""
+        rows, H, d = ref.shape[1:]
+        view = ref.at[0].reshape(rows * H, d)
+        if ref.dtype != jnp.bfloat16:
+            return view, (pl.ds(c * self.chunk * H + h, self.chunk, stride=H), slice(None)), None
+        at = (pl.ds(c * self.chunk * (H // 2) + h // 2, self.chunk, stride=H // 2), slice(None))
+        return view.bitcast(jnp.uint32), at, (16 * (h % 2)).astype(jnp.uint32)
+
+    def read(self, ref, h, c):
+        """Chunk ``c`` of head ``h`` from ``ref``: ``[C, d]`` float32."""
+        if not self.mosaic:
+            return ref[0, _at(c, self.chunk), h, :].astype(jnp.float32)
+        view, at, up = self._words(ref, h, c)
+        if up is None:
+            return view[at]
+        return lax.bitcast_convert_type((view[at] >> up) << 16, jnp.float32)
+
+    def write(self, ref, h, c, x) -> None:
+        """``x [C, d]`` float32 into chunk ``c`` of head ``h`` of ``ref``."""
+        if not self.mosaic:
+            ref[0, _at(c, self.chunk), h, :] = x.astype(ref.dtype)
+            return
+        view, at, up = self._words(ref, h, c)
+        if up is None:
+            view[at] = x
+            return
+        half = lax.bitcast_convert_type(x.astype(jnp.bfloat16).astype(jnp.float32), jnp.uint32) >> 16
+        view[at] = (view[at] & (jnp.uint32(0xFFFF0000) >> up)) | (half << up)       # the other head's half stays
+
+    def column(self, ref, h, c):
+        """Head ``h``'s column of chunk ``c`` of a ``[rows, H]`` block (``beta``): ``[C, 1]``."""
+        rows = ref[0, _at(c, self.chunk), :]
+        head = lax.broadcasted_iota(jnp.int32, rows.shape, 1) == h
+        return jnp.sum(jnp.where(head, rows, 0.0), axis=1, keepdims=True)
+
+    def write_column(self, ref, h, c, x) -> None:
+        """``x [C, 1]`` into head ``h``'s column: the block stays in VMEM while
+        the grid walks its heads, and each fills its own."""
+        at = (0, _at(c, self.chunk), slice(None))
+        head = lax.broadcasted_iota(jnp.int32, (self.chunk, self.H), 1) == h
+        ref[at] = jnp.where(head, x, ref[at])
 
 
-def _solve_chunks(refs, invs, bqs, geo: _Geometry, chunk: int, per: int, dtype) -> None:
-    """Every chunk's ``Phi(q, kb)`` into ``bqs`` and ``(I + A)^-1`` into
-    ``invs`` (scratch ``[per, C, C]``): the scores a chunk at a time, the
-    inverses together."""
+def _solve_chunks(q_ref, k_ref, g_ref, beta_ref, first, qs, ks, Gs, kbs, invs, bqs, geo: _Geometry, lay: _Rows, dtype) -> None:
+    """For every chunk of the grid step's heads (head ``first + j``'s chunk
+    ``c`` is unit ``j per + c``): ``q`` and ``k`` as float32 rows one after
+    another into ``qs`` and ``ks`` (read ``H`` rows apart once, not once a
+    pass), the summed decay ``G`` into ``Gs``, ``beta k`` into ``kbs`` (scratch
+    ``[units, C, d_k]`` float32), ``Phi(q, kb)`` into ``bqs`` and ``(I + A)^-1``
+    into ``invs`` (scratch ``[units, C, C]``): the scores a chunk at a time,
+    the inverses together."""
+    def head(j):
+        h = first + j
 
-    def scores(c, carry):
-        invs[c], bqs[c] = chunk_scores(*_load(refs, c, chunk), geo, dtype)
-        return carry
+        def scores(c, carry):
+            u = j * lay.per + c
+            qs[u] = q = lay.read(q_ref, h, c)
+            ks[u] = k = lay.read(k_ref, h, c)
+            g = lay.read(g_ref, h, c)
+            Gs[u] = G = _running_sum(g)
+            kbs[u] = kb = k * lay.column(beta_ref, h, c)
+            invs[u], bqs[u] = chunk_scores(q, k, kb, G, geo, dtype)
+            return carry
 
-    _chunks(per, scores, 0)
+        _chunks(lay.per, scores, 0)
+
+    _heads(lay.group, head)
     invs[...] = inverse(invs[...], geo)
 
 
-def _fwd_kernel(q_ref, k_ref, kb_ref, v_ref, g_ref, o_ref, start_ref, state, invs, bqs, *, chunk, per, scale):
-    geo = _geometry(chunk)
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, start_ref, state, qs, ks, Gs, kbs, invs, bqs, *, lay, scale):
+    geo = _geometry(lay.chunk)
     dtype = q_ref.dtype
+    first = pl.program_id(2) * lay.group
+    fresh = pl.program_id(1) == 0
+    _solve_chunks(q_ref, k_ref, g_ref, beta_ref, first, qs, ks, Gs, kbs, invs, bqs, geo, lay, dtype)
 
-    @pl.when(pl.program_id(1) == 0)
-    def _():
-        state[...] = jnp.zeros_like(state)
+    def head(j):
+        h = first + j
 
-    start_ref[0, 0] = state[...]
-    _solve_chunks((q_ref, k_ref, kb_ref, g_ref), invs, bqs, geo, chunk, per, dtype)
+        @pl.when(fresh)
+        def _():
+            state[h] = jnp.zeros(state.shape[1:], state.dtype)
 
-    def one(c, St):
-        q, k, kb, v, G = _load((q_ref, k_ref, kb_ref, v_ref, g_ref), c, chunk)
-        o, _, St = chunk_state(q, k, kb, v, G, St, invs[c], bqs[c], dtype, scale)
-        o_ref[0, _at(c, chunk), :] = o.astype(o_ref.dtype)
-        return St
+        start_ref[j, 0] = state[h]
 
-    state[...] = _chunks(per, one, state[...])
+        def one(c, St):
+            u = j * lay.per + c
+            o, _, St = chunk_state(
+                qs[u], ks[u], kbs[u], lay.read(v_ref, h, c), Gs[u], St, invs[u], bqs[u], dtype, scale
+            )
+            lay.write(o_ref, h, c, o)
+            return St
+
+        state[h] = _chunks(lay.per, one, state[h])
+
+    _heads(lay.group, head)
 
 
 def _bwd_kernel(
-    q_ref, k_ref, kb_ref, v_ref, g_ref, do_ref, start_ref,
-    dq_ref, dk_ref, dkb_ref, dv_ref, dg_ref,
-    dstate, states, us, invs, bqs, *, chunk, per, scale,
+    q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, start_ref,
+    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+    dstate, states, us, qs, ks, Gs, kbs, invs, bqs, *, lay, scale,
 ):
-    geo = _geometry(chunk)
+    geo = _geometry(lay.chunk)
     dtype = q_ref.dtype
+    per = lay.per
+    first = pl.program_id(2) * lay.group
+    fresh = pl.program_id(1) == 0
+    _solve_chunks(q_ref, k_ref, g_ref, beta_ref, first, qs, ks, Gs, kbs, invs, bqs, geo, lay, dtype)
 
-    @pl.when(pl.program_id(1) == 0)
-    def _():
-        dstate[...] = jnp.zeros_like(dstate)
+    def head(j):
+        h = first + j
 
-    _solve_chunks((q_ref, k_ref, kb_ref, g_ref), invs, bqs, geo, chunk, per, dtype)
+        @pl.when(fresh)
+        def _():
+            dstate[h] = jnp.zeros(dstate.shape[1:], dstate.dtype)
 
-    def again(c, St):
-        q, k, kb, v, G = _load((q_ref, k_ref, kb_ref, v_ref, g_ref), c, chunk)
-        states[c] = St
-        _, us[c], St = chunk_state(q, k, kb, v, G, St, invs[c], bqs[c], dtype, scale)
-        return St
+        def again(c, St):
+            u = j * per + c
+            states[u] = St
+            _, us[u], St = chunk_state(
+                qs[u], ks[u], kbs[u], lay.read(v_ref, h, c), Gs[u], St, invs[u], bqs[u], dtype, scale
+            )
+            return St
 
-    _chunks(per, again, start_ref[0, 0])
+        _chunks(per, again, start_ref[j, 0])
 
-    def back(i, dSt):
-        c = per - 1 - i
-        q, k, kb, G, do = _load((q_ref, k_ref, kb_ref, g_ref, do_ref), c, chunk)
-        dq, dk, dkb, dv, dG, dSt = chunk_backward(
-            q, k, kb, G, states[c], us[c], invs[c], bqs[c], do, dSt, geo, dtype, scale
-        )
-        at = _at(c, chunk)
-        dq_ref[0, at, :] = dq.astype(dq_ref.dtype)
-        dk_ref[0, at, :] = dk.astype(dk_ref.dtype)
-        dkb_ref[0, at, :] = dkb.astype(dkb_ref.dtype)
-        dv_ref[0, at, :] = dv.astype(dv_ref.dtype)
-        dg_ref[0, at, :] = dG
-        return dSt
+        def back(i, dSt):
+            c = per - 1 - i
+            u = j * per + c
+            k = ks[u]
+            dq, dk, dkb, dv, dG, dSt = chunk_backward(
+                qs[u], k, kbs[u], Gs[u], states[u], us[u], invs[u], bqs[u], lay.read(do_ref, h, c), dSt, geo, dtype, scale
+            )
+            lay.write(dq_ref, h, c, dq)
+            lay.write(dk_ref, h, c, dk + lay.column(beta_ref, h, c) * dkb)
+            lay.write(dv_ref, h, c, dv)
+            lay.write(dg_ref, h, c, _running_sum(dG, reverse=True))     # a step's decay is in every later row's G
+            lay.write_column(dbeta_ref, h, c, jnp.sum(dkb * k, axis=1, keepdims=True))
+            return dSt
 
-    dstate[...] = _chunks(per, back, dstate[...])
+        dstate[h] = _chunks(per, back, dstate[h])
+
+    _heads(lay.group, head)
 
 
-def _params():
-    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+def _params(interp, blocks: int):
+    """The blocks of rows over every head are held twice (one in flight);
+    Mosaic's default scoped limit is 16 MiB."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=None if interp else min(100 * 2**20, 2 * blocks + 16 * 2**20),
+    )
 
 
 # behind jax.jit, as the flash kernels are: a model's layers share one traced kernel
 @functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
-def _fwd_call(q, k, kb, v, G, scale, chunk, per, interp):
-    BH, T, dk = q.shape
+def _fwd_call(q, k, v, g, beta, scale, chunk, per, interp):
+    B, T, H, dk = q.shape
     dv = v.shape[-1]
-    rows = chunk * per
+    lay = _Rows.plan(H, chunk, per, interp)
+    rows, units = chunk * per, lay.group * per
     steps = T // rows
-    wide = lambda d: pl.BlockSpec((1, rows, d), lambda b, i: (b, i, 0))  # noqa: E731
+    wide = lambda d: pl.BlockSpec((1, rows, H, d), lambda b, i, p: (b, i, 0, 0))  # noqa: E731
+    blocks = rows * H * ((2 * dk + 2 * dv) * q.dtype.itemsize + dk * 4)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, chunk=chunk, per=per, scale=scale),
-        grid=(BH, steps),
-        in_specs=[wide(dk), wide(dk), wide(dk), wide(dv), wide(dk)],
-        out_specs=[wide(dv), pl.BlockSpec((1, 1, dv, dk), lambda b, i: (b, i, 0, 0))],
+        functools.partial(_fwd_kernel, lay=lay, scale=scale),
+        grid=(B, steps, H // lay.group),
+        in_specs=[wide(dk), wide(dk), wide(dv), wide(dk), pl.BlockSpec((1, rows, H), lambda b, i, p: (b, i, 0))],
+        out_specs=[
+            wide(dv),
+            pl.BlockSpec((lay.group, 1, dv, dk), lambda b, i, p: (b * (H // lay.group) + p, i, 0, 0)),
+        ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, T, dv), v.dtype),
-            jax.ShapeDtypeStruct((BH, steps, dv, dk), jnp.float32),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((B * H, steps, dv, dk), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((dv, dk), jnp.float32),
-            pltpu.VMEM((per, chunk, chunk), jnp.float32),
-            pltpu.VMEM((per, chunk, chunk), jnp.float32),
+            pltpu.VMEM((H, dv, dk), jnp.float32),
+            *[pltpu.VMEM((units, chunk, dk), jnp.float32)] * 4,
+            *[pltpu.VMEM((units, chunk, chunk), jnp.float32)] * 2,
         ],
-        compiler_params=_params(),
+        compiler_params=_params(interp, blocks),
         interpret=interp,
         name="kda_fwd",
-    )(q, k, kb, v, G)
+    )(q, k, v, g, beta)
 
 
 @functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
-def _bwd_call(q, k, kb, v, G, starts, do, scale, chunk, per, interp):
-    BH, T, dk = q.shape
+def _bwd_call(q, k, v, g, beta, starts, do, scale, chunk, per, interp):
+    B, T, H, dk = q.shape
     dv = v.shape[-1]
-    rows = chunk * per
+    lay = _Rows.plan(H, chunk, per, interp)
+    rows, units = chunk * per, lay.group * per
     steps = T // rows
-    wide = lambda d: pl.BlockSpec((1, rows, d), lambda b, i: (b, steps - 1 - i, 0))  # noqa: E731
+    wide = lambda d: pl.BlockSpec((1, rows, H, d), lambda b, i, p: (b, steps - 1 - i, 0, 0))  # noqa: E731
+    every = pl.BlockSpec((1, rows, H), lambda b, i, p: (b, steps - 1 - i, 0))
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
+    blocks = rows * H * ((4 * dk + 3 * dv) * q.dtype.itemsize + 2 * dk * 4)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, chunk=chunk, per=per, scale=scale),
-        grid=(BH, steps),
+        functools.partial(_bwd_kernel, lay=lay, scale=scale),
+        grid=(B, steps, H // lay.group),
         in_specs=[
-            wide(dk), wide(dk), wide(dk), wide(dv), wide(dk), wide(dv),
-            pl.BlockSpec((1, 1, dv, dk), lambda b, i: (b, steps - 1 - i, 0, 0)),
+            wide(dk), wide(dk), wide(dv), wide(dk), every, wide(dv),
+            pl.BlockSpec((lay.group, 1, dv, dk), lambda b, i, p: (b * (H // lay.group) + p, steps - 1 - i, 0, 0)),
         ],
-        out_specs=[wide(dk), wide(dk), wide(dk), wide(dv), wide(dk)],
-        out_shape=[like(q), like(k), like(kb), like(v), like(G)],
+        out_specs=[wide(dk), wide(dk), wide(dv), wide(dk), every],
+        out_shape=[like(q), like(k), like(v), like(g), like(beta)],
         scratch_shapes=[
-            pltpu.VMEM((dv, dk), jnp.float32),
-            pltpu.VMEM((per, dv, dk), jnp.float32),
-            pltpu.VMEM((per, chunk, dv), jnp.float32),
-            pltpu.VMEM((per, chunk, chunk), jnp.float32),
-            pltpu.VMEM((per, chunk, chunk), jnp.float32),
+            pltpu.VMEM((H, dv, dk), jnp.float32),
+            pltpu.VMEM((units, dv, dk), jnp.float32),
+            pltpu.VMEM((units, chunk, dv), jnp.float32),
+            *[pltpu.VMEM((units, chunk, dk), jnp.float32)] * 4,
+            *[pltpu.VMEM((units, chunk, chunk), jnp.float32)] * 2,
         ],
-        compiler_params=_params(),
+        compiler_params=_params(interp, blocks),
         interpret=interp,
         name="kda_bwd",
-    )(q, k, kb, v, G, do, starts)
+    )(q, k, v, g, beta, do, starts)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _kda_chunked(q, k, kb, v, G, scale, chunk, per, interpret):
-    return _kda_fwd(q, k, kb, v, G, scale, chunk, per, interpret)[0]
+def _kda_chunked(q, k, v, g, beta, scale, chunk, per, interp):
+    return _fwd_call(q, k, v, g, beta, scale, chunk, per, interp)[0]
 
 
-def _kda_fwd(q, k, kb, v, G, scale, chunk, per, interpret):
-    interp = resolve_interpret(interpret, "kda")
-    o, starts = _fwd_call(q, k, kb, v, G, scale, chunk, per, interp)
-    return o, (q, k, kb, v, G, starts)
+def _kda_fwd(q, k, v, g, beta, scale, chunk, per, interp):
+    o, starts = _fwd_call(q, k, v, g, beta, scale, chunk, per, interp)
+    return o, (q, k, v, g, beta, starts)
 
 
-def _kda_bwd(scale, chunk, per, interpret, res, do):
-    q, k, kb, v, G, starts = res
-    interp = resolve_interpret(interpret, "kda")
-    return tuple(_bwd_call(q, k, kb, v, G, starts, do, scale, chunk, per, interp))
+def _kda_bwd(scale, chunk, per, interp, res, do):
+    q, k, v, g, beta, starts = res
+    return tuple(_bwd_call(q, k, v, g, beta, starts, do, scale, chunk, per, interp))
 
 
 _kda_chunked.defvjp(_kda_fwd, _kda_bwd)
@@ -466,21 +620,21 @@ def kda(
     dv = v.shape[-1]
     if k.shape != q.shape or v.shape[:3] != q.shape[:3] or g.shape != q.shape or beta.shape != q.shape[:3]:
         raise ValueError(f"kda shapes: q {q.shape} k {k.shape} v {v.shape} g {g.shape} beta {beta.shape}")
+    interp = resolve_interpret(interpret, "kda")
+    if not interp and (dk % _LANES or dv % _LANES or (q.dtype == jnp.bfloat16 and H % 2) or q.dtype == jnp.float16):
+        raise ValueError(
+            f"kda through Mosaic reads a head as every H-th row of [B, T H, d]: d_k {dk} and d_v {dv} must be "
+            f"multiples of {_LANES}, and bfloat16 heads ({H}) come in pairs; float16 is not read"
+        )
     if scale is None:
         scale = float(1.0 / math.sqrt(dk))
-    chunk, per, padded = chunk_plan(T)
+    chunk, per, padded = chunk_plan(T, H * max(dk, dv))
     metrics = default_registry()
     metrics.gauge("kda.chunk", chunk)
     metrics.gauge("kda.tiles", B * H * (padded // chunk))
     metrics.gauge("kda.state_bytes", B * H * dk * dv * 4)
-
-    def heads(x):   # [B, T, H, d] -> [B H, padded T, d]; a padded step writes and forgets nothing
-        x = x.transpose(0, 2, 1, 3).reshape(B * H, T, x.shape[-1])
-        return jnp.pad(x, ((0, 0), (0, padded - T), (0, 0)))
-
-    kb = (k.astype(jnp.float32) * beta.astype(jnp.float32)[..., None]).astype(k.dtype)
-    G = jnp.cumsum(heads(g.astype(jnp.float32)).reshape(B * H, padded // chunk, chunk, dk), axis=2)
-    o = _kda_chunked(
-        heads(q), heads(k), heads(kb), heads(v), G.reshape(B * H, padded, dk), scale, chunk, per, interpret
-    )
-    return o[:, :T].reshape(B, H, T, dv).transpose(0, 2, 1, 3)
+    metrics.gauge("kda.padded_rows", padded - T)
+    args = [q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32)]
+    if padded != T:     # a padded step (g = 0, k = v = 0) forgets and writes nothing
+        args = [jnp.pad(x, ((0, 0), (0, padded - T)) + ((0, 0),) * (x.ndim - 2)) for x in args]
+    return _kda_chunked(*args, scale, chunk, per, interp)[:, :T]

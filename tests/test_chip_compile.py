@@ -414,8 +414,9 @@ def _mixers_through_mosaic(monkeypatch):
 def test_the_kda_scan_and_the_latent_attention_compile_at_the_hybrid_cells_shapes(one_chip):
     """``kimi-linear-ep32-train``'s two mixers' kernels, forward and backward,
     through Mosaic: the chunked scan over 32 heads of 128 at T = 8,192 (two
-    kernels; the backward one holds eight chunks' inverses, writes and states
-    in scratch), and the flash kernels with scores over 192 channels and
+    kernels over the model's ``[1, 8192, 32, 128]`` arrays, a head every 32nd
+    row of a block of 256 steps; the backward one holds eight chunks' summed decays, ``beta k``, inverses,
+    writes and states in scratch), and the flash kernels with scores over 192 channels and
     values over 128 (three)."""
     from adapcc_tpu.ops import flash_attention
     from adapcc_tpu.ops.kda import kda
@@ -433,7 +434,14 @@ def test_the_kda_scan_and_the_latent_attention_compile_at_the_hybrid_cells_shape
     compiled = jax.jit(jax.value_and_grad(scan, argnums=(0, 1, 2, 3, 4))).lower(
         wide, wide, wide, shape((1, T, H, D), jnp.float32), shape((1, T, H), jnp.float32)
     ).compile()
-    assert _kernels_in(compiled) == 2 and "%kda_fwd" in compiled.as_text() and "%kda_bwd" in compiled.as_text()
+    assert _kernels_in(compiled) == 2
+    # five operands and seven: q, k, v, the decay, beta; + do and the saved states (chipbench/trace_hybrid_lm.kernel_of)
+    flat, beta, states = r"bf16\[1,8192,32,128\]\S*", r"f32\[1,8192,32\]\S*", r"f32\[32,32,128,128\]\S*"
+    assert re.search(rf"%kda_fwd[\w.]* = \({flat}, {states}\) custom-call\(%[\w.\-]+(, %[\w.\-]+){{4}}\),", compiled.as_text())
+    assert re.search(
+        rf"%kda_bwd[\w.]* = \({flat}, {flat}, {flat}, f32\[1,8192,32,128\]\S*, {beta}\) custom-call\(%[\w.\-]+(, (/\*index=5\*/)?%[\w.\-]+){{6}}\),",
+        compiled.as_text(),
+    )
 
     def latent(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=True, interpret=False).astype(jnp.float32))
@@ -444,6 +452,44 @@ def test_the_kda_scan_and_the_latent_attention_compile_at_the_hybrid_cells_shape
     ).compile()
     assert _kernels_in(compiled) == 3
     assert re.search(r"%flash_bwd_dkv[\w.]* = \(bf16\[32,8192,192\]\S*, bf16\[32,8192,128\]", compiled.as_text())
+
+
+def test_the_kda_scan_is_its_two_kernels_and_no_pass_of_xlas_around_them(one_chip):
+    """``value_and_grad`` of ``kda`` at the cell's shape, ``[1, 8192, 32,
+    128]``: the program is ``kda_fwd``, ``kda_bwd`` and nothing else that
+    walks 8,192 x 4,096 elements but what stands for the test's own sum (a
+    reduction to a scalar, its cotangent's broadcast).  No ``transpose``,
+    ``copy``, physical ``reshape``, ``reduce-window`` or any other fusion: XLA
+    has not put the heads-major wrapper, the decay's cumulative sum or ``beta
+    k`` back around the kernels, and the kernels' operands and results are the
+    model's arrays themselves (a parameter goes in, a result comes out)."""
+    from adapcc_tpu.ops.kda import kda
+
+    _, cfg = _kimi_cell()
+    T, H, D = 8192, cfg.linear_attn_num_heads, cfg.linear_attn_head_dim
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def scan(q, k, v, g, beta):
+        return jnp.sum(kda(q, k, v, g, beta, interpret=False).astype(jnp.float32))
+
+    wide = shape((1, T, H, D))
+    text = jax.jit(jax.value_and_grad(scan, argnums=(0, 1, 2, 3, 4))).lower(
+        wide, wide, wide, shape((1, T, H, D), jnp.float32), shape((1, T, H), jnp.float32)
+    ).compile().as_text()
+    entry = text[text.index("ENTRY"):]      # a fusion's body has no pass of its own: its result in the entry counts
+    wide_results = {}
+    for name, out, op in re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", entry, re.M):
+        sizes = [int(np.prod([int(n) for n in dims.split(",")])) for dims in re.findall(r"\w+\[([\d,]+)\]", out)]
+        if T * H * D in sizes and op not in ("parameter", "get-tuple-element", "tuple", "bitcast"):
+            wide_results[name] = op
+    kernels = {name: op for name, op in wide_results.items() if op == "custom-call"}
+    assert sorted(name.split(".")[0] for name in kernels) == ["kda_bwd", "kda_fwd"], wide_results
+    others = {name: op for name, op in wide_results.items() if name not in kernels}
+    assert set(others.values()) <= {"broadcast"}, others            # the cotangent of the test's sum
+    assert not re.search(r" (transpose|reduce-window|cumsum)\(", text)
+    assert re.search(r"%kda_fwd[\w.]* = .* custom-call\(%q[\w.]*, %k[\w.]*, %v[\w.]*, %g[\w.]*, ", entry)
 
 
 def test_the_hybrid_cells_step_fits_the_chip(topo, monkeypatch):

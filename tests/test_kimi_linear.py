@@ -4,8 +4,8 @@ the Pallas interpreter.
 
 The chunked scan against the recurrence a step at a time
 (``chipbench/reference/kimi_linear_ref.kda_recurrence``), forward and all five
-gradients, at lengths that are no whole number of chunks and with decays from
-almost none to almost all; the latent mixer against the head-at-a-time form;
+gradients, at lengths that are no whole number of chunks, with decays from
+almost none to almost all, over one to three heads of two rows; the latent mixer against the head-at-a-time form;
 the model's logits, loss and every gradient leaf against the plain reference
 on seeded weights; the shares' routed parts plus the shared expert once add up
 to the uncut layer; the workload trains through ``DDPTrainer.step``.
@@ -75,10 +75,27 @@ def recurrence(q, k, v, g, beta, scale):
     return jnp.stack([kimi_linear_ref.kda_recurrence(*(x[b] for x in (q, k, v, g, beta)), scale, PROD) for b in range(q.shape[0])])
 
 
-@pytest.mark.parametrize("decay", ["near-one", "near-zero", "every-rate"])
-@pytest.mark.parametrize("T", [40, 100, 200], ids=["under-a-chunk", "a-chunk-and-a-part", "four-chunks-less-a-part"])
-def test_the_chunked_scan_is_the_recurrence_forward_and_in_all_five_gradients(T, decay):
-    args, mix = scan_inputs(T, T, decay)
+_LENGTHS = {"under-a-chunk": 40, "a-chunk-and-a-part": 100, "four-chunks-less-a-part": 200}
+# (T, decay, B, H, the one head whose output is weighed or None for all): a row and two heads at every length and
+# decay; then two rows of one, two and three heads, so that a head or a row picked by the wrong index reads another's
+# numbers, at lengths that need the pad (100, 200); then the last head's output alone, so that what g and beta get back
+# for a head other than the first is held to that head's column and the other columns to zero
+SCANS = {
+    **{f"{length}-{decay}": (T, decay, 1, 2, None)
+       for length, T in _LENGTHS.items() for decay in ("near-one", "near-zero", "every-rate")},
+    "two-rows-of-one-head": (100, "every-rate", 2, 1, None),
+    "two-rows-of-two-heads": (200, "every-rate", 2, 2, None),
+    "two-rows-of-three-heads": (100, "every-rate", 2, 3, None),
+    "the-last-of-three-heads-alone": (100, "near-one", 2, 3, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(SCANS))
+def test_the_chunked_scan_is_the_recurrence_forward_and_in_all_five_gradients(case):
+    T, decay, B, H, only = SCANS[case]
+    args, mix = scan_inputs(T, T, decay, B=B, H=H)
+    if only is not None:
+        mix = mix * (jnp.arange(H) == only)[None, None, :, None]
     scale = 0.25
     got = kda(*args, scale=scale)
     want = recurrence(*args, scale)
@@ -87,17 +104,45 @@ def test_the_chunked_scan_is_the_recurrence_forward_and_in_all_five_gradients(T,
     wants = jax.grad(lambda *a: jnp.sum(recurrence(*a, scale) * mix), argnums=(0, 1, 2, 3, 4))(*args)
     for name, a, b in zip(("q", "k", "v", "g", "beta"), grads, wants):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, err_msg=f"d{name}")
+    if only is not None:
+        dg, dbeta = np.asarray(grads[3]), np.asarray(grads[4])
+        assert np.abs(dg[:, :, only]).max() > 1e-3 and np.abs(dbeta[:, :, only]).max() > 1e-3
+        assert not dg[:, :, :only].any() and not dbeta[:, :, :only].any()
 
 
 def test_the_chunk_follows_the_shape_and_the_scan_leaves_its_gauges():
-    assert chunk_plan(8192) == (64, 8, 8192)          # the cell: 128 chunks, eight to a grid step
+    assert chunk_plan(8192) == (64, 8, 8192)          # 128 chunks, eight to a grid step where the rows are narrow
+    assert chunk_plan(8192, 32 * 128) == (64, 4, 8192)   # the cell: 256 steps over 32 heads of 128 are a block's million elements
+    assert chunk_plan(100, 1 << 20) == (64, 1, 128)
     assert chunk_plan(200) == (64, 4, 256) and chunk_plan(100) == (64, 2, 128) and chunk_plan(40) == (40, 1, 40)
     args, _ = scan_inputs(100, 0, "every-rate")
     kda(*args)
     gauges = default_registry().snapshot()["gauges"]
     assert (gauges["kda.chunk"], gauges["kda.tiles"], gauges["kda.state_bytes"]) == (64, 2 * 2, 2 * 16 * 8 * 4)
+    assert gauges["kda.padded_rows"] == 128 - 100        # the one copy left: the pad along T, where T is no whole chunks
+    kda(*scan_inputs(40, 0, "every-rate")[0])
+    assert default_registry().snapshot()["gauges"]["kda.padded_rows"] == 0
     with pytest.raises(ValueError, match="kda shapes"):
         kda(args[0], args[1], args[2], args[3][..., :4], args[4])
+
+
+def test_a_head_size_that_is_no_whole_lane_tiles_is_refused_through_mosaic_and_taken_by_the_interpreter():
+    """The kernels read a head as every ``H``-th row of the model's ``[T, H,
+    d]``: through Mosaic a row is whole 128-lane tiles, and bfloat16 rows are
+    read two to a 32-bit word, so its heads come in pairs; the scan says so
+    before any kernel is built.  The interpreter takes any head size."""
+    args, _ = scan_inputs(40, 1, "every-rate")                      # d_k 16, d_v 8
+    with pytest.raises(ValueError, match="multiples of 128"):
+        kda(*args, interpret=False)
+    wide_v = (args[0], args[1], jnp.zeros((1, 40, 2, 128)), args[3], args[4])
+    with pytest.raises(ValueError, match="multiples of 128"):
+        kda(*wide_v, interpret=False)                               # d_v alone a whole tile: d_k still is not
+    three, _ = scan_inputs(40, 1, "every-rate", H=3, dk=128, dv=128)
+    with pytest.raises(ValueError, match="come in pairs"):
+        kda(*(x.astype(jnp.bfloat16) for x in three[:3]), *three[3:], interpret=False)
+    np.testing.assert_allclose(
+        np.asarray(kda(*args, scale=0.25, interpret=True)), np.asarray(recurrence(*args, 0.25)), atol=1e-4
+    )
 
 
 def test_a_batch_of_rows_scans_each_from_a_zero_state():
