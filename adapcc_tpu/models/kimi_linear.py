@@ -1,7 +1,9 @@
 """Kimi-Linear's block (``model_type`` ``kimi_linear``): Kimi Delta Attention
 (KDA) layers three to one with latent attention that carries no positions,
 sigmoid-routed sparse experts beside a shared expert after a leading dense
-layer.
+layer.  The latent mixer and the layer are also JoyAI-LLM-Flash's
+(:mod:`adapcc_tpu.models.joyai_flash`), which gives the mixer a query rank
+and rotates its position channels.
 
 Written from the published ``config.json``
 (https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct/blob/main/config.json)
@@ -23,12 +25,14 @@ The model returns ``(logits, sizes)`` as ``Trinity`` does, so
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from adapcc_tpu.models.trinity import _REMAT, GatedMLP, RMSNorm, SparseExperts, _dense
 from adapcc_tpu.utils.observability import default_registry
@@ -53,11 +57,12 @@ class KimiLinearConfig:
     short_conv_kernel_size: int = 4
     num_attention_heads: int = 32          # the latent layers'
     kv_lora_rank: int = 512
-    q_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None      # None: one query projection, as published
     qk_nope_head_dim: int = 128
-    qk_rope_head_dim: int = 64             # carried, never rotated: ``mla_use_nope``
+    qk_rope_head_dim: int = 64             # carried, and rotated only where ``mla_use_nope`` is false
     v_head_dim: int = 128
-    mla_use_nope: bool = True
+    mla_use_nope: bool = True              # as published: no positions in the latent layers
+    rope_theta: float = 10000.0            # read only where ``mla_use_nope`` is false
     num_experts: int = 256
     num_experts_per_token: int = 8
     num_shared_experts: int = 1
@@ -81,11 +86,10 @@ class KimiLinearConfig:
         if (
             self.moe_router_activation_func != "sigmoid" or self.hidden_act != "silu" or self.tie_word_embeddings
             or self.num_shared_experts != 1 or self.num_expert_group != 1 or self.topk_group != 1
-            or self.q_lora_rank is not None or not self.mla_use_nope
         ):
             raise ValueError(
                 "only the published kimi_linear settings are implemented: sigmoid scores in one group, "
-                "silu, one shared expert, an untied head, no query rank, no rotation"
+                "silu, one shared expert, an untied head"
             )
         if self.remat not in _REMAT:
             raise ValueError(f"remat {self.remat!r} not in {sorted(_REMAT)}")
@@ -225,26 +229,107 @@ class KDAMixer(nn.Module):
         return _dense(cfg.hidden_size, cfg, "o_proj")(o.reshape(B, T, H * D) * jax.nn.sigmoid(gate))
 
 
-class MLAMixer(nn.Module):
-    """Latent attention without positions: keys and values come up from a
-    normed latent of ``kv_lora_rank``; ``qk_rope_head_dim`` more key channels
-    come straight from the token, the same for every head and never rotated."""
+@functools.lru_cache(maxsize=8)
+def _pair_turns(T: int, D: int, start: int, theta: float):
+    """``(cos, sin [T, D], swap [D, D])`` float32 for :func:`rotate_pairs`,
+    from float64 angles on the host: with ``i`` counted from ``start``,
+    channels ``start + 2i`` and ``start + 2i + 1`` of position ``m`` hold
+    ``cos(m theta^(-2i / (D - start)))``, and ``-sin`` and ``+sin`` of it; the
+    channels before ``start`` hold 1 and 0 (they do not turn).  ``x @ swap``
+    puts each turning channel's partner in its place."""
+    turned = D - start
+    angle = np.arange(T, dtype=np.float64)[:, None] * theta ** (-np.arange(0, turned, 2, dtype=np.float64) / turned)
+    cos, sin = np.ones((T, D)), np.zeros((T, D))
+    cos[:, start:] = np.repeat(np.cos(angle), 2, axis=-1)
+    sin[:, start:] = np.repeat(np.sin(angle), 2, axis=-1)
+    sin[:, start::2] *= -1.0
+    swap = np.zeros((D, D))
+    channels = np.arange(start, D)
+    swap[channels ^ 1, channels] = 1.0        # start is even: a pair is (2j, 2j + 1)
+    return cos.astype(np.float32), sin.astype(np.float32), swap.astype(np.float32)
 
-    cfg: KimiLinearConfig
+
+def _turn(x, cos, sin, swap):
+    """``x cos + partner(x) sin`` in float32.  The partners come by a product
+    with a 0/1 matrix (exact: one term a column): on a TPU a lane shuffle
+    written as two rolls is two slices written out, a ``[D, D]`` product is
+    nothing beside the projections."""
+    partner = jnp.einsum("bthd,de->bthe", x, swap.astype(x.dtype), precision="highest", preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * cos + partner * sin).astype(x.dtype)
+
+
+@jax.custom_vjp
+def _turned(x, cos, sin, swap):
+    return _turn(x, cos, sin, swap)
+
+
+def _turned_fwd(x, cos, sin, swap):
+    return _turn(x, cos, sin, swap), (cos, sin, swap)
+
+
+def _turned_bwd(tables, g):
+    # a rotation's transpose is the rotation back: the same pass with the sines' signs changed
+    cos, sin, swap = tables
+    return _turn(g, cos, -sin, swap), None, None, None
+
+
+_turned.defvjp(_turned_fwd, _turned_bwd)
+
+
+def rotate_pairs(x, theta: float, start: int = 0):
+    """Rotary positions over the channels of ``x [B, T, H, D]`` from ``start``
+    on, with neighbouring channels ``(start + 2i, start + 2i + 1)`` as the
+    rotated pairs (``rope_interleave``): the pair at position ``m`` turns by
+    ``m theta^(-2i / (D - start))``; the channels before ``start`` stay.  One
+    pass over the whole head: no channel moves (no de-interleaving, no split
+    and no concatenation of the head's two parts), the turn in float32, and
+    its backward pass is the turn back."""
+    T, D = x.shape[1], x.shape[-1]
+    if start % 2 or D % 2:
+        raise ValueError(f"channels {start}..{D} make no pairs (2i, 2i + 1)")
+    cos, sin, swap = _pair_turns(T, D, start, float(theta))
+    return _turned(x, jnp.asarray(cos)[None, :, None, :], jnp.asarray(sin)[None, :, None, :], jnp.asarray(swap))
+
+
+class MLAMixer(nn.Module):
+    """Latent attention: keys and values come up from a normed latent of
+    ``kv_lora_rank``; ``qk_rope_head_dim`` more key channels come straight
+    from the token, the same for every head.  Kimi-Linear's as published has
+    one query projection and rotates nothing (``mla_use_nope``);
+    JoyAI-LLM-Flash's brings the queries up from a normed latent of
+    ``q_lora_rank`` too and rotates the shared key channels and each head's
+    last ``qk_rope_head_dim`` query channels (:func:`rotate_pairs`).
+
+    ``cfg`` is either model's configuration: read are ``num_attention_heads``,
+    ``q_lora_rank``, ``kv_lora_rank``, the three head sizes, ``mla_use_nope``,
+    ``rope_theta``, ``rms_norm_eps``, ``hidden_size`` and ``dtype``."""
+
+    cfg: Any
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
         B, T, _ = x.shape
         H, nope, pe, dv = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        q = _dense(H * (nope + pe), cfg, "q_proj")(x).reshape(B, T, H, nope + pe)
+        if cfg.q_lora_rank is None:
+            q = _dense(H * (nope + pe), cfg, "q_proj")(x)
+        else:
+            q_latent = RMSNorm(cfg.rms_norm_eps, name="q_a_layernorm")(_dense(cfg.q_lora_rank, cfg, "q_a_proj")(x))
+            q = _dense(H * (nope + pe), cfg, "q_b_proj")(q_latent)
+        q = q.reshape(B, T, H, nope + pe)
         latent, k_pe = jnp.split(_dense(cfg.kv_lora_rank + pe, cfg, "kv_a_proj_with_mqa")(x), [cfg.kv_lora_rank], axis=-1)
         up = _dense(H * (nope + dv), cfg, "kv_b_proj")(RMSNorm(cfg.rms_norm_eps, name="kv_a_layernorm")(latent))
         k_nope, v = jnp.split(up.reshape(B, T, H, nope + dv), [nope], axis=-1)
-        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe[:, :, None, :], (B, T, H, pe))], axis=-1)
+        k_pe = k_pe[:, :, None, :]
+        if not cfg.mla_use_nope:
+            with jax.named_scope("mla_rope"):
+                q, k_pe = rotate_pairs(q, cfg.rope_theta, start=nope), rotate_pairs(k_pe, cfg.rope_theta)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (B, T, H, pe))], axis=-1)
         metrics = default_registry()
         metrics.gauge("mla.qk_dim", nope + pe)
         metrics.gauge("mla.v_dim", dv)
+        metrics.gauge("mla.q_rank", cfg.q_lora_rank or 0)
+        metrics.gauge("mla.rope_dim", 0 if cfg.mla_use_nope else pe)
         from adapcc_tpu.ops import flash_attention
 
         with jax.named_scope("mla_attn"):
@@ -253,9 +338,10 @@ class MLAMixer(nn.Module):
 
 
 class Block(nn.Module):
-    """One layer, two norms: ``h += mixer(norm(h))``, then ``h += ffn(norm(h))``."""
+    """One layer, two norms: ``h += mixer(norm(h))``, then ``h += ffn(norm(h))``
+    (``cfg`` Kimi-Linear's configuration or JoyAI-LLM-Flash's)."""
 
-    cfg: KimiLinearConfig
+    cfg: Any
     kind: str
     sparse: bool
 

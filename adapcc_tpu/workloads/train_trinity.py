@@ -75,12 +75,16 @@ def build_trainer(cfg, tx, mesh, loss: str = "dense", donate_state: bool = True)
     return trainer, model
 
 
-def train(args, cfg, build, banner: str, report: Optional[dict] = None) -> Tuple[float, float]:
-    """The loop of an expert model's entry point (this one's and
-    ``train_kimi_linear``'s): ``build(cfg, tx, mesh, loss)`` gives ``(trainer,
-    model)``; ``args`` carries ``vocab``, ``seq``, ``batch``, ``corpus_tokens``,
-    ``epochs``, ``lr``, ``world``, ``loss``.  Returns (first epoch's mean
-    loss, last epoch's)."""
+def train(args, cfg, build, banner: str, report: Optional[dict] = None, first_model_state=None,
+          record=None) -> Tuple[float, float]:
+    """The loop of an expert model's entry point (this one's,
+    ``train_kimi_linear``'s and ``train_joyai_flash``'s): ``build(cfg, tx,
+    mesh, loss)`` gives ``(trainer, model)``; ``args`` carries ``vocab``,
+    ``seq``, ``batch``, ``corpus_tokens``, ``epochs``, ``lr``, ``world``,
+    ``loss``.  ``first_model_state(cfg)`` is what the first state carries
+    beside the parameters (Trinity's by default) and ``record(model_state)``
+    what is sampled, under a profile, from what a step returned (the routing
+    counts by default).  Returns (first epoch's mean loss, last epoch's)."""
     from adapcc_tpu.launch import maybe_initialize_distributed
 
     maybe_initialize_distributed()
@@ -106,7 +110,8 @@ def train(args, cfg, build, banner: str, report: Optional[dict] = None) -> Tuple
     tx = optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(args.lr, weight_decay=0.01))
     trainer, model = build(cfg, tx, mesh, args.loss)
     params = model.init(jax.random.PRNGKey(0), jnp.asarray(rows[:1]))
-    state = trainer.init_state(params, initial_model_state(cfg))
+    state = trainer.init_state(params, (first_model_state or initial_model_state)(cfg))
+    record = record or (lambda model_state: record_routing(model_state["moe_sizes"]))
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params))
     print(
         f"{banner}: {n_params / 1e6:.2f} M parameters, layers {list(cfg.kinds)}, "
@@ -122,7 +127,7 @@ def train(args, cfg, build, banner: str, report: Optional[dict] = None) -> Tuple
             losses.append(jnp.mean(loss))
             with metrics.span("moe.read_routing") as live:
                 if live:   # per-step values only under a profile (docs/OBSERVABILITY.md)
-                    record_routing(jax.device_get(state.model_state["moe_sizes"]))
+                    record(jax.device_get(state.model_state))
         sizes = np.asarray(jax.device_get(state.model_state["moe_sizes"]))
         means.append(float(np.mean(jax.device_get(losses))))
         load = sizes.max(axis=1) / np.maximum(sizes.mean(axis=1), 1e-9) if sizes.size else np.zeros(0)

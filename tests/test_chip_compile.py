@@ -390,6 +390,34 @@ def test_cells_1_to_3_lower_to_the_text_they_lowered_to_before_the_hybrid_model(
     assert hashlib.sha256(_lowered_text(case, one_chip).encode()).hexdigest() == _PARENT_LOWERED[case]
 
 
+#: sha256 of cell 4's latent layer as lowered at ca7fb08 (the parent of PR 34), before the mixer learnt the query
+#: rank and the rotation
+_PARENT_LATENT_LAYER = "6fa0aef26edbf87f85d78733f469a3b6b057c33b73e4a411b20c5f4013e9181a"
+
+
+def test_cell_4s_latent_layer_lowers_to_the_text_it_lowered_to_before_the_rotation(one_chip, monkeypatch):
+    """PR 34 gave the latent mixer a query rank and rotated channels and moved
+    it beside the models that share it: ``kimi-linear-ep32-train``'s latent
+    layer (no query rank, nothing rotated: ``mla_use_nope``) at the cell's
+    shape, forward and backward with its projections, norm and the three
+    flash kernels at 192 / 128, lowers to the parent's text, character for
+    character."""
+    import functools
+    import hashlib
+
+    from adapcc_tpu.models.kimi_linear import MLAMixer
+
+    _flash_through_mosaic(monkeypatch)
+    _, cfg = _kimi_cell()
+    mixer = MLAMixer(cfg)
+    x = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
+    params = _shapes_on(jax.eval_shape(mixer.init, jax.random.PRNGKey(0), jnp.zeros((1, 64, cfg.hidden_size))), one_chip)
+    step = jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(mixer.apply(p, x).astype(jnp.float32)), argnums=(0, 1)))
+    text = _without_source_lines(functools.partial(step.lower, params, x))
+    assert text.count("tpu_custom_call") == 3 and "q_proj" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == _PARENT_LATENT_LAYER
+
+
 def _kimi_cell():
     import json
     from pathlib import Path
@@ -521,6 +549,80 @@ def test_the_hybrid_cells_step_fits_the_chip(topo, monkeypatch):
         "kda_fwd", "kda_bwd", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
     )}
     assert names == {"kda_fwd": 4, "kda_bwd": 4, "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 0.95 * 16 * 2**30
+
+
+# --- the latent-attention cell: six rotated latent blocks, two heads, one step ---
+
+
+def _joyai_cell():
+    import json
+    from pathlib import Path
+
+    from chipbench.runners.train_mla_lm import model_config
+
+    config = json.loads((Path(__file__).resolve().parents[1] / "chipbench/configs/joyai-flash-ep16.json").read_text())
+    return config, model_config(config)
+
+
+def test_the_rotated_latent_layer_compiles_at_the_latent_cells_shape(one_chip, monkeypatch):
+    """``joyai-flash-ep16-train``'s mixer, forward and backward at T = 8,192:
+    the query's two projections with the norm between them, the rotation of
+    64 of each head's 192 score channels and of the 64 shared key channels
+    (XLA's: no kernel of its own), and the three flash kernels at 192 / 128
+    through Mosaic under their own names."""
+    from adapcc_tpu.models.kimi_linear import MLAMixer
+
+    _flash_through_mosaic(monkeypatch)
+    _, cfg = _joyai_cell()
+    assert (cfg.q_lora_rank, cfg.mla_use_nope, cfg.rope_theta) == (1536, False, 32000000)
+    mixer = MLAMixer(cfg)
+    x = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), jnp.bfloat16, sharding=one_chip)
+    params = _shapes_on(jax.eval_shape(mixer.init, jax.random.PRNGKey(0), jnp.zeros((1, 64, cfg.hidden_size))), one_chip)
+    assert params["params"]["q_b_proj"]["kernel"].shape == (1536, 32 * 192)
+    step = jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(mixer.apply(p, x).astype(jnp.float32)), argnums=(0, 1)))
+    compiled = step.lower(params, x).compile()
+    text = compiled.as_text()
+    assert _kernels_in(compiled) == 3 and "mla_rope" in text
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert len(re.findall(rf"^\s*%{name}[\w.]* = ", text, re.M)) == 1, name
+    assert re.search(r"%flash_bwd_dkv[\w.]* = \(bf16\[32,8192,192\]\S*, bf16\[32,8192,128\]", text)
+
+
+def test_the_latent_cells_step_fits_the_chip(topo, monkeypatch):
+    """The whole donating step of ``joyai-flash-ep16-train`` (680.44 M float32
+    parameters with AdamW's moments, one row of 8,192 tokens through five
+    layers and the multi-token-prediction module, two heads and two losses,
+    the loss and remat the configuration file states) compiled for the
+    described chip: state and temporaries leave 5% of its 16 GiB free, and
+    each block's three flash kernels are in the program under their own
+    names (the device trace is read by them)."""
+    import optax
+
+    from adapcc_tpu.ddp.trainer import TrainState
+    from adapcc_tpu.models.joyai_flash import initial_model_state
+    from adapcc_tpu.workloads.train_joyai_flash import build_trainer
+
+    _flash_through_mosaic(monkeypatch)
+    config, cfg = _joyai_cell()
+    mesh = Mesh(np.array(topo.devices[:1]), (RANKS_AXIS,))
+    tx = optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(1e-6, weight_decay=0.01))
+    program = config["assumed"]["program"]
+    trainer, model = build_trainer(cfg, tx, mesh, loss=program["loss"], donate_state=program["donate_state"])
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 64), jnp.int32))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)) == 680_441_088
+    state = jax.eval_shape(lambda p: TrainState.create(p, tx, model_state=initial_model_state(cfg)), params)
+    assert state.model_state["moe_sizes"].shape == (5, 16)
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=NamedSharding(mesh, P(RANKS_AXIS)))
+    compiled = trainer._build().lower(_shapes_on(state, NamedSharding(mesh, P())), tokens).compile()
+    text = compiled.as_text()
+    names = {name: len(re.findall(rf"^\s*%{name}[\w.]* = ", text, re.M)) for name in (
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+    )}
+    assert names == {"flash_fwd": 6, "flash_bwd_dq": 6, "flash_bwd_dkv": 6}
+    for scope in ("mla_rope", "mtp_merge", "mtp_block", "mtp_head"):
+        assert f"/{scope}/" in text, scope
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 0.95 * 16 * 2**30
 
