@@ -30,10 +30,21 @@ key block ``kj`` takes query blocks from ``(kj·bk) // bq`` up, and the mask is
 applied only on the tiles the diagonal crosses (:func:`visited_tiles` counts
 them: 36 of 64 at T=1,024 with 128-tiles).  Where the code stays small the
 bounds are static: the kernel body is written out once for each grid position
-along the block axis (:func:`_per_program`), because a loop bounded by the
-traced ``program_id`` cost 3.4 times as much for each tile on a v5e; long
-sequences (past ``_STRAIGHT_LINE_ELEMENTS``) take that loop.  ``causal=False``
-(ring attention's off-diagonal shards) visits every tile from one body.
+along the block axis (:func:`_per_program`), because a loop of one tile a
+turn, bounded by the traced ``program_id``, cost 3.4 times as much for each
+tile on a v5e.  Long sequences (past ``_STRAIGHT_LINE_ELEMENTS``) take one
+body with traced bounds, and it too runs written-out tiles: the tiles every
+position has (the diagonal's) at a traced start, and the unmasked ones, whose
+count ``n`` differs with the position, as runs by what the code can see of
+``n`` (:func:`_tiles`): ``G`` tiles written out, their blocks ``base + r``
+with ``r`` a Python integer, for each of ``n // G`` turns of a loop, then
+``G/2`` where ``n`` has that bit, and so on down to one; ``G`` the largest
+whose code fits ``_TRACED_BASE_ELEMENTS`` (4 at T=8,192 with 512-tiles: 8
+tiles of code; :func:`looped_tiles` counts what is reached from the loop).
+The tiles come in the order the loop of one tile a turn took them, which is
+``G = 1`` of the same code, so the results are that loop's to the bit.
+``causal=False`` (ring attention's off-diagonal shards) visits every tile
+from one body.
 
 **A window.**  ``window=W`` (with ``causal=True``) lets query ``t`` see key
 ``s`` only where ``0 <= t - s < W``: a band under the diagonal.  A query
@@ -207,14 +218,37 @@ def visited_tiles(
     return sum(end - lo for lo, _, _, end in spans)
 
 
+def _query_side(T: int, block_q: int, block_k: int, causal: bool, window):
+    """``(n, span)`` of the kernels gridded over query blocks (forward, dq):
+    how many there are and the key blocks each takes (:func:`_key_span`)."""
+    n_k = T // block_k
+    return T // block_q, lambda qi: _key_span(qi, block_q, block_k, window) if causal else (0, 0, n_k, n_k)
+
+
+def _key_side(T: int, block_q: int, block_k: int, causal: bool, window):
+    """``(n, span)`` of the kernel gridded over key blocks (dk/dv): how many
+    there are and the query blocks each is seen by (:func:`_query_span`)."""
+    n_q = T // block_q
+    return T // block_k, lambda kj: _query_span(kj, block_q, block_k, window, n_q) if causal else (0, 0, n_q, n_q)
+
+
 #: Score-plane elements (tiles x block_q x block_k) a causal kernel writes out
-#: as straight-line code at most.  Under it every grid position gets its own
-#: copy of the body with static bounds; over it (long sequences: the code
+#: as straight-line code with static bounds at most.  Under it every grid
+#: position gets its own copy of the body; over it (long sequences: the code
 #: grows with T squared and falls out of instruction memory) the one body takes
-#: the traced ``program_id`` and loops with traced bounds.  Measured on a v5e
-#: (PERF.md §6, PR 25): static wins at T=1,024 and T=2,048 (0.8 and 2.6 M
-#: elements with 512-tiles), the loop at T=4,096 (9.4 M).
+#: the traced ``program_id`` and walks its tiles in runs at a traced base.
+#: Measured on a v5e (PERF.md §6, PR 25): static wins at T=1,024 and T=2,048
+#: (0.8 and 2.6 M elements with 512-tiles), the one body at T=4,096 (9.4 M).
 _STRAIGHT_LINE_ELEMENTS = 1 << 22
+
+#: Score-plane elements that one body with traced bounds writes out at most:
+#: its runs of tiles at a traced base and the tiles every position has.
+#: Measured on a v5e (PERF.md §6, PR 36) at T=8,192 with 512-tiles, the three
+#: kernels of one latent layer (32 heads, 192 / 128): 8 tiles of code (runs of
+#: 4 in a loop of up to three turns, then 2 and 1, beside the diagonal's tile)
+#: 24.73 ms; 16 tiles (a run of 8, then 4, 2, 1) 25.03 and twice the compile;
+#: 4 tiles 24.77; one tile a turn 25.65; the parent's loop 26.67.
+_TRACED_BASE_ELEMENTS = 1 << 21
 
 
 def _bodies(n: int, span, share: bool):
@@ -234,48 +268,133 @@ def _bodies(n: int, span, share: bool):
     return [(first, last, bounds) for first, last, bounds, _ in runs]
 
 
-def _per_program(body, n: int, span, block_q: int, block_k: int, causal: bool, window) -> None:
-    """``body(rel, bounds, base)`` for the grid position ``base + rel`` along
-    the block axis (of ``n``), ``bounds = span(position)`` counted from
-    ``base``.  Under the causal mask the positions differ in their bounds:
-    each gets its own copy of the body with the position a Python integer
-    (``base`` 0), where that keeps the code small enough (every bound and
-    slice is then static and Mosaic schedules a block's tiles as one basic
-    block); the positions inside a window's band share one copy, ``base`` the
-    traced position and the bounds static from it.  Else ``rel`` is the traced
-    ``pl.program_id`` and the bounds are traced.  Without the mask one body
-    serves them all."""
+def _top(n: int) -> int:
+    """The largest power of two that is at most ``n`` (at least 1)."""
+    return 1 << (n.bit_length() - 1)
+
+
+def _runs(n: int, span, block_q: int, block_k: int):
+    """How ``n`` positions walk their spans from ONE body whose bounds are
+    traced: ``(most, run)`` for each of a span's three parts (masked,
+    unmasked, masked), ``most`` the tiles any position has there.  ``run`` 0:
+    every position has that many, written out at the part's traced start.
+    Else the count differs and is split by what the code can see of it:
+    ``run`` tiles written out, in a loop, while that many are left, then a
+    half of that where the count's bit says so, and so on down to one.  The
+    runs are the largest power of two (at most the largest in ``most``) whose
+    code, ``2·run − 1`` tiles for each such part beside the written-out ones,
+    stays within ``_TRACED_BASE_ELEMENTS``, and 1 where nothing fits: one tile
+    a turn."""
+    spans = [span(i) for i in range(n)]
+    counts = [[s[i + 1] - s[i] for s in spans] for i in range(3)]
+    room = _TRACED_BASE_ELEMENTS // (block_q * block_k)
+
+    def parts(run):
+        return tuple((max(c), 0 if min(c) == max(c) else min(run, _top(max(c)))) for c in counts)
+
+    def code(run):
+        return sum(2 * run - 1 if run else most for most, run in parts(run))
+
+    run = 1
+    while 2 * run <= max(map(max, counts)) and code(2 * run) <= room:
+        run *= 2
+    return parts(run)
+
+
+def _written_out(n: int, span, block_q: int, block_k: int, causal: bool, window):
+    """The written-out bodies (:func:`_bodies`) of a kernel over ``n`` grid
+    positions along the block axis, or None where their code would pass
+    ``_STRAIGHT_LINE_ELEMENTS`` (one body then walks by :func:`_runs`)."""
     if not causal or n == 1:
-        return body(0, span(0), 0)
+        return [(0, 0, span(0))]
+    bodies = _bodies(n, span, share=window is not None and block_q == block_k)
+    written = sum(bounds[3] - bounds[0] for _, _, bounds in bodies)
+    return bodies if written * block_q * block_k <= _STRAIGHT_LINE_ELEMENTS else None
+
+
+def looped_tiles(
+    T: int, block_q: int, block_k: int, causal: bool, window: Optional[int] = None, key_side: bool = False
+) -> int:
+    """Of :func:`visited_tiles`, those a kernel gridded over query blocks (or
+    over key blocks: ``key_side``) reaches from inside a loop with a traced
+    trip count; the others are straight-line code, under a condition or not."""
+    n, span = (_key_side if key_side else _query_side)(T, block_q, block_k, causal, window)
+    if _written_out(n, span, block_q, block_k, causal, window) is not None:
+        return 0
+    parts = _runs(n, span, block_q, block_k)
+    return sum(
+        (s[i + 1] - s[i]) // run * run for s in map(span, range(n)) for i, (_, run) in enumerate(parts) if run
+    )
+
+
+def _per_program(body, n: int, span, block_q: int, block_k: int, causal: bool, window) -> None:
+    """``body(rel, bounds, base, walk)`` for the grid position ``base + rel``
+    along the block axis (of ``n``), ``bounds = span(position)`` counted from
+    ``base``, ``walk`` the :func:`_band` that visits them.  Under the causal
+    mask the positions differ in their bounds: each gets its own copy of the
+    body with the position a Python integer (``base`` 0), where that keeps the
+    code small enough (every bound and slice is then static and Mosaic
+    schedules a block's tiles as one basic block); the positions inside a
+    window's band share one copy, ``base`` the traced position and the bounds
+    static from it.  Else ``rel`` is the traced ``pl.program_id``, the bounds
+    are traced and ``walk`` splits each of them into written-out runs
+    (:func:`_runs`).  Without the mask one body serves them all."""
+    bodies = _written_out(n, span, block_q, block_k, causal, window)
+    if bodies is not None and len(bodies) == 1:
+        return body(0, bodies[0][2], 0, _band)
     position = pl.program_id(1)
-    runs = _bodies(n, span, share=window is not None and block_q == block_k)
-    written = sum(bounds[3] - bounds[0] for _, _, bounds in runs)
-    if written * block_q * block_k > _STRAIGHT_LINE_ELEMENTS:
-        return body(position, span(position), 0)
-    for first, last, bounds in runs:
+    if bodies is None:
+        return body(position, span(position), 0, functools.partial(_band, parts=_runs(n, span, block_q, block_k)))
+    for first, last, bounds in bodies:
         if first == last:
-            pl.when(position == first)(functools.partial(body, first, bounds, 0))
+            pl.when(position == first)(functools.partial(body, first, bounds, 0, _band))
         else:
-            shared = functools.partial(body, 0, tuple(x - first for x in bounds), position)
+            shared = functools.partial(body, 0, tuple(x - first for x in bounds), position, _band)
             pl.when((position >= first) & (position <= last))(shared)
 
 
-def _tiles(lo, hi, tile, carry):
-    """``carry = tile(j, carry)`` for ``j`` in ``[lo, hi)``: written out when
-    both bounds are Python integers, a ``fori_loop`` when one is traced."""
+def _tiles(lo, hi, tile, carry, most=0, run=0):
+    """``carry = tile(j, carry)`` for ``j`` in ``[lo, hi)``, ascending.  Both
+    bounds Python integers: written out.  One traced (:func:`_runs` gives
+    ``most`` and ``run``): ``most`` tiles written out from the traced ``lo``
+    where every position has as many (``run`` 0); else ``run`` tiles written
+    out for each turn of a loop while that many are left, then the rest by
+    the bits of the count, each bit's tiles written out under its own
+    condition.  (The loop stays where it turns once at most: under a
+    condition the first run would see the accumulators' zeros as constants,
+    Mosaic then folds the first sum away, and ``dq`` rounds otherwise than
+    from the loop: an ulp of bfloat16 in 1e-5 of its elements on a v5e,
+    PERF.md §6, PR 36.)"""
     if isinstance(lo, int) and isinstance(hi, int):
         for j in range(lo, hi):
             carry = tile(j, carry)
         return carry
-    return lax.fori_loop(lo, hi, tile, carry)
+
+    def written(base, count):
+        def tiles(carry):
+            for r in range(count):
+                carry = tile(base + r, carry)
+            return carry
+        return tiles
+
+    if not run:
+        return written(lo, most)(carry)
+    count = hi - lo
+    carry = lax.fori_loop(0, count // run, lambda turn, carry: written(lo + turn * run, run)(carry), carry)
+    at = lo + count // run * run
+    while run > 1:
+        run //= 2
+        carry = lax.cond((count & run) != 0, written(at, run), lambda carry: carry, carry)
+        at = at + (count & run)
+    return carry
 
 
-def _band(bounds, tile, carry):
-    """The three runs of a span: masked, unmasked, masked."""
+def _band(bounds, tile, carry, parts=((0, 0),) * 3):
+    """The three parts of a span: masked, unmasked, masked."""
     lo, a, b, end = bounds
-    carry = _tiles(lo, a, functools.partial(tile, masked=True), carry)
-    carry = _tiles(a, b, functools.partial(tile, masked=False), carry)
-    return _tiles(b, end, functools.partial(tile, masked=True), carry)
+    carry = _tiles(lo, a, functools.partial(tile, masked=True), carry, *parts[0])
+    carry = _tiles(a, b, functools.partial(tile, masked=False), carry, *parts[1])
+    return _tiles(b, end, functools.partial(tile, masked=True), carry, *parts[2])
 
 
 def _dot(a, b, contract):
@@ -306,10 +425,8 @@ def _held(base, r, first, band):
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k, window, seq, band):
     q = q_ref[0]
     bq = q.shape[0]
-    T = seq
-    n_k = T // block_k
 
-    def query_block(rel, bounds, base):
+    def query_block(rel, bounds, base, walk):
         def tile(r, carry, masked):
             m, l, acc = carry
             at = _held(base, r, bounds[0], band)
@@ -329,14 +446,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, 
             jnp.zeros((bq, 1), jnp.float32),
             jnp.zeros((bq, v_ref.shape[-1]), jnp.float32),
         )
-        m, l, acc = _band(bounds, tile, carry)
+        m, l, acc = walk(bounds, tile, carry)
         o_ref[0] = (acc / l).astype(o_ref.dtype)
         lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (bq, _LSE_LANES))
 
-    def span(qi):
-        return _key_span(qi, block_q, block_k, window) if causal else (0, 0, n_k, n_k)
-
-    _per_program(query_block, T // block_q, span, block_q, block_k, causal, window)
+    _per_program(query_block, *_query_side(seq, block_q, block_k, causal, window), block_q, block_k, causal, window)
 
 
 def _dq_kernel(
@@ -348,10 +462,8 @@ def _dq_kernel(
     lse_col = lse_ref[0][:, 0:1]      # [bq, 1] from the lane-padded layout
     delta_col = delta_ref[0][:, 0:1]
     bq, d = q.shape
-    T = seq
-    n_k = T // block_k
 
-    def query_block(rel, bounds, base):
+    def query_block(rel, bounds, base, walk):
         def tile(r, dq, masked):
             at = _held(base, r, bounds[0], band)
             k, v = _rows(k_ref, at, block_k), _rows(v_ref, at, block_k)
@@ -362,13 +474,10 @@ def _dq_kernel(
             ds = p * (_dot(do, v, (1, 1)) - delta_col) * scale
             return dq + _dot(ds.astype(k.dtype), k, (1, 0))
 
-        dq = _band(bounds, tile, jnp.zeros((bq, d), jnp.float32))
+        dq = walk(bounds, tile, jnp.zeros((bq, d), jnp.float32))
         dq_ref[0] = dq.astype(dq_ref.dtype)
 
-    def span(qi):
-        return _key_span(qi, block_q, block_k, window) if causal else (0, 0, n_k, n_k)
-
-    _per_program(query_block, T // block_q, span, block_q, block_k, causal, window)
+    _per_program(query_block, *_query_side(seq, block_q, block_k, causal, window), block_q, block_k, causal, window)
 
 
 def _dkv_kernel(
@@ -377,10 +486,9 @@ def _dkv_kernel(
 ):
     k = k_ref[0]
     v = v_ref[0]
-    T = seq
-    n_q = T // block_q
+    n_q = seq // block_q
 
-    def key_block(rel, bounds, base):
+    def key_block(rel, bounds, base, walk):
         # a band of queries starts at this key block, or as late as fits
         first = None if band is None else 0 if not isinstance(base, int) else _least(rel, n_q - band)
 
@@ -403,14 +511,11 @@ def _dkv_kernel(
             return dk, dv
 
         zeros = jnp.zeros(k.shape, jnp.float32)
-        dk, dv = _band(bounds, tile, (zeros, zeros if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32)))
+        dk, dv = walk(bounds, tile, (zeros, zeros if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32)))
         dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv.astype(dv_ref.dtype)
 
-    def span(kj):
-        return _query_span(kj, block_q, block_k, window, n_q) if causal else (0, 0, n_q, n_q)
-
-    _per_program(key_block, T // block_k, span, block_q, block_k, causal, window)
+    _per_program(key_block, *_key_side(seq, block_q, block_k, causal, window), block_q, block_k, causal, window)
 
 
 #: The tile by shape, measured on a TPU v5e (PERF.md §6, PR 25): rows of
@@ -470,11 +575,15 @@ def _block_sizes(T: int, D: int, dtype, block_q: Optional[int], block_k: Optiona
 def _record_tiles(T: int, bq: int, bk: int, causal: bool, kernels: int, window, groups: int) -> None:
     """Trace-time gauges (once per compile, nothing per step): the tiles of
     one ``[T, T]`` score plane the attention call's kernels visit and would
-    visit without skipping, summed over ``kernels`` of them, the tile, the
-    window (0: none) and the query heads that share a KV head."""
+    visit without skipping, summed over ``kernels`` of them (one: the forward;
+    three: with dq and dk/dv), of the visited those reached from a loop
+    (:func:`looped_tiles`), the tile, the window (0: none) and the query heads
+    that share a KV head."""
     metrics = default_registry()
     metrics.gauge("flash.tiles_visited", kernels * visited_tiles(T, bq, bk, causal, window))
     metrics.gauge("flash.tiles_total", kernels * (T // bq) * (T // bk))
+    by_query, by_key = (looped_tiles(T, bq, bk, causal, window, key_side=side) for side in (False, True))
+    metrics.gauge("flash.tiles_looped", by_query if kernels == 1 else 2 * by_query + by_key)
     metrics.gauge("flash.block_q", bq)
     metrics.gauge("flash.block_k", bk)
     metrics.gauge("flash.window", window or 0)

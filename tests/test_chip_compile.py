@@ -126,8 +126,9 @@ def test_flash_fwd_bwd_compiles_at_gpt2_small_shape(one_chip, dtype, block):
 
 
 def test_flash_fwd_bwd_compiles_at_a_long_shard(one_chip):
-    """T=4,096 (a ring shard): past ``_STRAIGHT_LINE_ELEMENTS`` the bodies
-    loop with bounds from the traced ``program_id`` and slice K/V by it."""
+    """T=4,096 (a ring shard): past ``_STRAIGHT_LINE_ELEMENTS`` one body takes
+    the traced ``program_id``, slices K/V by it and walks its unmasked tiles
+    (seven at most) in written-out runs of four, two and one."""
     assert _kernels_in(_flash_step((1, 4096, 12, 64), jnp.bfloat16, one_chip, None)) == 3
 
 
@@ -311,12 +312,15 @@ def test_the_expert_layer_writes_short_rows_when_the_assignments_fit_them(one_ch
 # --- what cells 1-3 lower to stays what it was; the hybrid cell's kernels compile --
 
 
-#: sha256 of the lowered text, made from ``git archive`` of 3f0513b (the parent of PR 32) by these same functions
+#: sha256 of the lowered text, made from ``git archive`` of 3f0513b (the parent of PR 32) by these same functions;
+#: ``flash-cell3-full`` from PR 36's own tree (on bfb9cb0), which means to alter it: past ``_STRAIGHT_LINE_ELEMENTS`` the
+#: one body with traced bounds runs written-out runs of tiles where it ran one tile a turn (the same tiles in the same
+#: order: tests/test_flash_attention.py holds the two equal to the bit)
 _PARENT_LOWERED = {
     "flash-cell1": "d6ef39f9ef10c6ff09bb99ef7a39eee183941bbd5361316660308c8e9c6173ac",
     "flash-cell2": "07e34fc7769e246ae1704f53f9261a71f0295aa67a92581d99e451c1112e807f",
     "flash-cell3-window": "f1a9d5c89a04985f0c37ecce26269183150d1510e8f48e67338db9aafc5b05cd",
-    "flash-cell3-full": "ea69f198c0f04d6c1b42b8e6798789c95097b56d4f7ceb8bd29e914c6c48581d",
+    "flash-cell3-full": "9be2ca384d10a629086003efd40ba1d6a07a056fb7d1ae8d180e10ec28cf5eef",
     "experts-cell3": "c13c04dad2a0fb4e8e8d6c336ea23dec01ad1e8bd5c1c4893c82a43f75e70bf7",
 }
 
@@ -390,9 +394,10 @@ def test_cells_1_to_3_lower_to_the_text_they_lowered_to_before_the_hybrid_model(
     assert hashlib.sha256(_lowered_text(case, one_chip).encode()).hexdigest() == _PARENT_LOWERED[case]
 
 
-#: sha256 of cell 4's latent layer as lowered at ca7fb08 (the parent of PR 34), before the mixer learnt the query
-#: rank and the rotation
-_PARENT_LATENT_LAYER = "6fa0aef26edbf87f85d78733f469a3b6b057c33b73e4a411b20c5f4013e9181a"
+#: sha256 of cell 4's latent layer as lowered by PR 36's own tree (on bfb9cb0), which means to alter it: the mixer's
+#: text is ca7fb08's (the parent of PR 34, before the mixer learnt the query rank and the rotation), the three flash
+#: kernels' bodies run written-out runs of tiles where they ran one tile a turn
+_PARENT_LATENT_LAYER = "ee3c8320d71d9c5166eb1afc103e3008a2a158d9a6a358021d044809cd036d5c"
 
 
 def test_cell_4s_latent_layer_lowers_to_the_text_it_lowered_to_before_the_rotation(one_chip, monkeypatch):
@@ -400,8 +405,8 @@ def test_cell_4s_latent_layer_lowers_to_the_text_it_lowered_to_before_the_rotati
     it beside the models that share it: ``kimi-linear-ep32-train``'s latent
     layer (no query rank, nothing rotated: ``mla_use_nope``) at the cell's
     shape, forward and backward with its projections, norm and the three
-    flash kernels at 192 / 128, lowers to the parent's text, character for
-    character."""
+    flash kernels at 192 / 128, lowers to the text it lowered to, character
+    for character (PR 36 changed the kernels' bodies and replaced the digest)."""
     import functools
     import hashlib
 
@@ -480,6 +485,29 @@ def test_the_kda_scan_and_the_latent_attention_compile_at_the_hybrid_cells_shape
     ).compile()
     assert _kernels_in(compiled) == 3
     assert re.search(r"%flash_bwd_dkv[\w.]* = \(bf16\[32,8192,192\]\S*, bf16\[32,8192,128\]", compiled.as_text())
+    # Each kernel walks a position's unmasked tiles (15 at most) four to a turn of its one loop, the four written out
+    # (two products a tile forward, three in dq, four in dk/dv), then two and one under the count's bits: no loop
+    # of one tile a turn is left at this shape, and 96 of a kernel's 136 tiles are reached from the loop.
+    from adapcc_tpu.utils.observability import default_registry
+
+    def equations(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+    traced = jax.make_jaxpr(jax.grad(latent, argnums=(0, 1, 2)))(
+        shape((1, T, heads, dqk)), shape((1, T, heads, dqk)), shape((1, T, heads, cfg.v_head_dim))
+    )
+    products = {}
+    for call in (e for e in equations(traced.jaxpr) if e.primitive.name == "pallas_call"):
+        loops = [e for e in equations(call.params["jaxpr"]) if e.primitive.name == "while"]
+        products[call.params["name"]] = [
+            sum(e.primitive.name == "dot_general" for e in equations(loop.params["body_jaxpr"].jaxpr)) for loop in loops
+        ]
+    assert products == {"flash_fwd": [4 * 2], "flash_bwd_dq": [4 * 3], "flash_bwd_dkv": [4 * 4]}
+    gauges = default_registry().snapshot()["gauges"]
+    assert (gauges["flash.tiles_visited"], gauges["flash.tiles_looped"]) == (3 * 136, 3 * 96)
 
 
 def test_the_kda_scan_is_its_two_kernels_and_no_pass_of_xlas_around_them(one_chip):

@@ -15,7 +15,13 @@ import numpy as np
 import pytest
 
 from adapcc_tpu.ops import flash_attention, flash_attention_with_lse
-from adapcc_tpu.ops.flash_attention import TILE_TABLE, default_blocks, resolve_block, visited_tiles
+from adapcc_tpu.ops.flash_attention import (
+    TILE_TABLE,
+    default_blocks,
+    looped_tiles,
+    resolve_block,
+    visited_tiles,
+)
 from adapcc_tpu.utils.observability import default_registry
 
 #: the module (the package's attribute of that name is the function)
@@ -168,20 +174,39 @@ def test_unaligned_block_raises_clearly():
 _BLOCKS = [(16, 16), (32, 16), (16, 32), (64, 64)]
 
 
-@pytest.fixture(params=["static", "traced"])
-def program_index(request, monkeypatch):
-    """Both ways a causal kernel learns its grid position: a Python integer
-    for each position (short sequences), or the traced ``program_id`` once
-    the written-out code would pass ``_STRAIGHT_LINE_ELEMENTS``."""
-    def forget():   # the jitted kernel calls cache their trace by shape, not by the threshold
-        flash_module._fwd_call.clear_cache()
-        flash_module._bwd_call.clear_cache()
+#: ``(_STRAIGHT_LINE_ELEMENTS, _TRACED_BASE_ELEMENTS)`` by the way a causal
+#: kernel walks its tiles: the module's own (every shape of this file is
+#: written out position by position); nothing written out (one body, one tile
+#: a turn of a loop); one body with four 16 x 16 tiles of code (at T=64 with
+#: such tiles runs of two in a loop, then one under the count's bit; with
+#: larger tiles one tile a turn)
+_LIMITS = {"static": None, "traced": (0, 0), "runs": (0, 1024)}
 
-    if request.param == "traced":
-        monkeypatch.setattr(flash_module, "_STRAIGHT_LINE_ELEMENTS", 0)
-    forget()
+
+def _forget():
+    """The jitted kernel calls cache their trace by shape, not by the limits."""
+    flash_module._fwd_call.clear_cache()
+    flash_module._bwd_call.clear_cache()
+
+
+def _limits(monkeypatch, bodies, traced_base):
+    monkeypatch.setattr(flash_module, "_STRAIGHT_LINE_ELEMENTS", bodies)
+    monkeypatch.setattr(flash_module, "_TRACED_BASE_ELEMENTS", traced_base)
+    _forget()
+
+
+@pytest.fixture(params=list(_LIMITS))
+def program_index(request, monkeypatch):
+    """The three ways a causal kernel learns its grid position and walks its
+    tiles: a Python integer for each position (short sequences); or, once the
+    written-out code would pass ``_STRAIGHT_LINE_ELEMENTS``, the traced
+    ``program_id`` with one tile a turn of a loop, or with written-out runs
+    of tiles by what the code sees of their count."""
+    if _LIMITS[request.param] is not None:
+        _limits(monkeypatch, *_LIMITS[request.param])
+    _forget()
     yield request.param
-    forget()
+    _forget()
 
 
 @pytest.mark.parametrize("with_lse", [False, True], ids=["out", "out+lse"])
@@ -294,7 +319,10 @@ def test_every_product_takes_the_inputs_dtype_and_accumulates_in_fp32(dtype, pro
     fp32 products."""
     for call in _pallas_calls(_grad_jaxpr(dtype, block_q=32, block_k=16)):
         names = [e.primitive.name for e in _eqns(call.params["jaxpr"])]
-        assert ("while" in names) == (program_index == "traced"), call.params["name"]
+        # a query block has none or two unmasked key blocks, a key block none or one query block: a loop of one tile a turn
+        looped = looped_tiles(64, 32, 16, True, key_side=call.params["name"] == "flash_bwd_dkv")
+        assert looped == (0 if program_index == "static" else 2)
+        assert ("while" in names) == (looped > 0), call.params["name"]
         dots = [e for e in _eqns(call.params["jaxpr"]) if e.primitive.name == "dot_general"]
         assert dots, call.params["name"]
         for dot in dots:
@@ -324,18 +352,28 @@ def test_causal_visits_36_of_64_tiles_at_t1024_with_128_tiles():
 
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize("blocks", [(16, 16), (32, 16), (16, 32)], ids=lambda b: f"{b[0]}x{b[1]}")
-def test_tile_gauges_follow_the_trace(blocks, causal):
+def test_tile_gauges_follow_the_trace(blocks, causal, program_index):
     """Trace time records the tiles the call's kernels visit: one kernel's
-    after a forward pass, all three's after a backward pass."""
+    after a forward pass, all three's after a backward pass; and of them the
+    tiles reached from inside a loop with a traced trip count: none where the
+    code is written out, the unmasked ones where a turn is one tile."""
     bq, bk = blocks
     q, k, v = _qkv(T=64, B=1, H=1)
     gauges = lambda: default_registry().snapshot()["gauges"]  # noqa: E731
     one = visited_tiles(64, bq, bk, causal)
+    by_query, by_key = looped_tiles(64, bq, bk, causal), looped_tiles(64, bq, bk, causal, key_side=True)
+    if not causal or program_index == "static":
+        assert (by_query, by_key) == (0, 0)
+    elif bq == bk:      # 4 positions, 0..3 unmasked tiles: each a turn, or in turns of two with the odd one under a condition
+        assert (by_query, by_key) == ((6, 6) if program_index == "traced" else (4, 4))
+    else:               # two unmasked tiles or none on the side of the smaller tiles, one or none on the other: a turn each
+        assert (by_query, by_key) == (2, 2)
 
     jax.make_jaxpr(lambda q, k, v: flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk))(q, k, v)
     g = gauges()
     assert (g["flash.tiles_visited"], g["flash.tiles_total"]) == (one, (64 // bq) * (64 // bk))
     assert (g["flash.block_q"], g["flash.block_k"]) == (bq, bk)
+    assert g["flash.tiles_looped"] == by_query
 
     jax.make_jaxpr(jax.grad(
         lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)),
@@ -343,15 +381,37 @@ def test_tile_gauges_follow_the_trace(blocks, causal):
     ))(q, k, v)
     g = gauges()
     assert (g["flash.tiles_visited"], g["flash.tiles_total"]) == (3 * one, 3 * (64 // bq) * (64 // bk))
+    assert g["flash.tiles_looped"] == 2 * by_query + by_key
 
 
+def _walked(span, i, parts):
+    """``(block, masked)`` in the order ``_band`` visits them for position
+    ``i``, its bounds handed over as arrays (as a traced ``program_id``
+    gives them) so that the runs, conditions and loops decide."""
+    visits = jnp.full((64, 2), -1, jnp.int32)
+
+    def tile(j, carry, masked):
+        visits, step = carry
+        return visits.at[step].set(jnp.stack([jnp.asarray(j, jnp.int32), jnp.int32(masked)])), step + 1
+
+    bounds = tuple(jnp.int32(x) for x in span(i))
+    visits, steps = flash_module._band(bounds, tile, (visits, jnp.int32(0)), parts=parts)
+    return [tuple(int(x) for x in row) for row in np.asarray(visits[: int(steps)])]
+
+
+@pytest.mark.parametrize("limit", [None, 0, 2, 4, 8, 16, 64], ids=lambda x: f"limit-{x}-tiles")
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
-def test_loop_bounds_visit_exactly_the_counted_tiles(causal):
+def test_loop_bounds_visit_exactly_the_counted_tiles(causal, limit, monkeypatch):
     """The kernels' own loop bounds, run as Python integers, cover each
-    counted tile once and no other, from the query side and the key side."""
-    from adapcc_tpu.ops.flash_attention import _key_blocks, _query_blocks
+    counted tile once and no other, from the query side and the key side.
+    And so does the walk the one body with traced bounds makes of them,
+    whatever ``_TRACED_BASE_ELEMENTS`` lets it write out (``limit`` tiles:
+    one tile a turn, or runs of 2, 4 and 8 in a loop and the rest under the
+    count's bits): each position's tiles once, ascending, masked where the
+    diagonal crosses them."""
+    from adapcc_tpu.ops.flash_attention import _key_blocks, _key_side, _query_blocks, _query_side, _runs
 
-    for T, bq, bk in [(64, 16, 16), (64, 32, 16), (64, 16, 32), (96, 24, 32), (96, 32, 24)]:
+    for T, bq, bk in [(64, 16, 16), (64, 32, 16), (64, 16, 32), (96, 24, 32), (96, 32, 24), (128, 8, 8)]:
         n_q, n_k = T // bq, T // bk
         allowed = np.tril(np.ones((T, T), bool)).reshape(n_q, bq, n_k, bk)
         want = {(i, j) for i in range(n_q) for j in range(n_k) if allowed[i, :, j].any() or not causal}
@@ -359,12 +419,89 @@ def test_loop_bounds_visit_exactly_the_counted_tiles(causal):
         if not causal:
             assert visited_tiles(T, bq, bk, False) == len(want)
             continue
+        if limit is not None:
+            monkeypatch.setattr(flash_module, "_TRACED_BASE_ELEMENTS", limit * bq * bk)
+            for side, transpose in ((_query_side, False), (_key_side, True)):
+                n, span = side(T, bq, bk, True, None)
+                parts = _runs(n, span, bq, bk)
+                code = sum(2 * run - 1 if run else most for most, run in parts)
+                assert code <= limit or max(run for _, run in parts) <= 1, (T, bq, bk, parts)
+                walked = {i: _walked(span, i, parts) for i in range(n)}
+                seen = [(j, i) if transpose else (i, j) for i in walked for j, _ in walked[i]]
+                assert len(seen) == len(want) and set(seen) == want, (T, bq, bk)
+                for i, tiles in walked.items():
+                    lo, a, b, end = span(i)
+                    assert [j for j, _ in tiles] == list(range(lo, end))
+                    assert [m for _, m in tiles] == [int(not a <= j < b) for j in range(lo, end)]
+            continue
         by_q = {(i, j) for i in range(n_q) for j in range(_key_blocks(i, bq, bk)[1])}
         by_k = {(i, j) for j in range(n_k) for i in range(_query_blocks(j, bq, bk)[0], n_q)}
         assert by_q == want and by_k == want, (T, bq, bk)
         # the unmasked ranges hold only tiles the diagonal does not cross
         assert {(i, j) for i in range(n_q) for j in range(_key_blocks(i, bq, bk)[0])} == whole
         assert {(i, j) for j in range(n_k) for i in range(_query_blocks(j, bq, bk)[1], n_q)} == whole
+
+
+def test_the_long_cells_shapes_run_written_out_runs():
+    """T = 8,192 with 512-tiles (cells 3-5's full-causal layers): 15 unmasked
+    tiles at most are runs of 4 in a loop of up to three turns, then 2 and 1
+    under the count's bits, beside the diagonal's one: 8 tiles of code, and 96
+    of a kernel's 136 tiles are reached from the loop (the one-tile loop
+    reached 120, a tile a turn).  A ring shard of 4,096 has 7 (a run of 4, 2,
+    1), 16,384 has 31 (up to seven turns); at GPT-2's 1,024 every position has
+    its own body and a band's positions share theirs: no loop."""
+    from adapcc_tpu.ops.flash_attention import _key_side, _query_side, _runs, _written_out
+
+    for side in (_query_side, _key_side):
+        for T, most in ((4096, 7), (8192, 15), (16384, 31)):
+            n, span = side(T, 512, 512, True, None)
+            assert _written_out(n, span, 512, 512, True, None) is None
+            # nothing, the unmasked tiles four to a turn, the diagonal's one
+            assert sorted(_runs(n, span, 512, 512)) == [(0, 0), (1, 0), (most, 4)]
+        assert len(_written_out(*side(1024, 512, 512, True, None), 512, 512, True, None)) == 2
+    for key_side in (False, True):
+        assert looped_tiles(8192, 512, 512, True, key_side=key_side) == sum(n // 4 * 4 for n in range(16)) == 96
+        assert looped_tiles(4096, 512, 512, True, key_side=key_side) == 16
+        assert looped_tiles(16384, 512, 512, True, key_side=key_side) == 448
+        assert looped_tiles(1024, 512, 512, True, key_side=key_side) == 0
+        assert looped_tiles(8192, 512, 512, True, 2048, key_side=key_side) == 0        # the band's shared bodies
+
+
+@pytest.mark.parametrize(
+    "limit,dtype,head",
+    [(limit, dtype, (16, 16)) for limit in (16, 8, 4) for dtype in (jnp.float32, jnp.bfloat16)]
+    + [(16, jnp.float32, (16, 8)), (8, jnp.bfloat16, (16, 8))],
+    ids=lambda x: {16: "runs-8-4-2-1", 8: "loop-of-4-then-2-1", 4: "loop-of-2-then-1"}.get(x)
+    or (f"d{x[0]}-dv{x[1]}" if isinstance(x, tuple) else jnp.dtype(x).name),
+)
+def test_runs_equal_the_one_tile_loop_bit_for_bit_at_every_count_of_tiles(limit, dtype, head, monkeypatch):
+    """T = 128 with 8-tiles: 16 positions, query block ``i`` with ``i``
+    unmasked key blocks and key block ``j`` with ``15 - j`` unmasked query
+    blocks, so every count 0..15 in each of the three kernels.  Written out as
+    runs (8, then 4, 2, 1 under the count's bits; or, where only 8 or 4 tiles
+    of code fit, a loop of runs of 4 or 2 with the rest by the bits), the same
+    tiles in the same order give the output and the three gradients of the
+    loop of one tile a turn, to the bit."""
+    d, dv = head
+    rng = np.random.default_rng(36)
+    q, k = (jnp.asarray(rng.normal(size=(1, 128, 2, d)) * 0.5, dtype) for _ in range(2))
+    v, do = (jnp.asarray(rng.normal(size=(1, 128, 2, dv)) * 0.5, dtype) for _ in range(2))
+
+    def outputs(tiles_of_code):
+        _limits(monkeypatch, 0, tiles_of_code * 64)
+        out, pull = jax.vjp(lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=8, block_k=8), q, k, v)
+        looped = looped_tiles(128, 8, 8, True), looped_tiles(128, 8, 8, True, key_side=True)
+        return [np.asarray(x.astype(jnp.float32)) for x in (out, *pull(do))], looped
+
+    try:
+        want, looped = outputs(2)
+        assert looped == (120, 120)         # every unmasked tile a turn of its own
+        got, looped = outputs(limit)
+        assert looped == {16: (64, 64), 8: (96, 96), 4: (112, 112)}[limit]
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            assert a.shape == b.shape and np.array_equal(a, b), name
+    finally:
+        _forget()
 
 
 def test_tile_resolves_by_shape_through_one_table():

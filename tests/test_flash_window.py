@@ -46,14 +46,18 @@ def _qkv(T, H, Hkv, D=16, B=2, seed=0):
     return mk(H), mk(Hkv), mk(Hkv)
 
 
-@pytest.fixture(params=["static", "traced"])
+@pytest.fixture(params=["static", "traced", "runs"])
 def program_index(request, monkeypatch):
+    """Written-out bodies; one body with traced bounds and one tile a turn;
+    that body with four 16 x 16 tiles of code, so that a band's three runs of
+    tiles, each of a count that differs with the position, go by its bits."""
     def forget():
         flash_module._fwd_call.clear_cache()
         flash_module._bwd_call.clear_cache()
 
-    if request.param == "traced":
+    if request.param != "static":
         monkeypatch.setattr(flash_module, "_STRAIGHT_LINE_ELEMENTS", 0)
+        monkeypatch.setattr(flash_module, "_TRACED_BASE_ELEMENTS", 0 if request.param == "traced" else 1024)
     forget()
     yield request.param
     forget()
