@@ -15,6 +15,9 @@ tearing down and re-creating transmission contexts, adapcc.py:63-67).
 
 from __future__ import annotations
 
+import contextlib
+import functools
+import time
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -22,11 +25,14 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from flax import struct
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from adapcc_tpu.comm.mesh import RANKS_AXIS
 from adapcc_tpu.ddp.hook import GradSyncHook
 from adapcc_tpu.strategy.ir import Strategy
+from adapcc_tpu.utils.compile_cache import compile_watch
+from adapcc_tpu.utils.observability import SPAN_PREFIX
 
 
 @struct.dataclass
@@ -350,6 +356,10 @@ class DDPTrainer:
         # of each compiled program (which pays tracing + XLA compile) never
         # lands in the database as a steady-state sample
         self._build_gen = 0
+        # the process's watch of JAX's compile events: it splits what each
+        # step program's first call costs, and finds a step that compiles
+        # when it should not (docs/OBSERVABILITY.md)
+        self._watch = compile_watch()
 
     def _tuning(self) -> bool:
         """Is per-step tuning live right now?  ``tune=True`` opts the
@@ -731,29 +741,37 @@ class DDPTrainer:
         # on every dispatch, serializing the loop
         idx = self._host_step if step_idx is None else step_idx
         span = self.hook.metrics.span
-        # three consecutive spans tile the call; no parent span (their sum
-        # is the parent): live only while a profile is being taken
-        # (docs/OBSERVABILITY.md)
-        with span("step.prepare", step=idx):
-            fn, args, active_mask = self._prepare_step(
-                state, batch, idx, active_mask
-            )
-        tuning = self._tuning()
-        with span("step.enqueue", step=idx):
-            # pjit dispatch, and the runtime's wait for output buffers
-            if tuning:
-                import time as _time
-
-                t0 = _time.perf_counter()
-                out = fn(*args)
-                jax.block_until_ready(out)
-                seconds = _time.perf_counter() - t0
-            else:
-                out = fn(*args)
-        with span("step.finish", step=idx):
-            if tuning:
-                self._tune_observe(state, seconds)
-            return self._finish_step(out, batch, active_mask)
+        # what JAX compiles on this thread from here to the return is this
+        # step's: a recompile on the hot path, unless the call turns out to
+        # be a program's first (_prepare_step clears the mark)
+        here = self._watch.here
+        here.step = idx
+        try:
+            # three consecutive spans tile the call; no parent span (their
+            # sum is the parent): live only while a profile is being taken
+            # (docs/OBSERVABILITY.md)
+            with span("step.prepare", step=idx):
+                fn, args, active_mask = self._prepare_step(
+                    state, batch, idx, active_mask
+                )
+            tuning = self._tuning()
+            with span("step.enqueue", step=idx):
+                # pjit dispatch, and the runtime's wait for output buffers
+                if tuning:
+                    t0 = time.perf_counter()
+                    out = fn(*args)
+                    jax.block_until_ready(out)
+                    seconds = time.perf_counter() - t0
+                else:
+                    out = fn(*args)
+            with span("step.finish", step=idx):
+                if tuning:
+                    self._tune_observe(state, seconds)
+                return self._finish_step(out, batch, active_mask)
+        finally:
+            stall, here.step = here.step, None
+            if stall is not idx and stall is not None:
+                self._watch.left_step(stall)  # an event arrived: say so, once
 
     def _prepare_step(
         self, state: TrainState, batch: Any, idx: int, active_mask
@@ -769,12 +787,14 @@ class DDPTrainer:
         fn = self._compiled
         if fn is None:
             key = self._program_key()
-            fn = self._program_cache.get(key)
+            fn = self._compiled = self._program_cache.get(key)
             if fn is None:
-                fn = self._build()
+                fn = self._compiled = self._program_cache[key] = self._build()
                 self._build_gen += 1  # an actual (re)trace, not a cache hit
-                self._program_cache[key] = fn
-            self._compiled = fn
+                # the call about to be made is this program's first: what
+                # compiles in this step is the build, and no recompile
+                self._watch.here.step = None
+                fn = functools.partial(self._first_call, fn, key, "step", idx)
         if not self._coord_calibrated:
             # rent-or-buy calibration: this trainer's actual gradient volume
             # + the bootstrap's profiled link bandwidth replace the
@@ -828,6 +848,43 @@ class DDPTrainer:
                 )
             args.append(self._residual)
         return fn, args, active_mask
+
+    def _first_call(
+        self, fn: Callable, key: tuple, cause: str, idx: int, *args: Any
+    ):
+        """A step program's first call, which traces, lowers and loads it,
+        as the span ``step.build``: kept by ``observe`` with its start, end
+        and cause (so it is there with no profile live, and after the next
+        one), and an ``adapcc.step.build`` annotation where a profile is
+        live.  It ends when the dispatch returns.  ``step.build.load`` is
+        the backend seconds JAX reported inside it (XLA's compile, or the
+        persistent cache's read) and ``step.build.trace_lower`` the rest:
+        JAX's trace, the lowering with its Mosaic kernels, the cache key's
+        hash.  The inner jitted functions' trace events nest in the outer
+        one's, so the rest is taken by subtraction and not by a sum."""
+        fingerprint, codec, overlap = key
+        meta = dict(
+            gen=self._build_gen, fingerprint=fingerprint, codec=codec,
+            overlap=overlap, cause=cause, step=idx,
+        )
+        annotation = (
+            TraceAnnotation(SPAN_PREFIX + "step.build", **meta)
+            if TraceAnnotation.is_enabled()
+            else contextlib.nullcontext()
+        )
+        with self._watch.building() as build:
+            t0 = time.perf_counter()
+            with annotation:
+                out = fn(*args)
+            end = time.perf_counter()
+        metrics = self._watch.registry
+        metrics.observe("step.build", end - t0, end=end, **meta)
+        metrics.observe("step.build.load", build.load_s)
+        metrics.observe("step.build.trace_lower", end - t0 - build.load_s)
+        # no hit in the load: XLA compiled it (with no persistent cache in
+        # force, every build)
+        metrics.incr("step.build.cache_misses", 0 if build.cache_hits else 1)
+        return out
 
     def _finish_step(
         self, out, batch: Any, active_mask
@@ -1127,7 +1184,9 @@ class DDPTrainer:
                         (self.mesh.devices.size,), dtype=jnp.bool_
                     )
                 args.append(active_mask)
-            jax.block_until_ready(fn(*args))
+            jax.block_until_ready(
+                self._first_call(fn, key, "prewarm", self._host_step, *args)
+            )
         finally:
             self.hook.strategy = saved_strategy
             self.donate_state = saved_donate

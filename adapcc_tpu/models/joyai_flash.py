@@ -199,10 +199,7 @@ class JoyAIFlash(nn.Module):
             cfg.vocab_size, cfg.hidden_size, embedding_init=nn.initializers.normal(0.02),
             dtype=cfg.dtype, name="embed_tokens",
         )
-        metrics = default_registry()
-        metrics.gauge("model.layers_mla", cfg.num_hidden_layers + cfg.num_nextn_predict_layers)
-        metrics.gauge("mtp.depth", cfg.num_nextn_predict_layers)
-        metrics.gauge("mtp.loss_weight", cfg.mtp_loss_weight)
+        default_registry().gauge("mtp.depth", cfg.num_nextn_predict_layers)
         h = embed(tokens)
         block = _block(cfg)
         sizes = []

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from adapcc_tpu.models.trinity import _REMAT, GatedMLP, RMSNorm, SparseExperts, _dense
-from adapcc_tpu.utils.observability import default_registry
 
 #: ``l2norm(x) = x * rsqrt(sum(x^2) + L2_EPS)`` over a head
 L2_EPS = 1e-6
@@ -325,11 +324,6 @@ class MLAMixer(nn.Module):
             with jax.named_scope("mla_rope"):
                 q, k_pe = rotate_pairs(q, cfg.rope_theta, start=nope), rotate_pairs(k_pe, cfg.rope_theta)
         k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (B, T, H, pe))], axis=-1)
-        metrics = default_registry()
-        metrics.gauge("mla.qk_dim", nope + pe)
-        metrics.gauge("mla.v_dim", dv)
-        metrics.gauge("mla.q_rank", cfg.q_lora_rank or 0)
-        metrics.gauge("mla.rope_dim", 0 if cfg.mla_use_nope else pe)
         from adapcc_tpu.ops import flash_attention
 
         with jax.named_scope("mla_attn"):
@@ -372,9 +366,6 @@ class KimiLinear(nn.Module):
             cfg.vocab_size, cfg.hidden_size, embedding_init=nn.initializers.normal(0.02),
             dtype=cfg.dtype, name="embed_tokens",
         )(tokens)
-        metrics = default_registry()
-        metrics.gauge("model.layers_kda", cfg.kinds.count("kda"))
-        metrics.gauge("model.layers_mla", cfg.kinds.count("mla"))
         policy = _REMAT[cfg.remat]
         block = Block if policy is False else nn.remat(Block, policy=policy)
         sizes = []

@@ -296,7 +296,6 @@ def routed_experts(
     bound = assignment_bound(x.shape[0], ids.shape[1], held)
     short = short_rows(x.shape[0], ids.shape[1], held, num_experts)
     metrics = default_registry()
-    metrics.gauge("moe.experts_held", held)
     metrics.gauge("moe.assignment_bound", bound)
     metrics.gauge("moe.short_rows", short)
     weights = weights.astype(jnp.float32)

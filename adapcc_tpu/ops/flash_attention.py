@@ -572,13 +572,12 @@ def _block_sizes(T: int, D: int, dtype, block_q: Optional[int], block_k: Optiona
     return bq, bk
 
 
-def _record_tiles(T: int, bq: int, bk: int, causal: bool, kernels: int, window, groups: int) -> None:
+def _record_tiles(T: int, bq: int, bk: int, causal: bool, kernels: int, window) -> None:
     """Trace-time gauges (once per compile, nothing per step): the tiles of
     one ``[T, T]`` score plane the attention call's kernels visit and would
     visit without skipping, summed over ``kernels`` of them (one: the forward;
     three: with dq and dk/dv), of the visited those reached from a loop
-    (:func:`looped_tiles`), the tile, the window (0: none) and the query heads
-    that share a KV head."""
+    (:func:`looped_tiles`), and the tile."""
     metrics = default_registry()
     metrics.gauge("flash.tiles_visited", kernels * visited_tiles(T, bq, bk, causal, window))
     metrics.gauge("flash.tiles_total", kernels * (T // bq) * (T // bk))
@@ -586,8 +585,6 @@ def _record_tiles(T: int, bq: int, bk: int, causal: bool, kernels: int, window, 
     metrics.gauge("flash.tiles_looped", by_query if kernels == 1 else 2 * by_query + by_key)
     metrics.gauge("flash.block_q", bq)
     metrics.gauge("flash.block_k", bk)
-    metrics.gauge("flash.window", window or 0)
-    metrics.gauge("flash.kv_groups", groups)
 
 
 #: Bytes of whole-sequence operands (K and V, or q and do, double-buffered)
@@ -644,7 +641,7 @@ def _flash_bhtd(q, k, v, scale, causal, block_q, block_k, interpret, window):
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window):
     _, T, D = q.shape
     bq, bk = _block_sizes(T, D, q.dtype, block_q, block_k)
-    _record_tiles(T, bq, bk, causal, 1, window, q.shape[0] // k.shape[0])
+    _record_tiles(T, bq, bk, causal, 1, window)
     interp = resolve_interpret(interpret, "flash_attention")
     out, lse = _fwd_call(q, k, v, scale, causal, bq, bk, interp, window)
     return out, (q, k, v, out, lse)
@@ -696,7 +693,7 @@ def _flash_bwd_core(scale, causal, block_q, block_k, interpret, window, res, do,
     _, T, D = q.shape
     bq, bk = _block_sizes(T, D, q.dtype, block_q, block_k)
     # a backward pass closes an attention call: its forward kernel and these two
-    _record_tiles(T, bq, bk, causal, 3, window, q.shape[0] // k.shape[0])
+    _record_tiles(T, bq, bk, causal, 3, window)
     interp = resolve_interpret(interpret, "flash_attention")
     return _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window)
 

@@ -632,7 +632,6 @@ def kda(
     metrics = default_registry()
     metrics.gauge("kda.chunk", chunk)
     metrics.gauge("kda.tiles", B * H * (padded // chunk))
-    metrics.gauge("kda.state_bytes", B * H * dk * dv * 4)
     metrics.gauge("kda.padded_rows", padded - T)
     args = [q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32)]
     if padded != T:     # a padded step (g = 0, k = v = 0) forgets and writes nothing

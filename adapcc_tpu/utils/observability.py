@@ -147,6 +147,9 @@ class MetricsRegistry:
     #: samples retained per series for the percentile estimate; above this
     #: count, reservoir sampling keeps a uniform subset
     RESERVOIR_SIZE = 512
+    #: spans kept whole (start, end, meta) per name, the newest last: the
+    #: compile path's, a few a program, not a step's
+    SPANS_KEPT = 256
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -154,6 +157,7 @@ class MetricsRegistry:
         self._gauges: Dict[str, float] = {}
         self._timings: Dict[str, _Series] = {}
         self._samples: Dict[str, _Series] = {}
+        self._spans: Dict[str, "deque[Dict[str, Any]]"] = {}
         # deterministic reservoir replacement: two identical runs snapshot
         # identical percentiles (the sim-bench byte-stability policy)
         self._rng = random.Random(0x5EED)
@@ -181,7 +185,8 @@ class MetricsRegistry:
             self.observe(name, time.perf_counter() - t0)
 
     def _add(
-        self, table: Dict[str, _Series], name: str, value: float, in_session: bool
+        self, table: Dict[str, _Series], name: str, value: float, in_session: bool,
+        span: Optional[Dict[str, Any]] = None,
     ) -> None:
         with self._lock:
             series = table.get(name)
@@ -189,10 +194,22 @@ class MetricsRegistry:
                 series = table[name] = _Series()
             series.in_session |= in_session
             series.add(float(value), self.RESERVOIR_SIZE, self._rng)
+            if span is not None:
+                kept = self._spans.get(name)
+                if kept is None:
+                    kept = self._spans[name] = deque(maxlen=self.SPANS_KEPT)
+                kept.append(span)
 
-    def observe(self, name: str, seconds: float) -> None:
-        """Record an externally measured duration into the ``name`` timing."""
-        self._add(self._timings, name, seconds, False)
+    def observe(
+        self, name: str, seconds: float, end: Optional[float] = None, **meta: Any
+    ) -> None:
+        """Record an externally measured duration into the ``name`` timing;
+        it belongs to no profiler session and outlives them all.  Given its
+        ``end`` on ``time.perf_counter``, it is also kept whole, as a span
+        (``start_s``, ``end_s`` and ``meta``) under ``snapshot()["spans"]``:
+        the last :attr:`SPANS_KEPT` of each name."""
+        span = None if end is None else dict(meta, start_s=end - seconds, end_s=end)
+        self._add(self._timings, name, seconds, False, span)
 
     def sample(self, name: str, value: float) -> None:
         """Record one value of the unitless distribution ``name`` (a queue
@@ -248,6 +265,7 @@ class MetricsRegistry:
                 "gauges": dict(self._gauges),
                 "timings": {k: t.summary("_s") for k, t in self._timings.items()},
                 "samples": {k: t.summary() for k, t in self._samples.items()},
+                "spans": {k: list(v) for k, v in self._spans.items()},
             }
 
     def to_json(self) -> str:

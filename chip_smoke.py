@@ -86,43 +86,24 @@ def require_tpu(chips: int) -> dict:
     return device
 
 
-class CompileLog:
-    """Backend-compile seconds and persistent-cache hits, from JAX's own
-    monitoring events — so the step's compile time needs no timer inside the
-    entry point, and a warm cache shows as a hit, not as a guess."""
+def compiles_since(mark: float) -> dict:
+    """The backend compiles (persistent-cache reads included) that began at
+    or after ``mark`` on ``time.perf_counter``: count, total seconds, the
+    longest.  From the program's own watch of JAX's compile events
+    (``adapcc_tpu/utils/compile_cache.py``, installed by the trainer's
+    construction at the latest), so the step's compile time needs no timer
+    inside the entry point; the registry keeps the newest 256 whole."""
+    from adapcc_tpu.utils.compile_cache import compile_watch
 
-    def __init__(self) -> None:
-        import jax
-
-        self.compiles: list = []  # (fun_name, seconds), cache reads included
-        self.cache_hits = 0
-        self.cache_misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event: str, seconds: float, **kwargs) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles.append((str(kwargs.get("fun_name", "?")), float(seconds)))
-
-    def _on_event(self, event: str, **kwargs) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
-
-    def mark(self) -> int:
-        return len(self.compiles)
-
-    def since(self, mark: int) -> dict:
-        """The compiles since ``mark``: count, total seconds, the longest."""
-        window = self.compiles[mark:]
-        name, longest = max(window, key=lambda c: c[1], default=("-", 0.0))
-        return {
-            "programs": len(window),
-            "seconds": round(sum(s for _, s in window), 2),
-            "longest": name,
-            "longest_seconds": round(longest, 2),
-        }
+    kept = compile_watch().registry.snapshot()["spans"].get("compile.backend", [])
+    window = [(c["fun_name"], c["end_s"] - c["start_s"]) for c in kept if c["start_s"] >= mark]
+    name, longest = max(window, key=lambda c: c[1], default=("-", 0.0))
+    return {
+        "programs": len(window),
+        "seconds": round(sum(s for _, s in window), 2),
+        "longest": name,
+        "longest_seconds": round(longest, 2),
+    }
 
 
 def peak_hbm_bytes() -> dict:
@@ -242,8 +223,7 @@ def train_args(
 
 
 def phase_train(
-    world: int, batch_per_chip: int, epochs: int, steps: int, widths: dict,
-    compiles: CompileLog,
+    world: int, batch_per_chip: int, epochs: int, steps: int, widths: dict
 ) -> dict:
     """A few ``train_gpt2`` steps through the normal entry point; returns
     what the run showed (losses, the step's compile seconds, the number of
@@ -258,7 +238,6 @@ def phase_train(
         f"T={widths['seq']} vocab={widths['vocab']} attn=flash fp32, "
         f"batch {args.batch} ({batch_per_chip}/chip), world {world}"
     )
-    mark = compiles.mark()
     report: dict = {}
     t0 = time.perf_counter()
     ppl0, ppl1 = train_gpt2.run(args, report)
@@ -266,7 +245,7 @@ def phase_train(
     losses = report["step_losses"]
     say(f"train_gpt2: {len(losses)} steps in {seconds:.1f}s (compiles included), losses {losses}")
     say(f"train_gpt2: val ppl {ppl0:.1f} -> {ppl1:.1f}")
-    say(f"train_gpt2: compiles {compiles.since(mark)}")
+    say(f"train_gpt2: compiles {compiles_since(t0)}")
     if len(losses) != epochs * steps:
         raise AssertionError(f"expected {epochs * steps} steps, the entry point took {len(losses)}")
     if not (np.all(np.isfinite(losses)) and np.isfinite(ppl0) and np.isfinite(ppl1)):
@@ -286,7 +265,7 @@ def phase_train(
         "losses": losses,
         "ppl": (ppl0, ppl1),
         "custom_calls": custom_calls,
-        "compile": compiles.since(mark),
+        "compile": compiles_since(t0),
     }
 
 
@@ -468,7 +447,7 @@ def phase_ddp_vs_psum(mesh, batch_per_chip: int, steps: int, widths: dict) -> di
 # --------------------------------------------------------------------------- #
 
 
-def run_one_chip(compiles: CompileLog) -> None:
+def run_one_chip() -> None:
     from adapcc_tpu.comm.mesh import build_world_mesh
 
     t0 = time.perf_counter()
@@ -478,7 +457,7 @@ def run_one_chip(compiles: CompileLog) -> None:
 
     t0 = time.perf_counter()
     train = phase_train(
-        1, TRAIN_BATCH_PER_CHIP, TRAIN_EPOCHS, TRAIN_STEPS_PER_EPOCH, GPT2_SMALL, compiles
+        1, TRAIN_BATCH_PER_CHIP, TRAIN_EPOCHS, TRAIN_STEPS_PER_EPOCH, GPT2_SMALL
     )
     say(f"phase train: {time.perf_counter() - t0:.1f}s")
     if train["custom_calls"] <= 0:
@@ -519,22 +498,25 @@ def main(argv=None) -> int:
     import jax
     import jaxlib
 
-    from adapcc_tpu.utils.compile_cache import enable_compile_cache
+    from adapcc_tpu.utils.compile_cache import compile_watch, enable_compile_cache
 
     cache_dir = enable_compile_cache()
+    registry = compile_watch().registry  # from here on, not from the first trainer's construction
     device = require_tpu(chips)
     say(f"device {device}; jax {jax.__version__}, jaxlib {jaxlib.__version__}, "
         f"libtpu {_libtpu_version()}")
     say(f"compile cache: {cache_dir}")
-    compiles = CompileLog()
 
     if chips == 1:
-        run_one_chip(compiles)
+        run_one_chip()
     else:
         run_four_chips(chips)
 
-    say(f"all compiles: {compiles.since(0)}; persistent cache hits "
-        f"{compiles.cache_hits}, misses {compiles.cache_misses}")
+    snap = registry.snapshot()
+    backend, counters = snap["timings"]["compile.backend"], snap["counters"]
+    say(f"all compiles: {backend['count']} programs, {backend['total_s']:.2f} s, the longest "
+        f"{backend['max_s']:.2f} s; persistent cache hits {counters.get('compile.cache_hits', 0):.0f}, "
+        f"misses {counters.get('compile.cache_misses', 0):.0f}")
     say(f"peak HBM bytes in use per device: {peak_hbm_bytes()}")
     say(f"total {time.perf_counter() - t_start:.1f}s")
     print(json.dumps({"ok": True, "device": device}), flush=True)

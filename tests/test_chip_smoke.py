@@ -50,8 +50,7 @@ def test_one_chip_phases_run_on_the_cpu_pod(tmp_path):
 
     chip_smoke.phase_bootstrap(build_world_mesh(1), str(tmp_path))
     assert (tmp_path / "logical_graph.xml").exists()  # artifacts land in the work dir
-    compiles = chip_smoke.CompileLog()
-    report = chip_smoke.phase_train(1, 4, 4, 1, TINY, compiles)
+    report = chip_smoke.phase_train(1, 4, 4, 1, TINY)
     assert len(report["losses"]) == 4 and report["losses"][-1] < report["losses"][0]
     assert report["compile"]["programs"] > 0 and report["compile"]["seconds"] > 0
     # the CPU inlined the interpreter: no kernel in the step, and the check

@@ -864,10 +864,16 @@ def test_step_without_a_profile_records_no_span(mesh4):
 
     trainer, state, batch = _tiny_trainer(mesh4)
     assert trainer.hook.metrics is default_registry()
-    before = default_registry().snapshot()["timings"]
+
+    def spans():
+        # the compile path's timings are always on (the first step is a build); the spans are not
+        timings = default_registry().snapshot()["timings"]
+        return {k: v for k, v in timings.items() if not k.startswith(("compile.", "step.build"))}
+
+    before = spans()
     for _ in range(3):
         state, _ = trainer.step(state, batch)
-    assert default_registry().snapshot()["timings"] == before
+    assert spans() == before
 
 
 def test_profiled_steps_tile_the_call_and_carry_the_step_index(mesh4, profile):

@@ -458,6 +458,8 @@ def test_observability_doc_names_everything_the_program_records():
         "flash_bwd_dq", "flash_bwd_dkv", "Perfetto", "step_enqueue_ms",
         "step_host_self_ms", "input_queue_depth", "input_h2d_ms",
         "grad_sync_bytes_per_step", "grad_sync_calls_per_step",
+        "step.build", "step.recompiles", "compile.backend", "step_trace_lower_s",
+        "step_load_s", "step_cache_misses", "step_recompiles",
     ):
         assert needle in text, f"OBSERVABILITY.md lost its {needle!r} coverage"
 

@@ -146,8 +146,6 @@ def test_the_latent_mixer_with_its_query_rank_and_rotation_is_the_head_at_a_time
     got = MLAMixer(CFG).apply({"params": p}, x)
     want = jnp.stack([joyai_flash_ref.mla_mixer(row, p, file_config(), PROD) for row in x])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
-    gauges = default_registry().snapshot()["gauges"]
-    assert (gauges["mla.qk_dim"], gauges["mla.v_dim"], gauges["mla.q_rank"], gauges["mla.rope_dim"]) == (24, 16, 24, 8)
     unrotated = jnp.stack([joyai_flash_ref.mla_mixer(row, p, file_config(), PROD, turn=False) for row in x])
     assert float(jnp.max(jnp.abs(unrotated - want))) > 1e-5           # and the rotation is no small thing beside the tolerance
 
@@ -155,8 +153,7 @@ def test_the_latent_mixer_with_its_query_rank_and_rotation_is_the_head_at_a_time
 def test_one_mixer_serves_both_models(params):
     """``MLAMixer`` under Kimi-Linear's configuration given the query rank and
     the rotation is this model's mixer on the same weights; as published
-    (no rank, ``mla_use_nope``) it has one query projection and leaves the
-    gauges at zero."""
+    (no rank, ``mla_use_nope``) it has one query projection."""
     p = params["params"]["layers_1"]["self_attn"]
     x = jnp.asarray(np.random.default_rng(4).normal(size=(1, 40, 32)), jnp.float32)
     kimi = KimiLinearConfig.tiny(q_lora_rank=24, mla_use_nope=False, rope_theta=CFG.rope_theta, rms_norm_eps=CFG.rms_norm_eps)
@@ -166,8 +163,6 @@ def test_one_mixer_serves_both_models(params):
     published = MLAMixer(KimiLinearConfig.tiny())
     shapes = jax.eval_shape(published.init, jax.random.PRNGKey(0), x)["params"]
     assert "q_proj" in shapes and "q_a_proj" not in shapes and "q_a_proj" in p
-    gauges = default_registry().snapshot()["gauges"]
-    assert (gauges["mla.q_rank"], gauges["mla.rope_dim"]) == (0, 0)
     # and a whole Kimi-Linear with the two keys it used to refuse runs, and is another function
     tokens = jnp.asarray(np.random.default_rng(5).integers(0, 256, (1, 40)), jnp.int32)
     turned = KimiLinearConfig.tiny(mla_use_nope=False)
@@ -200,8 +195,7 @@ def test_both_heads_logits_match_the_plain_reference(params, tokens):
         # the sliced module has T - 1 places: the shifted one's last place holds a filler's
         np.testing.assert_allclose(np.asarray(mtp_logits[row, :-1]), np.asarray(want_mtp), atol=5e-6)
     assert sizes.shape == (3, 8) and sizes.sum(axis=1).tolist() == [2 * 40 * 2] * 3      # two trunk layers, the module's
-    gauges = default_registry().snapshot()["gauges"]
-    assert (gauges["model.layers_mla"], gauges["mtp.depth"], gauges["mtp.loss_weight"]) == (4, 1, 0.3)
+    assert default_registry().snapshot()["gauges"]["mtp.depth"] == 1
 
 
 @pytest.mark.parametrize("loss", ["dense", "chunked"])

@@ -118,7 +118,7 @@ def test_the_chunk_follows_the_shape_and_the_scan_leaves_its_gauges():
     args, _ = scan_inputs(100, 0, "every-rate")
     kda(*args)
     gauges = default_registry().snapshot()["gauges"]
-    assert (gauges["kda.chunk"], gauges["kda.tiles"], gauges["kda.state_bytes"]) == (64, 2 * 2, 2 * 16 * 8 * 4)
+    assert (gauges["kda.chunk"], gauges["kda.tiles"]) == (64, 2 * 2)
     assert gauges["kda.padded_rows"] == 128 - 100        # the one copy left: the pad along T, where T is no whole chunks
     kda(*scan_inputs(40, 0, "every-rate")[0])
     assert default_registry().snapshot()["gauges"]["kda.padded_rows"] == 0
@@ -165,8 +165,6 @@ def test_the_latent_mixer_is_the_head_at_a_time_form(params, T):
     got = MLAMixer(CFG).apply({"params": p}, x)
     want = jnp.stack([kimi_linear_ref.mla_mixer(row, p, file_config(), PROD) for row in x])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
-    gauges = default_registry().snapshot()["gauges"]
-    assert (gauges["mla.qk_dim"], gauges["mla.v_dim"]) == (24, 16)
 
 
 def test_the_short_convolution_is_causal_and_depthwise():
@@ -199,8 +197,6 @@ def test_logits_match_the_plain_reference(params, tokens):
     want = jnp.stack([kimi_linear_ref.logits_fn(params, row, file_config()) for row in tokens])
     np.testing.assert_allclose(np.asarray(logits), np.asarray(want), atol=5e-6)
     assert sizes.shape == (3, 8) and sizes.sum(axis=1).tolist() == [2 * 40 * 2] * 3
-    gauges = default_registry().snapshot()["gauges"]
-    assert (gauges["model.layers_kda"], gauges["model.layers_mla"]) == (3, 1)
 
 
 @pytest.mark.parametrize("loss", ["dense", "chunked"])
