@@ -50,14 +50,16 @@ def test_lm_loss_chunked_matches_lm_loss_fp32():
     tokens = jnp.asarray(
         np.random.default_rng(2).integers(0, cfg.vocab_size, size=(2, 16)), jnp.int32
     )
-    params = model.init(jax.random.PRNGKey(0), tokens)
-    dense = lm_loss(model.apply(params, tokens), tokens)
-    chunked = lm_loss_chunked(model, params, tokens, block=16)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
+    dense_loss = lambda p: lm_loss(model.apply(p, tokens), tokens)  # noqa: E731
+    chunked_loss = lambda p: lm_loss_chunked(model, p, tokens, block=16)  # noqa: E731
+    dense = jax.jit(dense_loss)(params)
+    chunked = jax.jit(chunked_loss)(params)
     np.testing.assert_allclose(float(chunked), float(dense), rtol=2e-6)
 
     # full training gradient (incl. the weight-tied wte double contribution)
-    gd = jax.grad(lambda p: lm_loss(model.apply(p, tokens), tokens))(params)
-    gc = jax.grad(lambda p: lm_loss_chunked(model, p, tokens, block=16))(params)
+    gd = jax.jit(jax.grad(dense_loss))(params)
+    gc = jax.jit(jax.grad(chunked_loss))(params)
     for (pa, a), (_, b) in zip(
         jax.tree_util.tree_leaves_with_path(gd), jax.tree_util.tree_leaves_with_path(gc)
     ):
@@ -77,9 +79,9 @@ def test_lm_loss_chunked_bf16_close_and_trains():
     tokens = jnp.asarray(
         np.random.default_rng(3).integers(0, cfg.vocab_size, size=(4, 16)), jnp.int32
     )
-    params = model.init(jax.random.PRNGKey(0), tokens)
-    dense = float(lm_loss(model.apply(params, tokens), tokens))
-    chunked = float(lm_loss_chunked(model, params, tokens, block=16))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
+    dense = float(jax.jit(lambda p: lm_loss(model.apply(p, tokens), tokens))(params))
+    chunked = float(jax.jit(lambda p: lm_loss_chunked(model, p, tokens, block=16))(params))
     assert abs(dense - chunked) / dense < 0.02
 
     tx = optax.adam(1e-2)
@@ -135,7 +137,7 @@ def test_sp_chunked_loss_matches_dense_sp(mesh8):
     tokens = jnp.asarray(
         np.random.default_rng(8).integers(0, cfg.vocab_size, size=(2, 32)), jnp.int32
     )
-    params = GPT2(dataclasses.replace(cfg, sp_axis=None)).init(
+    params = jax.jit(GPT2(dataclasses.replace(cfg, sp_axis=None)).init)(
         jax.random.PRNGKey(0), tokens
     )
     dense = gpt2_sp_loss_and_grad(model, mesh8, loss="dense")
@@ -244,7 +246,7 @@ def test_sp_chunked_loss_ulysses_path(mesh8):
     tokens = jnp.asarray(
         np.random.default_rng(9).integers(0, cfg.vocab_size, size=(2, 32)), jnp.int32
     )
-    params = GPT2(dataclasses.replace(cfg, sp_axis=None)).init(
+    params = jax.jit(GPT2(dataclasses.replace(cfg, sp_axis=None)).init)(
         jax.random.PRNGKey(0), tokens
     )
     ld, gd = gpt2_sp_loss_and_grad(model, mesh8, loss="dense")(params, tokens)

@@ -64,12 +64,19 @@ def test_all_arrive_full_active_list():
 
 
 def test_straggler_demoted_to_relay():
-    logic = fast_logic(4)
+    # the healthy three are decided by no clock they could miss: at 0.15 s a
+    # unit of the collective the leader rents 0.3 s with two ranks ready and
+    # 0.5 s with three (at the nominal constants it bought some 10 ms after
+    # the second arrival, and a third thread on a busy host lands later than
+    # that); the straggler comes a second after the freeze
+    logic = fast_logic(
+        4, relay_threshold=1.0, accumulated_size=0.15, accumulated_bandwidth=1.0
+    )
     results = {}
 
     def worker(r):
         if r == 3:
-            time.sleep(0.4)  # way past the relay threshold
+            time.sleep(1.5)  # way past the freeze and the relay threshold
         results[r] = logic.hook_arrive(step=0, rank=r)
 
     run_workers(4, worker)

@@ -714,7 +714,12 @@ def test_late_old_step_arrival_does_not_regress_worldview():
     epoch = wv.epoch
 
     # a straggler lands its arrival for the OLD healthy step 4: the world
-    # picture must not regress (rank 2 stays dead, rank 3 stays demoted)
+    # picture must not regress (rank 2 stays dead, rank 3 stays demoted).
+    # Alone at step 4's barrier it is the sole leader, which leaves after
+    # ``fault_timeout`` with its singleton list: the fold into the world
+    # picture is done before that wait, so the wait is cut to a moment for
+    # this one arrival (the healthy round above kept the 30 s)
+    logic.fault_timeout = 0.05
     logic.hook_arrive(step=4, rank=1)
     wv2 = logic.worldview()
     assert wv2.dead == frozenset({2}), "old-step arrival resurrected a dead rank"

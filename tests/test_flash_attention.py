@@ -7,6 +7,7 @@ must match it; the Pallas interpreter runs on the CPU pod (Mosaic lowering
 is covered separately by tests/test_tpu_smoke.py).
 """
 
+import functools
 import sys
 
 import jax
@@ -384,18 +385,26 @@ def test_tile_gauges_follow_the_trace(blocks, causal, program_index):
     assert g["flash.tiles_looped"] == 2 * by_query + by_key
 
 
-def _walked(span, i, parts):
-    """``(block, masked)`` in the order ``_band`` visits them for position
-    ``i``, its bounds handed over as arrays (as a traced ``program_id``
-    gives them) so that the runs, conditions and loops decide."""
-    visits = jnp.full((64, 2), -1, jnp.int32)
+@functools.cache
+def _walk(parts):
+    """``bounds -> (visits, steps)``: ``_band``'s walk with ``parts`` as one
+    compiled program, shared by every position and shape whose runs are the
+    same (run eagerly, every call compiles its loops and conditions anew)."""
 
     def tile(j, carry, masked):
         visits, step = carry
         return visits.at[step].set(jnp.stack([jnp.asarray(j, jnp.int32), jnp.int32(masked)])), step + 1
 
-    bounds = tuple(jnp.int32(x) for x in span(i))
-    visits, steps = flash_module._band(bounds, tile, (visits, jnp.int32(0)), parts=parts)
+    return jax.jit(lambda bounds: flash_module._band(
+        bounds, tile, (jnp.full((64, 2), -1, jnp.int32), jnp.int32(0)), parts=parts
+    ))
+
+
+def _walked(span, i, parts):
+    """``(block, masked)`` in the order ``_band`` visits them for position
+    ``i``, its bounds handed over as arrays (as a traced ``program_id``
+    gives them) so that the runs, conditions and loops decide."""
+    visits, steps = _walk(tuple(parts))(tuple(jnp.int32(x) for x in span(i)))
     return [tuple(int(x) for x in row) for row in np.asarray(visits[: int(steps)])]
 
 

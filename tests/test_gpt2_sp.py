@@ -21,18 +21,28 @@ def _tokens(B=2, T=32, seed=0):
     )
 
 
-@pytest.mark.parametrize("sp_impl", ["ring", "ulysses"])
-def test_sp_loss_and_grads_match_single_device(mesh4, sp_impl):
-    # ulysses needs n_head % world == 0
-    base = {**BASE, "n_head": 4}
-    tokens = _tokens()
-    plain = GPT2(GPT2Config(**base))
-    params = plain.init(jax.random.PRNGKey(0), tokens)
-    loss_ref, grads_ref = jax.value_and_grad(
-        lambda p: lm_loss(plain.apply(p, tokens), tokens)
-    )(params)
+# ulysses needs n_head % world == 0
+FOUR_HEADS = {**BASE, "n_head": 4}
 
-    sp_model = GPT2(GPT2Config(**base, sp_axis="ranks", sp_impl=sp_impl))
+
+@pytest.fixture(scope="module")
+def single_device():
+    """``(tokens, params, loss, grads)`` of the plain four-head model on one
+    device, each a compiled program run once: both schemes are held to it."""
+    tokens = _tokens()
+    plain = GPT2(GPT2Config(**FOUR_HEADS))
+    params = jax.jit(plain.init)(jax.random.PRNGKey(0), tokens)
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: lm_loss(plain.apply(p, tokens), tokens)
+    ))(params)
+    return tokens, params, loss, grads
+
+
+@pytest.mark.parametrize("sp_impl", ["ring", "ulysses"])
+def test_sp_loss_and_grads_match_single_device(mesh4, single_device, sp_impl):
+    tokens, params, loss_ref, grads_ref = single_device
+
+    sp_model = GPT2(GPT2Config(**FOUR_HEADS, sp_axis="ranks", sp_impl=sp_impl))
     loss_sp, grads_sp = gpt2_sp_loss_and_grad(sp_model, mesh4)(params, tokens)
 
     np.testing.assert_allclose(float(loss_sp), float(loss_ref), atol=1e-5)
@@ -44,7 +54,7 @@ def test_sp_loss_and_grads_match_single_device(mesh4, sp_impl):
 
 def test_sp_flash_blocks_match_dense(mesh4):
     tokens = _tokens(seed=1)
-    params = GPT2(GPT2Config(**BASE)).init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(GPT2(GPT2Config(**BASE)).init)(jax.random.PRNGKey(0), tokens)
     dense = GPT2(GPT2Config(**BASE, sp_axis="ranks", attention="xla"))
     flash = GPT2(GPT2Config(**BASE, sp_axis="ranks", attention="flash"))
     l_dense, g_dense = gpt2_sp_loss_and_grad(dense, mesh4)(params, tokens)
@@ -80,7 +90,7 @@ def test_sp_axis_mismatch_rejected(mesh4):
 def test_sp_rejects_dropout(mesh4):
     model = GPT2(GPT2Config(**BASE, sp_axis="ranks", dropout=0.1))
     tokens = _tokens()
-    params = GPT2(GPT2Config(**BASE)).init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(GPT2(GPT2Config(**BASE)).init)(jax.random.PRNGKey(0), tokens)
     with pytest.raises(ValueError, match="dropout"):
         gpt2_sp_loss_and_grad(model, mesh4)(params, tokens)
 

@@ -13,6 +13,7 @@ model came; the workload trains through ``DDPTrainer.step``.
 """
 
 import dataclasses
+import functools
 import hashlib
 
 import jax
@@ -53,10 +54,34 @@ def tokens():
     return jnp.asarray(np.random.default_rng(0).integers(0, CFG.vocab_size, (2, 40)), jnp.int32)
 
 
-def loss_and_grads(cfg, params, tokens, loss="dense"):
-    return jax.value_and_grad(stateful_loss(JoyAIFlash(cfg), loss, block=64), has_aux=True)(
-        params, initial_model_state(cfg), tokens
-    )
+@pytest.fixture(scope="module")
+def loss_and_grads(params, tokens):
+    """``loss_and_grads(cfg, loss)``: ``((value, state), grads)`` of the model's
+    loss on the module's weights and tokens, one compiled program for each
+    configuration and form of the loss, run once a module: the tests that
+    weigh the same step against different things read the one result."""
+
+    @functools.cache
+    def step(cfg=CFG, loss="dense"):
+        return jax.jit(jax.value_and_grad(stateful_loss(JoyAIFlash(cfg), loss, block=64), has_aux=True))(
+            params, initial_model_state(cfg), tokens
+        )
+
+    return step
+
+
+@pytest.fixture(scope="module")
+def reference(params, tokens):
+    """The plain reference's ``((loss, (main, mtp)), grads)`` on the module's
+    weights and tokens, once a module: both forms of the model's loss are held
+    to the same numbers."""
+    return jax.jit(lambda p, t: joyai_flash_ref.loss_and_grads(p, t, file_config()))(params, tokens)
+
+
+@pytest.fixture(scope="module")
+def heads(params, tokens):
+    """``(logits, mtp_logits, sizes)`` of the model on the module's weights and tokens."""
+    return jax.jit(JoyAIFlash(CFG).apply)(params, tokens)
 
 
 def assert_trees_close(got, want, tol=5e-4):
@@ -143,10 +168,14 @@ def test_the_latent_mixer_with_its_query_rank_and_rotation_is_the_head_at_a_time
     p = params["params"]["layers_1"]["self_attn"]
     assert p["q_a_proj"]["kernel"].shape == (32, 24) and p["q_b_proj"]["kernel"].shape == (24, 2 * 24)
     x = jnp.asarray(np.random.default_rng(3).normal(size=(2, T, 32)), jnp.float32)
-    got = MLAMixer(CFG).apply({"params": p}, x)
-    want = jnp.stack([joyai_flash_ref.mla_mixer(row, p, file_config(), PROD) for row in x])
+    got = jax.jit(MLAMixer(CFG).apply)({"params": p}, x)
+    plain = jax.jit(
+        lambda p, x, turn: jnp.stack([joyai_flash_ref.mla_mixer(row, p, file_config(), PROD, turn=turn) for row in x]),
+        static_argnums=2,
+    )
+    want = plain(p, x, True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
-    unrotated = jnp.stack([joyai_flash_ref.mla_mixer(row, p, file_config(), PROD, turn=False) for row in x])
+    unrotated = plain(p, x, False)
     assert float(jnp.max(jnp.abs(unrotated - want))) > 1e-5           # and the rotation is no small thing beside the tolerance
 
 
@@ -158,7 +187,8 @@ def test_one_mixer_serves_both_models(params):
     x = jnp.asarray(np.random.default_rng(4).normal(size=(1, 40, 32)), jnp.float32)
     kimi = KimiLinearConfig.tiny(q_lora_rank=24, mla_use_nope=False, rope_theta=CFG.rope_theta, rms_norm_eps=CFG.rms_norm_eps)
     np.testing.assert_array_equal(
-        np.asarray(MLAMixer(kimi).apply({"params": p}, x)), np.asarray(MLAMixer(CFG).apply({"params": p}, x))
+        np.asarray(jax.jit(MLAMixer(kimi).apply)({"params": p}, x)),
+        np.asarray(jax.jit(MLAMixer(CFG).apply)({"params": p}, x)),
     )
     published = MLAMixer(KimiLinearConfig.tiny())
     shapes = jax.eval_shape(published.init, jax.random.PRNGKey(0), x)["params"]
@@ -166,9 +196,9 @@ def test_one_mixer_serves_both_models(params):
     # and a whole Kimi-Linear with the two keys it used to refuse runs, and is another function
     tokens = jnp.asarray(np.random.default_rng(5).integers(0, 256, (1, 40)), jnp.int32)
     turned = KimiLinearConfig.tiny(mla_use_nope=False)
-    weights = KimiLinear(turned).init(jax.random.PRNGKey(1), tokens)
-    rotated, _ = KimiLinear(turned).apply(weights, tokens)
-    plain, _ = KimiLinear(KimiLinearConfig.tiny()).apply(weights, tokens)
+    weights = jax.jit(KimiLinear(turned).init)(jax.random.PRNGKey(1), tokens)
+    rotated, _ = jax.jit(KimiLinear(turned).apply)(weights, tokens)
+    plain, _ = jax.jit(KimiLinear(KimiLinearConfig.tiny()).apply)(weights, tokens)
     assert float(jnp.max(jnp.abs(rotated - plain))) > 1e-5
 
 
@@ -186,11 +216,12 @@ def test_the_weight_maker_makes_the_tree_the_model_reads(params):
     assert "embed_tokens" not in module and "lm_head" not in module        # the trunk's own, not copies
 
 
-def test_both_heads_logits_match_the_plain_reference(params, tokens):
-    logits, mtp_logits, sizes = JoyAIFlash(CFG).apply(params, tokens)
+def test_both_heads_logits_match_the_plain_reference(params, tokens, heads):
+    logits, mtp_logits, sizes = heads
     assert logits.shape == mtp_logits.shape == (2, 40, 256)
+    plain = jax.jit(lambda p, row: joyai_flash_ref.logits_fn(p, row, file_config()))
     for row in range(2):
-        want, want_mtp = joyai_flash_ref.logits_fn(params, tokens[row], file_config())
+        want, want_mtp = plain(params, tokens[row])
         np.testing.assert_allclose(np.asarray(logits[row]), np.asarray(want), atol=5e-6)
         # the sliced module has T - 1 places: the shifted one's last place holds a filler's
         np.testing.assert_allclose(np.asarray(mtp_logits[row, :-1]), np.asarray(want_mtp), atol=5e-6)
@@ -199,9 +230,9 @@ def test_both_heads_logits_match_the_plain_reference(params, tokens):
 
 
 @pytest.mark.parametrize("loss", ["dense", "chunked"])
-def test_both_loss_terms_and_every_gradient_leaf_match_the_plain_reference(params, tokens, loss):
-    (value, state), grads = loss_and_grads(CFG, params, tokens, loss)
-    (want, (want_main, want_mtp)), want_grads = joyai_flash_ref.loss_and_grads(params, tokens, file_config())
+def test_both_loss_terms_and_every_gradient_leaf_match_the_plain_reference(loss_and_grads, reference, loss):
+    (value, state), grads = loss_and_grads(CFG, loss)
+    (want, (want_main, want_mtp)), want_grads = reference
     assert float(value) == pytest.approx(float(want), rel=1e-6)
     assert float(state["loss_main"]) == pytest.approx(float(want_main), rel=1e-6)
     assert float(state["loss_mtp"]) == pytest.approx(float(want_mtp), rel=1e-6)
@@ -213,15 +244,17 @@ def test_both_loss_terms_and_every_gradient_leaf_match_the_plain_reference(param
 
 
 @pytest.mark.parametrize("remat", ["dots", "full"])
-def test_recomputing_a_block_changes_no_number(params, tokens, remat):
-    (value, _), grads = loss_and_grads(CFG, params, tokens)
-    (again, _), grads_again = loss_and_grads(dataclasses.replace(CFG, remat=remat), params, tokens)
+def test_recomputing_a_block_changes_no_number(loss_and_grads, remat):
+    (value, _), grads = loss_and_grads(CFG)
+    (again, _), grads_again = loss_and_grads(dataclasses.replace(CFG, remat=remat))
     assert float(again) == pytest.approx(float(value), rel=1e-6)
     assert_trees_close(grads_again, grads, tol=1e-5)
 
 
-def test_a_weight_of_zero_gives_the_trunks_gradient_and_nothing_on_the_modules_own_leaves(params, tokens):
-    (value, state), grads = loss_and_grads(dataclasses.replace(CFG, mtp_loss_weight=0.0), params, tokens)
+def test_a_weight_of_zero_gives_the_trunks_gradient_and_nothing_on_the_modules_own_leaves(
+    params, tokens, loss_and_grads
+):
+    (value, state), grads = loss_and_grads(dataclasses.replace(CFG, mtp_loss_weight=0.0))
     assert float(value) == float(state["loss_main"]) and float(state["loss_mtp"]) > 0
     assert not any(np.any(np.asarray(g)) for g in jax.tree_util.tree_leaves(grads["params"]["mtp"]))
 
@@ -232,10 +265,10 @@ def test_a_weight_of_zero_gives_the_trunks_gradient_and_nothing_on_the_modules_o
             - jnp.take_along_axis(logits[:, :-1], tokens[:, 1:, None], axis=-1)[..., 0]
         )
 
-    assert_trees_close(grads, jax.grad(trunk_alone)(params), tol=1e-5)
+    assert_trees_close(grads, jax.jit(jax.grad(trunk_alone))(params), tol=1e-5)
 
 
-def test_the_embeddings_and_the_heads_gradients_are_the_sums_of_their_two_uses(params, tokens):
+def test_the_embeddings_and_the_heads_gradients_are_the_sums_of_their_two_uses(params, tokens, loss_and_grads):
     """``embed_tokens`` is read for the trunk's input and for the module's
     merge, ``lm_head`` by both losses: what each gets back for ``L`` is what
     it gets for ``L_main`` plus 0.3 of what it gets for ``L_mtp``, and neither
@@ -243,17 +276,17 @@ def test_the_embeddings_and_the_heads_gradients_are_the_sums_of_their_two_uses(p
     model = JoyAIFlash(CFG)
 
     def term(name):
-        return jax.grad(lambda p: stateful_loss(model)(p, None, tokens)[1][name])(params)["params"]
+        return jax.jit(jax.grad(lambda p: stateful_loss(model)(p, None, tokens)[1][name]))(params)["params"]
 
     main, mtp = term("loss_main"), term("loss_mtp")
-    total = loss_and_grads(CFG, params, tokens)[1]["params"]
+    total = loss_and_grads(CFG)[1]["params"]
     for leaf in (lambda g: g["embed_tokens"]["embedding"], lambda g: g["lm_head"]):
         assert float(jnp.max(jnp.abs(leaf(main)))) > 1e-5 and float(jnp.max(jnp.abs(leaf(mtp)))) > 1e-5
         np.testing.assert_allclose(np.asarray(leaf(total)), np.asarray(leaf(main) + 0.3 * leaf(mtp)), atol=1e-7)
     assert not any(np.any(np.asarray(g)) for g in jax.tree_util.tree_leaves(main["mtp"]))
 
 
-def test_the_shifted_full_length_module_is_the_sliced_one_on_the_places_that_count(params, tokens):
+def test_the_shifted_full_length_module_is_the_sliced_one_on_the_places_that_count(params, tokens, heads):
     """The module over all ``T`` places with a filler last, against the module
     over the ``T - 1`` places that exist: the block is causal, so whatever
     stands in the last place moves no place before it, and the loss reads
@@ -261,7 +294,7 @@ def test_the_shifted_full_length_module_is_the_sliced_one_on_the_places_that_cou
     p = params["params"]
     trunk = jnp.asarray(np.random.default_rng(7).normal(size=(2, 40, 32)), jnp.float32)
     nxt = jnp.asarray(np.random.default_rng(8).normal(size=(2, 40, 32)), jnp.float32)
-    module = lambda a, b: MTPModule(CFG).apply({"params": p["mtp"]}, a, b)[0]  # noqa: E731
+    module = jax.jit(lambda a, b: MTPModule(CFG).apply({"params": p["mtp"]}, a, b)[0])
     full = module(trunk, nxt)
     other_filler = module(trunk.at[:, -1].set(9.0), nxt.at[:, -1].set(-9.0))
     sliced = module(trunk[:, :-1], nxt[:, :-1])
@@ -270,8 +303,8 @@ def test_the_shifted_full_length_module_is_the_sliced_one_on_the_places_that_cou
     assert float(jnp.max(jnp.abs(full[:, -1] - other_filler[:, -1]))) > 1e-3
     # and the loss's module term is blind to the filler: the last token moved, the places 0..T-3 answer as before
     moved = tokens.at[:, -1].set((tokens[:, -1] + 1) % CFG.vocab_size)
-    _, mtp_logits, _ = JoyAIFlash(CFG).apply(params, tokens)
-    _, mtp_moved, _ = JoyAIFlash(CFG).apply(params, moved)
+    _, mtp_logits, _ = heads
+    _, mtp_moved, _ = jax.jit(JoyAIFlash(CFG).apply)(params, moved)
     np.testing.assert_allclose(np.asarray(mtp_logits[:, :-2]), np.asarray(mtp_moved[:, :-2]), atol=1e-6)
 
 
@@ -305,8 +338,10 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(params)
         for k in ("experts_w1", "experts_w3", "experts_w2"):
             layer["mlp"][k] = layer["mlp"][k][4:6]
     toks = jnp.asarray(np.random.default_rng(2).integers(0, 256, (1, 40)), jnp.int32)
-    logits, mtp_logits, sizes = JoyAIFlash(held).apply(cut, toks)
-    want, want_mtp = joyai_flash_ref.logits_fn(cut, toks[0], file_config(held, expert_offset=4))
+    logits, mtp_logits, sizes = jax.jit(JoyAIFlash(held).apply)(cut, toks)
+    want, want_mtp = jax.jit(
+        lambda p, row: joyai_flash_ref.logits_fn(p, row, file_config(held, expert_offset=4))
+    )(cut, toks[0])
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(want), atol=5e-6)
     np.testing.assert_allclose(np.asarray(mtp_logits[0, :-1]), np.asarray(want_mtp), atol=5e-6)
     assert sizes.shape == (3, 2)

@@ -58,6 +58,14 @@ def tokens():
     return jnp.asarray(np.random.default_rng(0).integers(0, CFG.vocab_size, (2, 40)), jnp.int32)
 
 
+@pytest.fixture(scope="module")
+def reference(params, tokens):
+    """The plain reference's ``(loss, grads)`` on the module's weights and
+    tokens, once a module: both forms of the model's loss are held to the
+    same numbers."""
+    return jax.jit(lambda p, t: kimi_linear_ref.loss_and_grads(p, t, file_config()))(params, tokens)
+
+
 # --- the kernel --------------------------------------------------------------
 
 
@@ -163,7 +171,7 @@ def test_the_latent_mixer_is_the_head_at_a_time_form(params, T):
     p = params["params"]["layers_2"]["self_attn"]
     x = jnp.asarray(np.random.default_rng(3).normal(size=(2, T, 32)), jnp.float32)
     got = MLAMixer(CFG).apply({"params": p}, x)
-    want = jnp.stack([kimi_linear_ref.mla_mixer(row, p, file_config(), PROD) for row in x])
+    want = jax.jit(lambda p, x: jnp.stack([kimi_linear_ref.mla_mixer(row, p, file_config(), PROD) for row in x]))(p, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
 
 
@@ -194,18 +202,20 @@ def test_the_weight_maker_makes_the_tree_the_model_reads(params):
 
 def test_logits_match_the_plain_reference(params, tokens):
     logits, sizes = KimiLinear(CFG).apply(params, tokens)
-    want = jnp.stack([kimi_linear_ref.logits_fn(params, row, file_config()) for row in tokens])
+    want = jax.jit(
+        lambda p, t: jnp.stack([kimi_linear_ref.logits_fn(p, row, file_config()) for row in t])
+    )(params, tokens)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(want), atol=5e-6)
     assert sizes.shape == (3, 8) and sizes.sum(axis=1).tolist() == [2 * 40 * 2] * 3
 
 
 @pytest.mark.parametrize("loss", ["dense", "chunked"])
-def test_loss_and_every_gradient_leaf_match_the_plain_reference(params, tokens, loss):
+def test_loss_and_every_gradient_leaf_match_the_plain_reference(params, tokens, reference, loss):
     model = KimiLinear(CFG)
     (value, state), grads = jax.value_and_grad(stateful_loss(model, loss, block=64), has_aux=True)(
         params, initial_model_state(CFG), tokens
     )
-    want, want_grads = kimi_linear_ref.loss_and_grads(params, tokens, file_config())
+    want, want_grads = reference
     assert float(value) == pytest.approx(float(want), rel=1e-6)
     assert state["moe_sizes"].shape == (3, 8)
     for (path, got), ref in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree_util.tree_leaves(want_grads)):
@@ -246,7 +256,7 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(params)
             cut["params"][name]["mlp"][k] = params["params"][name]["mlp"][k][4:6]
     toks = jnp.asarray(np.random.default_rng(2).integers(0, 256, (1, 40)), jnp.int32)
     logits, sizes = KimiLinear(held).apply(cut, toks)
-    want = kimi_linear_ref.logits_fn(cut, toks[0], file_config(held, expert_offset=4))
+    want = jax.jit(lambda p, row: kimi_linear_ref.logits_fn(p, row, file_config(held, expert_offset=4)))(cut, toks[0])
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(want), atol=5e-6)
     assert sizes.shape == (3, 2)
 

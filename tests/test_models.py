@@ -15,8 +15,8 @@ def test_gpt2_forward_and_loss():
     cfg = GPT2Config.tiny()
     model = GPT2(cfg)
     tokens = jnp.ones((2, cfg.max_seq), dtype=jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), tokens)
-    logits = model.apply(params, tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
+    logits = jax.jit(model.apply)(params, tokens)
     assert logits.shape == (2, cfg.max_seq, cfg.vocab_size)
     assert logits.dtype == jnp.float32
     loss = lm_loss(logits, tokens)
@@ -70,10 +70,10 @@ def test_trinity_dense_loss_is_the_textbook_loss_of_its_logits():
     cfg = TrinityConfig.tiny()
     model = Trinity(cfg)
     tokens = jnp.asarray(np.random.default_rng(3).integers(0, cfg.vocab_size, (2, 24)), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     loss_fn = stateful_loss(model, "dense")
-    (got, _), got_grad = jax.value_and_grad(loss_fn, has_aux=True)(params, initial_model_state(cfg), tokens)
-    want, want_grad = jax.value_and_grad(lambda p: _textbook_loss(model.apply(p, tokens)[0], tokens))(params)
+    (got, _), got_grad = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(params, initial_model_state(cfg), tokens)
+    want, want_grad = jax.jit(jax.value_and_grad(lambda p: _textbook_loss(model.apply(p, tokens)[0], tokens)))(params)
     assert float(got) == pytest.approx(float(want), rel=2e-6)
     for a, b in zip(jax.tree_util.tree_leaves(got_grad), jax.tree_util.tree_leaves(want_grad)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-7)
@@ -85,11 +85,13 @@ def test_pipeline_last_stage_loss_is_the_textbook_loss_of_its_logits():
     cfg = GPT2Config.tiny()
     model = GPT2(cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(5), (3, 13), 0, cfg.vocab_size)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     part = partition_gpt2(cfg, 2)
     # every stage in order; the last applies the head and lm_loss
-    got, got_grad = jax.value_and_grad(lambda p: composed_loss(cfg, part, split_params(p, part), tokens))(params)
-    want, want_grad = jax.value_and_grad(lambda p: _textbook_loss(model.apply(p, tokens), tokens))(params)
+    got, got_grad = jax.jit(
+        jax.value_and_grad(lambda p: composed_loss(cfg, part, split_params(p, part), tokens))
+    )(params)
+    want, want_grad = jax.jit(jax.value_and_grad(lambda p: _textbook_loss(model.apply(p, tokens), tokens)))(params)
     assert float(got) == pytest.approx(float(want), rel=2e-6)
     for a, b in zip(jax.tree_util.tree_leaves(got_grad), jax.tree_util.tree_leaves(want_grad)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-7)
@@ -111,12 +113,12 @@ def test_gpt2_gradients_nonzero():
 def test_gpt2_remat_variant_matches():
     cfg = GPT2Config.tiny()
     tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 16), 0, cfg.vocab_size)
-    params = GPT2(cfg).init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(GPT2(cfg).init)(jax.random.PRNGKey(0), tokens)
     import dataclasses
 
     cfg_r = dataclasses.replace(cfg, remat=True)
-    out_a = GPT2(cfg).apply(params, tokens)
-    out_b = GPT2(cfg_r).apply(params, tokens)
+    out_a = jax.jit(GPT2(cfg).apply)(params, tokens)
+    out_b = jax.jit(GPT2(cfg_r).apply)(params, tokens)
     np.testing.assert_allclose(np.asarray(out_a), np.asarray(out_b), rtol=1e-5)
 
 
@@ -134,8 +136,8 @@ def test_vit_forward():
     cfg = ViTConfig.tiny()
     model = ViT(cfg)
     x = jnp.ones((2, cfg.image_size, cfg.image_size, 3))
-    params = model.init(jax.random.PRNGKey(0), x)
-    out = model.apply(params, x)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x)
+    out = jax.jit(model.apply)(params, x)
     assert out.shape == (2, cfg.num_classes)
 
 
@@ -143,8 +145,8 @@ def test_moe_forward_and_aux_loss():
     cfg = MoEConfig.tiny()
     model = MoEMLP(cfg)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, cfg.d_model))
-    params = model.init(jax.random.PRNGKey(1), x)
-    y, aux = model.apply(params, x)
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), x)
+    y, aux = jax.jit(model.apply)(params, x)
     assert y.shape == x.shape
     assert np.isfinite(np.asarray(y)).all()
     # balanced-ish routing on random inputs: aux loss near 1 (perfect balance
@@ -156,8 +158,8 @@ def test_moe_tokens_actually_routed():
     cfg = MoEConfig.tiny()
     model = MoEMLP(cfg)
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 16, cfg.d_model))
-    params = model.init(jax.random.PRNGKey(4), x)
-    y, _ = model.apply(params, x)
+    params = jax.jit(model.init)(jax.random.PRNGKey(4), x)
+    y, _ = jax.jit(model.apply)(params, x)
     # output differs from input (experts transformed it) and is token-dependent
     assert not np.allclose(np.asarray(y), np.asarray(x))
     assert np.asarray(y).std(axis=1).mean() > 0
@@ -167,13 +169,13 @@ def test_moe_gradients_flow_to_experts():
     cfg = MoEConfig.tiny()
     model = MoEMLP(cfg)
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 8, cfg.d_model))
-    params = model.init(jax.random.PRNGKey(6), x)
+    params = jax.jit(model.init)(jax.random.PRNGKey(6), x)
 
     def loss(p):
         y, aux = model.apply(p, x)
         return jnp.mean(y**2) + 0.01 * aux
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     w1g = g["params"]["w1"]
     assert float(jnp.linalg.norm(w1g)) > 0
 
@@ -186,17 +188,17 @@ def test_gpt2_remat_policies_match(policy):
 
     cfg = GPT2Config.tiny()
     tokens = jax.random.randint(jax.random.PRNGKey(4), (1, 16), 0, cfg.vocab_size)
-    params = GPT2(cfg).init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(GPT2(cfg).init)(jax.random.PRNGKey(0), tokens)
     cfg_r = dataclasses.replace(cfg, remat=True, remat_policy=policy)
-    out_a = GPT2(cfg).apply(params, tokens)
-    out_b = GPT2(cfg_r).apply(params, tokens)
+    out_a = jax.jit(GPT2(cfg).apply)(params, tokens)
+    out_b = jax.jit(GPT2(cfg_r).apply)(params, tokens)
     # bf16 activations: what a dots policy *recomputes* in backward/refused
     # fusions may re-round differently from the saved value, so equality
     # holds only to bf16 resolution (~2^-8), not fp32 eps
     tol = 1e-2 if cfg.dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(out_a), np.asarray(out_b), atol=tol)
-    ga = jax.grad(lambda p: lm_loss(GPT2(cfg).apply(p, tokens), tokens))(params)
-    gb = jax.grad(lambda p: lm_loss(GPT2(cfg_r).apply(p, tokens), tokens))(params)
+    ga = jax.jit(jax.grad(lambda p: lm_loss(GPT2(cfg).apply(p, tokens), tokens)))(params)
+    gb = jax.jit(jax.grad(lambda p: lm_loss(GPT2(cfg_r).apply(p, tokens), tokens)))(params)
     # gradients compare RELATIVELY (bf16 re-rounding scales with magnitude;
     # a flat atol=1e-2 would pass 100%-wrong small gradients), with an
     # absolute floor of one bf16 ulp-at-1 (2^-8) for near-zero leaves
@@ -259,18 +261,18 @@ def test_resnet_forward_group_and_batch_norm():
     x = jnp.ones((2, 16, 16, 3), jnp.float32)
     gn = ResNet(stage_sizes=(1, 1), block_cls=BasicBlock, num_classes=10,
                 width=8, small_inputs=True, dtype=jnp.float32)
-    v = gn.init(jax.random.PRNGKey(0), x)
+    v = jax.jit(gn.init)(jax.random.PRNGKey(0), x)
     # GroupNorm variant is stateless: params only
     assert set(v.keys()) == {"params"}
-    out = gn.apply(v, x)
+    out = jax.jit(gn.apply)(v, x)
     assert out.shape == (2, 10) and out.dtype == jnp.float32
     assert np.all(np.isfinite(np.asarray(out)))
 
     bn = ResNet(stage_sizes=(1, 1), block_cls=BasicBlock, num_classes=10,
                 width=8, small_inputs=True, dtype=jnp.float32, norm="batch")
-    vb = bn.init(jax.random.PRNGKey(0), x, train=True)
+    vb = jax.jit(lambda key, x: bn.init(key, x, train=True))(jax.random.PRNGKey(0), x)
     assert "batch_stats" in vb
-    out_t, upd = bn.apply(vb, x, train=True, mutable=["batch_stats"])
+    out_t, upd = jax.jit(lambda v, x: bn.apply(v, x, train=True, mutable=["batch_stats"]))(vb, x)
     assert out_t.shape == (2, 10)
     # train-mode batch statistics actually update the running stats
     before = jax.tree_util.tree_leaves(vb["batch_stats"])
@@ -279,8 +281,8 @@ def test_resnet_forward_group_and_batch_norm():
         float(np.abs(np.asarray(a) - np.asarray(b)).max()) > 0
         for a, b in zip(after, before)
     )
-    out_e = bn.apply(
-        {"params": vb["params"], "batch_stats": upd["batch_stats"]}, x, train=False
+    out_e = jax.jit(lambda v, x: bn.apply(v, x, train=False))(
+        {"params": vb["params"], "batch_stats": upd["batch_stats"]}, x
     )
     assert out_e.shape == (2, 10)
 
@@ -291,8 +293,8 @@ def test_resnet50_bottleneck_forward():
     x = jnp.ones((1, 16, 16, 3), jnp.float32)
     m = ResNet(stage_sizes=(1, 1), block_cls=Bottleneck, num_classes=7,
                width=8, small_inputs=True, dtype=jnp.float32)
-    v = m.init(jax.random.PRNGKey(0), x)
-    assert m.apply(v, x).shape == (1, 7)
+    v = jax.jit(m.init)(jax.random.PRNGKey(0), x)
+    assert jax.jit(m.apply)(v, x).shape == (1, 7)
 
 
 def test_resnet_param_counts_match_torchvision():
@@ -321,8 +323,8 @@ def test_resnet_imagenet_stem_downsamples():
     m = ResNet(stage_sizes=(1,), block_cls=BasicBlock, num_classes=5,
                width=8, dtype=jnp.float32)
     x = jnp.ones((1, 64, 64, 3), jnp.float32)
-    v = m.init(jax.random.PRNGKey(0), x)
-    assert m.apply(v, x).shape == (1, 5)
+    v = jax.jit(m.init)(jax.random.PRNGKey(0), x)
+    assert jax.jit(m.apply)(v, x).shape == (1, 5)
 
 
 def test_resnet_non_power_of_two_width():
@@ -333,5 +335,5 @@ def test_resnet_non_power_of_two_width():
     x = jnp.ones((1, 16, 16, 3), jnp.float32)
     m = ResNet(stage_sizes=(1, 1), block_cls=BasicBlock, num_classes=5,
                width=48, small_inputs=True, dtype=jnp.float32)
-    v = m.init(jax.random.PRNGKey(0), x)
-    assert m.apply(v, x).shape == (1, 5)
+    v = jax.jit(m.init)(jax.random.PRNGKey(0), x)
+    assert jax.jit(m.apply)(v, x).shape == (1, 5)

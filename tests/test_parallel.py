@@ -325,16 +325,21 @@ def test_moe_a2a_parity_flat_engine_and_two_level():
     model = MoEMLP(cfg)
     rng = np.random.default_rng(11)
     x = jnp.asarray(rng.normal(size=(64, cfg.d_model)), jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x[None])
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x[None])
+
+    def exchange(mesh, engine=None):
+        # one compiled program for each data plane: run eagerly, the shard_map
+        # dispatches (and compiles) its body an operation at a time on 8 devices
+        return jax.jit(lambda p, x: expert_parallel_moe(p, x, cfg, mesh, engine=engine))(params, x)
 
     flat = Mesh(np.array(jax.devices()[:8]), ("experts",))
-    y_flat, aux_flat = expert_parallel_moe(params, x, cfg, flat)
+    y_flat, aux_flat = exchange(flat)
 
     trace = CollectiveTrace()
     engine = CollectiveEngine(
         flat, Strategy.ring(8), axis_name="experts", trace=trace
     )
-    y_eng, aux_eng = expert_parallel_moe(params, x, cfg, flat, engine=engine)
+    y_eng, aux_eng = exchange(flat, engine)
     np.testing.assert_array_equal(np.asarray(y_eng), np.asarray(y_flat))
     np.testing.assert_array_equal(np.asarray(aux_eng), np.asarray(aux_flat))
     # the engine-routed exchanges were traced: 2 a2as per forward
@@ -345,11 +350,11 @@ def test_moe_a2a_parity_flat_engine_and_two_level():
     assert len(moe_events) == 2 and all(e.extra.get("moe") for e in moe_events)
 
     mesh2x4 = build_two_level_mesh(2, 4)
-    y_2l, aux_2l = expert_parallel_moe(params, x, cfg, mesh2x4)
+    y_2l, aux_2l = exchange(mesh2x4)
     np.testing.assert_array_equal(np.asarray(y_2l), np.asarray(y_flat))
     trace2 = CollectiveTrace()
     engine2 = CollectiveEngine(mesh2x4, Strategy.ring(8), trace=trace2)
-    y_2le, _ = expert_parallel_moe(params, x, cfg, mesh2x4, engine=engine2)
+    y_2le, _ = exchange(mesh2x4, engine2)
     np.testing.assert_array_equal(np.asarray(y_2le), np.asarray(y_flat))
     assert [
         e.impl for e in trace2.events() if e.primitive == "all_to_all"

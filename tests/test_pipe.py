@@ -481,13 +481,13 @@ def test_dp_pp_composition_matches_full_batch_pipeline(mesh2):
     # psum mode: stateless per-leaf sync, so one hook serves every stage's
     # differently-shaped gradient pytree
     hook = GradSyncHook(Strategy.ring(2), mode="psum")
-    hook_fn = jax.shard_map(
+    hook_fn = jax.jit(jax.shard_map(
         hook.sync,
         mesh=mesh2,
         in_specs=(P(RANKS_AXIS), P()),
         out_specs=P(RANKS_AXIS),
         check_vma=False,
-    )
+    ))
     mask = jnp.ones((2,), dtype=bool)
     stage_iter = iter(range(part.num_stages))
 

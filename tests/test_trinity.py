@@ -48,6 +48,20 @@ def tokens():
     return jnp.asarray(np.random.default_rng(0).integers(0, CFG.vocab_size, (2, 64)), jnp.int32)
 
 
+@pytest.fixture(scope="module")
+def reference(params, tokens):
+    """The plain reference's ``(loss, grads)`` on the module's weights and
+    tokens, once a module: both forms of the model's loss are held to the
+    same numbers."""
+    return jax.jit(lambda p, t: trinity_ref.loss_and_grads(p, t, file_config()))(params, tokens)
+
+
+@pytest.fixture(scope="module")
+def reference_logits(params, tokens):
+    """The plain reference's logits, once a module: both attentions are held to them."""
+    return jax.jit(lambda p, t: jnp.stack([trinity_ref.logits_fn(p, row, file_config()) for row in t]))(params, tokens)
+
+
 def test_the_weight_maker_makes_the_tree_the_model_reads(params):
     shapes = jax.eval_shape(Trinity(CFG).init, jax.random.PRNGKey(0), jnp.zeros((1, 64), jnp.int32))
     assert jax.tree_util.tree_structure(shapes) == jax.tree_util.tree_structure(params)
@@ -59,22 +73,21 @@ def test_the_weight_maker_makes_the_tree_the_model_reads(params):
 
 
 @pytest.mark.parametrize("attention", ["xla", "flash"])
-def test_logits_match_the_plain_reference(params, tokens, attention):
+def test_logits_match_the_plain_reference(params, tokens, reference_logits, attention):
     model = Trinity(dataclasses.replace(CFG, attention=attention, flash_block=16))
-    logits, sizes = model.apply(params, tokens)
-    want = jnp.stack([trinity_ref.logits_fn(params, row, file_config()) for row in tokens])
-    np.testing.assert_allclose(np.asarray(logits), np.asarray(want), atol=2e-6)
+    logits, sizes = jax.jit(model.apply)(params, tokens)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(reference_logits), atol=2e-6)
     assert sizes.shape == (2, 8) and sizes.dtype == jnp.int32
     assert sizes.sum(axis=1).tolist() == [2 * 64 * 2] * 2       # every assignment, all experts held
 
 
 @pytest.mark.parametrize("loss", ["dense", "chunked"])
-def test_loss_and_every_gradient_leaf_match_the_plain_reference(params, tokens, loss):
+def test_loss_and_every_gradient_leaf_match_the_plain_reference(params, tokens, reference, loss):
     model = Trinity(CFG)
-    (value, state), grads = jax.value_and_grad(stateful_loss(model, loss, block=64), has_aux=True)(
+    (value, state), grads = jax.jit(jax.value_and_grad(stateful_loss(model, loss, block=64), has_aux=True))(
         params, initial_model_state(CFG), tokens
     )
-    want, want_grads = trinity_ref.loss_and_grads(params, tokens, file_config())
+    want, want_grads = reference
     assert float(value) == pytest.approx(float(want), rel=1e-6)
     assert state["moe_sizes"].shape == (2, 8)
     flat = jax.tree_util.tree_leaves_with_path(grads)
@@ -115,8 +128,8 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(p
         for k in ("experts_w1", "experts_w3", "experts_w2"):
             cut["params"][name]["mlp"][k] = params["params"][name]["mlp"][k][2:6]
     toks = jnp.asarray(np.random.default_rng(2).integers(0, 256, (1, 64)), jnp.int32)
-    logits, _ = Trinity(held).apply(cut, toks)
-    want = trinity_ref.logits_fn(cut, toks[0], file_config(held, expert_offset=2))
+    logits, _ = jax.jit(Trinity(held).apply)(cut, toks)
+    want = jax.jit(lambda p, row: trinity_ref.logits_fn(p, row, file_config(held, expert_offset=2)))(cut, toks[0])
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(want), atol=2e-6)
 
 
