@@ -167,7 +167,9 @@ def l2norm(x):
 def short_conv(x, taps):
     """A causal depthwise convolution over time: ``y_t = sum_j taps[j] *
     x_{t - (K - 1) + j}`` for ``x [B, T, C]``, ``taps [K, C]``, zeros before
-    the sequence, no bias; summed in float32."""
+    the sequence, no bias; summed in float32.  A mixer whose convolution is
+    biased adds its own to the result (``granite_hybrid.Mamba2Mixer``, over
+    ``x``, ``B`` and ``C`` together, before the silu)."""
     K, T = taps.shape[0], x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
     y = sum(taps[j].astype(jnp.float32) * padded[:, j:j + T] for j in range(K))

@@ -59,7 +59,12 @@ body for each of the 16 positions would be 70.
 **Grouped KV heads.**  ``k`` and ``v`` may carry fewer heads than ``q``
 (``H_q = G * H_kv``): query head ``h`` reads KV head ``h // G`` through the
 K/V block index, nothing is repeated in HBM, and the dk/dv kernel writes one
-fp32 partial for each query head that the wrapper sums over the group.
+fp32 partial for each query head that the wrapper sums over the group.  Run
+on a chip (PERF.md §4): equal heads at head size 64 and T=1,024 (cells 1-2);
+eight query heads to a K/V head at head size 128 and T=8,192, windowed and
+full (cell 3); equal heads with scores over 192 and values over 128 at
+T=8,192 (cells 4-5); four query heads to a K/V head at head size 64 and
+T=8,192 with a given ``scale`` (cell 6, PR 39).
 
 **A head size of its own for v.**  ``v`` may be ``[B, T, H_kv, D_v]`` with
 ``D_v != D`` (latent attention: scores over 192 channels, values over 128):
@@ -527,7 +532,8 @@ def _dkv_kernel(
 TILE_TABLE = (
     ((4096, 64, 2), (512, 512)),   # bf16, measured at T=1,024 (B.H = 144 and 32) and T=4,096
     ((1024, 64, 4), (512, 512)),   # fp32, measured at T=1,024
-    ((8192, 128, 2), (512, 512)),  # bf16, head size 128 at T=8,192, window 2,048 and none (PERF.md §6, PR 26)
+    # bf16, head size 128 at T=8,192, window 2,048 and none (PERF.md §6, PR 26); head size 64 on 8 K/V heads there too (PR 39)
+    ((8192, 128, 2), (512, 512)),
     ((8192, 192, 2), (512, 512)),  # bf16, q/k of 192 over v of 128 at T=8,192 (PERF.md §6, PR 32)
     ((float("inf"),) * 3, (128, 128)),   # not measured: the tile every shape ran before
 )

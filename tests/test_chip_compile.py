@@ -655,6 +655,142 @@ def test_the_latent_cells_step_fits_the_chip(topo, monkeypatch):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 0.95 * 16 * 2**30
 
 
+# --- the state-space cell: nine scans, one grouped-query layer at head 64, one step ---
+
+
+def _granite_cell():
+    import json
+    from pathlib import Path
+
+    from chipbench.runners.train_ssm_lm import model_config
+
+    config = json.loads((Path(__file__).resolve().parents[1] / "chipbench/configs/granite-h-micro-vp8.json").read_text())
+    return config, model_config(config)
+
+
+def _scan_through_mosaic(monkeypatch):
+    import sys
+
+    _flash_through_mosaic(monkeypatch)
+    monkeypatch.setattr(sys.modules["adapcc_tpu.ops.ssd"], "resolve_interpret", lambda interpret, site: False)
+
+
+def test_the_state_space_scan_is_its_two_kernels_and_no_pass_of_xlas_around_them(one_chip):
+    """``value_and_grad`` of ``ssd`` at ``granite-h-micro-vp8-train``'s shape
+    (``x [1, 8192, 4096]``: 64 heads of 64 lanes, ``dt [1, 8192, 64]``, ``B``
+    and ``C`` ``[1, 8192, 128]`` shared by the heads) through Mosaic: the
+    program is ``ssd_fwd``, ``ssd_bwd`` and nothing else that walks 8,192 x
+    4,096 elements but what stands for the test's own sum.  No ``transpose``,
+    physical ``reshape``, ``reduce-window`` or cumulative sum: the heads are
+    found by the block specs, the decay is summed in VMEM, and the kernels'
+    operands and results are the model's arrays themselves.  The forward
+    kernel takes six arrays and gives ``y`` and the saved states; the backward
+    one eight, and gives the four arrays' gradients and a partial row a grid
+    step for ``A`` and ``D``."""
+    from adapcc_tpu.ops.ssd import ssd
+
+    _, cfg = _granite_cell()
+    T, H, P, N = 8192, cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def scan(x, dt, A, B, C, D):
+        return jnp.sum(ssd(x, dt, A, B, C, D, interpret=False).astype(jnp.float32))
+
+    args = (
+        shape((1, T, H * P)), shape((1, T, H), jnp.float32), shape((H,), jnp.float32), shape((1, T, N)),
+        shape((1, T, N)), shape((H,), jnp.float32),
+    )
+    compiled = jax.jit(jax.value_and_grad(scan, argnums=tuple(range(6)))).lower(*args).compile()
+    assert _kernels_in(compiled) == 2
+    text = compiled.as_text()
+    wide, states = r"bf16\[1,8192,4096\]\S*", r"f32\[1,16,128,4096\]\S*"
+    assert re.search(rf"%ssd_fwd[\w.]* = \({wide}, {states}\) custom-call\(%[\w.\-]+(, (/\*index=5\*/)?%[\w.\-]+){{5}}\),", text)
+    assert re.search(
+        rf"%ssd_bwd[\w.]* = \({wide}, f32\[1,8192,64\]\S*, bf16\[1,8192,128\]\S*, bf16\[1,8192,128\]\S*, "
+        rf"f32\[1,16,1,64\]\S*, (/\*index=5\*/)?f32\[1,16,1,4096\]\S*\) custom-call\(%[\w.\-]+(, (/\*index=5\*/)?%[\w.\-]+){{7}}\),",
+        text,
+    )
+    entry = text[text.index("ENTRY"):]      # a fusion's body has no pass of its own: its result in the entry counts
+    wide_results = {}
+    for name, out, op in re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", entry, re.M):
+        sizes = [int(np.prod([int(n) for n in dims.split(",")])) for dims in re.findall(r"\w+\[([\d,]+)\]", out)]
+        if T * H * P in sizes and op not in ("parameter", "get-tuple-element", "tuple", "bitcast"):
+            wide_results[name] = op
+    kernels = {name: op for name, op in wide_results.items() if op == "custom-call"}
+    assert sorted(name.split(".")[0] for name in kernels) == ["ssd_bwd", "ssd_fwd"], wide_results
+    others = {name: op for name, op in wide_results.items() if name not in kernels}
+    assert set(others.values()) <= {"broadcast"}, others            # the cotangent of the test's sum
+    assert not re.search(r" (transpose|reduce-window|cumsum)\(", text)
+
+
+def test_the_grouped_query_layer_compiles_at_head_64_over_8192_steps(one_chip):
+    """The cell's one attention layer in ten: 32 query heads on 8 K/V heads
+    at head size 64 and T = 8,192, scores scaled by ``attention_multiplier``:
+    a pair ``TILE_TABLE`` had not met (head 64 ran at T = 1,024 with equal
+    heads, grouped heads at T = 8,192 with heads of 128 and 192).  The row for
+    head sizes up to 128 at T = 8,192 holds it: 512-tiles, three kernels."""
+    from adapcc_tpu.ops import flash_attention
+    from adapcc_tpu.ops.flash_attention import default_blocks
+
+    _, cfg = _granite_cell()
+    assert default_blocks(8192, cfg.head_dim, jnp.bfloat16) == (512, 512)
+
+    def shape(dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    def grouped(q, k, v):
+        o = flash_attention(q, k, v, causal=True, scale=cfg.attention_multiplier, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    kv = shape((1, 8192, cfg.num_key_value_heads, cfg.head_dim))
+    compiled = jax.jit(jax.value_and_grad(grouped, argnums=(0, 1, 2))).lower(
+        shape((1, 8192, cfg.num_attention_heads, cfg.head_dim)), kv, kv
+    ).compile()
+    assert _kernels_in(compiled) == 3
+    assert re.search(r"%flash_bwd_dkv[\w.]* = \(f32\[32,8192,64\]\S*, f32\[32,8192,64\]", compiled.as_text())
+
+
+def test_the_state_space_cells_step_fits_the_chip(topo, monkeypatch):
+    """The whole donating step of ``granite-h-micro-vp8-train`` (772.16 M
+    float32 parameters with AdamW's moments, one row of 8,192 tokens through
+    nine Mamba-2 layers and one attention layer, the tied head inside the
+    chunked loss, the loss and remat the configuration file states) compiled
+    for the described chip: state and temporaries leave 5% of its 16 GiB
+    free, and the five kernels are in the program under their own names (the
+    device trace is read by them: chipbench/runners/train_ssm_lm.kernel_of).
+    Under ``dots`` what is no product is run again in the backward pass: the
+    scan's and the attention's forward kernels appear twice a layer."""
+    import optax
+
+    from adapcc_tpu.ddp.trainer import TrainState
+    from adapcc_tpu.models.granite_hybrid import initial_model_state
+    from adapcc_tpu.workloads.train_granite_hybrid import build_trainer
+
+    _scan_through_mosaic(monkeypatch)
+    config, cfg = _granite_cell()
+    mesh = Mesh(np.array(topo.devices[:1]), (RANKS_AXIS,))
+    tx = optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(1e-6, weight_decay=0.01))
+    program = config["assumed"]["program"]
+    assert (program["loss"], program["remat"]) == ("chunked", "dots")
+    trainer, model = build_trainer(cfg, tx, mesh, loss=program["loss"], donate_state=program["donate_state"])
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 64), jnp.int32))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)) == 772_160_448
+    state = jax.eval_shape(lambda p: TrainState.create(p, tx, model_state=initial_model_state()), params)
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=NamedSharding(mesh, P(RANKS_AXIS)))
+    compiled = trainer._build().lower(_shapes_on(state, NamedSharding(mesh, P())), tokens).compile()
+    text = compiled.as_text()
+    names = {name: len(re.findall(rf"^\s*%{name}[\w.]* = ", text, re.M)) for name in (
+        "ssd_fwd", "ssd_bwd", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+    )}
+    assert names == {"ssd_fwd": 18, "ssd_bwd": 9, "flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    for scope in ("ssd_conv", "ssd_gate", "ssd_scan", "gqa_attn"):
+        assert f"/{scope}/" in text, scope
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 0.95 * 16 * 2**30
+
+
 # --- the composed programs the old on-chip smoke covered ---------------------
 
 
