@@ -159,9 +159,89 @@ class KimiLinearConfig:
         return KimiLinearConfig(**base)
 
 
-def l2norm(x):
+@functools.lru_cache(maxsize=8)
+def _heads_matrix(width: int, heads: int):
+    """``[width, heads]`` float32 of zeros and ones: channel ``c`` belongs to
+    head ``c // (width / heads)``."""
+    return (np.arange(width)[:, None] // (width // heads) == np.arange(heads)[None, :]).astype(np.float32)
+
+
+def _head_sums(x32, heads: int):
+    """``x32 [B, T, H D]`` summed over each head's ``D`` channels: ``[B, T,
+    H]``.  On a TPU ``[B, T, H D]`` and ``[B, T, H, D]`` are two tilings, so a
+    sum over a reshaped last axis is a relayout and a pass of its own; the
+    product with the heads' 0/1 matrix is a sum XLA fuses its neighbours
+    into (float32 at ``highest``: a term a channel, each times one)."""
+    return jnp.einsum("btc,ch->bth", x32, _heads_matrix(x32.shape[-1], heads), precision="highest")
+
+
+def _head_spread(s, width: int):
+    """``s [B, T, H]`` float32 repeated over each head's channels: ``[B, T,
+    width]``, exact (one term a channel)."""
+    return jnp.einsum("bth,ch->btc", s, _heads_matrix(width, s.shape[-1]), precision="highest")
+
+
+def _normed(x, scale, heads: int, eps: float, share: float):
+    """``(y, r)``: ``y = x r [scale]`` in ``x``'s dtype with ``r [B, T, H] =
+    rsqrt(share * sum over a head of x^2 + eps)`` in float32; ``scale [D]`` is
+    one weight for every head, or None."""
     x32 = x.astype(jnp.float32)
-    return (x32 * jax.lax.rsqrt(jnp.sum(jnp.square(x32), axis=-1, keepdims=True) + L2_EPS)).astype(x.dtype)
+    r = jax.lax.rsqrt(share * _head_sums(jnp.square(x32), heads) + eps)
+    y = x32 * _head_spread(r, x.shape[-1])
+    if scale is not None:
+        y = y * jnp.tile(scale.astype(jnp.float32), heads)
+    return y.astype(x.dtype), r
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _head_norm(x, scale, heads: int, eps: float, share: float):
+    return _normed(x, scale, heads, eps, share)[0]
+
+
+def _head_norm_fwd(x, scale, heads, eps, share):
+    # kept for the backward pass: the input as it came and a statistic a head, nothing wide in float32
+    y, r = _normed(x, scale, heads, eps, share)
+    return y, (x, scale, r)
+
+
+def _head_norm_bwd(heads, eps, share, kept, dy):
+    x, scale, r = kept
+    width = x.shape[-1]
+    x32, z, wide_r = x.astype(jnp.float32), dy.astype(jnp.float32), _head_spread(r, width)
+    dscale = None
+    if scale is not None:
+        dscale = jnp.sum((z * x32 * wide_r).reshape(-1, heads, width // heads), axis=(0, 1)).astype(scale.dtype)
+        z = z * jnp.tile(scale.astype(jnp.float32), heads)
+    # y = x r(s) with s the head's sum of squares: dr/ds = -share r^3 / 2, ds/dx = 2 x
+    pull = share * _head_sums(z * x32, heads) * r ** 3
+    return (z * wide_r - x32 * _head_spread(pull, width)).astype(x.dtype), dscale
+
+
+_head_norm.defvjp(_head_norm_fwd, _head_norm_bwd)
+
+
+def l2norm(x, heads: int):
+    """``x rsqrt(sum of x^2 over a head + L2_EPS)`` for ``x [B, T, H D]``, head
+    ``h`` its channels ``h D … (h + 1) D``; the sum and the root in float32."""
+    return _head_norm(x, None, heads, L2_EPS, 1.0)
+
+
+def o_norm(x, scale, heads: int, eps: float):
+    """RMSNorm over each head of ``x [B, T, H D]`` with the one weight ``scale
+    [D]`` for every head: ``x rsqrt(mean of x^2 over a head + eps) scale``."""
+    return _head_norm(x, scale, heads, eps, heads / x.shape[-1])
+
+
+class HeadRMSNorm(nn.Module):
+    """:func:`o_norm` with its weight: ``scale [D]``, ones at the start
+    (``trinity.RMSNorm``'s parameter, over a head of the flat array)."""
+
+    eps: float
+    heads: int
+
+    @nn.compact
+    def __call__(self, x):
+        return o_norm(x, self.param("scale", nn.initializers.ones, (x.shape[-1] // self.heads,)), self.heads, self.eps)
 
 
 def short_conv(x, taps):
@@ -207,27 +287,25 @@ class KDAMixer(nn.Module):
         from adapcc_tpu.ops.kda import kda
 
         cfg = self.cfg
-        B, T, _ = x.shape
         H, D, K = cfg.linear_attn_num_heads, cfg.linear_attn_head_dim, cfg.short_conv_kernel_size
 
         def mixed(name):
             y = _dense(H * D, cfg, f"{name}_proj")(x)
-            return nn.silu(short_conv(y, self.param(f"{name}_conv", taps_init, (K, H * D)))).reshape(B, T, H, D)
+            return nn.silu(short_conv(y, self.param(f"{name}_conv", taps_init, (K, H * D))))
 
-        q, k, v = l2norm(mixed("q")), l2norm(mixed("k")), mixed("v")
+        # one layout from the projections through the scan to o_proj: [B, T, H D], head h its channels h D … (h + 1) D
+        q, k, v = l2norm(mixed("q"), H), l2norm(mixed("k"), H), mixed("v")
         with jax.named_scope("kda_gate"):
             a_log = self.param("A_log", a_log_init, (H,))
             dt_bias = self.param("dt_bias", dt_bias_init, (H * D,))
             f = _dense(H * D, cfg, "f_b_proj")(_dense(D, cfg, "f_a_proj")(x))
-            g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(
-                f.astype(jnp.float32) + dt_bias
-            ).reshape(B, T, H, D)
+            g = jnp.repeat(-jnp.exp(a_log.astype(jnp.float32)), D) * jax.nn.softplus(f.astype(jnp.float32) + dt_bias)
             beta = jax.nn.sigmoid(_dense(H, cfg, "b_proj")(x).astype(jnp.float32))
         with jax.named_scope("kda_scan"):
             o = kda(q, k, v, g, beta)
-        o = RMSNorm(cfg.rms_norm_eps, name="o_norm")(o)     # one weight of head_dim for every head
+        o = HeadRMSNorm(cfg.rms_norm_eps, H, name="o_norm")(o)     # one weight of head_dim for every head
         gate = _dense(H * D, cfg, "g_b_proj")(_dense(D, cfg, "g_a_proj")(x))
-        return _dense(cfg.hidden_size, cfg, "o_proj")(o.reshape(B, T, H * D) * jax.nn.sigmoid(gate))
+        return _dense(cfg.hidden_size, cfg, "o_proj")(o * jax.nn.sigmoid(gate))
 
 
 @functools.lru_cache(maxsize=8)

@@ -41,25 +41,28 @@ at full precision.  The other products take their operands in the inputs'
 dtype (bfloat16 in a bf16 model) and accumulate in float32, as the flash
 kernels do.
 
-**The kernels read and write the model's own arrays.**  ``q, k, g [B, T, H,
-d_k]``, ``v [B, T, H, d_v]`` and ``beta [B, T, H]`` go into the two
-``pallas_call``s as they are, and ``o``, ``dq``, ``dk``, ``dv``, ``dg``,
-``dbeta`` come out in the same shapes: XLA adds no transpose, copy or sum
-around them (``tests/test_chip_compile.py`` holds that).  On the chip such an
-array's tile is heads x channels of one step, so a block of ``rows`` steps is
-``[rows H, d]`` in memory and a head's chunk is every ``H``-th row of it
-(:class:`_Rows`).  The grid is ``(B, T / rows, H / 2)``: a block of rows over
-every head stays in VMEM while the grid walks its heads two at a time (two
-bfloat16 rows share a 32-bit word, and only words are read ``H`` rows apart).
-Formed in VMEM, where a chunk is first loaded: ``G``, by six shifted adds
-down the chunk's rows, and ``kb = beta k`` in float32, a head's ``beta`` picked
-out of the ``[rows, H]`` block.  On the way back: ``dg`` by the same adds up
-the rows, ``beta dkb`` folded into ``dk``, and ``dbeta = sum_c dkb k`` written
-into the head's column of its block.  Through Mosaic ``d_k`` and ``d_v`` are
-multiples of 128 (a row is whole lane tiles) and bfloat16 heads come in pairs;
-the interpreter takes any shape.  Where ``T`` is no whole number of chunks the
-five inputs are padded along ``T``, the one copy left (gauge
-``kda.padded_rows``).
+**The kernels read and write the model's own arrays.**  ``q, k, g [B, T, H
+d_k]``, ``v [B, T, H d_v]`` and ``beta [B, T, H]`` go into the two
+``pallas_call``s as the mixer's projections wrote them, and ``o``, ``dq``,
+``dk``, ``dv``, ``dg``, ``dbeta`` come out in the same shapes: XLA adds no
+transpose, copy or sum around them (``tests/test_chip_compile.py`` holds
+that).  **A head is a lane block**: head ``h`` is channels ``h d … (h + 1) d``
+of the last axis, so a head's chunk is ``[C, d]`` contiguous rows of its
+block, whole ``(16, 128)`` tiles in HBM, and no ``[B, T, H, d]`` array exists
+on either side of the call (on a TPU that is another tiling of the same
+bytes, and every crossing a pass over HBM).  The grid is ``(B, T / rows, H /
+2)`` with blocks ``(1, rows, 2 d)`` at lane-block index ``p``: a grid step
+takes two heads' rows, its two state chains written out side by side, and
+every head's state waits in scratch (``[H, d_v, d_k]`` float32) for the next
+block of rows.  ``beta``'s block is ``[rows, H]`` and stays in VMEM while the
+grid walks the heads.  Formed in VMEM, where a chunk is first loaded: ``G``, by
+six shifted adds down the chunk's rows, and ``kb = beta k`` in float32, a head's
+``beta`` picked out of the ``[rows, H]`` block.  On the way back: ``dg`` by the
+same adds up the rows, ``beta dkb`` folded into ``dk``, and ``dbeta = sum_c dkb
+k`` written into the head's column of its block.  Through Mosaic ``d_k`` and
+``d_v`` are multiples of 128 (a head is whole lane tiles); the interpreter
+takes any head size.  Where ``T`` is no whole number of chunks the five inputs
+are padded along ``T``, the one copy left (gauge ``kda.padded_rows``).
 
 **Backward.**  The forward kernel keeps the state at the start of every grid
 step (``[B H, T / rows, d_v, d_k]`` float32: 64 KB each).  The backward kernel
@@ -91,8 +94,8 @@ from adapcc_tpu.utils.observability import default_registry
 
 _SUB = 8       # rows of a sub-block: pairs inside one are formed on the VPU
 _CHUNK = 64    # rows of a chunk: eight sub-blocks, one solve
-_BLOCK = 512   # rows of a grid step at most: eight chunks behind one DMA
-_SLAB = 1 << 20  # elements of a grid step's rows over every head at most: the backward kernel holds nine such blocks, twice
+_BLOCK = 256   # rows of a grid step at most: four chunks of each of its heads behind one DMA
+_GROUP = 2     # heads of a grid step: their state chains run in each other's waits
 _LANES = 128   # through Mosaic a head's channels are whole lane tiles
 _NEG = -1e30
 
@@ -101,17 +104,14 @@ _NT = ((1,), (1,))
 _TN = ((0,), (0,))
 
 
-def chunk_plan(T: int, width: int = 0) -> Tuple[int, int, int]:
+def chunk_plan(T: int) -> Tuple[int, int, int]:
     """``(chunk, chunks per grid step, padded T)`` for a sequence of ``T``
-    steps whose every step is ``width`` elements over all heads: chunks of 64
-    (a short sequence: what holds it, in sub-blocks), as many to a grid step
-    as divide the padded length, eight at most, and no more than keep a block
-    of rows within ``_SLAB`` elements."""
+    steps: chunks of 64 (a short sequence: what holds it, in sub-blocks), as
+    many to a grid step as divide the padded length, ``_BLOCK`` rows at most."""
     chunk = min(_CHUNK, -(-T // _SUB) * _SUB)
     padded = -(-T // chunk) * chunk
     n = padded // chunk
-    fits = lambda p: p * chunk <= _BLOCK and n % p == 0 and p * chunk * width <= _SLAB  # noqa: E731
-    return chunk, max(p for p in (8, 4, 2, 1) if p == 1 or fits(p)), padded
+    return chunk, max(p for p in (8, 4, 2, 1) if p == 1 or (p * chunk <= _BLOCK and n % p == 0)), padded
 
 
 class _Geometry(NamedTuple):
@@ -339,56 +339,30 @@ def _at(c, chunk: int):
     return pl.ds(c * chunk if isinstance(c, int) else pl.multiple_of(c * chunk, chunk), chunk)
 
 
-class _Rows(NamedTuple):
-    """How a grid step's rows lie in its blocks.  A block is the model's own
-    ``[rows, H, d]``; in memory a tile is heads x channels of one step, so a
-    head's chunk is every ``H``-th row of ``[rows H, d]``.  ``mosaic``: the
-    kernel runs through Mosaic, which reads and writes rows ``H`` apart as
-    32-bit words only: two bfloat16 rows share a sublane of words (the even
-    row the low halves), so a pair of heads is read as words and a head
-    written into its half.  The interpreter indexes the head."""
+class _Heads(NamedTuple):
+    """What a grid step walks: ``group`` heads, lane blocks ``first … first +
+    group`` of the flat arrays, each ``per`` chunks of ``chunk`` rows."""
 
     H: int          # heads
-    group: int      # heads a grid step walks: a packed pair, or one
+    group: int      # heads a grid step walks: ``_GROUP``, or one where that does not divide the count
     per: int        # chunks of each
     chunk: int
-    mosaic: bool
 
     @classmethod
-    def plan(cls, H: int, chunk: int, per: int, interp) -> "_Rows":
-        return cls(H=H, group=2 - H % 2, per=per, chunk=chunk, mosaic=not interp)
+    def plan(cls, H: int, chunk: int, per: int) -> "_Heads":
+        return cls(H=H, group=1 if H % _GROUP else _GROUP, per=per, chunk=chunk)
 
-    def _words(self, ref, h, c):
-        """Head ``h``'s chunk ``c`` as rows of 32-bit words ``H`` rows apart:
-        ``(view, index, bits a bfloat16 head sits above the word's low end or
-        None)``."""
-        rows, H, d = ref.shape[1:]
-        view = ref.at[0].reshape(rows * H, d)
-        if ref.dtype != jnp.bfloat16:
-            return view, (pl.ds(c * self.chunk * H + h, self.chunk, stride=H), slice(None)), None
-        at = (pl.ds(c * self.chunk * (H // 2) + h // 2, self.chunk, stride=H // 2), slice(None))
-        return view.bitcast(jnp.uint32), at, (16 * (h % 2)).astype(jnp.uint32)
+    def _block(self, ref, j: int, c):
+        d = ref.shape[2] // self.group
+        return (0, _at(c, self.chunk), slice(j * d, (j + 1) * d))
 
-    def read(self, ref, h, c):
-        """Chunk ``c`` of head ``h`` from ``ref``: ``[C, d]`` float32."""
-        if not self.mosaic:
-            return ref[0, _at(c, self.chunk), h, :].astype(jnp.float32)
-        view, at, up = self._words(ref, h, c)
-        if up is None:
-            return view[at]
-        return lax.bitcast_convert_type((view[at] >> up) << 16, jnp.float32)
+    def read(self, ref, j: int, c):
+        """Chunk ``c`` of the block's ``j``-th head from ``ref``: ``[C, d]`` float32."""
+        return ref[self._block(ref, j, c)].astype(jnp.float32)
 
-    def write(self, ref, h, c, x) -> None:
-        """``x [C, d]`` float32 into chunk ``c`` of head ``h`` of ``ref``."""
-        if not self.mosaic:
-            ref[0, _at(c, self.chunk), h, :] = x.astype(ref.dtype)
-            return
-        view, at, up = self._words(ref, h, c)
-        if up is None:
-            view[at] = x
-            return
-        half = lax.bitcast_convert_type(x.astype(jnp.bfloat16).astype(jnp.float32), jnp.uint32) >> 16
-        view[at] = (view[at] & (jnp.uint32(0xFFFF0000) >> up)) | (half << up)       # the other head's half stays
+    def write(self, ref, j: int, c, x) -> None:
+        """``x [C, d]`` float32 into chunk ``c`` of the block's ``j``-th head."""
+        ref[self._block(ref, j, c)] = x.astype(ref.dtype)
 
     def column(self, ref, h, c):
         """Head ``h``'s column of chunk ``c`` of a ``[rows, H]`` block (``beta``): ``[C, 1]``."""
@@ -404,11 +378,11 @@ class _Rows(NamedTuple):
         ref[at] = jnp.where(head, x, ref[at])
 
 
-def _solve_chunks(q_ref, k_ref, g_ref, beta_ref, first, qs, ks, Gs, kbs, invs, bqs, geo: _Geometry, lay: _Rows, dtype) -> None:
+def _solve_chunks(q_ref, k_ref, g_ref, beta_ref, first, qs, ks, Gs, kbs, invs, bqs, geo: _Geometry, lay: _Heads, dtype) -> None:
     """For every chunk of the grid step's heads (head ``first + j``'s chunk
-    ``c`` is unit ``j per + c``): ``q`` and ``k`` as float32 rows one after
-    another into ``qs`` and ``ks`` (read ``H`` rows apart once, not once a
-    pass), the summed decay ``G`` into ``Gs``, ``beta k`` into ``kbs`` (scratch
+    ``c`` is unit ``j per + c``): ``q`` and ``k`` as float32 into ``qs`` and
+    ``ks`` (converted once, not once a pass), the summed decay ``G`` into
+    ``Gs``, ``beta k`` into ``kbs`` (scratch
     ``[units, C, d_k]`` float32), ``Phi(q, kb)`` into ``bqs`` and ``(I + A)^-1``
     into ``invs`` (scratch ``[units, C, C]``): the scores a chunk at a time,
     the inverses together."""
@@ -417,9 +391,9 @@ def _solve_chunks(q_ref, k_ref, g_ref, beta_ref, first, qs, ks, Gs, kbs, invs, b
 
         def scores(c, carry):
             u = j * lay.per + c
-            qs[u] = q = lay.read(q_ref, h, c)
-            ks[u] = k = lay.read(k_ref, h, c)
-            g = lay.read(g_ref, h, c)
+            qs[u] = q = lay.read(q_ref, j, c)
+            ks[u] = k = lay.read(k_ref, j, c)
+            g = lay.read(g_ref, j, c)
             Gs[u] = G = _running_sum(g)
             kbs[u] = kb = k * lay.column(beta_ref, h, c)
             invs[u], bqs[u] = chunk_scores(q, k, kb, G, geo, dtype)
@@ -450,9 +424,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, start_ref, state, q
         def one(c, St):
             u = j * lay.per + c
             o, _, St = chunk_state(
-                qs[u], ks[u], kbs[u], lay.read(v_ref, h, c), Gs[u], St, invs[u], bqs[u], dtype, scale
+                qs[u], ks[u], kbs[u], lay.read(v_ref, j, c), Gs[u], St, invs[u], bqs[u], dtype, scale
             )
-            lay.write(o_ref, h, c, o)
+            lay.write(o_ref, j, c, o)
             return St
 
         state[h] = _chunks(lay.per, one, state[h])
@@ -483,7 +457,7 @@ def _bwd_kernel(
             u = j * per + c
             states[u] = St
             _, us[u], St = chunk_state(
-                qs[u], ks[u], kbs[u], lay.read(v_ref, h, c), Gs[u], St, invs[u], bqs[u], dtype, scale
+                qs[u], ks[u], kbs[u], lay.read(v_ref, j, c), Gs[u], St, invs[u], bqs[u], dtype, scale
             )
             return St
 
@@ -494,12 +468,12 @@ def _bwd_kernel(
             u = j * per + c
             k = ks[u]
             dq, dk, dkb, dv, dG, dSt = chunk_backward(
-                qs[u], k, kbs[u], Gs[u], states[u], us[u], invs[u], bqs[u], lay.read(do_ref, h, c), dSt, geo, dtype, scale
+                qs[u], k, kbs[u], Gs[u], states[u], us[u], invs[u], bqs[u], lay.read(do_ref, j, c), dSt, geo, dtype, scale
             )
-            lay.write(dq_ref, h, c, dq)
-            lay.write(dk_ref, h, c, dk + lay.column(beta_ref, h, c) * dkb)
-            lay.write(dv_ref, h, c, dv)
-            lay.write(dg_ref, h, c, _running_sum(dG, reverse=True))     # a step's decay is in every later row's G
+            lay.write(dq_ref, j, c, dq)
+            lay.write(dk_ref, j, c, dk + lay.column(beta_ref, h, c) * dkb)
+            lay.write(dv_ref, j, c, dv)
+            lay.write(dg_ref, j, c, _running_sum(dG, reverse=True))     # a step's decay is in every later row's G
             lay.write_column(dbeta_ref, h, c, jnp.sum(dkb * k, axis=1, keepdims=True))
             return dSt
 
@@ -509,8 +483,8 @@ def _bwd_kernel(
 
 
 def _params(interp, blocks: int):
-    """The blocks of rows over every head are held twice (one in flight);
-    Mosaic's default scoped limit is 16 MiB."""
+    """A grid step's blocks are held twice (one in flight); Mosaic's default
+    scoped limit is 16 MiB."""
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         vmem_limit_bytes=None if interp else min(100 * 2**20, 2 * blocks + 16 * 2**20),
@@ -520,19 +494,19 @@ def _params(interp, blocks: int):
 # behind jax.jit, as the flash kernels are: a model's layers share one traced kernel
 @functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
 def _fwd_call(q, k, v, g, beta, scale, chunk, per, interp):
-    B, T, H, dk = q.shape
-    dv = v.shape[-1]
-    lay = _Rows.plan(H, chunk, per, interp)
+    B, T, H = beta.shape
+    dk, dv = q.shape[-1] // H, v.shape[-1] // H
+    lay = _Heads.plan(H, chunk, per)
     rows, units = chunk * per, lay.group * per
     steps = T // rows
-    wide = lambda d: pl.BlockSpec((1, rows, H, d), lambda b, i, p: (b, i, 0, 0))  # noqa: E731
-    blocks = rows * H * ((2 * dk + 2 * dv) * q.dtype.itemsize + dk * 4)
+    heads = lambda d: pl.BlockSpec((1, rows, lay.group * d), lambda b, i, p: (b, i, p))  # noqa: E731
+    blocks = rows * (lay.group * ((2 * dk + 2 * dv) * q.dtype.itemsize + dk * 4) + H * 4)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, lay=lay, scale=scale),
         grid=(B, steps, H // lay.group),
-        in_specs=[wide(dk), wide(dk), wide(dv), wide(dk), pl.BlockSpec((1, rows, H), lambda b, i, p: (b, i, 0))],
+        in_specs=[heads(dk), heads(dk), heads(dv), heads(dk), pl.BlockSpec((1, rows, H), lambda b, i, p: (b, i, 0))],
         out_specs=[
-            wide(dv),
+            heads(dv),
             pl.BlockSpec((lay.group, 1, dv, dk), lambda b, i, p: (b * (H // lay.group) + p, i, 0, 0)),
         ],
         out_shape=[
@@ -552,23 +526,23 @@ def _fwd_call(q, k, v, g, beta, scale, chunk, per, interp):
 
 @functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
 def _bwd_call(q, k, v, g, beta, starts, do, scale, chunk, per, interp):
-    B, T, H, dk = q.shape
-    dv = v.shape[-1]
-    lay = _Rows.plan(H, chunk, per, interp)
+    B, T, H = beta.shape
+    dk, dv = q.shape[-1] // H, v.shape[-1] // H
+    lay = _Heads.plan(H, chunk, per)
     rows, units = chunk * per, lay.group * per
     steps = T // rows
-    wide = lambda d: pl.BlockSpec((1, rows, H, d), lambda b, i, p: (b, steps - 1 - i, 0, 0))  # noqa: E731
+    heads = lambda d: pl.BlockSpec((1, rows, lay.group * d), lambda b, i, p: (b, steps - 1 - i, p))  # noqa: E731
     every = pl.BlockSpec((1, rows, H), lambda b, i, p: (b, steps - 1 - i, 0))
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
-    blocks = rows * H * ((4 * dk + 3 * dv) * q.dtype.itemsize + 2 * dk * 4)
+    blocks = rows * (lay.group * ((4 * dk + 3 * dv) * q.dtype.itemsize + 2 * dk * 4) + 2 * H * 4)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, lay=lay, scale=scale),
         grid=(B, steps, H // lay.group),
         in_specs=[
-            wide(dk), wide(dk), wide(dv), wide(dk), every, wide(dv),
+            heads(dk), heads(dk), heads(dv), heads(dk), every, heads(dv),
             pl.BlockSpec((lay.group, 1, dv, dk), lambda b, i, p: (b * (H // lay.group) + p, steps - 1 - i, 0, 0)),
         ],
-        out_specs=[wide(dk), wide(dk), wide(dv), wide(dk), every],
+        out_specs=[heads(dk), heads(dk), heads(dv), heads(dk), every],
         out_shape=[like(q), like(k), like(v), like(g), like(beta)],
         scratch_shapes=[
             pltpu.VMEM((H, dv, dk), jnp.float32),
@@ -610,30 +584,37 @@ def kda(
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """The gated delta rule over ``q, k [B, T, H, d_k]``, ``v [B, T, H, d_v]``,
-    the log-decay ``g [B, T, H, d_k]`` (``<= 0``) and ``beta [B, T, H]``, from a
-    zero state: ``o [B, T, H, d_v]`` in ``v``'s dtype.  ``g`` and ``beta`` are
-    taken in float32 whatever they come in; ``scale`` defaults to
-    ``1 / sqrt(d_k)``.  Differentiable in all five.  ``interpret=None`` asks
-    :func:`ops.kernel_mode.resolve_interpret` (site ``"kda"``)."""
-    B, T, H, dk = q.shape
-    dv = v.shape[-1]
-    if k.shape != q.shape or v.shape[:3] != q.shape[:3] or g.shape != q.shape or beta.shape != q.shape[:3]:
-        raise ValueError(f"kda shapes: q {q.shape} k {k.shape} v {v.shape} g {g.shape} beta {beta.shape}")
+    """The gated delta rule over ``q, k [B, T, H d_k]``, ``v [B, T, H d_v]``,
+    the log-decay ``g [B, T, H d_k]`` (``<= 0``) and ``beta [B, T, H]``, from a
+    zero state: ``o [B, T, H d_v]`` in ``v``'s dtype.  Head ``h`` is channels
+    ``h d … (h + 1) d`` of each; the head count is ``beta``'s last axis.  ``g``
+    and ``beta`` are taken in float32 whatever they come in; ``scale``
+    defaults to ``1 / sqrt(d_k)``.  Differentiable in all five.
+    ``interpret=None`` asks :func:`ops.kernel_mode.resolve_interpret` (site
+    ``"kda"``)."""
+    shapes = f"kda shapes: q {q.shape} k {k.shape} v {v.shape} g {g.shape} beta {beta.shape}"
+    H = beta.shape[-1]
+    if (
+        any(x.ndim != 3 or x.shape[:2] != beta.shape[:2] for x in (q, k, v, g, beta))
+        or k.shape != q.shape or g.shape != q.shape or q.shape[-1] % H or v.shape[-1] % H
+    ):
+        raise ValueError(shapes)
+    B, T, _ = beta.shape
+    dk, dv = q.shape[-1] // H, v.shape[-1] // H
     interp = resolve_interpret(interpret, "kda")
-    if not interp and (dk % _LANES or dv % _LANES or (q.dtype == jnp.bfloat16 and H % 2) or q.dtype == jnp.float16):
+    if not interp and (dk % _LANES or dv % _LANES):
         raise ValueError(
-            f"kda through Mosaic reads a head as every H-th row of [B, T H, d]: d_k {dk} and d_v {dv} must be "
-            f"multiples of {_LANES}, and bfloat16 heads ({H}) come in pairs; float16 is not read"
+            f"kda through Mosaic reads a head as a lane block of [B, T, H d]: d_k {dk} and d_v {dv} must be "
+            f"multiples of {_LANES} ({shapes})"
         )
     if scale is None:
         scale = float(1.0 / math.sqrt(dk))
-    chunk, per, padded = chunk_plan(T, H * max(dk, dv))
+    chunk, per, padded = chunk_plan(T)
     metrics = default_registry()
     metrics.gauge("kda.chunk", chunk)
     metrics.gauge("kda.tiles", B * H * (padded // chunk))
     metrics.gauge("kda.padded_rows", padded - T)
     args = [q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32)]
     if padded != T:     # a padded step (g = 0, k = v = 0) forgets and writes nothing
-        args = [jnp.pad(x, ((0, 0), (0, padded - T)) + ((0, 0),) * (x.ndim - 2)) for x in args]
+        args = [jnp.pad(x, ((0, 0), (0, padded - T), (0, 0))) for x in args]
     return _kda_chunked(*args, scale, chunk, per, interp)[:, :T]

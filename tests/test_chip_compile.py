@@ -438,17 +438,17 @@ def _kimi_cell():
 
 
 def _mixers_through_mosaic(monkeypatch):
-    import sys
+    import importlib
 
     _flash_through_mosaic(monkeypatch)
-    monkeypatch.setattr(sys.modules["adapcc_tpu.ops.kda"], "resolve_interpret", lambda interpret, site: False)
+    monkeypatch.setattr(importlib.import_module("adapcc_tpu.ops.kda"), "resolve_interpret", lambda interpret, site: False)
 
 
 def test_the_kda_scan_and_the_latent_attention_compile_at_the_hybrid_cells_shapes(one_chip):
     """``kimi-linear-ep32-train``'s two mixers' kernels, forward and backward,
     through Mosaic: the chunked scan over 32 heads of 128 at T = 8,192 (two
-    kernels over the model's ``[1, 8192, 32, 128]`` arrays, a head every 32nd
-    row of a block of 256 steps; the backward one holds eight chunks' summed decays, ``beta k``, inverses,
+    kernels over the model's ``[1, 8192, 4096]`` arrays, a head a block of 128
+    lanes, two heads and 256 steps a grid step; the backward one holds eight chunks' summed decays, ``beta k``, inverses,
     writes and states in scratch), and the flash kernels with scores over 192 channels and
     values over 128 (three)."""
     from adapcc_tpu.ops import flash_attention
@@ -463,16 +463,16 @@ def test_the_kda_scan_and_the_latent_attention_compile_at_the_hybrid_cells_shape
     def scan(q, k, v, g, beta):
         return jnp.sum(kda(q, k, v, g, beta, interpret=False).astype(jnp.float32))
 
-    wide = shape((1, T, H, D))
+    wide = shape((1, T, H * D))
     compiled = jax.jit(jax.value_and_grad(scan, argnums=(0, 1, 2, 3, 4))).lower(
-        wide, wide, wide, shape((1, T, H, D), jnp.float32), shape((1, T, H), jnp.float32)
+        wide, wide, wide, shape((1, T, H * D), jnp.float32), shape((1, T, H), jnp.float32)
     ).compile()
     assert _kernels_in(compiled) == 2
     # five operands and seven: q, k, v, the decay, beta; + do and the saved states (chipbench/trace_hybrid_lm.kernel_of)
-    flat, beta, states = r"bf16\[1,8192,32,128\]\S*", r"f32\[1,8192,32\]\S*", r"f32\[32,32,128,128\]\S*"
+    flat, beta, states = r"bf16\[1,8192,4096\]\S*", r"f32\[1,8192,32\]\S*", r"f32\[32,32,128,128\]\S*"
     assert re.search(rf"%kda_fwd[\w.]* = \({flat}, {states}\) custom-call\(%[\w.\-]+(, %[\w.\-]+){{4}}\),", compiled.as_text())
     assert re.search(
-        rf"%kda_bwd[\w.]* = \({flat}, {flat}, {flat}, f32\[1,8192,32,128\]\S*, {beta}\) custom-call\(%[\w.\-]+(, (/\*index=5\*/)?%[\w.\-]+){{6}}\),",
+        rf"%kda_bwd[\w.]* = \({flat}, {flat}, {flat}, f32\[1,8192,4096\]\S*, {beta}\) custom-call\(%[\w.\-]+(, (/\*index=5\*/)?%[\w.\-]+){{6}}\),",
         compiled.as_text(),
     )
 
@@ -511,8 +511,8 @@ def test_the_kda_scan_and_the_latent_attention_compile_at_the_hybrid_cells_shape
 
 
 def test_the_kda_scan_is_its_two_kernels_and_no_pass_of_xlas_around_them(one_chip):
-    """``value_and_grad`` of ``kda`` at the cell's shape, ``[1, 8192, 32,
-    128]``: the program is ``kda_fwd``, ``kda_bwd`` and nothing else that
+    """``value_and_grad`` of ``kda`` at the cell's shape, ``[1, 8192,
+    4096]``: the program is ``kda_fwd``, ``kda_bwd`` and nothing else that
     walks 8,192 x 4,096 elements but what stands for the test's own sum (a
     reduction to a scalar, its cotangent's broadcast).  No ``transpose``,
     ``copy``, physical ``reshape``, ``reduce-window`` or any other fusion: XLA
@@ -530,9 +530,9 @@ def test_the_kda_scan_is_its_two_kernels_and_no_pass_of_xlas_around_them(one_chi
     def scan(q, k, v, g, beta):
         return jnp.sum(kda(q, k, v, g, beta, interpret=False).astype(jnp.float32))
 
-    wide = shape((1, T, H, D))
+    wide = shape((1, T, H * D))
     text = jax.jit(jax.value_and_grad(scan, argnums=(0, 1, 2, 3, 4))).lower(
-        wide, wide, wide, shape((1, T, H, D), jnp.float32), shape((1, T, H), jnp.float32)
+        wide, wide, wide, shape((1, T, H * D), jnp.float32), shape((1, T, H), jnp.float32)
     ).compile().as_text()
     entry = text[text.index("ENTRY"):]      # a fusion's body has no pass of its own: its result in the entry counts
     wide_results = {}
@@ -548,37 +548,83 @@ def test_the_kda_scan_is_its_two_kernels_and_no_pass_of_xlas_around_them(one_chi
     assert re.search(r"%kda_fwd[\w.]* = .* custom-call\(%q[\w.]*, %k[\w.]*, %v[\w.]*, %g[\w.]*, ", entry)
 
 
-def test_the_hybrid_cells_step_fits_the_chip(topo, monkeypatch):
+@pytest.fixture(scope="module")
+def hybrid_step(topo):
     """The whole donating step of ``kimi-linear-ep32-train`` (602 M float32
     parameters with AdamW's moments, one row of 8,192 tokens, the loss and
-    remat the configuration file states) compiled for the described chip:
-    state and temporaries leave 5% of its 16 GiB free, and the five kernels
-    are in the program under their own names (the device trace is read by
-    them: chipbench/trace_hybrid_lm.py)."""
+    remat the configuration file states) compiled for the described chip,
+    once a module: ``(compiled, its text)``."""
     import optax
 
     from adapcc_tpu.ddp.trainer import TrainState
     from adapcc_tpu.models.trinity import initial_model_state
     from adapcc_tpu.workloads.train_kimi_linear import build_trainer
 
-    _mixers_through_mosaic(monkeypatch)
-    config, cfg = _kimi_cell()
-    mesh = Mesh(np.array(topo.devices[:1]), (RANKS_AXIS,))
-    tx = optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(1e-6, weight_decay=0.01))
-    program = config["assumed"]["program"]
-    trainer, model = build_trainer(cfg, tx, mesh, loss=program["loss"], donate_state=program["donate_state"])
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 64), jnp.int32))
-    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)) == 602_434_432
-    state = jax.eval_shape(lambda p: TrainState.create(p, tx, model_state=initial_model_state(cfg)), params)
-    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=NamedSharding(mesh, P(RANKS_AXIS)))
-    compiled = trainer._build().lower(_shapes_on(state, NamedSharding(mesh, P())), tokens).compile()
-    text = compiled.as_text()
+    with pytest.MonkeyPatch.context() as patch:
+        _mixers_through_mosaic(patch)
+        config, cfg = _kimi_cell()
+        mesh = Mesh(np.array(topo.devices[:1]), (RANKS_AXIS,))
+        tx = optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(1e-6, weight_decay=0.01))
+        program = config["assumed"]["program"]
+        trainer, model = build_trainer(cfg, tx, mesh, loss=program["loss"], donate_state=program["donate_state"])
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 64), jnp.int32))
+        assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)) == 602_434_432
+        state = jax.eval_shape(lambda p: TrainState.create(p, tx, model_state=initial_model_state(cfg)), params)
+        tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=NamedSharding(mesh, P(RANKS_AXIS)))
+        compiled = trainer._build().lower(_shapes_on(state, NamedSharding(mesh, P())), tokens).compile()
+    return compiled, compiled.as_text()
+
+
+def test_the_hybrid_cells_step_fits_the_chip(hybrid_step):
+    """The whole donating step of ``kimi-linear-ep32-train`` compiled for the
+    described chip: state and temporaries leave 5% of its 16 GiB free, and the
+    five kernels are in the program under their own names (the device trace
+    is read by them: chipbench/trace_hybrid_lm.py)."""
+    compiled, text = hybrid_step
     names = {name: len(re.findall(rf"^\s*%{name}[\w.]* = ", text, re.M)) for name in (
         "kda_fwd", "kda_bwd", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
     )}
     assert names == {"kda_fwd": 4, "kda_bwd": 4, "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 0.95 * 16 * 2**30
+
+
+def test_the_hybrid_cells_kda_layers_keep_one_layout(hybrid_step):
+    """``KDAMixer`` works on ``[B, T, H D]`` from its projections through the
+    scan to ``o_proj``.  On a TPU ``[1, 8192, 32, 128]`` is another tiling of
+    the same bytes, and each crossing between the two is a pass over HBM: in
+    the cell's whole step no instruction's result has that shape but the
+    latent layer's own (its ``k_nope`` and ``v`` are 32 heads of 128 for the
+    flash kernels), and inside the four KDA mixers (by ``op_name`` scope) no
+    ``copy``, ``transpose`` or physical ``reshape``, alone or as a fusion's
+    root, results in 8,192 x 4,096 elements."""
+    _, text = hybrid_step
+    kinds = _kimi_cell()[1].kinds
+    kda_layers, latent = [i for i, kind in enumerate(kinds) if kind == "kda"], kinds.index("mla")
+    assert (kda_layers, latent) == ([0, 1, 2, 4], 3)
+    scope = re.compile(rf"layers_({'|'.join(map(str, kda_layers))})/self_attn/")
+    roots = dict(re.findall(r"^%?([\w.\-]+) \([^\n]*\n(?:[^\n]+\n)*?\s*ROOT %[\w.\-]+ = \S+ ([\w\-]+)\(", text, re.M))
+    inside, moved, by_heads = 0, {}, {}
+    for line in text[text.index("ENTRY"):].splitlines():
+        found = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if not found:
+            continue
+        name, out, op = found.groups()
+        name_of = re.search(r'op_name="([^"]*)"', line)
+        where = name_of.group(1) if name_of else ""
+        if re.search(r"\[(1,)?8192,32,128\]", out) and op != "bitcast" and f"layers_{latent}/self_attn/" not in where:
+            by_heads[name] = (op, out, where)
+        if not scope.search(where):
+            continue
+        inside += 1
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        kind = roots.get(called.group(1), op) if op == "fusion" and called else op
+        sizes = [int(np.prod([int(n) for n in dims.split(",")])) for dims in re.findall(r"\w+\[([\d,]+)\]", out)]
+        if 8192 * 4096 in sizes and kind in ("copy", "transpose", "reshape"):
+            moved[name] = (kind, out)
+    assert inside > 100, inside            # the scope's name still finds the mixers' instructions
+    assert not by_heads, by_heads
+    assert not moved, moved
 
 
 # --- the latent-attention cell: six rotated latent blocks, two heads, one step ---

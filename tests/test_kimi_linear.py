@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from adapcc_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig, MLAMixer, l2norm, short_conv
+from adapcc_tpu.models.kimi_linear import KDAMixer, KimiLinear, KimiLinearConfig, MLAMixer, l2norm, o_norm, short_conv
 from adapcc_tpu.models.moe import routed_experts
 from adapcc_tpu.models.trinity import initial_model_state, stateful_loss
 from adapcc_tpu.ops.kda import chunk_plan, kda
@@ -69,18 +69,35 @@ def reference(params, tokens):
 # --- the kernel --------------------------------------------------------------
 
 
+def heads(x, H):
+    """The flat ``[B, T, H d]`` as ``[B, T, H, d]``: how the oracles see a head."""
+    return x.reshape(*x.shape[:2], H, -1)
+
+
+def flat(x):
+    return x.reshape(*x.shape[:2], -1)
+
+
 def scan_inputs(T, seed, decay, B=1, H=2, dk=16, dv=8):
+    """The scan's five arguments as the mixer hands them over, a head a block
+    of channels of ``[B, T, H d]``, and a cotangent for its output."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    q, k = (l2norm(jax.random.normal(key, (B, T, H, dk))) for key in ks[:2])
-    v = jax.random.normal(ks[2], (B, T, H, dv))
+    q, k = (flat(kimi_linear_ref.l2norm(jax.random.normal(key, (B, T, H, dk)))) for key in ks[:2])
+    v = jax.random.normal(ks[2], (B, T, H * dv))
     lo, hi = {"near-one": (1e-5, 1e-3), "near-zero": (5.0, 40.0), "every-rate": (1e-4, 30.0)}[decay]
-    g = -jnp.exp(jax.random.uniform(ks[3], (B, T, H, dk), minval=math.log(lo), maxval=math.log(hi)))
+    g = -jnp.exp(jax.random.uniform(ks[3], (B, T, H * dk), minval=math.log(lo), maxval=math.log(hi)))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, T, H)))
-    return (q, k, v, g, beta), jax.random.normal(ks[5], (B, T, H, dv))
+    return (q, k, v, g, beta), jax.random.normal(ks[5], (B, T, H * dv))
 
 
 def recurrence(q, k, v, g, beta, scale):
-    return jnp.stack([kimi_linear_ref.kda_recurrence(*(x[b] for x in (q, k, v, g, beta)), scale, PROD) for b in range(q.shape[0])])
+    """The recurrence a step at a time over flat arrays: the reference takes a row's ``[T, H, d]``."""
+    H = beta.shape[-1]
+    rows = [
+        kimi_linear_ref.kda_recurrence(heads(q, H)[b], heads(k, H)[b], heads(v, H)[b], heads(g, H)[b], beta[b], scale, PROD)
+        for b in range(q.shape[0])
+    ]
+    return flat(jnp.stack(rows))
 
 
 _LENGTHS = {"under-a-chunk": 40, "a-chunk-and-a-part": 100, "four-chunks-less-a-part": 200}
@@ -103,7 +120,7 @@ def test_the_chunked_scan_is_the_recurrence_forward_and_in_all_five_gradients(ca
     T, decay, B, H, only = SCANS[case]
     args, mix = scan_inputs(T, T, decay, B=B, H=H)
     if only is not None:
-        mix = mix * (jnp.arange(H) == only)[None, None, :, None]
+        mix = flat(heads(mix, H) * (jnp.arange(H) == only)[None, None, :, None])
     scale = 0.25
     got = kda(*args, scale=scale)
     want = recurrence(*args, scale)
@@ -113,16 +130,15 @@ def test_the_chunked_scan_is_the_recurrence_forward_and_in_all_five_gradients(ca
     for name, a, b in zip(("q", "k", "v", "g", "beta"), grads, wants):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, err_msg=f"d{name}")
     if only is not None:
-        dg, dbeta = np.asarray(grads[3]), np.asarray(grads[4])
+        dg, dbeta = np.asarray(heads(grads[3], H)), np.asarray(grads[4])
         assert np.abs(dg[:, :, only]).max() > 1e-3 and np.abs(dbeta[:, :, only]).max() > 1e-3
         assert not dg[:, :, :only].any() and not dbeta[:, :, :only].any()
 
 
 def test_the_chunk_follows_the_shape_and_the_scan_leaves_its_gauges():
-    assert chunk_plan(8192) == (64, 8, 8192)          # 128 chunks, eight to a grid step where the rows are narrow
-    assert chunk_plan(8192, 32 * 128) == (64, 4, 8192)   # the cell: 256 steps over 32 heads of 128 are a block's million elements
-    assert chunk_plan(100, 1 << 20) == (64, 1, 128)
+    assert chunk_plan(8192) == (64, 4, 8192)          # the cell: 128 chunks, four of each head to a grid step
     assert chunk_plan(200) == (64, 4, 256) and chunk_plan(100) == (64, 2, 128) and chunk_plan(40) == (40, 1, 40)
+    assert chunk_plan(64 * 6) == (64, 2, 384) and chunk_plan(64 * 7) == (64, 1, 448)
     args, _ = scan_inputs(100, 0, "every-rate")
     kda(*args)
     gauges = default_registry().snapshot()["gauges"]
@@ -132,22 +148,27 @@ def test_the_chunk_follows_the_shape_and_the_scan_leaves_its_gauges():
     assert default_registry().snapshot()["gauges"]["kda.padded_rows"] == 0
     with pytest.raises(ValueError, match="kda shapes"):
         kda(args[0], args[1], args[2], args[3][..., :4], args[4])
+    with pytest.raises(ValueError, match="kda shapes"):
+        kda(*args[:4], args[4][..., :1].repeat(3, axis=-1))      # three heads do not divide q's 32 channels, nor v's 16
+    with pytest.raises(ValueError, match="kda shapes"):
+        kda(*(heads(x, 2) for x in args[:4]), args[4])           # the 4-D arrays the scan took before: no longer
 
 
 def test_a_head_size_that_is_no_whole_lane_tiles_is_refused_through_mosaic_and_taken_by_the_interpreter():
-    """The kernels read a head as every ``H``-th row of the model's ``[T, H,
-    d]``: through Mosaic a row is whole 128-lane tiles, and bfloat16 rows are
-    read two to a 32-bit word, so its heads come in pairs; the scan says so
-    before any kernel is built.  The interpreter takes any head size."""
+    """The kernels read a head as a lane block of the model's ``[B, T, H d]``:
+    through Mosaic a head is whole 128-lane tiles, and the scan says so,
+    naming the shapes, before any kernel is built.  Heads need not come in
+    pairs any more, whatever the dtype.  The interpreter takes any head
+    size."""
     args, _ = scan_inputs(40, 1, "every-rate")                      # d_k 16, d_v 8
-    with pytest.raises(ValueError, match="multiples of 128"):
+    with pytest.raises(ValueError, match=r"multiples of 128 .*q \(1, 40, 32\).*v \(1, 40, 16\)"):
         kda(*args, interpret=False)
-    wide_v = (args[0], args[1], jnp.zeros((1, 40, 2, 128)), args[3], args[4])
-    with pytest.raises(ValueError, match="multiples of 128"):
+    wide_v = (args[0], args[1], jnp.zeros((1, 40, 2 * 128)), args[3], args[4])
+    with pytest.raises(ValueError, match=r"d_k 16 and d_v 128 must be multiples of 128"):
         kda(*wide_v, interpret=False)                               # d_v alone a whole tile: d_k still is not
-    three, _ = scan_inputs(40, 1, "every-rate", H=3, dk=128, dv=128)
-    with pytest.raises(ValueError, match="come in pairs"):
-        kda(*(x.astype(jnp.bfloat16) for x in three[:3]), *three[3:], interpret=False)
+    wide_k, _ = scan_inputs(40, 1, "every-rate", dk=128, dv=8)
+    with pytest.raises(ValueError, match=r"d_k 128 and d_v 8 must be multiples of 128"):
+        kda(*wide_k, interpret=False)
     np.testing.assert_allclose(
         np.asarray(kda(*args, scale=0.25, interpret=True)), np.asarray(recurrence(*args, 0.25)), atol=1e-4
     )
@@ -175,6 +196,83 @@ def test_the_latent_mixer_is_the_head_at_a_time_form(params, T):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
 
 
+@pytest.mark.parametrize("H", [2, 4, 32])
+@pytest.mark.parametrize("norm", ["l2norm", "o_norm"])
+def test_the_flat_norms_are_the_per_head_norms_they_replace(norm, H):
+    """``l2norm`` and ``o_norm`` on ``[B, T, H D]`` against the forms over the
+    last axis of ``[B, T, H, D]`` that stood in the mixer (the reference's
+    own ``l2norm`` and ``rms_norm``), float32 at 1e-6: values, the input's
+    gradient and the weight's."""
+    D = 16
+    ks = jax.random.split(jax.random.PRNGKey(H), 3)
+    x, mix = jax.random.normal(ks[0], (2, 9, H * D)) * 3.0, jax.random.normal(ks[1], (2, 9, H * D))
+    scale = 1.0 + 0.1 * jax.random.normal(ks[2], (D,))
+    if norm == "l2norm":
+        got, want = (lambda x, w: l2norm(x, H)), (lambda x, w: flat(kimi_linear_ref.l2norm(heads(x, H))))
+    else:
+        got, want = (lambda x, w: o_norm(x, w, H, 1e-5)), (lambda x, w: flat(trinity_ref.rms_norm(heads(x, H), w, 1e-5)))
+    np.testing.assert_allclose(np.asarray(got(x, scale)), np.asarray(want(x, scale)), atol=1e-6)
+    grads = jax.grad(lambda x, w: jnp.sum(got(x, w) * mix), argnums=(0, 1))(x, scale)
+    wants = jax.grad(lambda x, w: jnp.sum(want(x, w) * mix), argnums=(0, 1))(x, scale)
+    np.testing.assert_allclose(np.asarray(grads[0]), np.asarray(wants[0]), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(grads[1]), np.asarray(wants[1]), atol=1e-5 if norm == "o_norm" else 0)
+    assert got(x.astype(jnp.bfloat16), scale).dtype == jnp.bfloat16
+
+
+def test_a_flat_norm_keeps_its_input_and_a_statistic_a_head_for_the_backward_pass():
+    """What a norm holds from the forward pass to the backward one is held for
+    the whole step (the cell recomputes nothing): the input as it came and
+    ``[B, T, H]`` float32, nothing ``H D`` wide in float32."""
+    x = jnp.ones((1, 8, 4 * 16), jnp.bfloat16)
+    for fn in (lambda x: l2norm(x, 4), lambda x: o_norm(x, jnp.ones((16,)), 4, 1e-5)):
+        _, pull = jax.vjp(fn, x)
+        kept = [leaf for leaf in jax.tree_util.tree_leaves(pull) if hasattr(leaf, "shape") and leaf.size >= x.size]
+        assert [(leaf.shape, leaf.dtype) for leaf in kept] == [(x.shape, jnp.bfloat16)]
+
+
+#: ``KDAMixer``'s parameter tree at the tiny configuration as the parent commit made it (names, shapes):
+#: ``chipbench/weights_hybrid_lm.py`` and the plain reference read it
+PARENT_KDA_TREE = {
+    "A_log": (2,), "dt_bias": (32,), "o_norm": {"scale": (16,)},
+    "q_conv": (4, 32), "k_conv": (4, 32), "v_conv": (4, 32),
+    "q_proj": {"kernel": (32, 32)}, "k_proj": {"kernel": (32, 32)}, "v_proj": {"kernel": (32, 32)},
+    "f_a_proj": {"kernel": (32, 16)}, "f_b_proj": {"kernel": (16, 32)}, "b_proj": {"kernel": (32, 2)},
+    "g_a_proj": {"kernel": (32, 16)}, "g_b_proj": {"kernel": (16, 32)}, "o_proj": {"kernel": (32, 32)},
+}
+
+
+def test_the_parents_parameter_tree_initialises_and_loads_into_the_mixer_unchanged(params):
+    made = KDAMixer(CFG).init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 32)))["params"]
+    assert jax.tree_util.tree_map(lambda a: a.shape, made) == PARENT_KDA_TREE
+    np.testing.assert_array_equal(np.asarray(made["o_norm"]["scale"]), np.ones(16))
+    loaded = jax.tree_util.tree_map(
+        lambda shape: jnp.full(shape, 0.01), PARENT_KDA_TREE, is_leaf=lambda node: isinstance(node, tuple)
+    )
+    assert KDAMixer(CFG).apply({"params": loaded}, jnp.ones((1, 8, 32))).shape == (1, 8, 32)
+    for layer in ("layers_0", "layers_1", "layers_3"):        # and the weight maker's tree is that tree
+        assert jax.tree_util.tree_map(lambda a: a.shape, params["params"][layer]["self_attn"]) == PARENT_KDA_TREE
+
+
+@pytest.mark.parametrize("T", [40, 100])
+def test_the_kda_mixer_is_the_reference_a_step_at_a_time_in_value_and_every_gradient(params, T):
+    """The mixer on flat arrays (projections, convolutions, the two flat
+    norms, the flat decay, the scan through the interpreter, the gate) against
+    the reference that reshapes to heads and runs the recurrence a step at a
+    time: the output, the input's gradient and every parameter's."""
+    p = params["params"]["layers_1"]["self_attn"]
+    x = jnp.asarray(np.random.default_rng(T).normal(size=(2, T, 32)), jnp.float32)
+    mix = jnp.asarray(np.random.default_rng(T + 1).normal(size=(2, T, 32)), jnp.float32)
+    ours = lambda p, x: KDAMixer(CFG).apply({"params": p}, x)                                                # noqa: E731
+    theirs = lambda p, x: jnp.stack([kimi_linear_ref.kda_mixer(row, p, file_config(), PROD) for row in x])  # noqa: E731
+    np.testing.assert_allclose(np.asarray(ours(p, x)), np.asarray(jax.jit(theirs)(p, x)), atol=2e-6)
+    got = jax.grad(lambda p, x: jnp.sum(ours(p, x) * mix), argnums=(0, 1))(p, x)
+    want = jax.jit(jax.grad(lambda p, x: jnp.sum(theirs(p, x) * mix), argnums=(0, 1)))(p, x)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=1e-6 + 5e-4 * float(jnp.max(jnp.abs(b))), err_msg=jax.tree_util.keystr(path)
+        )
+
+
 def test_the_short_convolution_is_causal_and_depthwise():
     x = jnp.asarray(np.random.default_rng(4).normal(size=(1, 9, 3)), jnp.float32)
     taps = jnp.asarray(np.random.default_rng(5).normal(size=(4, 3)), jnp.float32)
@@ -195,7 +293,7 @@ def test_the_weight_maker_makes_the_tree_the_model_reads(params):
         assert want.shape == got.shape and got.dtype == jnp.float32
     assert CFG.kinds == ("kda", "kda", "mla", "kda") == weights_hybrid_lm.layer_kinds(file_config())
     scan = params["params"]["layers_0"]["self_attn"]
-    rate = np.exp(np.asarray(scan["A_log"]))[:, None] * np.asarray(jax.nn.softplus(scan["dt_bias"])).reshape(2, 16)
+    rate = np.repeat(np.exp(np.asarray(scan["A_log"])), 16) * np.asarray(jax.nn.softplus(scan["dt_bias"]))
     assert 0.15 < np.exp(-rate).min() and np.exp(-rate).max() < 0.9995     # a step forgets neither all nor nothing
     assert np.abs(np.asarray(scan["q_conv"])).max() <= 0.5
 
