@@ -837,6 +837,102 @@ def test_the_state_space_cells_step_fits_the_chip(topo, monkeypatch):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 0.95 * 16 * 2**30
 
 
+# --- the SambaY cell: two selective scans, differential attention at 64 over 128, one step ---
+
+
+def _phi4_cell():
+    import json
+    from pathlib import Path
+
+    from chipbench.runners.train_sambay_lm import model_config
+
+    config = json.loads((Path(__file__).resolve().parents[1] / "chipbench/configs/phi4-mini-flash-vp8.json").read_text())
+    return config, model_config(config)
+
+
+def _selective_scan_through_mosaic(monkeypatch):
+    import sys
+
+    _flash_through_mosaic(monkeypatch)
+    monkeypatch.setattr(sys.modules["adapcc_tpu.ops.selective_scan"], "resolve_interpret", lambda interpret, site: False)
+
+
+def test_the_selective_scan_is_its_two_kernels_on_the_models_own_arrays(one_chip):
+    """``value_and_grad`` of ``selective_scan`` at ``phi4-mini-flash-vp8-train``'s
+    shape (``x`` and ``dt`` ``[1, 8192, 5120]``, ``A [5120, 16]``, ``B`` and
+    ``C`` ``[1, 8192, 16]``) through Mosaic: ``sscan_fwd`` takes the six
+    arrays and gives ``y`` and the state each of the 32 blocks of rows starts
+    from; ``sscan_bwd`` takes eight and gives the four arrays' gradients and a
+    partial a grid step for ``A`` and ``D``.  What Mosaic refuses (a slice off
+    the tiling, a layout it has none for, more scoped VMEM than the kernel
+    asks) fails here."""
+    from adapcc_tpu.ops.selective_scan import selective_scan
+
+    _, cfg = _phi4_cell()
+    T, C, N = 8192, cfg.d_inner, cfg.mamba_d_state
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def scan(x, dt, A, B, Cm, D):
+        return jnp.sum(selective_scan(x, dt, A, B, Cm, D, interpret=False).astype(jnp.float32))
+
+    args = (
+        shape((1, T, C)), shape((1, T, C), jnp.float32), shape((C, N), jnp.float32), shape((1, T, N)),
+        shape((1, T, N)), shape((C,), jnp.float32),
+    )
+    compiled = jax.jit(jax.value_and_grad(scan, argnums=tuple(range(6)))).lower(*args).compile()
+    assert _kernels_in(compiled) == 2
+    text = compiled.as_text()
+    wide, states = r"bf16\[1,8192,5120\]\S*", r"f32\[1,32,16,5120\]\S*"
+    assert re.search(rf"%sscan_fwd[\w.]* = \({wide}, {states}\) custom-call\(%[\w.\-]+(, (/\*index=5\*/)?%[\w.\-]+){{5}}\),", text)
+    assert re.search(
+        rf"%sscan_bwd[\w.]* = \({wide}, f32\[1,8192,5120\]\S*, bf16\[1,8192,16\]\S*, bf16\[1,8192,16\]\S*, "
+        rf"{states}, (/\*index=5\*/)?f32\[1,32,1,5120\]\S*\) custom-call\(%[\w.\-]+(, (/\*index=5\*/)?%[\w.\-]+){{7}}\),",
+        text,
+    )
+    assert not re.search(r" (reduce-window|cumsum)\(", text)
+
+
+def test_the_sambay_cells_step_fits_the_chip(topo, monkeypatch):
+    """The whole donating step of ``phi4-mini-flash-vp8-train`` (697,094,272
+    float32 parameters with AdamW's moments, one row of 8,192 tokens through
+    two selective scans, a window, a full and a cross differential-attention
+    layer at scores of 64 over values of 128 on 10 K/V heads, a gated memory
+    unit, the tied head inside the loss; the loss and remat the configuration
+    file states) compiled for the described chip: state and temporaries leave
+    5% of its 16 GiB free, and the five kernels are in the program under their
+    own names (the device trace is read by them:
+    chipbench/runners/train_sambay_lm.kernel_of).  Two flash calls a layer
+    forward (one softmax of a pair each), and as many ``dq`` and ``dkv``."""
+    import optax
+
+    from adapcc_tpu.ddp.trainer import TrainState
+    from adapcc_tpu.workloads.train_phi4_flash import build_trainer
+
+    _selective_scan_through_mosaic(monkeypatch)
+    config, cfg = _phi4_cell()
+    mesh = Mesh(np.array(topo.devices[:1]), (RANKS_AXIS,))
+    tx = optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(1e-6, weight_decay=0.01))
+    program = config["assumed"]["program"]
+    trainer, model = build_trainer(cfg, tx, mesh, loss=program["loss"], donate_state=program["donate_state"])
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 64), jnp.int32))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params)) == 697_094_272
+    state = jax.eval_shape(lambda p: TrainState.create(p, tx), params)
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=NamedSharding(mesh, P(RANKS_AXIS)))
+    compiled = trainer._build().lower(_shapes_on(state, NamedSharding(mesh, P())), tokens).compile()
+    text = compiled.as_text()
+    names = {name: len(re.findall(rf"^\s*%{name}[\w.]* = ", text, re.M)) for name in (
+        "sscan_fwd", "sscan_bwd", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+    )}
+    again = 2 if program["remat"] in ("dots", "full") else 1          # a recomputed block runs its forward kernels again
+    assert names == {"sscan_fwd": 2 * again, "sscan_bwd": 2, "flash_fwd": 6 * again, "flash_bwd_dq": 6, "flash_bwd_dkv": 6}
+    for scope in ("sscan_conv", "sscan_gate", "sscan_scan", "gmu", "diff_attn", "diff_mix"):
+        assert f"/{scope}/" in text, scope
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 0.95 * 16 * 2**30
+
+
 # --- the composed programs the old on-chip smoke covered ---------------------
 
 
