@@ -12,8 +12,9 @@ state a head (:mod:`adapcc_tpu.ops.ssd`), the attention layer a causal softmax
 at head size 64 with four query heads to a K/V head
 (:mod:`adapcc_tpu.ops.flash_attention`, its scale the configuration's
 ``attention_multiplier``, not ``1 / sqrt(d)``).  Norm, gated MLP and the remat
-table are :mod:`adapcc_tpu.models.trinity`'s, the short convolution and the
-decay's initialisation :mod:`adapcc_tpu.models.kimi_linear`'s.
+table are :mod:`adapcc_tpu.models.trinity`'s, the taps' and the decay's
+initialisation :mod:`adapcc_tpu.models.kimi_linear`'s, the short convolution
+with its bias and silu one kernel (:mod:`adapcc_tpu.ops.short_conv`).
 
 Four scalings no other model here has: ``h = embedding_multiplier E[ids]``;
 both branches of a layer enter the stream times ``residual_multiplier``; the
@@ -36,7 +37,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from adapcc_tpu.models.kimi_linear import a_log_init, dt_bias_init, short_conv, taps_init
+from adapcc_tpu.models.kimi_linear import a_log_init, dt_bias_init, taps_init
 from adapcc_tpu.models.trinity import _REMAT, GatedMLP, RMSNorm, _dense
 from adapcc_tpu.utils.observability import default_registry
 
@@ -144,6 +145,7 @@ class Mamba2Mixer(nn.Module):
 
     @nn.compact
     def __call__(self, u):
+        from adapcc_tpu.ops.short_conv import short_conv
         from adapcc_tpu.ops.ssd import chunk_decay_floor, ssd
 
         cfg = self.cfg
@@ -153,7 +155,7 @@ class Mamba2Mixer(nn.Module):
         with jax.named_scope("ssd_conv"):
             taps = self.param("conv_taps", taps_init, (cfg.mamba_d_conv, conv))
             bias = self.param("conv_bias", nn.initializers.zeros, (conv,))
-            xBC = nn.silu(short_conv(xBC, taps) + bias.astype(xBC.dtype))
+            xBC = short_conv(xBC, taps, bias)       # bias and silu in the kernel
         x, B, C = jnp.split(xBC, [d_in, d_in + N], axis=-1)
         with jax.named_scope("ssd_gate"):
             A = -jnp.exp(self.param("A_log", a_log_init, (H,)).astype(jnp.float32))

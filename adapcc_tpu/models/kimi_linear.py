@@ -244,18 +244,6 @@ class HeadRMSNorm(nn.Module):
         return o_norm(x, self.param("scale", nn.initializers.ones, (x.shape[-1] // self.heads,)), self.heads, self.eps)
 
 
-def short_conv(x, taps):
-    """A causal depthwise convolution over time: ``y_t = sum_j taps[j] *
-    x_{t - (K - 1) + j}`` for ``x [B, T, C]``, ``taps [K, C]``, zeros before
-    the sequence, no bias; summed in float32.  A mixer whose convolution is
-    biased adds its own to the result (``granite_hybrid.Mamba2Mixer``, over
-    ``x``, ``B`` and ``C`` together, before the silu)."""
-    K, T = taps.shape[0], x.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
-    y = sum(taps[j].astype(jnp.float32) * padded[:, j:j + T] for j in range(K))
-    return y.astype(x.dtype)
-
-
 def taps_init(key, shape, dtype=jnp.float32):
     """A short convolution's taps ``[K, channels]``: uniform(-1/sqrt(K), 1/sqrt(K))."""
     bound = shape[0] ** -0.5
@@ -285,13 +273,14 @@ class KDAMixer(nn.Module):
     @nn.compact
     def __call__(self, x):
         from adapcc_tpu.ops.kda import kda
+        from adapcc_tpu.ops.short_conv import short_conv
 
         cfg = self.cfg
         H, D, K = cfg.linear_attn_num_heads, cfg.linear_attn_head_dim, cfg.short_conv_kernel_size
 
         def mixed(name):
             y = _dense(H * D, cfg, f"{name}_proj")(x)
-            return nn.silu(short_conv(y, self.param(f"{name}_conv", taps_init, (K, H * D))))
+            return short_conv(y, self.param(f"{name}_conv", taps_init, (K, H * D)))      # the silu is the kernel's
 
         # one layout from the projections through the scan to o_proj: [B, T, H D], head h its channels h D … (h + 1) D
         q, k, v = l2norm(mixed("q"), H), l2norm(mixed("k"), H), mixed("v")

@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from adapcc_tpu.models.kimi_linear import dt_bias_init, short_conv, taps_init
+from adapcc_tpu.models.kimi_linear import dt_bias_init, taps_init
 from adapcc_tpu.models.trinity import _REMAT, GatedMLP, _dense
 from adapcc_tpu.utils.observability import default_registry
 
@@ -220,6 +220,7 @@ class MambaMixer(nn.Module):
     @nn.compact
     def __call__(self, u):
         from adapcc_tpu.ops.selective_scan import selective_scan
+        from adapcc_tpu.ops.short_conv import short_conv
 
         cfg = self.cfg
         d_in, N, R = cfg.d_inner, cfg.mamba_d_state, cfg.dt_rank
@@ -227,7 +228,7 @@ class MambaMixer(nn.Module):
         with jax.named_scope("sscan_conv"):
             taps = self.param("conv_taps", taps_init, (cfg.mamba_d_conv, d_in))
             bias = self.param("conv_bias", nn.initializers.zeros, (d_in,))
-            x = nn.silu(short_conv(x, taps) + bias.astype(x.dtype))
+            x = short_conv(x, taps, bias)       # bias and silu in the kernel
         with jax.named_scope("sscan_gate"):
             r, B, C = jnp.split(_dense(R + 2 * N, cfg, "x_proj")(x), [R, R + N], axis=-1)
             w_dt = self.param("dt_proj", nn.initializers.normal(R ** -0.5), (R, d_in))

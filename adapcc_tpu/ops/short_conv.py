@@ -1,0 +1,339 @@
+"""The short causal convolution in front of a scan, with its bias and its
+silu, as one Pallas TPU kernel each way.
+
+For ``x [B, T, C]``, taps ``[K, C]`` (``K`` small: 4 in every published
+configuration here) and an optional bias ``[C]``,
+
+    pre_t[c] = sum_j taps[j, c] x_{t - (K - 1) + j}[c]  (+ bias[c])
+    y_t[c]   = silu(pre_t[c]) = pre_t[c] sigmoid(pre_t[c])
+
+depthwise (a channel sees only itself), causal, zeros before the sequence, no
+reset anywhere inside a row of the batch (a packed row's joins are crossed,
+as the references cross them).  Kimi-Linear's KDA mixer runs it over q, k and
+v (no bias), Granite's Mamba-2 mixer over ``x``, ``B`` and ``C`` together and
+Phi-4-flash's Mamba-1 mixer over ``x`` (both biased).  Written in
+``jax.numpy`` it is a zero-padded float32 copy of ``x``, ``K`` shifted slices,
+a sum and three roundings, none of which XLA can fuse across the scan's
+``pallas_call``, and four more float32 passes for its derivative; here each
+direction reads its arrays once and writes its result once.
+
+**Layout.**  Channels on lanes, time on sublanes.  A grid step owns ``rows``
+steps of ``width`` channels (:func:`plan_for`: about a million elements) and
+walks them :data:`_CHUNK` lanes at a time, down the rows in turns of
+:data:`_TURN` groups of sixteen (a bfloat16 tile, two float32 ones), all in
+registers: ``x_{t - s}`` of an ``[8, lanes]`` slab is the slab and the one
+before it, selected by row and rotated by ``s`` sublanes (the XLU); nothing is
+padded and no float32 array leaves VMEM.  The VPU binds both kernels, not the
+HBM: a turn is written out because the next one starts only when it ends, and
+a block is long because the walk down a chunk of lanes starts and ends once a
+block.  The sigmoid is ``(1 + tanh(pre / 2)) / 2``: one transcendental, no
+division.  The taps and the bias reach the kernels as one float32 array ``[8,
+C]`` (the taps in rows ``0 .. K - 1``, the bias in row ``K``): one sublane
+tile, and two operands where there would be three.
+
+**Forward** (``short_conv_fwd``; grid ``(B, C / width, T / rows)``, rows
+innermost).  The last eight rows of a grid step's ``x`` wait in scratch for
+the next block of rows (zeros before the first): the halo.  The sum, the bias
+and the silu are float32; ``y`` is rounded once, to ``x``'s dtype.
+
+**Backward** (``short_conv_bwd``; the same grid, the blocks of rows from the
+last to the first).  The residuals are ``x`` and the taps' array; ``pre`` is
+formed again in registers.  With ``dpre = dy silu'(pre)``,
+
+    dx_t        = sum_j taps[j] dpre_{t + (K - 1) - j}
+    dtaps[j, c] = sum_t dpre_t[c] x_{t - (K - 1) + j}[c]      dbias[c] = sum_t dpre_t[c]
+
+``dx`` wants ``dpre`` of up to ``K - 1`` later rows: walking backward, the
+first eight rows of ``dpre`` of the block after wait in scratch.  ``pre``
+wants ``x`` of up to ``K - 1`` earlier rows, which that walk has not met:
+they come as a second, sixteen-row block of the same array (``x``'s rows just
+before the grid step's; the one extra read, 16 / rows of ``x``).  The taps'
+and the bias's gradients are summed in float32, a sublane tile a tap in
+registers along a grid step, then into one output block ``[8, width]`` a row
+of the batch that the grid revisits along the rows (hence rows innermost and
+``arbitrary``).
+
+Operands / results: forward 2 -> 1, backward 4 -> 2 (``x``, its halo, ``dy``,
+the taps' array -> ``dx`` and the taps' array's gradient): none of the
+signatures ``chipbench/trace_reduce.flash_kernel`` knows a flash kernel by.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from adapcc_tpu.ops.kernel_mode import resolve_interpret
+from adapcc_tpu.utils.observability import default_registry
+
+_LANES = 128    # a lane tile
+_SLAB = 8       # a float32 sublane tile: what a shift is formed on
+_GROUP = 16     # rows loaded and stored at once: a bfloat16 sublane tile, two slabs
+_CHUNK = 256    # lanes walked at once: every carried array two registers a slab
+_TURN = 2       # groups written out in one turn of the inner loop (Mosaic unrolls no loop in part)
+
+# a grid step at most: rows (long: the walk down a chunk of lanes starts and ends once a block; 0.37 -> 0.30 ms a
+# call forward from 512 rows to 4,096 at 4,352 channels), lane tiles, and elements of the two together (two
+# megabytes of bfloat16 a block; the backward kernel holds three, twice)
+_ROWS = 4096
+_TILES = 8
+_BLOCK = 1 << 20
+
+
+class _Plan(NamedTuple):
+    """The blocks of one call."""
+
+    rows: int       # steps of a grid step, whole groups
+    width: int      # channels of a grid step, whole lane tiles
+    K: int
+    biased: bool
+
+    @property
+    def tiles(self) -> int:
+        return self.width // _LANES
+
+
+def plan_for(T: int, C: int, K: int = 4, biased: bool = False) -> "tuple[_Plan, int, int]":
+    """``(plan, padded T, padded C)``: channels in whole lane tiles, as many
+    to a grid step as divide them, :data:`_TILES` at most (4,352 channels are
+    34 lane tiles: 2 a grid step; 4,096 and 5,120 take 8); ``T`` in as few
+    equal blocks of rows as :data:`_ROWS` and :data:`_BLOCK` allow, each
+    whole turns of the inner loop (T = 8,192: two blocks of 4,096 rows at
+    256 channels, eight of 1,024 at 1,024)."""
+    tiles = -(-C // _LANES)
+    width = _LANES * max(n for n in range(1, _TILES + 1) if tiles % n == 0)
+    steps = -(-T // min(_ROWS, _BLOCK // width))
+    turn = _GROUP * _TURN
+    rows = -(-T // (steps * turn)) * turn
+    return _Plan(rows=rows, width=width, K=K, biased=biased), -(-T // rows) * rows, tiles * _LANES
+
+
+def _chunk(width: int) -> int:
+    """Lanes walked at once: :data:`_CHUNK` where it divides the grid step's, else a lane tile."""
+    return _CHUNK if width % _CHUNK == 0 else _LANES
+
+
+def _sigmoid(a):
+    """Through ``tanh``: one transcendental and no division (0.40 ms a call forward for 0.46 at 4,352 channels)."""
+    return 0.5 * jnp.tanh(0.5 * a) + 0.5
+
+
+def _down(before, cur, s: int, row):
+    """``cur`` shifted ``s`` rows later: row ``t`` holds ``cur[t - s]``, and
+    ``before[8 + t - s]`` where that is above the slab."""
+    return cur if s == 0 else pltpu.roll(jnp.where(row >= _SLAB - s, before, cur), s, 0)
+
+
+def _up(cur, after, s: int, row):
+    """``cur`` shifted ``s`` rows earlier: row ``t`` holds ``cur[t + s]``, and
+    ``after[t + s - 8]`` where that is under the slab."""
+    return cur if s == 0 else pltpu.roll(jnp.where(row < s, after, cur), _SLAB - s, 0)
+
+
+def _weights(w_ref, lanes, plan: _Plan):
+    """The taps and the bias of a chunk of lanes, each spread over a slab."""
+    w = w_ref[:, lanes]
+    spread = lambda j: jnp.broadcast_to(w[j:j + 1], (_SLAB, w.shape[1]))  # noqa: E731
+    return [spread(j) for j in range(plan.K)], spread(plan.K) if plan.biased else None
+
+
+def _pre(taps, bias, shifted):
+    """``sum_s taps[K - 1 - s] x_{t - s} + bias`` from the shifted slabs."""
+    K = len(taps)
+    acc = taps[K - 1] * shifted[0]
+    for s in range(1, K):
+        acc = acc + taps[K - 1 - s] * shifted[s]
+    return acc if bias is None else acc + bias
+
+
+def _fwd_kernel(x_ref, w_ref, y_ref, tail, *, plan: _Plan):
+    K, chunk, per = plan.K, _chunk(plan.width), _TURN
+    first = pl.program_id(2) == 0
+    row = lax.broadcasted_iota(jnp.int32, (_SLAB, chunk), 0)
+
+    def some_lanes(c, _):
+        lanes = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        taps, bias = _weights(w_ref, lanes, plan)
+
+        def some_rows(k, before):
+            for u in range(per):
+                rows = pl.ds(pl.multiple_of((k * per + u) * _GROUP, _GROUP), _GROUP)
+                xs = x_ref[0, rows, lanes].astype(jnp.float32)
+                out = []
+                for cur in (xs[:_SLAB], xs[_SLAB:]):
+                    pre = _pre(taps, bias, [_down(before, cur, s, row) for s in range(K)])
+                    out.append(pre * _sigmoid(pre))
+                    before = cur
+                y_ref[0, rows, lanes] = jnp.concatenate(out, axis=0).astype(y_ref.dtype)
+            return before
+
+        before = jnp.where(first, 0.0, tail[:, lanes])
+        tail[:, lanes] = lax.fori_loop(0, plan.rows // (_GROUP * per), some_rows, before)
+        return 0
+
+    lax.fori_loop(0, plan.width // chunk, some_lanes, 0)
+
+
+def _bwd_kernel(x_ref, halo_ref, dy_ref, w_ref, dx_ref, dw_ref, head, *, plan: _Plan):
+    K, chunk, per = plan.K, _chunk(plan.width), _TURN
+    groups = plan.rows // _GROUP
+    i = pl.program_id(2)                        # the grid's first step is the sequence's last rows
+    start = i == pl.num_programs(2) - 1         # this grid step holds the sequence's first rows
+    row = lax.broadcasted_iota(jnp.int32, (_SLAB, chunk), 0)
+
+    @pl.when(i == 0)
+    def _():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, dw_ref.dtype)
+
+    def some_lanes(c, _):
+        lanes = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        taps, bias = _weights(w_ref, lanes, plan)
+
+        def slab(before, cur, dy, after, sums):
+            """``dpre`` of the slab ``cur`` (``before``: the slab of ``x``
+            above it), the sums with it added, and the slab's ``dx`` from its
+            ``dpre`` and the slab's under it (``after``)."""
+            shifted = [_down(before, cur, s, row) for s in range(K)]
+            pre = _pre(taps, bias, shifted)
+            sig = _sigmoid(pre)
+            dpre = dy * (sig * (1.0 + pre * (1.0 - sig)))
+            sums = [sums[j] + dpre * shifted[K - 1 - j] for j in range(K)] + [sums[K] + dpre]
+            dx = taps[K - 1] * dpre
+            for s in range(1, K):
+                dx = dx + taps[K - 1 - s] * _up(dpre, after, s, row)
+            return dpre, dx, sums
+
+        def turn(first_group, above, after, sums):
+            """``per`` groups of sixteen rows from ``first_group`` on, the last
+            row first; ``above`` is the slab of ``x`` over them."""
+            group = lambda g: pl.ds(pl.multiple_of((first_group + g) * _GROUP, _GROUP), _GROUP)  # noqa: E731
+            xs = [x_ref[0, group(g), lanes].astype(jnp.float32) for g in range(per)]
+            for g in reversed(range(per)):
+                dys = dy_ref[0, group(g), lanes].astype(jnp.float32)
+                lo, hi = xs[g][:_SLAB], xs[g][_SLAB:]
+                after, dx_hi, sums = slab(lo, hi, dys[_SLAB:], after, sums)
+                after, dx_lo, sums = slab(xs[g - 1][_SLAB:] if g else above, lo, dys[:_SLAB], after, sums)
+                dx_ref[0, group(g), lanes] = jnp.concatenate([dx_lo, dx_hi], axis=0).astype(dx_ref.dtype)
+            return after, sums
+
+        def some_rows(k, carry):
+            first_group = groups - (k + 1) * per
+            above = x_ref[0, pl.ds(pl.multiple_of((first_group - 1) * _GROUP, _GROUP), _GROUP), lanes]
+            return turn(first_group, above[_SLAB:].astype(jnp.float32), *carry)
+
+        zero = jnp.zeros((_SLAB, chunk), jnp.float32)
+        after = jnp.where(i == 0, 0.0, head[:, lanes])
+        carry = lax.fori_loop(0, groups // per - 1, some_rows, (after, [zero] * (K + 1)))
+        halo = jnp.where(start, 0.0, halo_ref[0, _SLAB:, lanes].astype(jnp.float32))
+        after, sums = turn(0, halo, *carry)
+        head[:, lanes] = after
+        for j in range(K + 1 if plan.biased else K):
+            dw_ref[0, j:j + 1, lanes] += jnp.sum(sums[j], axis=0, keepdims=True)
+        return 0
+
+    lax.fori_loop(0, plan.width // chunk, some_lanes, 0)
+
+
+def _params(interp):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=None if interp else 64 * 2**20,
+    )
+
+
+# behind jax.jit, as the other kernels are: a model's layers share one traced kernel
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _fwd_call(x, w, plan: _Plan, interp):
+    Bt, T, Cp = x.shape
+    rows, W = plan.rows, plan.width
+    block = pl.BlockSpec((1, rows, W), lambda b, p, i: (b, i, p))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, plan=plan),
+        grid=(Bt, Cp // W, T // rows),
+        in_specs=[block, pl.BlockSpec((w.shape[0], W), lambda b, p, i: (0, p))],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        scratch_shapes=[pltpu.VMEM((_SLAB, W), jnp.float32)],
+        compiler_params=_params(interp),
+        interpret=interp,
+        name="short_conv_fwd",
+    )(x, w)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _bwd_call(x, w, dy, plan: _Plan, interp):
+    Bt, T, Cp = x.shape
+    rows, W = plan.rows, plan.width
+    steps, per = T // rows, rows // _GROUP
+    block = pl.BlockSpec((1, rows, W), lambda b, p, i: (b, steps - 1 - i, p))
+    # the sixteen rows before the grid step's (its own first sixteen where it has none before: not read then)
+    halo = pl.BlockSpec((1, _GROUP, W), lambda b, p, i: (b, jnp.maximum((steps - 1 - i) * per - 1, 0), p))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, plan=plan),
+        grid=(Bt, Cp // W, steps),
+        in_specs=[block, halo, block, pl.BlockSpec((w.shape[0], W), lambda b, p, i: (0, p))],
+        out_specs=[block, pl.BlockSpec((1, w.shape[0], W), lambda b, p, i: (b, 0, p))],
+        out_shape=[
+            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct((Bt, *w.shape), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((_SLAB, W), jnp.float32)],
+        compiler_params=_params(interp),
+        interpret=interp,
+        name="short_conv_bwd",
+    )(x, x, dy, w)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _conv(x, w, plan, interp):
+    return _fwd_call(x, w, plan, interp)
+
+
+def _conv_fwd(x, w, plan, interp):
+    return _fwd_call(x, w, plan, interp), (x, w)
+
+
+def _conv_bwd(plan, interp, res, dy):
+    x, w = res
+    dx, dw = _bwd_call(x, w, dy, plan, interp)
+    return dx, dw.sum(axis=0)
+
+
+_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+def short_conv(
+    x: jnp.ndarray, taps: jnp.ndarray, bias: Optional[jnp.ndarray] = None, interpret: Optional[bool] = None
+) -> jnp.ndarray:
+    """``silu(sum_j taps[j] x_{t - (K - 1) + j} + bias)`` over ``x [B, T,
+    C]`` with ``taps [K, C]`` and ``bias [C]`` or none, each row of the batch
+    from zeros before its first step: ``[B, T, C]`` in ``x``'s dtype, summed
+    in float32 and rounded once.  Differentiable in ``x``, ``taps`` and
+    ``bias`` (the parameters' gradients summed in float32).  Any ``T`` and
+    ``C``: rows are padded with zeros to whole blocks and channels to whole
+    lane tiles (the published shapes are both already: no copy there).
+    ``interpret=None`` asks
+    :func:`ops.kernel_mode.resolve_interpret` (site ``"short_conv"``)."""
+    Bt, T, C = x.shape
+    K = taps.shape[0]
+    if taps.shape != (K, C) or not 1 <= K < _SLAB or (bias is not None and bias.shape != (C,)):
+        raise ValueError(f"short_conv shapes: x {x.shape} taps {taps.shape} bias {None if bias is None else bias.shape}")
+    interp = resolve_interpret(interpret, "short_conv")
+    plan, Tp, Cp = plan_for(T, C, K, bias is not None)
+    metrics = default_registry()
+    metrics.incr("conv.calls")
+    metrics.gauge("conv.block_rows", plan.rows)
+    metrics.gauge("conv.lane_tiles", plan.tiles)
+    w = taps.astype(jnp.float32)
+    if bias is not None:
+        w = jnp.concatenate([w, bias.astype(jnp.float32)[None]])
+    w = jnp.pad(w, ((0, _SLAB - w.shape[0]), (0, Cp - C)))
+    if (Tp, Cp) != (T, C):
+        x = jnp.pad(x, ((0, 0), (0, Tp - T), (0, Cp - C)))
+    return _conv(x, w, plan, interp)[:, :T, :C]

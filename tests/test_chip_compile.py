@@ -437,10 +437,40 @@ def _kimi_cell():
     return config, cfg
 
 
+def _wide_results(text: str, elements: int) -> "dict[str, str]":
+    """The entry computation's instructions whose result holds ``elements``
+    elements, by name, with their op: each is a pass over HBM (a fusion's
+    body has no pass of its own: its result in the entry counts)."""
+    entry = text[text.index("ENTRY"):]
+    wide = {}
+    for name, out, op in re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", entry, re.M):
+        sizes = [int(np.prod([int(n) for n in dims.split(",")])) for dims in re.findall(r"\w+\[([\d,]+)\]", out)]
+        if elements in sizes and op not in ("parameter", "get-tuple-element", "tuple", "bitcast"):
+            wide[name] = op
+    return wide
+
+
+def _conv_through_mosaic(monkeypatch):
+    import importlib
+
+    monkeypatch.setattr(
+        importlib.import_module("adapcc_tpu.ops.short_conv"), "resolve_interpret", lambda interpret, site: False
+    )
+
+
+def _conv_kernels(text: str, rows: int = 8192) -> "dict[str, int]":
+    """How often the short convolution's two kernels stand in a compiled
+    step, and that the parent's zero-padded float32 copy of their input (``K -
+    1`` rows on top of the sequence's) is gone from it."""
+    assert not re.search(rf"f32\[1,{rows + 3},\d+\]", text)
+    return {name: len(re.findall(rf"^\s*%{name}[\w.]* = ", text, re.M)) for name in ("short_conv_fwd", "short_conv_bwd")}
+
+
 def _mixers_through_mosaic(monkeypatch):
     import importlib
 
     _flash_through_mosaic(monkeypatch)
+    _conv_through_mosaic(monkeypatch)
     monkeypatch.setattr(importlib.import_module("adapcc_tpu.ops.kda"), "resolve_interpret", lambda interpret, site: False)
 
 
@@ -534,18 +564,13 @@ def test_the_kda_scan_is_its_two_kernels_and_no_pass_of_xlas_around_them(one_chi
     text = jax.jit(jax.value_and_grad(scan, argnums=(0, 1, 2, 3, 4))).lower(
         wide, wide, wide, shape((1, T, H * D), jnp.float32), shape((1, T, H), jnp.float32)
     ).compile().as_text()
-    entry = text[text.index("ENTRY"):]      # a fusion's body has no pass of its own: its result in the entry counts
-    wide_results = {}
-    for name, out, op in re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", entry, re.M):
-        sizes = [int(np.prod([int(n) for n in dims.split(",")])) for dims in re.findall(r"\w+\[([\d,]+)\]", out)]
-        if T * H * D in sizes and op not in ("parameter", "get-tuple-element", "tuple", "bitcast"):
-            wide_results[name] = op
+    wide_results = _wide_results(text, T * H * D)
     kernels = {name: op for name, op in wide_results.items() if op == "custom-call"}
     assert sorted(name.split(".")[0] for name in kernels) == ["kda_bwd", "kda_fwd"], wide_results
     others = {name: op for name, op in wide_results.items() if name not in kernels}
     assert set(others.values()) <= {"broadcast"}, others            # the cotangent of the test's sum
     assert not re.search(r" (transpose|reduce-window|cumsum)\(", text)
-    assert re.search(r"%kda_fwd[\w.]* = .* custom-call\(%q[\w.]*, %k[\w.]*, %v[\w.]*, %g[\w.]*, ", entry)
+    assert re.search(r"%kda_fwd[\w.]* = .* custom-call\(%q[\w.]*, %k[\w.]*, %v[\w.]*, %g[\w.]*, ", text[text.index("ENTRY"):])
 
 
 @pytest.fixture(scope="module")
@@ -585,6 +610,7 @@ def test_the_hybrid_cells_step_fits_the_chip(hybrid_step):
         "kda_fwd", "kda_bwd", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
     )}
     assert names == {"kda_fwd": 4, "kda_bwd": 4, "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    assert _conv_kernels(text) == {"short_conv_fwd": 12, "short_conv_bwd": 12}         # q, k and v of four KDA layers
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 0.95 * 16 * 2**30
 
@@ -715,10 +741,11 @@ def _granite_cell():
 
 
 def _scan_through_mosaic(monkeypatch):
-    import sys
+    import importlib
 
     _flash_through_mosaic(monkeypatch)
-    monkeypatch.setattr(sys.modules["adapcc_tpu.ops.ssd"], "resolve_interpret", lambda interpret, site: False)
+    _conv_through_mosaic(monkeypatch)
+    monkeypatch.setattr(importlib.import_module("adapcc_tpu.ops.ssd"), "resolve_interpret", lambda interpret, site: False)
 
 
 def test_the_state_space_scan_is_its_two_kernels_and_no_pass_of_xlas_around_them(one_chip):
@@ -758,12 +785,7 @@ def test_the_state_space_scan_is_its_two_kernels_and_no_pass_of_xlas_around_them
         rf"f32\[1,16,1,64\]\S*, (/\*index=5\*/)?f32\[1,16,1,4096\]\S*\) custom-call\(%[\w.\-]+(, (/\*index=5\*/)?%[\w.\-]+){{7}}\),",
         text,
     )
-    entry = text[text.index("ENTRY"):]      # a fusion's body has no pass of its own: its result in the entry counts
-    wide_results = {}
-    for name, out, op in re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", entry, re.M):
-        sizes = [int(np.prod([int(n) for n in dims.split(",")])) for dims in re.findall(r"\w+\[([\d,]+)\]", out)]
-        if T * H * P in sizes and op not in ("parameter", "get-tuple-element", "tuple", "bitcast"):
-            wide_results[name] = op
+    wide_results = _wide_results(text, T * H * P)
     kernels = {name: op for name, op in wide_results.items() if op == "custom-call"}
     assert sorted(name.split(".")[0] for name in kernels) == ["ssd_bwd", "ssd_fwd"], wide_results
     others = {name: op for name, op in wide_results.items() if name not in kernels}
@@ -831,6 +853,7 @@ def test_the_state_space_cells_step_fits_the_chip(topo, monkeypatch):
         "ssd_fwd", "ssd_bwd", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
     )}
     assert names == {"ssd_fwd": 18, "ssd_bwd": 9, "flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    assert _conv_kernels(text) == {"short_conv_fwd": 18, "short_conv_bwd": 9}          # nine mixers, the forward again under dots
     for scope in ("ssd_conv", "ssd_gate", "ssd_scan", "gqa_attn"):
         assert f"/{scope}/" in text, scope
     memory = compiled.memory_analysis()
@@ -851,10 +874,13 @@ def _phi4_cell():
 
 
 def _selective_scan_through_mosaic(monkeypatch):
-    import sys
+    import importlib
 
     _flash_through_mosaic(monkeypatch)
-    monkeypatch.setattr(sys.modules["adapcc_tpu.ops.selective_scan"], "resolve_interpret", lambda interpret, site: False)
+    _conv_through_mosaic(monkeypatch)
+    monkeypatch.setattr(
+        importlib.import_module("adapcc_tpu.ops.selective_scan"), "resolve_interpret", lambda interpret, site: False
+    )
 
 
 def test_the_selective_scan_is_its_two_kernels_on_the_models_own_arrays(one_chip):
@@ -927,10 +953,53 @@ def test_the_sambay_cells_step_fits_the_chip(topo, monkeypatch):
     )}
     again = 2 if program["remat"] in ("dots", "full") else 1          # a recomputed block runs its forward kernels again
     assert names == {"sscan_fwd": 2 * again, "sscan_bwd": 2, "flash_fwd": 6 * again, "flash_bwd_dq": 6, "flash_bwd_dkv": 6}
+    assert _conv_kernels(text) == {"short_conv_fwd": 2 * again, "short_conv_bwd": 2}
     for scope in ("sscan_conv", "sscan_gate", "sscan_scan", "gmu", "diff_attn", "diff_mix"):
         assert f"/{scope}/" in text, scope
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 0.95 * 16 * 2**30
+
+
+# --- the short convolution in front of the three scans ----------------------------
+
+
+@pytest.mark.parametrize(
+    "channels, biased", [(4352, True), (4096, False), (5120, True)], ids=["granite-xBC", "kimi-qkv", "phi4-x"],
+)
+def test_the_short_convolution_is_its_two_kernels_at_a_published_shape(one_chip, channels, biased):
+    """``value_and_grad`` of ``short_conv`` over ``[1, 8192, C]`` bfloat16 at
+    the three mixers' widths through Mosaic: the program is ``short_conv_fwd``
+    (``x`` and the taps' array in, ``y`` out) and ``short_conv_bwd`` (``x``
+    twice, ``dy`` and the taps' array in; ``dx`` and the taps' array's
+    gradient out) and nothing else that walks 8,192 x C elements but what
+    stands for the test's own sum: no padded float32 copy, no pass for the
+    bias, the silu or their derivative.  Two operands and four: neither of the
+    counts ``chipbench/trace_reduce.flash_kernel`` takes for a flash kernel."""
+    from adapcc_tpu.ops.short_conv import short_conv
+
+    T = 8192
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def conv(x, taps, *bias):
+        return jnp.sum(short_conv(x, taps, *bias, interpret=False).astype(jnp.float32))
+
+    args = (shape((1, T, channels), jnp.bfloat16), shape((4, channels))) + ((shape((channels,)),) if biased else ())
+    compiled = jax.jit(jax.value_and_grad(conv, argnums=tuple(range(len(args))))).lower(*args).compile()
+    assert _kernels_in(compiled) == 2
+    text = compiled.as_text()
+    assert _conv_kernels(text) == {"short_conv_fwd": 1, "short_conv_bwd": 1}
+    wide = rf"bf16\[1,8192,{channels}\]\S*"
+    assert re.search(rf"%short_conv_fwd[\w.]* = {wide} custom-call\(%[\w.\-]+, %[\w.\-]+\),", text)
+    assert re.search(
+        rf"%short_conv_bwd[\w.]* = \({wide}, f32\[1,8,{channels}\]\S*\) custom-call\(%[\w.\-]+(, %[\w.\-]+){{3}}\),", text
+    )
+    wide_results = _wide_results(text, T * channels)
+    kernels = {name: op for name, op in wide_results.items() if op == "custom-call"}
+    assert sorted(name.split(".")[0] for name in kernels) == ["short_conv_bwd", "short_conv_fwd"], wide_results
+    others = {name: op for name, op in wide_results.items() if name not in kernels}
+    assert set(others.values()) <= {"broadcast"}, others            # the cotangent of the test's sum
 
 
 # --- the composed programs the old on-chip smoke covered ---------------------

@@ -19,10 +19,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from adapcc_tpu.models.kimi_linear import KDAMixer, KimiLinear, KimiLinearConfig, MLAMixer, l2norm, o_norm, short_conv
+from adapcc_tpu.models.kimi_linear import KDAMixer, KimiLinear, KimiLinearConfig, MLAMixer, l2norm, o_norm
 from adapcc_tpu.models.moe import routed_experts
 from adapcc_tpu.models.trinity import initial_model_state, stateful_loss
 from adapcc_tpu.ops.kda import chunk_plan, kda
+from adapcc_tpu.ops.short_conv import short_conv
 from adapcc_tpu.utils.observability import default_registry
 from chipbench import weights_hybrid_lm
 from chipbench.reference import kimi_linear_ref, trinity_ref
@@ -274,13 +275,16 @@ def test_the_kda_mixer_is_the_reference_a_step_at_a_time_in_value_and_every_grad
 
 
 def test_the_short_convolution_is_causal_and_depthwise():
+    """The mixer's convolution is :func:`ops.short_conv.short_conv`, the silu
+    inside it: against the sum written out a step at a time and against the
+    reference's own convolution, each under the silu."""
     x = jnp.asarray(np.random.default_rng(4).normal(size=(1, 9, 3)), jnp.float32)
     taps = jnp.asarray(np.random.default_rng(5).normal(size=(4, 3)), jnp.float32)
     y = np.asarray(short_conv(x, taps))
     for t in range(9):
         want = sum(np.asarray(taps)[j] * np.asarray(x)[0, t - 3 + j] for j in range(4) if t - 3 + j >= 0)
-        np.testing.assert_allclose(y[0, t], want, atol=1e-6)
-    np.testing.assert_allclose(y[0], np.asarray(kimi_linear_ref.short_conv(x[0], taps)), atol=1e-6)
+        np.testing.assert_allclose(y[0, t], want / (1.0 + np.exp(-want)), atol=1e-6)
+    np.testing.assert_allclose(y[0], np.asarray(jax.nn.silu(kimi_linear_ref.short_conv(x[0], taps))), atol=1e-6)
 
 
 # --- the model ---------------------------------------------------------------
