@@ -278,12 +278,14 @@ class KDAMixer(nn.Module):
         cfg = self.cfg
         H, D, K = cfg.linear_attn_num_heads, cfg.linear_attn_head_dim, cfg.short_conv_kernel_size
 
-        def mixed(name):
+        def mixed(name, normed):
+            # the silu is the kernel's, and so is q's and k's l2norm (:func:`l2norm` is its plain form)
             y = _dense(H * D, cfg, f"{name}_proj")(x)
-            return short_conv(y, self.param(f"{name}_conv", taps_init, (K, H * D)))      # the silu is the kernel's
+            taps = self.param(f"{name}_conv", taps_init, (K, H * D))
+            return short_conv(y, taps, norm_heads=H if normed else None, norm_eps=L2_EPS)
 
         # one layout from the projections through the scan to o_proj: [B, T, H D], head h its channels h D … (h + 1) D
-        q, k, v = l2norm(mixed("q"), H), l2norm(mixed("k"), H), mixed("v")
+        q, k, v = mixed("q", True), mixed("k", True), mixed("v", False)
         with jax.named_scope("kda_gate"):
             a_log = self.param("A_log", a_log_init, (H,))
             dt_bias = self.param("dt_bias", dt_bias_init, (H * D,))

@@ -10,8 +10,9 @@ configuration here) and an optional bias ``[C]``,
 depthwise (a channel sees only itself), causal, zeros before the sequence, no
 reset anywhere inside a row of the batch (a packed row's joins are crossed,
 as the references cross them).  Kimi-Linear's KDA mixer runs it over q, k and
-v (no bias), Granite's Mamba-2 mixer over ``x``, ``B`` and ``C`` together and
-Phi-4-flash's Mamba-1 mixer over ``x`` (both biased).  Written in
+v (no bias; q and k with the norm below), Granite's Mamba-2 mixer over ``x``,
+``B`` and ``C`` together and Phi-4-flash's Mamba-1 mixer over ``x`` (both
+biased).  Written in
 ``jax.numpy`` it is a zero-padded float32 copy of ``x``, ``K`` shifted slices,
 a sum and three roundings, none of which XLA can fuse across the scan's
 ``pallas_call``, and four more float32 passes for its derivative; here each
@@ -53,6 +54,32 @@ registers along a grid step, then into one output block ``[8, width]`` a row
 of the batch that the grid revisits along the rows (hence rows innermost and
 ``arbitrary``).
 
+**The norm** (``norm_heads = H``: Kimi-Linear's q and k, whose published
+mixer is ``l2norm(silu(conv(x W)))``).  With a head the ``D = C / H`` channels
+``h D … (h + 1) D`` and ``s_t[h]`` the sum of ``y_t[c]^2`` over them,
+
+    r_t[h] = rsqrt(s_t[h] + eps)        n_t[c] = y_t[c] r_t[h]
+
+and the kernel's result is ``n``, from the float32 ``y`` a slab holds and
+rounded once: a square, one lane reduction a head (a head of several lane
+tiles adds its tiles first), a root on an ``[8, 1]`` column and a product,
+where XLA ran five passes over ``[T, C]`` and three products with the heads'
+0/1 matrix around the ``pallas_call`` it could not fuse into.  The norm is
+local to a row and a head, so the halos are what they were.  Backward, the
+kernel forms ``y``, ``r`` and ``n`` again beside ``pre`` and turns the
+incoming cotangent ``dn`` into
+
+    dy_t[c] = r_t[h] (dn_t[c] - n_t[c] sum over the head of dn_t n_t)
+
+in the same slab, before ``dpre = dy silu'(pre)``; neither ``y`` nor a
+statistic is kept for it.  A chunk of lanes and a grid step hold whole heads
+(:func:`plan_for`); through Mosaic a head is whole lane tiles.  A head's
+reduction, root and spread are one dependent chain through the XLU and the
+EUP, and a turn of the inner loop is all the scheduler sees at once: under a
+norm a turn writes out :data:`_NORM_TURN` groups, so that other slabs' work
+fills the chain's waits.  Without a norm both kernels trace to what they
+traced to before they knew one.
+
 Operands / results: forward 2 -> 1, backward 4 -> 2 (``x``, its halo, ``dy``,
 the taps' array -> ``dx`` and the taps' array's gradient): none of the
 signatures ``chipbench/trace_reduce.flash_kernel`` knows a flash kernel by.
@@ -61,6 +88,8 @@ signatures ``chipbench/trace_reduce.flash_kernel`` knows a flash kernel by.
 from __future__ import annotations
 
 import functools
+import math
+import operator
 from typing import NamedTuple, Optional
 
 import jax
@@ -77,6 +106,9 @@ _SLAB = 8       # a float32 sublane tile: what a shift is formed on
 _GROUP = 16     # rows loaded and stored at once: a bfloat16 sublane tile, two slabs
 _CHUNK = 256    # lanes walked at once: every carried array two registers a slab
 _TURN = 2       # groups written out in one turn of the inner loop (Mosaic unrolls no loop in part)
+# and under a norm: a head's lane reduction, root and spread are one long chain of the XLU's and the EUP's, and only
+# what stands written out in one turn can fill its waits (two groups: 0.63 ms a call forward at 4,096 channels; eight: 0.30)
+_NORM_TURN = 8
 
 # a grid step at most: rows (long: the walk down a chunk of lanes starts and ends once a block; 0.37 -> 0.30 ms a
 # call forward from 512 rows to 4,096 at 4,352 channels), lane tiles, and elements of the two together (two
@@ -86,6 +118,13 @@ _TILES = 8
 _BLOCK = 1 << 20
 
 
+def _unit(head: Optional[int]) -> int:
+    """The fewest lanes that are whole lane tiles and, under a norm over
+    heads of ``head`` channels, whole heads (128 for a head of 128 or of 16,
+    256 for one of 256, 384 for one of 48)."""
+    return _LANES if head is None else math.lcm(head, _LANES)
+
+
 class _Plan(NamedTuple):
     """The blocks of one call."""
 
@@ -93,30 +132,46 @@ class _Plan(NamedTuple):
     width: int      # channels of a grid step, whole lane tiles
     K: int
     biased: bool
+    head: Optional[int] = None      # channels of a head whose L2 norm the kernels apply to ``y``; None: no norm
+    eps: float = 0.0                # under that norm's root
 
     @property
     def tiles(self) -> int:
         return self.width // _LANES
 
+    @property
+    def per(self) -> int:
+        """Groups written out in one turn of the inner loop."""
+        return _TURN if self.head is None else _NORM_TURN
 
-def plan_for(T: int, C: int, K: int = 4, biased: bool = False) -> "tuple[_Plan, int, int]":
+    @property
+    def chunk(self) -> int:
+        """Lanes walked at once: :data:`_CHUNK` where it divides the grid
+        step's (and is whole heads under a norm), else a lane tile, or the
+        lane tiles that hold whole heads."""
+        unit = _unit(self.head)
+        return _CHUNK if self.width % _CHUNK == 0 and _CHUNK % unit == 0 else unit
+
+
+def plan_for(
+    T: int, C: int, K: int = 4, biased: bool = False, head: Optional[int] = None, eps: float = 0.0
+) -> "tuple[_Plan, int, int]":
     """``(plan, padded T, padded C)``: channels in whole lane tiles, as many
     to a grid step as divide them, :data:`_TILES` at most (4,352 channels are
     34 lane tiles: 2 a grid step; 4,096 and 5,120 take 8); ``T`` in as few
     equal blocks of rows as :data:`_ROWS` and :data:`_BLOCK` allow, each
     whole turns of the inner loop (T = 8,192: two blocks of 4,096 rows at
-    256 channels, eight of 1,024 at 1,024)."""
-    tiles = -(-C // _LANES)
-    width = _LANES * max(n for n in range(1, _TILES + 1) if tiles % n == 0)
+    256 channels, eight of 1,024 at 1,024).  Under a norm over heads of
+    ``head`` channels :func:`_unit` stands in a lane tile's place, so no head
+    lies across two grid steps or two chunks of lanes."""
+    unit = _unit(head)
+    units = -(-C // unit)
+    width = unit * max(n for n in range(1, max(_TILES * _LANES // unit, 1) + 1) if units % n == 0)
     steps = -(-T // min(_ROWS, _BLOCK // width))
-    turn = _GROUP * _TURN
+    plan = _Plan(rows=0, width=width, K=K, biased=biased, head=head, eps=eps)
+    turn = _GROUP * plan.per
     rows = -(-T // (steps * turn)) * turn
-    return _Plan(rows=rows, width=width, K=K, biased=biased), -(-T // rows) * rows, tiles * _LANES
-
-
-def _chunk(width: int) -> int:
-    """Lanes walked at once: :data:`_CHUNK` where it divides the grid step's, else a lane tile."""
-    return _CHUNK if width % _CHUNK == 0 else _LANES
+    return plan._replace(rows=rows), -(-T // rows) * rows, units * unit
 
 
 def _sigmoid(a):
@@ -152,8 +207,40 @@ def _pre(taps, bias, shifted):
     return acc if bias is None else acc + bias
 
 
+def _head_sum(a):
+    """``a [8, a head's lanes]`` summed over the lanes: ``[8, 1]``, one lane
+    reduction (a head of several lane tiles adds its tiles first)."""
+    if a.shape[1] % _LANES == 0:
+        a = functools.reduce(operator.add, [a[:, i:i + _LANES] for i in range(0, a.shape[1], _LANES)])
+    return jnp.sum(a, axis=1, keepdims=True)
+
+
+def _by_head(fn, plan: _Plan, *slabs):
+    """``fn`` on each head's lanes of the ``[8, lanes]`` slabs, the results side by side again."""
+    lanes = slabs[0].shape[1]
+    out = [fn(*(a[:, h:h + plan.head] for a in slabs)) for h in range(0, lanes, plan.head)]
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+
+
+def _normed(y, plan: _Plan):
+    """``n = y r``, ``r = rsqrt(sum over the head of y^2 + eps)``."""
+    return _by_head(lambda y: y * lax.rsqrt(_head_sum(y * y) + plan.eps), plan, y)
+
+
+def _norm_pulled(y, dn, plan: _Plan):
+    """``dy = r (dn - n sum over the head of (dn n))``: ``n``'s cotangent back
+    through the norm, ``r`` and ``n`` formed again from ``y``."""
+
+    def pull(y, dn):
+        r = lax.rsqrt(_head_sum(y * y) + plan.eps)
+        n = y * r
+        return r * (dn - n * _head_sum(dn * n))
+
+    return _by_head(pull, plan, y, dn)
+
+
 def _fwd_kernel(x_ref, w_ref, y_ref, tail, *, plan: _Plan):
-    K, chunk, per = plan.K, _chunk(plan.width), _TURN
+    K, chunk, per = plan.K, plan.chunk, plan.per
     first = pl.program_id(2) == 0
     row = lax.broadcasted_iota(jnp.int32, (_SLAB, chunk), 0)
 
@@ -168,7 +255,8 @@ def _fwd_kernel(x_ref, w_ref, y_ref, tail, *, plan: _Plan):
                 out = []
                 for cur in (xs[:_SLAB], xs[_SLAB:]):
                     pre = _pre(taps, bias, [_down(before, cur, s, row) for s in range(K)])
-                    out.append(pre * _sigmoid(pre))
+                    y = pre * _sigmoid(pre)
+                    out.append(y if plan.head is None else _normed(y, plan))
                     before = cur
                 y_ref[0, rows, lanes] = jnp.concatenate(out, axis=0).astype(y_ref.dtype)
             return before
@@ -181,7 +269,7 @@ def _fwd_kernel(x_ref, w_ref, y_ref, tail, *, plan: _Plan):
 
 
 def _bwd_kernel(x_ref, halo_ref, dy_ref, w_ref, dx_ref, dw_ref, head, *, plan: _Plan):
-    K, chunk, per = plan.K, _chunk(plan.width), _TURN
+    K, chunk, per = plan.K, plan.chunk, plan.per
     groups = plan.rows // _GROUP
     i = pl.program_id(2)                        # the grid's first step is the sequence's last rows
     start = i == pl.num_programs(2) - 1         # this grid step holds the sequence's first rows
@@ -197,11 +285,14 @@ def _bwd_kernel(x_ref, halo_ref, dy_ref, w_ref, dx_ref, dw_ref, head, *, plan: _
 
         def slab(before, cur, dy, after, sums):
             """``dpre`` of the slab ``cur`` (``before``: the slab of ``x``
-            above it), the sums with it added, and the slab's ``dx`` from its
-            ``dpre`` and the slab's under it (``after``)."""
+            above it; ``dy`` the cotangent of the kernel's result, ``n``'s
+            under a norm), the sums with it added, and the slab's ``dx`` from
+            its ``dpre`` and the slab's under it (``after``)."""
             shifted = [_down(before, cur, s, row) for s in range(K)]
             pre = _pre(taps, bias, shifted)
             sig = _sigmoid(pre)
+            if plan.head is not None:
+                dy = _norm_pulled(pre * sig, dy, plan)
             dpre = dy * (sig * (1.0 + pre * (1.0 - sig)))
             sums = [sums[j] + dpre * shifted[K - 1 - j] for j in range(K)] + [sums[K] + dpre]
             dx = taps[K - 1] * dpre
@@ -309,25 +400,46 @@ _conv.defvjp(_conv_fwd, _conv_bwd)
 
 
 def short_conv(
-    x: jnp.ndarray, taps: jnp.ndarray, bias: Optional[jnp.ndarray] = None, interpret: Optional[bool] = None
+    x: jnp.ndarray,
+    taps: jnp.ndarray,
+    bias: Optional[jnp.ndarray] = None,
+    interpret: Optional[bool] = None,
+    *,
+    norm_heads: Optional[int] = None,
+    norm_eps: float = 1e-6,
 ) -> jnp.ndarray:
     """``silu(sum_j taps[j] x_{t - (K - 1) + j} + bias)`` over ``x [B, T,
     C]`` with ``taps [K, C]`` and ``bias [C]`` or none, each row of the batch
     from zeros before its first step: ``[B, T, C]`` in ``x``'s dtype, summed
-    in float32 and rounded once.  Differentiable in ``x``, ``taps`` and
-    ``bias`` (the parameters' gradients summed in float32).  Any ``T`` and
-    ``C``: rows are padded with zeros to whole blocks and channels to whole
-    lane tiles (the published shapes are both already: no copy there).
-    ``interpret=None`` asks
+    in float32 and rounded once.  With ``norm_heads = H`` the result is that
+    ``y`` times ``rsqrt(sum over a head of y^2 + norm_eps)``, head ``h`` the
+    channels ``h C / H … (h + 1) C / H``, still float32 until the one
+    rounding (what the mixer's mathematics says of this call: q and k are
+    normed, v is not).  Differentiable in ``x``, ``taps`` and ``bias`` (the
+    parameters' gradients summed in float32).  Any ``T`` and ``C``: rows are
+    padded with zeros to whole blocks and channels to whole lane tiles (the
+    published shapes are both already: no copy there).  Through Mosaic a
+    normed head is whole lane tiles (``ValueError`` otherwise); the
+    interpreter takes any head size.  ``interpret=None`` asks
     :func:`ops.kernel_mode.resolve_interpret` (site ``"short_conv"``)."""
     Bt, T, C = x.shape
     K = taps.shape[0]
     if taps.shape != (K, C) or not 1 <= K < _SLAB or (bias is not None and bias.shape != (C,)):
         raise ValueError(f"short_conv shapes: x {x.shape} taps {taps.shape} bias {None if bias is None else bias.shape}")
+    if norm_heads is not None and (norm_heads < 1 or C % norm_heads):
+        raise ValueError(f"short_conv shapes: {C} channels are no {norm_heads} heads")
     interp = resolve_interpret(interpret, "short_conv")
-    plan, Tp, Cp = plan_for(T, C, K, bias is not None)
+    head = None if norm_heads is None else C // norm_heads
+    if head is not None and not interp and head % _LANES:
+        raise ValueError(
+            f"short_conv through Mosaic norms heads of whole lane tiles ({_LANES} channels): "
+            f"x {x.shape} in {norm_heads} heads of {head}"
+        )
+    plan, Tp, Cp = plan_for(T, C, K, bias is not None, head, float(norm_eps) if head else 0.0)
     metrics = default_registry()
     metrics.incr("conv.calls")
+    if head is not None:
+        metrics.incr("conv.norm_calls")
     metrics.gauge("conv.block_rows", plan.rows)
     metrics.gauge("conv.lane_tiles", plan.tiles)
     w = taps.astype(jnp.float32)

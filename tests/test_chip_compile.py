@@ -613,6 +613,29 @@ def test_the_hybrid_cells_step_fits_the_chip(hybrid_step):
     assert _conv_kernels(text) == {"short_conv_fwd": 12, "short_conv_bwd": 12}         # q, k and v of four KDA layers
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 0.95 * 16 * 2**30
+    # no more than with q's and k's norm as XLA's passes (1f4764e, the parent of PR 43: 14.3053 GiB; 14.2962 with it in the kernels)
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes <= 15_360_188_416
+
+
+def test_the_hybrid_cells_kda_layers_hold_no_head_matrix_product_but_o_norms(hybrid_step):
+    """q's and k's ``l2norm`` rides ``short_conv``'s kernels (PR 43): in the
+    cell's whole step, fused bodies included, no instruction whose ``op_name``
+    lies in a KDA layer's ``self_attn/`` holds a product with the heads' 0/1
+    matrix (``bth,ch->btc`` spreads a head's statistic over its channels,
+    ``btc,ch->bth`` sums them: ``kimi_linear._head_spread`` /
+    ``_head_sums``) but ``o_norm``'s own, which stand in all four layers
+    forward and backward.  The parent held 38 such instructions a step outside
+    ``o_norm``, thirty of them a pass over ``[8192, 4096]``."""
+    _, text = hybrid_step
+    inside, outside = 0, []
+    for line in text.splitlines():
+        where = re.search(r'op_name="([^"]*layers_(?:0|1|2|4)/self_attn/[^"]*(?:bth,ch->btc|btc,ch->bth)[^"]*)"', line)
+        if where and "/self_attn/o_norm/" in where.group(1):
+            inside += 1
+        elif where:
+            outside.append(line.strip()[:200])
+    assert inside >= 16, inside             # the names still find the products: a sum and a spread each way in four layers
+    assert not outside, outside
 
 
 def test_the_hybrid_cells_kda_layers_keep_one_layout(hybrid_step):
@@ -963,18 +986,36 @@ def test_the_sambay_cells_step_fits_the_chip(topo, monkeypatch):
 # --- the short convolution in front of the three scans ----------------------------
 
 
+#: sha256 of ``value_and_grad`` of the short convolution as lowered at 1f4764e (the parent of PR 43, which taught the
+#: kernels q's and k's norm), text without source locations, Mosaic bodies included: cell 6's and cell 7's shapes and
+#: cell 4's v, none of them normed
+_PARENT_CONV_LOWERED = {
+    4352: "e1f76e19a4396c6cf6d5a5c0f3c4ff48ba647393ab8bfd4d3484e9c718ba1ab5",
+    4096: "14681f19e4cb33287b37c04a4eb5ad42854622c4d03ce101571cd4ebe3b19a5d",
+    5120: "5e3933611675e9fd40cfc9fea7c9d391d0e6062dcb1c54e7aa60b9cf08bc201d",
+}
+
+
 @pytest.mark.parametrize(
-    "channels, biased", [(4352, True), (4096, False), (5120, True)], ids=["granite-xBC", "kimi-qkv", "phi4-x"],
+    "channels, biased, heads", [(4352, True, None), (4096, False, None), (5120, True, None), (4096, False, 32)],
+    ids=["granite-xBC", "kimi-v", "phi4-x", "kimi-qk-normed"],
 )
-def test_the_short_convolution_is_its_two_kernels_at_a_published_shape(one_chip, channels, biased):
+def test_the_short_convolution_is_its_two_kernels_at_a_published_shape(one_chip, channels, biased, heads):
     """``value_and_grad`` of ``short_conv`` over ``[1, 8192, C]`` bfloat16 at
     the three mixers' widths through Mosaic: the program is ``short_conv_fwd``
     (``x`` and the taps' array in, ``y`` out) and ``short_conv_bwd`` (``x``
     twice, ``dy`` and the taps' array in; ``dx`` and the taps' array's
     gradient out) and nothing else that walks 8,192 x C elements but what
     stands for the test's own sum: no padded float32 copy, no pass for the
-    bias, the silu or their derivative.  Two operands and four: neither of the
-    counts ``chipbench/trace_reduce.flash_kernel`` takes for a flash kernel."""
+    bias, the silu or their derivative, and none for the norm over 32 heads
+    of 128 that Kimi-Linear's q and k ride the kernels with (no statistic
+    leaves the forward kernel: the same operands and results).  Two operands
+    and four: neither of the counts ``chipbench/trace_reduce.flash_kernel``
+    takes for a flash kernel.  Without a norm the lowered text is the
+    parent's, character for character: cells 6 and 7 run what they ran."""
+    import functools
+    import hashlib
+
     from adapcc_tpu.ops.short_conv import short_conv
 
     T = 8192
@@ -983,10 +1024,14 @@ def test_the_short_convolution_is_its_two_kernels_at_a_published_shape(one_chip,
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     def conv(x, taps, *bias):
-        return jnp.sum(short_conv(x, taps, *bias, interpret=False).astype(jnp.float32))
+        return jnp.sum(short_conv(x, taps, *bias, interpret=False, norm_heads=heads).astype(jnp.float32))
 
     args = (shape((1, T, channels), jnp.bfloat16), shape((4, channels))) + ((shape((channels,)),) if biased else ())
-    compiled = jax.jit(jax.value_and_grad(conv, argnums=tuple(range(len(args))))).lower(*args).compile()
+    step = jax.jit(jax.value_and_grad(conv, argnums=tuple(range(len(args)))))
+    if heads is None:
+        lowered = _without_source_lines(functools.partial(step.lower, *args))
+        assert hashlib.sha256(lowered.encode()).hexdigest() == _PARENT_CONV_LOWERED[channels]
+    compiled = step.lower(*args).compile()
     assert _kernels_in(compiled) == 2
     text = compiled.as_text()
     assert _conv_kernels(text) == {"short_conv_fwd": 1, "short_conv_bwd": 1}

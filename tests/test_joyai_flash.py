@@ -376,13 +376,13 @@ def test_the_config_reads_config_json_and_refuses_what_it_does_not_implement():
 # --- the models that were here lower to what they lowered to ---------------------
 
 #: sha256 of the lowered loss-and-gradient step at ca7fb08 (the parent of PR 34), by ``lowered_digest`` below;
-#: ``kimi-linear-dense`` as PR 42's own tree lowers it, which means to alter it (its KDA layers' convolutions and
-#: their silu are ``ops/short_conv.py``'s two kernels; PR 40 had replaced it for the flat layout); the latent layers
-#: alone still lower to ca7fb08's text
+#: ``kimi-linear-dense`` as PR 43's own tree lowers it, which means to alter it (q's and k's ``l2norm`` rides the
+#: short convolution's two kernels; PR 42 had replaced it for those kernels, PR 40 for the flat layout); the latent
+#: layers alone still lower to ca7fb08's text
 _PARENT_LOWERED = {
     "trinity-dense": "37a8171073a0b4a3dacc27e8b8545e81591e23a904e1e308408aa025b260041e",
     "trinity-chunked": "a932872d3198be9fdbb2f0ba03f5d0f9a185c0419a89343cf30e19254da6bb44",
-    "kimi-linear-dense": "ef3536e3b8ba56aa5c491737e176b61e2fcff41837401bfb2c6834ee0b12cf71",
+    "kimi-linear-dense": "29b67dd7cd4dae8d53c36a4352943a400ca8b67ed3210220088215ded08ee99b",
     "kimi-linear-latent-layers-chunked": "7bcb169f9e9314357cf7c76268c4d8396b67fa42046cc894470afee6819f579a",
 }
 

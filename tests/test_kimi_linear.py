@@ -256,8 +256,9 @@ def test_the_parents_parameter_tree_initialises_and_loads_into_the_mixer_unchang
 
 @pytest.mark.parametrize("T", [40, 100])
 def test_the_kda_mixer_is_the_reference_a_step_at_a_time_in_value_and_every_gradient(params, T):
-    """The mixer on flat arrays (projections, convolutions, the two flat
-    norms, the flat decay, the scan through the interpreter, the gate) against
+    """The mixer on flat arrays (projections, the convolutions' kernels with
+    q's and k's norm inside them, the flat decay, the scan through the
+    interpreter, the flat ``o_norm``, the gate) against
     the reference that reshapes to heads and runs the recurrence a step at a
     time: the output, the input's gradient and every parameter's."""
     p = params["params"]["layers_1"]["self_attn"]
