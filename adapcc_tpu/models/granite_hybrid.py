@@ -11,10 +11,10 @@ code with each other: the Mamba-2 mixer is a recurrence over a ``[64, 128]``
 state a head (:mod:`adapcc_tpu.ops.ssd`), the attention layer a causal softmax
 at head size 64 with four query heads to a K/V head
 (:mod:`adapcc_tpu.ops.flash_attention`, its scale the configuration's
-``attention_multiplier``, not ``1 / sqrt(d)``).  Norm, gated MLP and the remat
-table are :mod:`adapcc_tpu.models.trinity`'s, the taps' and the decay's
-initialisation :mod:`adapcc_tpu.models.kimi_linear`'s, the short convolution
-with its bias and silu one kernel (:mod:`adapcc_tpu.ops.short_conv`).
+``attention_multiplier``, not ``1 / sqrt(d)``).  Norm, gated MLP, the remat
+table and the taps' and the decay's initialisation are
+:mod:`adapcc_tpu.models.lm`'s, the short convolution with its bias and silu
+one kernel (:mod:`adapcc_tpu.ops.short_conv`).
 
 Four scalings no other model here has: ``h = embedding_multiplier E[ids]``;
 both branches of a layer enter the stream times ``residual_multiplier``; the
@@ -37,8 +37,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from adapcc_tpu.models.kimi_linear import a_log_init, dt_bias_init, taps_init
-from adapcc_tpu.models.trinity import _REMAT, GatedMLP, RMSNorm, _dense
+from adapcc_tpu.models.lm import (
+    REMAT, GatedMLP, RMSNorm, a_log_init, dense, dt_bias_init, next_token_loss, taps_init,
+)
 from adapcc_tpu.utils.observability import default_registry
 
 _PUBLISHED_LAYERS = tuple("attention" if i % 10 == 5 else "mamba" for i in range(40))
@@ -89,8 +90,8 @@ class GraniteHybridConfig:
                 "no positions, silu, a tied head, a biased convolution and no other bias, "
                 "mamba_expand * hidden_size = mamba_n_heads * mamba_d_head"
             )
-        if self.remat not in _REMAT:
-            raise ValueError(f"remat {self.remat!r} not in {sorted(_REMAT)}")
+        if self.remat not in REMAT:
+            raise ValueError(f"remat {self.remat!r} not in {sorted(REMAT)}")
         if self.num_attention_heads % self.num_key_value_heads or self.hidden_size % self.num_attention_heads:
             raise ValueError(f"heads {self.num_attention_heads} over {self.num_key_value_heads} of {self.hidden_size}")
         self.kinds   # every layer run has a known mixer
@@ -151,7 +152,7 @@ class Mamba2Mixer(nn.Module):
         cfg = self.cfg
         H, N, d_in = cfg.mamba_n_heads, cfg.mamba_d_state, cfg.d_inner
         conv = d_in + 2 * N
-        z, xBC, dt = jnp.split(_dense(d_in + conv + H, cfg, "in_proj")(u), [d_in, d_in + conv], axis=-1)
+        z, xBC, dt = jnp.split(dense(d_in + conv + H, cfg, "in_proj")(u), [d_in, d_in + conv], axis=-1)
         with jax.named_scope("ssd_conv"):
             taps = self.param("conv_taps", taps_init, (cfg.mamba_d_conv, conv))
             bias = self.param("conv_bias", nn.initializers.zeros, (conv,))
@@ -164,7 +165,7 @@ class Mamba2Mixer(nn.Module):
         with jax.named_scope("ssd_scan"):
             y = ssd(x, dt, A, B, C, self.param("D", nn.initializers.ones, (H,)))
         y = RMSNorm(cfg.rms_norm_eps, name="norm")(y * nn.silu(z))
-        return _dense(cfg.hidden_size, cfg, "out_proj")(y), floor
+        return dense(cfg.hidden_size, cfg, "out_proj")(y), floor
 
 
 class AttentionMixer(nn.Module):
@@ -180,12 +181,12 @@ class AttentionMixer(nn.Module):
         cfg = self.cfg
         B, T, _ = u.shape
         H, Hkv, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-        q = _dense(H * D, cfg, "q_proj")(u).reshape(B, T, H, D)
-        k = _dense(Hkv * D, cfg, "k_proj")(u).reshape(B, T, Hkv, D)
-        v = _dense(Hkv * D, cfg, "v_proj")(u).reshape(B, T, Hkv, D)
+        q = dense(H * D, cfg, "q_proj")(u).reshape(B, T, H, D)
+        k = dense(Hkv * D, cfg, "k_proj")(u).reshape(B, T, Hkv, D)
+        v = dense(Hkv * D, cfg, "v_proj")(u).reshape(B, T, Hkv, D)
         with jax.named_scope("gqa_attn"):
             o = flash_attention(q, k, v, causal=True, scale=float(cfg.attention_multiplier))
-        return _dense(cfg.hidden_size, cfg, "o_proj")(o.reshape(B, T, H * D))
+        return dense(cfg.hidden_size, cfg, "o_proj")(o.reshape(B, T, H * D))
 
 
 class Block(nn.Module):
@@ -227,7 +228,7 @@ class GraniteHybrid(nn.Module):
             dtype=cfg.dtype, name="embed_tokens",
         )
         h = embed(tokens) * jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
-        policy = _REMAT[cfg.remat]
+        policy = REMAT[cfg.remat]
         block = Block if policy is False else nn.remat(Block, policy=policy)
         floors = []
         for i, kind in enumerate(cfg.kinds):
@@ -249,24 +250,11 @@ def stateful_loss(model: GraniteHybrid, loss: str = "dense", block: int = 2048):
     the vocabulary held, through ``gpt2.lm_loss`` (or ``ops/chunked_ce.py``
     with ``loss="chunked"``, the head product fused into the loss) with the
     embedding as the head; the state the step returns is ``{"ssd_decay_floor"}``."""
-    from adapcc_tpu.models.gpt2 import lm_loss
-
-    if loss not in ("dense", "chunked"):
-        raise ValueError(f"loss {loss!r} not in ('dense', 'chunked')")
+    hidden, value = next_token_loss(loss, block, model.cfg.dtype)
 
     def loss_fn(params, model_state, batch):
-        if loss == "dense":
-            logits, floor = model.apply(params, batch)
-            value = lm_loss(logits, batch)
-        else:
-            from adapcc_tpu.ops.chunked_ce import chunked_lm_loss
-
-            hidden, floor = model.apply(params, batch, return_hidden=True)
-            with jax.named_scope("loss"):
-                value = chunked_lm_loss(
-                    hidden, params["params"]["embed_tokens"]["embedding"], batch, block, model.cfg.dtype
-                )
-        return value, {"ssd_decay_floor": floor}
+        out, floor = model.apply(params, batch, return_hidden=hidden)
+        return value(out, params["params"]["embed_tokens"]["embedding"], batch), {"ssd_decay_floor": floor}
 
     return loss_fn
 

@@ -9,10 +9,10 @@ and the layer equations docs/JOYAI_FLASH.md states; the fields of
 :class:`JoyAIFlashConfig` are that file's keys.  The layer and its latent
 mixer are :mod:`adapcc_tpu.models.kimi_linear`'s (``Block``, ``MLAMixer``:
 here with ``q_lora_rank`` and the rotation of ``rope_interleave``); norm,
-gated MLP, the expert layer with its share and the remat table are
-:mod:`adapcc_tpu.models.trinity`'s (``noaux_tc`` in one group is Trinity's
-router to the letter: sigmoid scores, a bias for the choice only, top-k,
-renormalised, scaled).
+gated MLP and the remat table are :mod:`adapcc_tpu.models.lm`'s, the expert
+layer with its share :mod:`adapcc_tpu.models.trinity`'s (``noaux_tc`` in one
+group is Trinity's router to the letter: sigmoid scores, a bias for the choice
+only, top-k, renormalised, scaled).
 
 **Two loss terms over shared weights.**  The MTP module (depth 1) merges the
 trunk's final-norm output at position ``i`` with the embedding of token
@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from adapcc_tpu.models.kimi_linear import Block
-from adapcc_tpu.models.trinity import _REMAT, RMSNorm, _dense
+from adapcc_tpu.models.lm import REMAT, RMSNorm, dense, next_token_loss
 from adapcc_tpu.utils.observability import default_registry
 
 
@@ -96,8 +96,8 @@ class JoyAIFlashConfig:
                 "group, silu, one shared expert, an untied head, no bias, interleaved rotation without scaling, "
                 "one multi-token-prediction module"
             )
-        if self.remat not in _REMAT:
-            raise ValueError(f"remat {self.remat!r} not in {sorted(_REMAT)}")
+        if self.remat not in REMAT:
+            raise ValueError(f"remat {self.remat!r} not in {sorted(REMAT)}")
         if not 0 <= self.expert_offset <= self.num_experts - self.held:
             raise ValueError(f"experts {self.expert_offset}+{self.held} of {self.num_experts}")
 
@@ -128,7 +128,7 @@ class JoyAIFlashConfig:
 
     @property
     def kinds(self):
-        """Every trunk layer's mixer (``train_trinity.train`` prints them)."""
+        """Every trunk layer's mixer (``train_lm.train`` prints them)."""
         return ("mla",) * self.num_hidden_layers
 
     @property
@@ -159,7 +159,7 @@ class JoyAIFlashConfig:
 
 
 def _block(cfg: JoyAIFlashConfig):
-    policy = _REMAT[cfg.remat]
+    policy = REMAT[cfg.remat]
     return Block if policy is False else nn.remat(Block, policy=policy)
 
 
@@ -177,7 +177,7 @@ class MTPModule(nn.Module):
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, name=name)  # noqa: E731
         with jax.named_scope("mtp_merge"):
             merged = jnp.concatenate([norm("enorm")(next_embedded), norm("hnorm")(trunk)], axis=-1)
-            z = _dense(cfg.hidden_size, cfg, "eh_proj")(merged)
+            z = dense(cfg.hidden_size, cfg, "eh_proj")(merged)
         with jax.named_scope("mtp_block"):
             u, sizes = _block(cfg)(cfg, "mla", True, name="block")(z)
         return norm("shared_head_norm")(u), sizes
@@ -231,28 +231,16 @@ def stateful_loss(model: JoyAIFlash, loss: str = "dense", block: int = 2048):
     through ``gpt2.lm_loss`` (or ``ops/chunked_ce.py`` with ``loss="chunked"``)
     over the vocabulary held; the state the step returns is ``{"moe_sizes",
     "loss_main", "loss_mtp"}``."""
-    from adapcc_tpu.models.gpt2 import lm_loss
-
-    if loss not in ("dense", "chunked"):
-        raise ValueError(f"loss {loss!r} not in ('dense', 'chunked')")
+    hidden, value = next_token_loss(loss, block, model.cfg.dtype)
     weight = model.cfg.mtp_loss_weight
 
     def loss_fn(params, model_state, batch):
+        out, mtp_out, sizes = model.apply(params, batch, return_hidden=hidden)
+        head = params["params"]["lm_head"]
+        main = value(out, head, batch)
         # the module's place i answers for token i + 2: its places but the last against the tokens from the second on
-        if loss == "dense":
-            logits, mtp_logits, sizes = model.apply(params, batch)
-            main = lm_loss(logits, batch)
-            with jax.named_scope("mtp_head"):
-                mtp = lm_loss(mtp_logits[:, :-1], batch[:, 1:])
-        else:
-            from adapcc_tpu.ops.chunked_ce import chunked_lm_loss
-
-            hidden, mtp_hidden, sizes = model.apply(params, batch, return_hidden=True)
-            head = params["params"]["lm_head"]
-            with jax.named_scope("loss"):
-                main = chunked_lm_loss(hidden, head, batch, block, model.cfg.dtype)
-            with jax.named_scope("mtp_head"):
-                mtp = chunked_lm_loss(mtp_hidden[:, :-1], head, batch[:, 1:], block, model.cfg.dtype)
+        with jax.named_scope("mtp_head"):
+            mtp = value(mtp_out[:, :-1], head, batch[:, 1:], scope=None)
         return main + weight * mtp, {"moe_sizes": sizes, "loss_main": main, "loss_mtp": mtp}
 
     return loss_fn

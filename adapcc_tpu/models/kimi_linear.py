@@ -13,14 +13,16 @@ group flattened).  The two mixers share no code with each other: KDA is a
 recurrence over a ``[128, 128]`` state a head (:mod:`adapcc_tpu.ops.kda`), the
 latent layer a causal softmax whose scores run over 192 channels and whose
 values over 128 (:mod:`adapcc_tpu.ops.flash_attention`).  Norm, gated MLP,
-the expert layer with its share, the remat table and the training loss are
+the remat table and the scans' initialisers are :mod:`adapcc_tpu.models.lm`'s;
+the expert layer with its share and the training loss are
 :mod:`adapcc_tpu.models.trinity`'s: the router is Trinity's to the letter
 (sigmoid scores, a bias for the choice only, top-k of one group,
 renormalised, scaled), so a chip holds ``experts_held`` of the
 ``num_experts`` from ``expert_offset`` on, as there.
 
-The model returns ``(logits, sizes)`` as ``Trinity`` does, so
-``trinity.stateful_loss`` and ``trinity.initial_model_state`` serve it.
+The model returns ``(logits, sizes)`` as ``Trinity`` does, so Trinity's
+``stateful_loss`` and ``initial_model_state`` serve it and are this module's
+names too.
 """
 
 from __future__ import annotations
@@ -34,7 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from adapcc_tpu.models.trinity import _REMAT, GatedMLP, RMSNorm, SparseExperts, _dense
+from adapcc_tpu.models.lm import REMAT, GatedMLP, RMSNorm, a_log_init, dense, dt_bias_init, taps_init
+from adapcc_tpu.models.trinity import SparseExperts, initial_model_state, stateful_loss  # noqa: F401
 
 #: ``l2norm(x) = x * rsqrt(sum(x^2) + L2_EPS)`` over a head
 L2_EPS = 1e-6
@@ -90,8 +93,8 @@ class KimiLinearConfig:
                 "only the published kimi_linear settings are implemented: sigmoid scores in one group, "
                 "silu, one shared expert, an untied head"
             )
-        if self.remat not in _REMAT:
-            raise ValueError(f"remat {self.remat!r} not in {sorted(_REMAT)}")
+        if self.remat not in REMAT:
+            raise ValueError(f"remat {self.remat!r} not in {sorted(REMAT)}")
         self.kinds   # every layer is of one kind
         if not 0 <= self.expert_offset <= self.num_experts - self.held:
             raise ValueError(f"experts {self.expert_offset}+{self.held} of {self.num_experts}")
@@ -234,7 +237,7 @@ def o_norm(x, scale, heads: int, eps: float):
 
 class HeadRMSNorm(nn.Module):
     """:func:`o_norm` with its weight: ``scale [D]``, ones at the start
-    (``trinity.RMSNorm``'s parameter, over a head of the flat array)."""
+    (``lm.RMSNorm``'s parameter, over a head of the flat array)."""
 
     eps: float
     heads: int
@@ -242,25 +245,6 @@ class HeadRMSNorm(nn.Module):
     @nn.compact
     def __call__(self, x):
         return o_norm(x, self.param("scale", nn.initializers.ones, (x.shape[-1] // self.heads,)), self.heads, self.eps)
-
-
-def taps_init(key, shape, dtype=jnp.float32):
-    """A short convolution's taps ``[K, channels]``: uniform(-1/sqrt(K), 1/sqrt(K))."""
-    bound = shape[0] ** -0.5
-    return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-
-def a_log_init(key, shape, dtype=jnp.float32):
-    """``A_log = log(uniform(1, 16))``: a head forgets 1 to 16 times as fast as its gate says."""
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-
-def dt_bias_init(key, shape, dtype=jnp.float32):
-    """``softplus(dt_bias)`` log-uniform in [0.001, 0.1], so that a step's
-    decay ``exp(-exp(A_log) softplus(dt_bias))`` starts between 0.2 and 0.999:
-    neither forgetting all nor nothing."""
-    dt = jnp.exp(jax.random.uniform(key, shape, dtype, jnp.log(0.001), jnp.log(0.1)))
-    return dt + jnp.log(-jnp.expm1(-dt))       # softplus^-1
 
 
 class KDAMixer(nn.Module):
@@ -280,7 +264,7 @@ class KDAMixer(nn.Module):
 
         def mixed(name, normed):
             # the silu is the kernel's, and so is q's and k's l2norm (:func:`l2norm` is its plain form)
-            y = _dense(H * D, cfg, f"{name}_proj")(x)
+            y = dense(H * D, cfg, f"{name}_proj")(x)
             taps = self.param(f"{name}_conv", taps_init, (K, H * D))
             return short_conv(y, taps, norm_heads=H if normed else None, norm_eps=L2_EPS)
 
@@ -289,14 +273,14 @@ class KDAMixer(nn.Module):
         with jax.named_scope("kda_gate"):
             a_log = self.param("A_log", a_log_init, (H,))
             dt_bias = self.param("dt_bias", dt_bias_init, (H * D,))
-            f = _dense(H * D, cfg, "f_b_proj")(_dense(D, cfg, "f_a_proj")(x))
+            f = dense(H * D, cfg, "f_b_proj")(dense(D, cfg, "f_a_proj")(x))
             g = jnp.repeat(-jnp.exp(a_log.astype(jnp.float32)), D) * jax.nn.softplus(f.astype(jnp.float32) + dt_bias)
-            beta = jax.nn.sigmoid(_dense(H, cfg, "b_proj")(x).astype(jnp.float32))
+            beta = jax.nn.sigmoid(dense(H, cfg, "b_proj")(x).astype(jnp.float32))
         with jax.named_scope("kda_scan"):
             o = kda(q, k, v, g, beta)
         o = HeadRMSNorm(cfg.rms_norm_eps, H, name="o_norm")(o)     # one weight of head_dim for every head
-        gate = _dense(H * D, cfg, "g_b_proj")(_dense(D, cfg, "g_a_proj")(x))
-        return _dense(cfg.hidden_size, cfg, "o_proj")(o * jax.nn.sigmoid(gate))
+        gate = dense(H * D, cfg, "g_b_proj")(dense(D, cfg, "g_a_proj")(x))
+        return dense(cfg.hidden_size, cfg, "o_proj")(o * jax.nn.sigmoid(gate))
 
 
 @functools.lru_cache(maxsize=8)
@@ -382,13 +366,13 @@ class MLAMixer(nn.Module):
         B, T, _ = x.shape
         H, nope, pe, dv = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         if cfg.q_lora_rank is None:
-            q = _dense(H * (nope + pe), cfg, "q_proj")(x)
+            q = dense(H * (nope + pe), cfg, "q_proj")(x)
         else:
-            q_latent = RMSNorm(cfg.rms_norm_eps, name="q_a_layernorm")(_dense(cfg.q_lora_rank, cfg, "q_a_proj")(x))
-            q = _dense(H * (nope + pe), cfg, "q_b_proj")(q_latent)
+            q_latent = RMSNorm(cfg.rms_norm_eps, name="q_a_layernorm")(dense(cfg.q_lora_rank, cfg, "q_a_proj")(x))
+            q = dense(H * (nope + pe), cfg, "q_b_proj")(q_latent)
         q = q.reshape(B, T, H, nope + pe)
-        latent, k_pe = jnp.split(_dense(cfg.kv_lora_rank + pe, cfg, "kv_a_proj_with_mqa")(x), [cfg.kv_lora_rank], axis=-1)
-        up = _dense(H * (nope + dv), cfg, "kv_b_proj")(RMSNorm(cfg.rms_norm_eps, name="kv_a_layernorm")(latent))
+        latent, k_pe = jnp.split(dense(cfg.kv_lora_rank + pe, cfg, "kv_a_proj_with_mqa")(x), [cfg.kv_lora_rank], axis=-1)
+        up = dense(H * (nope + dv), cfg, "kv_b_proj")(RMSNorm(cfg.rms_norm_eps, name="kv_a_layernorm")(latent))
         k_nope, v = jnp.split(up.reshape(B, T, H, nope + dv), [nope], axis=-1)
         k_pe = k_pe[:, :, None, :]
         if not cfg.mla_use_nope:
@@ -399,7 +383,7 @@ class MLAMixer(nn.Module):
 
         with jax.named_scope("mla_attn"):
             o = flash_attention(q, k, v, causal=True)
-        return _dense(cfg.hidden_size, cfg, "o_proj")(o.reshape(B, T, H * dv))
+        return dense(cfg.hidden_size, cfg, "o_proj")(o.reshape(B, T, H * dv))
 
 
 class Block(nn.Module):
@@ -437,7 +421,7 @@ class KimiLinear(nn.Module):
             cfg.vocab_size, cfg.hidden_size, embedding_init=nn.initializers.normal(0.02),
             dtype=cfg.dtype, name="embed_tokens",
         )(tokens)
-        policy = _REMAT[cfg.remat]
+        policy = REMAT[cfg.remat]
         block = Block if policy is False else nn.remat(Block, policy=policy)
         sizes = []
         for i, kind in enumerate(cfg.kinds):

@@ -54,8 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from adapcc_tpu.models.kimi_linear import dt_bias_init, taps_init
-from adapcc_tpu.models.trinity import _REMAT, GatedMLP, _dense
+from adapcc_tpu.models.lm import REMAT, GatedMLP, dense, dt_bias_init, next_token_loss, taps_init
 from adapcc_tpu.utils.observability import default_registry
 
 def kind_of(layer: int, published_layers: int, mb_per_layer: int = 2) -> str:
@@ -127,8 +126,8 @@ class Phi4FlashConfig:
                 "only the published phi4flash settings are implemented: silu, a tied head, no bias in the MLP or the "
                 "head, no dropout, a Mamba layer every second (mb_per_layer 2), a depth that is a multiple of four"
             )
-        if self.remat not in _REMAT:
-            raise ValueError(f"remat {self.remat!r} not in {sorted(_REMAT)}")
+        if self.remat not in REMAT:
+            raise ValueError(f"remat {self.remat!r} not in {sorted(REMAT)}")
         H, Hkv = self.num_attention_heads, self.num_key_value_heads
         if self.hidden_size % H or H % 2 or Hkv % 2 or H % Hkv:
             raise ValueError(f"heads {H} over {Hkv} of {self.hidden_size}: differential attention pairs them up")
@@ -204,7 +203,7 @@ def _biased(features: int, cfg: Phi4FlashConfig, name: str):
     return nn.Dense(features, use_bias=True, dtype=cfg.dtype, name=name, kernel_init=nn.initializers.normal(0.02))
 
 
-def a_log_init(key, shape, dtype=jnp.float32):
+def state_log_init(key, shape, dtype=jnp.float32):
     """``A_log [channels, N] = log(1 .. N)`` along the state axis, as published."""
     return jnp.broadcast_to(jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)), shape)
 
@@ -224,20 +223,20 @@ class MambaMixer(nn.Module):
 
         cfg = self.cfg
         d_in, N, R = cfg.d_inner, cfg.mamba_d_state, cfg.dt_rank
-        x, z = jnp.split(_dense(2 * d_in, cfg, "in_proj")(u), 2, axis=-1)
+        x, z = jnp.split(dense(2 * d_in, cfg, "in_proj")(u), 2, axis=-1)
         with jax.named_scope("sscan_conv"):
             taps = self.param("conv_taps", taps_init, (cfg.mamba_d_conv, d_in))
             bias = self.param("conv_bias", nn.initializers.zeros, (d_in,))
             x = short_conv(x, taps, bias)       # bias and silu in the kernel
         with jax.named_scope("sscan_gate"):
-            r, B, C = jnp.split(_dense(R + 2 * N, cfg, "x_proj")(x), [R, R + N], axis=-1)
+            r, B, C = jnp.split(dense(R + 2 * N, cfg, "x_proj")(x), [R, R + N], axis=-1)
             w_dt = self.param("dt_proj", nn.initializers.normal(R ** -0.5), (R, d_in))
             dt = jnp.dot(r, w_dt.astype(cfg.dtype), preferred_element_type=jnp.float32)
             dt = jax.nn.softplus(dt + self.param("dt_bias", dt_bias_init, (d_in,)))
-            A = -jnp.exp(self.param("A_log", a_log_init, (d_in, N)).astype(jnp.float32))
+            A = -jnp.exp(self.param("A_log", state_log_init, (d_in, N)).astype(jnp.float32))
         with jax.named_scope("sscan_scan"):
             y = selective_scan(x, dt, A, B, C, self.param("D", nn.initializers.ones, (d_in,)))
-        return _dense(cfg.hidden_size, cfg, "out_proj")(y * nn.silu(z)), y
+        return dense(cfg.hidden_size, cfg, "out_proj")(y * nn.silu(z)), y
 
 
 class GatedMemoryUnit(nn.Module):
@@ -249,8 +248,8 @@ class GatedMemoryUnit(nn.Module):
     def __call__(self, u, m):
         cfg = self.cfg
         with jax.named_scope("gmu"):
-            gated = m * nn.silu(_dense(cfg.d_inner, cfg, "in_proj")(u))
-        return _dense(cfg.hidden_size, cfg, "out_proj")(gated)
+            gated = m * nn.silu(dense(cfg.d_inner, cfg, "in_proj")(u))
+        return dense(cfg.hidden_size, cfg, "out_proj")(gated)
 
 
 def band_waste(T: int, window: int, dtype, head_dim: int) -> float:
@@ -353,7 +352,7 @@ class Phi4Flash(nn.Module):
             dtype=cfg.dtype, name="embed_tokens",
         )
         h = embed(tokens)
-        policy = _REMAT[cfg.remat]
+        policy = REMAT[cfg.remat]
         block = Block if policy is False else nn.remat(Block, policy=policy)
         carried = {}
         for i, (layer, kind) in enumerate(zip(cfg.held, cfg.kinds)):
@@ -373,21 +372,10 @@ def stateful_loss(model: Phi4Flash, loss: str = "dense", block: int = 2048):
     with ``loss="chunked"``, the head product fused into the loss) with the
     embedding as the head.  The model carries nothing from step to step: the
     state comes back as it went in (``init_state``'s empty default)."""
-    from adapcc_tpu.models.gpt2 import lm_loss
-
-    if loss not in ("dense", "chunked"):
-        raise ValueError(f"loss {loss!r} not in ('dense', 'chunked')")
+    hidden, value = next_token_loss(loss, block, model.cfg.dtype)
 
     def loss_fn(params, model_state, batch):
-        if loss == "dense":
-            return lm_loss(model.apply(params, batch), batch), model_state
-        from adapcc_tpu.ops.chunked_ce import chunked_lm_loss
-
-        hidden = model.apply(params, batch, return_hidden=True)
-        with jax.named_scope("loss"):
-            value = chunked_lm_loss(
-                hidden, params["params"]["embed_tokens"]["embedding"], batch, block, model.cfg.dtype
-            )
-        return value, model_state
+        out = model.apply(params, batch, return_hidden=hidden)
+        return value(out, params["params"]["embed_tokens"]["embedding"], batch), model_state
 
     return loss_fn

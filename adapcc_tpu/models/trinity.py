@@ -34,13 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from adapcc_tpu.models.lm import REMAT, GatedMLP, RMSNorm, dense, next_token_loss
 from adapcc_tpu.models.moe import routed_experts
-
-_REMAT = {
-    "none": False,
-    "dots": jax.checkpoint_policies.checkpoint_dots,
-    "full": None,   # recompute everything in the block
-}
 
 
 @dataclass(frozen=True)
@@ -86,8 +81,8 @@ class TrinityConfig:
             raise ValueError("only the published afmoe settings are implemented: sigmoid scores, silu, untied head")
         if self.num_shared_experts != 1:
             raise ValueError("one shared expert, as published")
-        if self.remat not in _REMAT:
-            raise ValueError(f"remat {self.remat!r} not in {sorted(_REMAT)}")
+        if self.remat not in REMAT:
+            raise ValueError(f"remat {self.remat!r} not in {sorted(REMAT)}")
         if len(self.kinds) != self.num_hidden_layers:
             raise ValueError(f"{len(self.kinds)} layer_types for {self.num_hidden_layers} layers")
         if not 0 <= self.expert_offset <= self.num_experts - self.held:
@@ -123,17 +118,6 @@ class TrinityConfig:
         return TrinityConfig(**base)
 
 
-class RMSNorm(nn.Module):
-    eps: float
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        x32 = x.astype(jnp.float32)
-        y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
-        return (y * scale).astype(x.dtype)
-
-
 def rotary(x, theta: float):
     """Rotary positions over the whole head of ``x [B, T, H, D]`` (the two
     halves of the head form the rotated pairs), angles in float32."""
@@ -147,13 +131,6 @@ def rotary(x, theta: float):
     return (x32 * cos + jnp.concatenate([-second, first], axis=-1) * sin).astype(x.dtype)
 
 
-def _dense(features: int, cfg: TrinityConfig, name: str):
-    return nn.Dense(
-        features, use_bias=False, dtype=cfg.dtype, name=name,
-        kernel_init=nn.initializers.normal(0.02),
-    )
-
-
 class GatedAttention(nn.Module):
     cfg: TrinityConfig
     kind: str
@@ -163,10 +140,10 @@ class GatedAttention(nn.Module):
         cfg = self.cfg
         B, T, _ = x.shape
         H, Hkv, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-        q = _dense(H * D, cfg, "q_proj")(x).reshape(B, T, H, D)
-        k = _dense(Hkv * D, cfg, "k_proj")(x).reshape(B, T, Hkv, D)
-        v = _dense(Hkv * D, cfg, "v_proj")(x).reshape(B, T, Hkv, D)
-        gate = _dense(H * D, cfg, "gate_proj")(x)
+        q = dense(H * D, cfg, "q_proj")(x).reshape(B, T, H, D)
+        k = dense(Hkv * D, cfg, "k_proj")(x).reshape(B, T, Hkv, D)
+        v = dense(Hkv * D, cfg, "v_proj")(x).reshape(B, T, Hkv, D)
+        gate = dense(H * D, cfg, "gate_proj")(x)
         q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(q)
         k = RMSNorm(cfg.rms_norm_eps, name="k_norm")(k)
         sliding = self.kind == "sliding_attention"
@@ -186,7 +163,7 @@ class GatedAttention(nn.Module):
             else:
                 raise ValueError(f"unknown attention {cfg.attention!r} (flash|xla)")
         out = out.reshape(B, T, H * D) * jax.nn.sigmoid(gate)
-        return _dense(cfg.hidden_size, cfg, "o_proj")(out)
+        return dense(cfg.hidden_size, cfg, "o_proj")(out)
 
 
 def _dense_attention(q, k, v, window):
@@ -198,19 +175,6 @@ def _dense_attention(q, k, v, window):
     seen = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
     p = jax.nn.softmax(jnp.where(seen[None, None], s, -1e30), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
-
-
-class GatedMLP(nn.Module):
-    """``(silu(x W1) ∘ x W3) W2``."""
-
-    cfg: TrinityConfig
-    width: int
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        h = nn.silu(_dense(self.width, cfg, "gate_proj")(x)) * _dense(self.width, cfg, "up_proj")(x)
-        return _dense(cfg.hidden_size, cfg, "down_proj")(h)
 
 
 class SparseExperts(nn.Module):
@@ -291,7 +255,7 @@ class Trinity(nn.Module):
         h = embed(tokens)
         if cfg.mup_enabled:
             h = h * jnp.asarray(np.sqrt(cfg.hidden_size), h.dtype)
-        policy = _REMAT[cfg.remat]
+        policy = REMAT[cfg.remat]
         block = Block if policy is False else nn.remat(Block, policy=policy)
         sizes = []
         for i, kind in enumerate(cfg.kinds):
@@ -316,24 +280,11 @@ def stateful_loss(model: Trinity, loss: str = "dense", block: int = 2048):
     state the step returns beside it.  ``loss`` is "dense" (float32 logits of
     the whole batch) or "chunked" (``ops/chunked_ce.py``: the head product
     fused into the loss, ``block`` rows of the vocabulary at a time)."""
-    from adapcc_tpu.models.gpt2 import lm_loss
-
-    if loss not in ("dense", "chunked"):
-        raise ValueError(f"loss {loss!r} not in ('dense', 'chunked')")
+    hidden, value = next_token_loss(loss, block, model.cfg.dtype)
 
     def loss_fn(params, model_state, batch):
-        if loss == "dense":
-            logits, sizes = model.apply(params, batch)
-            value = lm_loss(logits, batch)
-        else:
-            from adapcc_tpu.ops.chunked_ce import chunked_lm_loss
-
-            hidden, sizes = model.apply(params, batch, return_hidden=True)
-            with jax.named_scope("loss"):
-                value = chunked_lm_loss(
-                    hidden, params["params"]["lm_head"], batch, block, model.cfg.dtype
-                )
-        return value, {"moe_sizes": sizes}
+        out, sizes = model.apply(params, batch, return_hidden=hidden)
+        return value(out, params["params"]["lm_head"], batch), {"moe_sizes": sizes}
 
     return loss_fn
 

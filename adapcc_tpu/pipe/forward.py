@@ -9,9 +9,6 @@ stashes, a 1F1B-bounded memory window, and per-hop trace events, all of
 which live outside one fused scan.  What this block is for is cheap
 forward sweeps (evaluation, pipelined inference over a block stack)
 where one compiled program beats a host-driven tick loop.
-
-Formerly ``adapcc_tpu.parallel.pipeline`` (still importable there via a
-warn-once deprecation shim).
 """
 
 from __future__ import annotations

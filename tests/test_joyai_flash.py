@@ -9,7 +9,8 @@ on seeded weights; the loss's weight at zero; the two uses of the embedding
 and the head; the shifted full-length module against the sliced one; the
 shares' routed parts plus the shared expert once add up to the uncut layer;
 ``KimiLinear`` and ``Trinity`` lower to the text they lowered to before this
-model came; the workload trains through ``DDPTrainer.step``.
+model came, and this model, Granite's and Phi-4-flash's to what they lowered
+to before ``models/lm.py``; the workload trains through ``DDPTrainer.step``.
 """
 
 import dataclasses
@@ -387,32 +388,62 @@ _PARENT_LOWERED = {
 }
 
 
-def lowered_digest(model, loss):
+#: the same for the three models whose ``stateful_loss`` had nothing holding it, through each model's own
+#: ``stateful_loss`` and first ``model_state`` at its ``Config.tiny()``: made on 41e404e (the parent of PR 44, which
+#: moved the loss's dense/chunked fork into ``models/lm.py``), before any model file was touched
+_LOWERED_AT_41E404E = {
+    "joyai-flash-dense": "e5971d94bb5197d9e1994a55b0625a448c5d2004327963ec51a35e028309437b",
+    "joyai-flash-chunked": "7fb918d3996dc3a9fdccdd891edde8631ff1d5a09ede226e49c20d78eec8f591",
+    "granite-hybrid-dense": "255f09cdfe64cb7466e920fe4690d7dd723dc395cbc971f7851351b514f5d875",
+    "granite-hybrid-chunked": "ca5b37138f6a6e5334074f4b4e78fd8ef760f7a9c389eddccc3a843170ec5186",
+    "phi4-flash-dense": "0fe143720abb0b4c5ba9b4ff558a20ffdf26d477ceb21ad9a3a99dd4e7f4cfea",
+    "phi4-flash-chunked": "4890f5850c9c97df475c8e9bd9c1ebd3e826fcb74695e61cac50e41dcbb05171",
+}
+
+
+def lowered_digest(model, loss, stateful_loss, first_state):
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 40), jnp.int32))
-    step = jax.jit(jax.value_and_grad(trinity.stateful_loss(model, loss, block=64), has_aux=True))
+    step = jax.jit(jax.value_and_grad(stateful_loss(model, loss, block=64), has_aux=True))
     limit = jax.config.jax_traceback_in_locations_limit
     jax.config.update("jax_traceback_in_locations_limit", 0)
     try:
-        text = step.lower(params, trinity.initial_model_state(model.cfg), jax.ShapeDtypeStruct((2, 40), jnp.int32)).as_text()
+        text = step.lower(params, first_state, jax.ShapeDtypeStruct((2, 40), jnp.int32)).as_text()
     finally:
         jax.config.update("jax_traceback_in_locations_limit", limit)
     return hashlib.sha256(text.encode()).hexdigest()
 
 
-@pytest.mark.parametrize("case", sorted(_PARENT_LOWERED))
+@pytest.mark.parametrize("case", sorted({**_PARENT_LOWERED, **_LOWERED_AT_41E404E}))
 def test_kimi_linear_and_trinity_lower_to_the_parents_text(case):
-    """PR 34 gave the latent mixer a query rank and a rotation, widened
-    ``train_trinity.train`` and added a model with a loss of its own:
-    ``Trinity``'s and ``KimiLinear``'s loss-and-gradient steps (tiny sizes,
-    ``trinity.stateful_loss``, both forms of the loss) lower to the parent's
-    text, character for character.  A change that means to alter them
-    replaces the digest and says so."""
+    """PR 34 gave the latent mixer a query rank and a rotation, widened the
+    expert models' loop (``train_lm.train`` since PR 44) and added a model
+    with a loss of its own: ``Trinity``'s and ``KimiLinear``'s
+    loss-and-gradient steps (tiny sizes, ``trinity.stateful_loss``, both forms
+    of the loss) lower to the parent's text, character for character.  PR 44
+    moved the loss's fork and the modules the models share into
+    ``models/lm.py``: JoyAI's, Granite's and Phi-4-flash's steps, each through
+    its own ``stateful_loss`` and first ``model_state``, lower to what they
+    lowered to before it.  A change that means to alter them replaces the
+    digest and says so."""
+    from adapcc_tpu.models import granite_hybrid, phi4_flash
+
+    def routed(model):
+        return model, trinity.stateful_loss, trinity.initial_model_state(model.cfg)
+
     latent_alone = KimiLinearConfig.tiny(num_hidden_layers=2, kda_layers=(), full_attn_layers=(1, 2))
-    model = {
-        "trinity": trinity.Trinity(trinity.TrinityConfig.tiny()), "kimi-linear": KimiLinear(KimiLinearConfig.tiny()),
-        "kimi-linear-latent-layers": KimiLinear(latent_alone),
-    }[case.rsplit("-", 1)[0]]
-    assert lowered_digest(model, case.rsplit("-", 1)[1]) == _PARENT_LOWERED[case]
+    name, loss = case.rsplit("-", 1)
+    model, loss_of, first_state = {
+        "trinity": routed(trinity.Trinity(trinity.TrinityConfig.tiny())),
+        "kimi-linear": routed(KimiLinear(KimiLinearConfig.tiny())),
+        "kimi-linear-latent-layers": routed(KimiLinear(latent_alone)),
+        "joyai-flash": (JoyAIFlash(CFG), stateful_loss, initial_model_state(CFG)),
+        "granite-hybrid": (
+            granite_hybrid.GraniteHybrid(granite_hybrid.GraniteHybridConfig.tiny()), granite_hybrid.stateful_loss,
+            granite_hybrid.initial_model_state(),
+        ),
+        "phi4-flash": (phi4_flash.Phi4Flash(phi4_flash.Phi4FlashConfig.tiny()), phi4_flash.stateful_loss, {}),
+    }[name]
+    assert lowered_digest(model, loss, loss_of, first_state) == {**_PARENT_LOWERED, **_LOWERED_AT_41E404E}[case]
 
 
 # --- the workload ------------------------------------------------------------------

@@ -79,6 +79,58 @@ def test_trinity_dense_loss_is_the_textbook_loss_of_its_logits():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-7)
 
 
+#: the language models' entry points (``workloads/train_<name>.py`` over ``models/<name>.py``): the configuration's and the model's class
+_LM_ENTRIES = {
+    "trinity": ("TrinityConfig", "Trinity"),
+    "kimi_linear": ("KimiLinearConfig", "KimiLinear"),
+    "joyai_flash": ("JoyAIFlashConfig", "JoyAIFlash"),
+    "granite_hybrid": ("GraniteHybridConfig", "GraniteHybrid"),
+    "phi4_flash": ("Phi4FlashConfig", "Phi4Flash"),
+}
+#: the twelve flags of a job, the same in every entry (``train_lm.job_parser``), with the defaults they had before it
+_JOB_FLAGS = dict(
+    vocab=256, hidden=64, dense_width=128, seq=64, batch=8, corpus_tokens=16384, epochs=2, lr=3e-3, world=None,
+    loss="dense", remat="none", dtype="float32",
+)
+
+
+@pytest.mark.parametrize("what", ["flags", "trainer"])
+@pytest.mark.parametrize("entry", sorted(_LM_ENTRIES))
+def test_every_language_models_entry_keeps_the_jobs_flags_and_builds_the_one_trainer(entry, what, mesh2):
+    """The five entries share ``workloads/train_lm.py``: each parser carries
+    the job's twelve flags at the defaults it always had, and each
+    ``build_trainer(cfg, tx, mesh)`` (the signature the benchmark's runners
+    call) gives ``(DDPTrainer, model)``, the trainer with a stateful loss and
+    a donated state on a ring of the mesh's size.  Nothing is compiled."""
+    import importlib
+
+    import optax
+
+    from adapcc_tpu.ddp import DDPTrainer
+
+    module = importlib.import_module(f"adapcc_tpu.workloads.train_{entry}")
+    if what == "flags":
+        parser = module.build_parser()
+        args = parser.parse_args([])
+        assert {name: getattr(args, name) for name in _JOB_FLAGS} == _JOB_FLAGS
+        choices = {a.dest: a.choices for a in parser._actions if a.choices}
+        assert (choices["loss"], choices["remat"], choices["dtype"]) == (
+            ("dense", "chunked"), ("none", "dots", "full"), ("float32", "bfloat16")
+        )
+        return
+    models = importlib.import_module(f"adapcc_tpu.models.{entry}")
+    config, model = (getattr(models, name) for name in _LM_ENTRIES[entry])
+    cfg, tx = config.tiny(), optax.sgd(0.1)
+    trainer, built = module.build_trainer(cfg, tx, mesh2)
+    assert isinstance(trainer, DDPTrainer) and isinstance(built, model) and built.cfg is cfg
+    assert trainer.stateful_loss is True and trainer.donate_state is True
+    strategy = trainer.hook.strategy
+    assert (strategy.synthesis, strategy.world_size) == ("ring", int(mesh2.devices.size))
+    assert module.build_trainer(cfg, tx, mesh2, loss="chunked", donate_state=False)[0].donate_state is False
+    with pytest.raises(ValueError, match="not in \\('dense', 'chunked'\\)"):
+        module.build_trainer(cfg, tx, mesh2, loss="fused")
+
+
 def test_pipeline_last_stage_loss_is_the_textbook_loss_of_its_logits():
     from adapcc_tpu.pipe.partition import composed_loss, partition_gpt2, split_params
 

@@ -6,19 +6,17 @@ ScheduleProgram and its verifier's p2p rejections, the executor's
 bit-parity against the composed single-stage math (with the tied
 embedding's Megatron-style gradient exchange), the traced ``pipe_send``
 hops, the closed-form pricing twins, the env > arg > tuner schedule
-resolution, the DP×PP grad-sync composition, and the warn-once
-deprecation shim over the old ``parallel.pipeline`` spelling.
+resolution, and the DP×PP grad-sync composition.
 """
 
 import dataclasses
 import os
-import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from adapcc_tpu.comm.engine import CollectiveEngine
 from adapcc_tpu.comm.mesh import RANKS_AXIS
@@ -661,40 +659,8 @@ def test_pipeline_program_replay_engine_parity(kind):
 
 
 # --------------------------------------------------------------------------- #
-# the deprecation shim + forward-only parity
+# the forward-only block (its parity with the sequential composition: tests/test_parallel.py)
 # --------------------------------------------------------------------------- #
-
-def _stage_mesh(n):
-    return Mesh(np.array(jax.devices()[:n]), ("stages",))
-
-
-def test_parallel_pipeline_shim_warns_once_and_delegates():
-    import adapcc_tpu.parallel.pipeline as shim
-    from adapcc_tpu.pipe.forward import pipeline_apply as direct
-
-    mesh = _stage_mesh(2)
-    params = jnp.stack([jnp.eye(4) * (s + 1) for s in range(2)])
-    batch = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
-    stage_fn = lambda p, x: x @ p  # noqa: E731
-
-    shim._MOVED_WARNED = False
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        a = shim.pipeline_apply(stage_fn, params, batch, mesh, num_microbatches=4)
-        moved = [x for x in w if issubclass(x.category, DeprecationWarning)]
-        assert len(moved) == 1
-        assert "adapcc_tpu.pipe.forward" in str(moved[0].message)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        shim.pipeline_apply(stage_fn, params, batch, mesh, num_microbatches=4)
-        assert not [x for x in w if issubclass(x.category, DeprecationWarning)]
-
-    b = direct(stage_fn, params, batch, mesh, num_microbatches=4)
-    assert jnp.array_equal(a, b)
-    # the fill/drain drains: the pipeline IS the sequential composition
-    want = stage_fn(params[1], stage_fn(params[0], batch))
-    np.testing.assert_allclose(a, want, rtol=1e-6)
-
 
 def test_pipe_package_reexports_the_forward_block():
     from adapcc_tpu.pipe import pipeline_apply
