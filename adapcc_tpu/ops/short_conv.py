@@ -12,7 +12,10 @@ reset anywhere inside a row of the batch (a packed row's joins are crossed,
 as the references cross them).  Kimi-Linear's KDA mixer runs it over q, k and
 v (no bias; q and k with the norm below), Granite's Mamba-2 mixer over ``x``,
 ``B`` and ``C`` together and Phi-4-flash's Mamba-1 mixer over ``x`` (both
-biased).  Written in
+biased).  A fourth user, LFM2's ``conv`` layers, has no scan behind the
+convolution and no silu: there it is the mixer itself, gated before and after
+(:func:`gated_short_conv`, at the end of this file, over the same walk).
+Written in
 ``jax.numpy`` it is a zero-padded float32 copy of ``x``, ``K`` shifted slices,
 a sum and three roundings, none of which XLA can fuse across the scan's
 ``pallas_call``, and four more float32 passes for its derivative; here each
@@ -83,6 +86,25 @@ traced to before they knew one.
 Operands / results: forward 2 -> 1, backward 4 -> 2 (``x``, its halo, ``dy``,
 the taps' array -> ``dx`` and the taps' array's gradient): none of the
 signatures ``chipbench/trace_reduce.flash_kernel`` knows a flash kernel by.
+
+**The gated form** (``gated_conv_fwd`` / ``gated_conv_bwd``;
+:func:`gated_short_conv`): ``y = C * conv(B * x)`` over a projection's output
+``[B | C | x]`` as it stands, no bias, no activation.  The thirds come as
+three ``BlockSpec``s on the one array at lane-block offsets 0, ``C / width``
+and ``2 C / width``, so nothing is sliced or copied; ``z = B * x`` is formed on
+the slab and stands where ``x`` stood in the walk above (the forward's halo
+scratch keeps ``z``'s last eight rows; the backward's second, sixteen-row
+block comes twice, for ``B`` and for ``x``); ``C`` multiplies the float32 sum
+before the one rounding.  Backward, with ``dc = dy * C`` in ``dpre``'s place
+(there is no ``silu'``): ``c`` is formed again, ``dC = dy * c``, ``dz`` is the
+walk's ``dx``, ``dB = dz * x``, ``dx = dz * B``, the taps' gradient ``sum_t
+dc_t z_{t - (K - 1) + j}``.  The three gradients are written as the thirds of
+ONE array, which the projection's two gradient products read in place: the
+backward kernel's grid step holds every channel and as many fewer rows
+(:func:`_whole_width`), its one output block the rows of all ``3 C``.
+Operands / results: forward 4 -> 1, backward 7 -> 2: again none of the flash
+kernels' signatures.  Without gates the two kernels above trace to what they
+traced to before this form came.
 """
 
 from __future__ import annotations
@@ -449,3 +471,215 @@ def short_conv(
     if (Tp, Cp) != (T, C):
         x = jnp.pad(x, ((0, 0), (0, Tp - T), (0, Cp - C)))
     return _conv(x, w, plan, interp)[:, :T, :C]
+
+
+# --------------------------------------------------------------------------- #
+# the double-gated form: the convolution as the mixer itself
+# --------------------------------------------------------------------------- #
+
+
+def _gated_fwd_kernel(b_ref, c_ref, x_ref, w_ref, y_ref, tail, *, plan: _Plan):
+    """``y = C * conv(B * x)``: :func:`_fwd_kernel`'s walk with ``z = B * x``
+    formed on the slab in ``x``'s place (``tail`` keeps ``z``'s last eight
+    rows), no bias and no silu, and ``C`` on the float32 sum."""
+    K, chunk, per = plan.K, plan.chunk, plan.per
+    first = pl.program_id(2) == 0
+    row = lax.broadcasted_iota(jnp.int32, (_SLAB, chunk), 0)
+
+    def some_lanes(c, _):
+        lanes = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        taps, _ = _weights(w_ref, lanes, plan)
+
+        def some_rows(k, before):
+            for u in range(per):
+                rows = pl.ds(pl.multiple_of((k * per + u) * _GROUP, _GROUP), _GROUP)
+                zs = b_ref[0, rows, lanes].astype(jnp.float32) * x_ref[0, rows, lanes].astype(jnp.float32)
+                gate = c_ref[0, rows, lanes].astype(jnp.float32)
+                out = []
+                for cur, g in ((zs[:_SLAB], gate[:_SLAB]), (zs[_SLAB:], gate[_SLAB:])):
+                    out.append(g * _pre(taps, None, [_down(before, cur, s, row) for s in range(K)]))
+                    before = cur
+                y_ref[0, rows, lanes] = jnp.concatenate(out, axis=0).astype(y_ref.dtype)
+            return before
+
+        before = jnp.where(first, 0.0, tail[:, lanes])
+        tail[:, lanes] = lax.fori_loop(0, plan.rows // (_GROUP * per), some_rows, before)
+        return 0
+
+    lax.fori_loop(0, plan.width // chunk, some_lanes, 0)
+
+
+def _gated_bwd_kernel(b_ref, c_ref, x_ref, bh_ref, xh_ref, dy_ref, w_ref, d_ref, dw_ref, head, *, plan: _Plan):
+    """:func:`_bwd_kernel`'s walk with ``dc = dy * C`` in ``dpre``'s place
+    (there is no ``silu'``): ``c`` is formed again from ``z = B * x``, ``dC =
+    dy * c``, ``dz`` is ``dx``'s sum, ``dB = dz * x`` and ``dx = dz * B``.
+    The three gradients go to the thirds of ``d_ref``, whose block is the
+    grid step's rows of all ``3 C`` channels (the grid step holds every
+    channel: ``plan.width`` is ``C``)."""
+    K, chunk, per = plan.K, plan.chunk, plan.per
+    groups = plan.rows // _GROUP
+    i = pl.program_id(2)                        # the grid's first step is the sequence's last rows
+    start = i == pl.num_programs(2) - 1         # this grid step holds the sequence's first rows
+    row = lax.broadcasted_iota(jnp.int32, (_SLAB, chunk), 0)
+
+    @pl.when(i == 0)
+    def _():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, dw_ref.dtype)
+
+    def some_lanes(c, _):
+        lanes = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        taps, _ = _weights(w_ref, lanes, plan)
+
+        def slab(before, z, b, x, gate, dy, after, sums):
+            """The slab's three gradients and its ``dc`` (``before``: the slab
+            of ``z`` above it; ``after``: ``dc`` of the slab under it), the
+            taps' sums with it added."""
+            shifted = [_down(before, z, s, row) for s in range(K)]
+            dgate = dy * _pre(taps, None, shifted)
+            dc = dy * gate
+            sums = [sums[j] + dc * shifted[K - 1 - j] for j in range(K)]
+            dz = taps[K - 1] * dc
+            for s in range(1, K):
+                dz = dz + taps[K - 1 - s] * _up(dc, after, s, row)
+            return dc, (dz * x, dgate, dz * b), sums
+
+        def turn(first_group, above, after, sums):
+            """``per`` groups of sixteen rows from ``first_group`` on, the last
+            row first; ``above`` is the slab of ``z`` over them."""
+            group = lambda g: pl.ds(pl.multiple_of((first_group + g) * _GROUP, _GROUP), _GROUP)  # noqa: E731
+            load = lambda ref, g: ref[0, group(g), lanes].astype(jnp.float32)  # noqa: E731
+            zs = [load(b_ref, g) * load(x_ref, g) for g in range(per)]
+            for g in reversed(range(per)):
+                b, x, gate, dy = (load(ref, g) for ref in (b_ref, x_ref, c_ref, dy_ref))
+                lo, hi = slice(0, _SLAB), slice(_SLAB, _GROUP)
+                after, d_hi, sums = slab(zs[g][lo], zs[g][hi], b[hi], x[hi], gate[hi], dy[hi], after, sums)
+                over = zs[g - 1][hi] if g else above
+                after, d_lo, sums = slab(over, zs[g][lo], b[lo], x[lo], gate[lo], dy[lo], after, sums)
+                for third, (a_lo, a_hi) in enumerate(zip(d_lo, d_hi)):       # dB, dC, dx
+                    there = pl.ds(pl.multiple_of(third * plan.width + c * chunk, _LANES), chunk)
+                    d_ref[0, group(g), there] = jnp.concatenate([a_lo, a_hi], axis=0).astype(d_ref.dtype)
+            return after, sums
+
+        def some_rows(k, carry):
+            first_group = groups - (k + 1) * per
+            over = pl.ds(pl.multiple_of((first_group - 1) * _GROUP, _GROUP), _GROUP)
+            above = b_ref[0, over, lanes][_SLAB:].astype(jnp.float32) * x_ref[0, over, lanes][_SLAB:].astype(jnp.float32)
+            return turn(first_group, above, *carry)
+
+        zero = jnp.zeros((_SLAB, chunk), jnp.float32)
+        after = jnp.where(i == 0, 0.0, head[:, lanes])
+        carry = lax.fori_loop(0, groups // per - 1, some_rows, (after, [zero] * K))
+        halo = bh_ref[0, _SLAB:, lanes].astype(jnp.float32) * xh_ref[0, _SLAB:, lanes].astype(jnp.float32)
+        after, sums = turn(0, jnp.where(start, 0.0, halo), *carry)
+        head[:, lanes] = after
+        for j in range(K):
+            dw_ref[0, j:j + 1, lanes] += jnp.sum(sums[j], axis=0, keepdims=True)
+        return 0
+
+    lax.fori_loop(0, plan.width // chunk, some_lanes, 0)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _gated_fwd_call(bcx, w, plan: _Plan, interp):
+    Bt, T, _ = bcx.shape
+    rows, W = plan.rows, plan.width
+    Cp = w.shape[1]
+    third = lambda a: pl.BlockSpec((1, rows, W), lambda b, p, i: (b, i, a * (Cp // W) + p))  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_gated_fwd_kernel, plan=plan),
+        grid=(Bt, Cp // W, T // rows),
+        in_specs=[third(0), third(1), third(2), pl.BlockSpec((w.shape[0], W), lambda b, p, i: (0, p))],
+        out_specs=third(0),
+        out_shape=jax.ShapeDtypeStruct((Bt, T, Cp), bcx.dtype),
+        scratch_shapes=[pltpu.VMEM((_SLAB, W), jnp.float32)],
+        compiler_params=_params(interp),
+        interpret=interp,
+        name="gated_conv_fwd",
+    )(bcx, bcx, bcx, w)
+
+
+def _whole_width(plan: _Plan, Cp: int) -> _Plan:
+    """``plan`` with every channel in a grid step and as many fewer rows, a
+    whole part of ``plan``'s: the backward kernel's blocks, which let it
+    write the three gradients as the thirds of one array."""
+    turns = plan.rows // (_GROUP * plan.per)
+    need = -(-plan.rows * Cp // max(_BLOCK, plan.rows * plan.width))
+    parts = next(n for n in range(need, turns + 1) if turns % n == 0)
+    return plan._replace(rows=plan.rows // parts, width=Cp)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _gated_bwd_call(bcx, w, dy, plan: _Plan, interp):
+    """``(d[B | C | x], dw)``: the three gradients as the thirds of one
+    array, written where they belong, on :func:`_whole_width`'s blocks."""
+    Bt, T, _ = bcx.shape
+    Cp = w.shape[1]
+    plan = _whole_width(plan, Cp)
+    rows, per = plan.rows, plan.rows // _GROUP
+    steps = T // rows
+    third = lambda a, wide=1: pl.BlockSpec((1, rows, wide * Cp), lambda b, p, i: (b, steps - 1 - i, a))  # noqa: E731
+    # the sixteen rows before the grid step's (its own first sixteen where it has none before: not read then)
+    halo = lambda a: pl.BlockSpec((1, _GROUP, Cp), lambda b, p, i: (b, jnp.maximum((steps - 1 - i) * per - 1, 0), a))  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_gated_bwd_kernel, plan=plan),
+        grid=(Bt, 1, steps),
+        in_specs=[
+            third(0), third(1), third(2), halo(0), halo(2), third(0),
+            pl.BlockSpec((w.shape[0], Cp), lambda b, p, i: (0, 0)),
+        ],
+        out_specs=[third(0, 3), pl.BlockSpec((1, w.shape[0], Cp), lambda b, p, i: (b, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(bcx.shape, bcx.dtype), jax.ShapeDtypeStruct((Bt, *w.shape), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((_SLAB, Cp), jnp.float32)],
+        compiler_params=_params(interp),
+        interpret=interp,
+        name="gated_conv_bwd",
+    )(bcx, bcx, bcx, bcx, bcx, dy, w)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _gated(bcx, w, plan, interp):
+    return _gated_fwd_call(bcx, w, plan, interp)
+
+
+def _gated_fwd(bcx, w, plan, interp):
+    return _gated_fwd_call(bcx, w, plan, interp), (bcx, w)
+
+
+def _gated_bwd(plan, interp, res, dy):
+    bcx, w = res
+    dbcx, dw = _gated_bwd_call(bcx, w, dy, plan, interp)
+    return dbcx, dw.sum(axis=0)
+
+
+_gated.defvjp(_gated_fwd, _gated_bwd)
+
+
+def gated_short_conv(bcx: jnp.ndarray, taps: jnp.ndarray, interpret: Optional[bool] = None) -> jnp.ndarray:
+    """``C * conv(B * x)`` over ``bcx [Bt, T, 3 C]``, a projection's output
+    as it stands (``B | C | x`` by thirds), with ``taps [K, C]``: ``y_t = C_t
+    sum_j taps[j] (B x)_{t - (K - 1) + j}``, depthwise and causal, each row of
+    the batch from zeros, no bias and no activation: ``[Bt, T, C]`` in
+    ``bcx``'s dtype, the product ``B x``, the sum and ``C``'s product in
+    float32, rounded once.  Differentiable in ``bcx`` and ``taps`` (the taps'
+    gradient summed in float32); the gradient of ``bcx`` is one array whose
+    thirds the backward kernel writes where they belong.  Any ``T`` and ``C``:
+    rows are padded with zeros to whole blocks and each third to whole lane
+    tiles (2,048 channels a third are sixteen: no copy there).
+    ``interpret=None`` asks :func:`ops.kernel_mode.resolve_interpret` (site
+    ``"short_conv"``)."""
+    Bt, T, C3 = bcx.shape
+    K, C = taps.shape[0], C3 // 3
+    if C3 % 3 or taps.shape != (K, C) or not 1 <= K < _SLAB:
+        raise ValueError(f"gated_short_conv shapes: bcx {bcx.shape} (three thirds) taps {taps.shape}")
+    interp = resolve_interpret(interpret, "short_conv")
+    plan, Tp, Cp = plan_for(T, C, K)
+    metrics = default_registry()
+    metrics.incr("conv.gated_calls")
+    metrics.gauge("gconv.block_rows", plan.rows)
+    metrics.gauge("gconv.lane_tiles", plan.tiles)
+    w = jnp.pad(taps.astype(jnp.float32), ((0, _SLAB - K), (0, Cp - C)))
+    if (Tp, Cp) != (T, C):
+        bcx = jnp.concatenate(
+            [jnp.pad(a, ((0, 0), (0, Tp - T), (0, Cp - C))) for a in jnp.split(bcx, 3, axis=-1)], axis=-1
+        )
+    return _gated(bcx, w, plan, interp)[:, :T, :C]

@@ -121,7 +121,8 @@ def train(args, cfg, job: Job, report: Optional[dict] = None) -> Tuple[float, fl
 
     tx = optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(args.lr, weight_decay=0.01))
     trainer, model = job.build_trainer(cfg, tx, mesh, args.loss)
-    params = model.init(jax.random.PRNGKey(0), jnp.asarray(rows[:1]))
+    # one program: run eagerly, a kernel in the interpreter is dispatched an operation at a time
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), jnp.asarray(rows[:1]))
     state = trainer.init_state(params, job.first_state(cfg))
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params))
     print(f"{job.name}: {n_params / 1e6:.2f} M parameters, {job.banner(cfg)}, world {world}")
