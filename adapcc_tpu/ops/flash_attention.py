@@ -24,6 +24,16 @@ from the residuals with ``Δ_i = rowsum(do_i ∘ o_i)``.  The dk/dv pass works
 on the transposed tile ``s_ji = k_j q_i^T``, so that ``dv += p^T do`` and
 ``dk += dS^T q`` are plain products and lse, Δ are rows.
 
+**What crosses the boundary.**  lse and Δ leave and enter all three kernels
+as lane-dense rows ``f32[B·H, 1, T]``, whole (a band's dk/dv kernel takes the
+band's lanes): the forward turns its ``[bq, 1]`` column ``m + log l`` into a
+``[1, bq]`` row once a query block, after the walk, and writes its own lanes;
+the dq kernel turns its lanes of both rows into the columns it subtracts once
+a program, before the walk; the dk/dv kernel reads rows as they are.  The
+turn moves values and computes none, so the results are to the bit those of
+any other layout, and nothing outside the kernels pads, broadcasts or slices
+a statistic: the forward's array is the one the backward kernels read.
+
 **Which tiles.**  With ``causal=True`` a kernel visits only the tiles the
 mask leaves: query block ``qi`` takes key blocks ``0 … ((qi+1)·bq − 1) // bk``,
 key block ``kj`` takes query blocks from ``(kj·bk) // bq`` up, and the mask is
@@ -116,14 +126,34 @@ from adapcc_tpu.utils.observability import default_registry
 _NEG_INF = -1e30
 
 # Mosaic requires the last two dims of every block shape to be divisible by
-# the (8, 128) tile or equal to the whole array's dims.  A naive ``[BH, T]``
-# logsumexp output with block ``(1, bq)`` violates the sublane rule (the 1),
-# so the forward kernel's lse and the dq kernel's lse/delta, which they hold as
-# columns, cross the pallas_call boundary lane-padded to ``[BH, T, _LSE_LANES]``
-# (block ``(1, bq, 8)``: bq % 8 == 0, 8 == minor dim) and are sliced back to
-# ``[BH, T]`` outside.  The dk/dv kernel holds them as rows and takes
-# ``[BH, 1, T]`` whole (block ``(1, 1, T)``).
-_LSE_LANES = 8
+# the (8, 128) tile or equal to the whole array's dims.  The per-row statistics
+# (the forward's lse out, lse and delta into both backward kernels) cross every
+# pallas_call boundary as rows, ``f32[BH, 1, T]`` with T along the lanes, in
+# blocks ``(1, 1, T)``: both minor dims are the array's own, so the rule holds
+# at every block size, and HBM tiles such an array a sublane deep, so the array
+# is its content.  (With T on the sublanes the rule wants a lane axis beside
+# it, which HBM tiles out to 128 lanes: sixteen times the content at eight
+# lanes, made, broadcast and sliced by XLA around every call; PERF.md §6, PR
+# 47.)  A program takes its own ``bq`` lanes of the row; the kernels gridded
+# over query blocks hold the statistics as ``[bq, 1]`` columns and turn them
+# once a program, outside the walk (:func:`_as_row`, :func:`_as_column`: a
+# relayout, every bit kept).
+_LANES = 128
+
+
+def _as_row(column):
+    """A ``[n, 1]`` column of per-row statistics as the ``[1, n]`` row that
+    crosses the kernel's boundary: the same values, moved (the column spread
+    over a tile's lanes, the slab transposed on the XLU, its first row kept;
+    a ``reshape`` lowers too and cost ``flash_fwd`` 0.55 us a program on a
+    v5e, PERF.md §6, PR 47)."""
+    return jnp.transpose(jnp.broadcast_to(column, (column.shape[0], _LANES)))[:1]
+
+
+def _as_column(row):
+    """A ``[1, n]`` row as the ``[n, 1]`` column a query block's tiles
+    subtract: the same values, moved."""
+    return jnp.transpose(jnp.broadcast_to(row, (_LANES, row.shape[1])))[:, :1]
 
 
 def _causal_mask(s, qi, kj, block_q, block_k, q_axis=0):
@@ -334,20 +364,27 @@ def looped_tiles(
 
 def _per_program(body, n: int, span, block_q: int, block_k: int, causal: bool, window) -> None:
     """``body(rel, bounds, base, walk)`` for the grid position ``base + rel``
-    along the block axis (of ``n``), ``bounds = span(position)`` counted from
-    ``base``, ``walk`` the :func:`_band` that visits them.  Under the causal
+    along the block axis (of ``n``): ``_at(base, rel)`` is the program's own
+    block in every body (a query block finds its lanes of a statistic's row by
+    it).  ``bounds = span(position)`` counted from ``base``, ``walk`` the
+    :func:`_band` that visits them.  Under the causal
     mask the positions differ in their bounds: each gets its own copy of the
     body with the position a Python integer (``base`` 0), where that keeps the
     code small enough (every bound and slice is then static and Mosaic
     schedules a block's tiles as one basic block); the positions inside a
     window's band share one copy, ``base`` the traced position and the bounds
-    static from it.  Else ``rel`` is the traced ``pl.program_id``, the bounds
-    are traced and ``walk`` splits each of them into written-out runs
-    (:func:`_runs`).  Without the mask one body serves them all."""
+    static from it (a window of one key on square tiles is all band: one copy
+    for every position).  Else ``rel`` is the traced ``pl.program_id``, the
+    bounds are traced and ``walk`` splits each of them into written-out runs
+    (:func:`_runs`).  Without the mask one body serves them all: every
+    position has its bounds, counted from 0, and ``rel`` is the traced
+    position."""
     bodies = _written_out(n, span, block_q, block_k, causal, window)
-    if bodies is not None and len(bodies) == 1:
+    if n == 1:
         return body(0, bodies[0][2], 0, _band)
     position = pl.program_id(1)
+    if not causal:
+        return body(position, bodies[0][2], 0, _band)
     if bodies is None:
         return body(position, span(position), 0, functools.partial(_band, parts=_runs(n, span, block_q, block_k)))
     for first, last, bounds in bodies:
@@ -453,7 +490,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, 
         )
         m, l, acc = walk(bounds, tile, carry)
         o_ref[0] = (acc / l).astype(o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (bq, _LSE_LANES))
+        lse_ref[0, :, _block(_at(base, rel), block_q)] = _as_row(m + jnp.log(l))
 
     _per_program(query_block, *_query_side(seq, block_q, block_k, causal, window), block_q, block_k, causal, window)
 
@@ -464,11 +501,13 @@ def _dq_kernel(
 ):
     q = q_ref[0]
     do = do_ref[0]
-    lse_col = lse_ref[0][:, 0:1]      # [bq, 1] from the lane-padded layout
-    delta_col = delta_ref[0][:, 0:1]
     bq, d = q.shape
 
     def query_block(rel, bounds, base, walk):
+        own = _block(_at(base, rel), block_q)
+        lse_col = _as_column(lse_ref[0, :, own])
+        delta_col = _as_column(delta_ref[0, :, own])
+
         def tile(r, dq, masked):
             at = _held(base, r, bounds[0], band)
             k, v = _rows(k_ref, at, block_k), _rows(v_ref, at, block_k)
@@ -565,10 +604,11 @@ def _block_sizes(T: int, D: int, dtype, block_q: Optional[int], block_k: Optiona
     bk = by_shape[1] if block_k is None else min(block_k, T)
     if T % bq or T % bk:
         raise ValueError(f"seq len {T} must divide into blocks ({bq}, {bk})")
-    # Mosaic sublane rule: the lane-padded (1, bq, _LSE_LANES) block specs
-    # require 8-aligned block sizes (or the degenerate bq == T case).  An
-    # unaligned block compiles past tracing and dies deep in Mosaic with a
-    # cryptic tiling error on hardware — reject it here with the real reason.
+    # Mosaic sublane rule: the (1, bq, D) blocks of q, o, do, dq and the
+    # (1, bk, D) ones of k, v, dk, dv require 8-aligned block sizes (or the
+    # degenerate bq == T case).  An unaligned block compiles past tracing and
+    # dies deep in Mosaic with a cryptic tiling error on hardware — reject it
+    # here with the real reason.
     for name, b in (("block_q", bq), ("block_k", bk)):
         if b % 8 and b != T:
             raise ValueError(
@@ -630,6 +670,17 @@ def _resident(T: int, D: int, band: Optional[int], block: int, index, first):
     )
 
 
+def _stat_row(T: int):
+    """The spec of a ``[B·H, 1, T]`` statistic: head-row ``b``'s row, whole.
+    As the forward's result it is one block that every program along grid
+    axis 1 revisits, each storing its own ``bq`` lanes; it is written back
+    when ``b`` moves on.  That holds while axis 1 runs in order on one core
+    (Mosaic's default, ``arbitrary``): marked ``parallel`` for a chip with
+    two cores, each core would write back a whole row over the other's
+    lanes, so that axis takes no such mark while the row is the block."""
+    return pl.BlockSpec((1, 1, T), lambda b, i: (b, 0, 0))
+
+
 def _kv_index(groups: int):
     """Query head ``b`` of ``[B·H_q]`` reads KV head ``b // groups`` of
     ``[B·H_kv]`` (``H_q = groups · H_kv``, heads minor)."""
@@ -665,7 +716,7 @@ def _fwd_call(q, k, v, scale, causal, bq, bk, interp, window):
     band = _band_blocks(T, bq, bk, window)
     ahead = 0 if band is None else band - 1     # the band starts this many blocks before the diagonal
     held = lambda d: _resident(T, d, band, bk, kv, lambda i: jnp.maximum(i - ahead, 0))  # noqa: E731
-    out, lse3 = pl.pallas_call(
+    out, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk, window=window,
             seq=T, band=band,
@@ -674,17 +725,17 @@ def _fwd_call(q, k, v, scale, causal, bq, bk, interp, window):
         in_specs=[pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)), held(D), held(Dv)],
         out_specs=[
             pl.BlockSpec((1, bq, Dv), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, _LSE_LANES), lambda b, i: (b, i, 0)),
+            _stat_row(T),   # revisited along axis 1, which therefore stays "arbitrary" (see _stat_row)
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, T, Dv), q.dtype),
-            jax.ShapeDtypeStruct((BH, T, _LSE_LANES), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, T), jnp.float32),
         ],
         interpret=interp,
         name="flash_fwd",
         **_compiler_params(T if band is None else band * bk, D, Dv, k.dtype),
     )(q, k, v)
-    return out, lse3[:, :, 0]
+    return out, lse.reshape(BH, T)
 
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, window, res, do):
@@ -714,9 +765,7 @@ def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window):
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
-    # lane-pad the per-row statistics for the dq kernel's tiled block specs
-    lse3 = jnp.broadcast_to(lse[..., None], (BH, T, _LSE_LANES))
-    delta3 = jnp.broadcast_to(delta[..., None], (BH, T, _LSE_LANES))
+    stats = lse[:, None, :], delta[:, None, :]
 
     band = _band_blocks(T, bq, bk, window)
     rows = T if band is None else band * bk
@@ -733,15 +782,15 @@ def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window):
             held(D),
             held(Dv),
             pl.BlockSpec((1, bq, Dv), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, _LSE_LANES), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, _LSE_LANES), lambda b, i: (b, i, 0)),
+            _stat_row(T),
+            _stat_row(T),
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         interpret=interp,
         name="flash_bwd_dq",
         **_compiler_params(rows, D, Dv, k.dtype),
-    )(q, k, v, do, lse3, delta3)
+    )(q, k, v, do, *stats)
 
     # grouped heads: each query head writes its own fp32 share of dk and dv
     # (a grid program owns its output block), summed over the group below
@@ -749,7 +798,7 @@ def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window):
     last = 0 if band is None else T // bq - band    # the last block a band of queries can start at
     seen = lambda d: _resident(T, d, band, bq, lambda b: b, lambda j: jnp.minimum(j, last))  # noqa: E731
     if band is None:
-        stat = pl.BlockSpec((1, 1, T), lambda b, j: (b, 0, 0))
+        stat = _stat_row(T)
     else:
         stat = pl.BlockSpec(
             (pl.Element(1), pl.Element(1), pl.Element(rows)),
@@ -780,7 +829,7 @@ def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window):
         interpret=interp,
         name="flash_bwd_dkv",
         **_compiler_params(rows, D, Dv, q.dtype),
-    )(q, k, v, do, lse[:, None, :], delta[:, None, :])
+    )(q, k, v, do, *stats)
     if groups > 1:
         dk = dk.reshape(BH // groups, groups, T, D).sum(axis=1).astype(k.dtype)
         dv = dv.reshape(BH // groups, groups, T, Dv).sum(axis=1).astype(v.dtype)

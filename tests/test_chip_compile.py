@@ -315,12 +315,15 @@ def test_the_expert_layer_writes_short_rows_when_the_assignments_fit_them(one_ch
 #: sha256 of the lowered text, made from ``git archive`` of 3f0513b (the parent of PR 32) by these same functions;
 #: ``flash-cell3-full`` from PR 36's own tree (on bfb9cb0), which means to alter it: past ``_STRAIGHT_LINE_ELEMENTS`` the
 #: one body with traced bounds runs written-out runs of tiles where it ran one tile a turn (the same tiles in the same
-#: order: tests/test_flash_attention.py holds the two equal to the bit)
+#: order: tests/test_flash_attention.py holds the two equal to the bit).  **The four ``flash-*`` digests are PR 47's own
+#: tree's (on fd18a10), which means to alter them**: the forward writes lse and the dq kernel reads lse and delta as rows
+#: ``[B.H, 1, T]``, turned to and from the kernels' columns once a program (the values moved, none recomputed: on the chip
+#: o, lse, dq, dk, dv came out the parent's bit for bit at every cell's shape, CHANGES.md PR 47); ``experts-cell3`` is 3f0513b's
 _PARENT_LOWERED = {
-    "flash-cell1": "d6ef39f9ef10c6ff09bb99ef7a39eee183941bbd5361316660308c8e9c6173ac",
-    "flash-cell2": "07e34fc7769e246ae1704f53f9261a71f0295aa67a92581d99e451c1112e807f",
-    "flash-cell3-window": "f1a9d5c89a04985f0c37ecce26269183150d1510e8f48e67338db9aafc5b05cd",
-    "flash-cell3-full": "9be2ca384d10a629086003efd40ba1d6a07a056fb7d1ae8d180e10ec28cf5eef",
+    "flash-cell1": "ef15c7184539b013482d2cbbc39410fc7b00d9f0daf151a14f8420013ad805f1",
+    "flash-cell2": "6a29440be55a87789147446952be2a94a7bf919eef195418ccd4aa45419c9d76",
+    "flash-cell3-window": "eb295fca2c402f9bfa7e63bd2e457c89acccc094d3a2f457a16e062e7ed33565",
+    "flash-cell3-full": "5a8a18e94ecd769e4102257f4f52321d1f02e3c4f5dcd32a16b38c0380c1c018",
     "experts-cell3": "c13c04dad2a0fb4e8e8d6c336ea23dec01ad1e8bd5c1c4893c82a43f75e70bf7",
 }
 
@@ -330,6 +333,44 @@ _FLASH_CELLS = {
     "flash-cell3-window": ((1, 8192, 32, 128), (1, 8192, 4, 128), 2048),   # trinity-mini-ep8-train, sliding layers
     "flash-cell3-full": ((1, 8192, 32, 128), (1, 8192, 4, 128), None),     # and the full layer
 }
+
+
+#: q, k, v and the window of a cell's attention call: cell 1, cell 3's band (the ``pl.Element`` specs), cell 5's latent
+#: layer (scores over 192, values over 128)
+_STATISTIC_ROWS = {
+    "cell1": (*_FLASH_CELLS["flash-cell1"][:2], *_FLASH_CELLS["flash-cell1"][1:]),
+    "cell3-band": (*_FLASH_CELLS["flash-cell3-window"][:2], *_FLASH_CELLS["flash-cell3-window"][1:]),
+    "cell5-192-128": ((1, 8192, 32, 192), (1, 8192, 32, 192), (1, 8192, 32, 128), None),
+    # a band of one key: one body at the traced position for all sixteen blocks, each writing its own lanes of the row
+    "band-of-one": (*_FLASH_CELLS["flash-cell3-window"][:2], _FLASH_CELLS["flash-cell3-window"][1], 1),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_STATISTIC_ROWS))
+def test_the_statistics_cross_the_compiled_kernels_as_rows_a_sublane_deep(one_chip, cell):
+    """PR 47: lse and delta reach the three compiled kernels as
+    ``f32[B.H, 1, T]`` tiled ``(1, 128)``, so the array in HBM is its content;
+    the forward's is the very array both backward kernels read, and no
+    ``f32[B.H, T, 8]`` (tiled to 128 lanes: 16 times its content) is left in
+    the program for XLA to slice or broadcast."""
+    from adapcc_tpu.ops import flash_attention
+
+    q, k, v, window = _STATISTIC_ROWS[cell]
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, window=window, interpret=False).astype(jnp.float32))
+
+    shapes = [jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip) for dims in (q, k, v)]
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(*shapes).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    heads, T = q[0] * q[2], q[1]
+    row = rf"f32\[{heads},1,{T}\]\{{2,1,0:T\(1,128\)"
+    forward = re.search(rf"%(\S+) = \(bf16\[\S+, {row}\S*\) custom-call\(", text)
+    assert forward, "the forward kernel writes no row"
+    lse = re.search(rf"%(\S+) = {row}\S* get-tuple-element\(%{re.escape(forward.group(1))}\), index=1", text).group(1)
+    backward = [line for line in text.splitlines() if "custom-call(" in line and "flash_bwd" in line]
+    assert len(backward) == 2 and all(f"%{lse}," in line for line in backward), "a pass of XLA's stands between the kernels"
+    assert not re.search(rf"f32\[{heads},{T},8\]", text)
 
 
 def _without_source_lines(lower):
@@ -396,8 +437,9 @@ def test_cells_1_to_3_lower_to_the_text_they_lowered_to_before_the_hybrid_model(
 
 #: sha256 of cell 4's latent layer as lowered by PR 36's own tree (on bfb9cb0), which means to alter it: the mixer's
 #: text is ca7fb08's (the parent of PR 34, before the mixer learnt the query rank and the rotation), the three flash
-#: kernels' bodies run written-out runs of tiles where they ran one tile a turn
-_PARENT_LATENT_LAYER = "ee3c8320d71d9c5166eb1afc103e3008a2a158d9a6a358021d044809cd036d5c"
+#: kernels' bodies run written-out runs of tiles where they ran one tile a turn; replaced again by PR 47's own tree
+#: (on fd18a10) for the statistics' rows: the mixer's text around the kernels is unmoved
+_PARENT_LATENT_LAYER = "17c34e1b494ee77e491736f67a5e1a12811dfaa05e01b5e77c154bb56217cc46"
 
 
 def test_cell_4s_latent_layer_lowers_to_the_text_it_lowered_to_before_the_rotation(one_chip, monkeypatch):

@@ -529,3 +529,97 @@ def test_tile_resolves_by_shape_through_one_table():
     jax.make_jaxpr(flash_attention)(q, k, v)
     g = default_registry().snapshot()["gauges"]
     assert (g["flash.block_q"], g["flash.block_k"]) == default_blocks(64, 16, jnp.bfloat16)
+
+
+# --- PR 47: lse and delta cross the kernels' boundary as rows [B.H, 1, T] --------
+
+
+#: what calls the kernels: ``(entry, its keywords, H_kv, whether lse gets a cotangent)``
+_BOUNDARIES = {
+    "causal": (flash_attention, {}, 2, False),
+    "full": (flash_attention, {"causal": False}, 2, False),
+    "band": (flash_attention, {"window": 40}, 2, False),
+    "grouped-kv": (flash_attention, {}, 1, False),
+    "band-grouped-kv": (flash_attention, {"window": 40}, 1, False),
+    "band-of-one": (flash_attention, {"window": 1}, 2, False),
+    "with-lse": (flash_attention_with_lse, {}, 2, False),
+    "with-lse-and-dlse": (flash_attention_with_lse, {}, 2, True),
+    "with-lse-and-dlse-full": (flash_attention_with_lse, {"causal": False}, 2, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BOUNDARIES))
+def test_the_statistics_cross_every_kernel_boundary_as_rows_and_nothing_pads_them(case, program_index):
+    """Through ``jax.grad`` of every caller's form: the forward's second
+    result and the last two operands of both backward kernels are
+    ``float32[B.H, 1, T]``; no float32 array anywhere outside or inside the
+    kernels has a minor dimension of 8 (the padded form was ``[B.H, T, 8]``),
+    and no ``broadcast_in_dim`` makes a ``[B.H, T]`` statistic larger than it
+    was (an axis of one is all it may add)."""
+    entry, kw, h_kv, dlse = _BOUNDARIES[case]
+    B, T, H, D = 2, 64, 2, 16
+    rng = np.random.default_rng(47)
+    q, k, v = (jnp.asarray(rng.normal(size=(B, T, h, D)), jnp.bfloat16) for h in (H, h_kv, h_kv))
+
+    def loss(q, k, v):
+        out = entry(q, k, v, block_q=32, block_k=32, **kw)
+        if entry is flash_attention:
+            return jnp.sum(out.astype(jnp.float32))
+        out, lse = out
+        return jnp.sum(out.astype(jnp.float32)) + (jnp.sum(lse * lse) if dlse else 0.0)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr
+    fwd, dq, dkv = _pallas_calls(jaxpr)
+    row = (B * H, 1, T)
+    statistics = [fwd.outvars[1], *dq.invars[4:], *dkv.invars[4:]]
+    assert [(s.aval.shape, s.aval.dtype) for s in statistics] == [(row, jnp.float32)] * 5
+    for eqn in _eqns(jaxpr):
+        for aval in (var.aval for var in [*eqn.invars, *eqn.outvars]):
+            assert not (aval.dtype == jnp.float32 and len(aval.shape) >= 2 and aval.shape[-1] == 8), (eqn.primitive.name, aval)
+        if eqn.primitive.name == "broadcast_in_dim" and eqn.invars[0].aval.shape == (B * H, T):
+            assert eqn.outvars[0].aval.shape == row, eqn
+
+
+@pytest.mark.parametrize("rows", [8, 128, 512])
+def test_the_turn_between_column_and_row_keeps_every_bit(rows):
+    """The two helpers the kernels call, in the interpreter: a column that
+    holds ``_NEG_INF`` rows, a subnormal, a negative zero and neighbours in
+    their last bit comes back as the row of the same bits, and the row as the
+    column (a product with a selector under ``highest`` precision would fail
+    the neighbours or the subnormal)."""
+    from jax.experimental import pallas as pl
+
+    rng = np.random.default_rng(rows)
+    bits = rng.integers(0, 1 << 32, size=rows, dtype=np.uint64).astype(np.uint32)
+    bits[(bits & 0x7F800000) == 0x7F800000] = 0x3F800000      # no NaN or infinity: they have no order to keep
+    values = bits.view(np.float32).copy()
+    values[0] = flash_module._NEG_INF
+    values[1] = np.float32(1e-42)                               # subnormal
+    values[2:5] = np.float32(9.123456), np.nextafter(np.float32(9.123456), np.float32(10)), -0.0
+    values[5] = np.nextafter(np.float32(flash_module._NEG_INF), np.float32(0))
+    column = jnp.asarray(values.reshape(rows, 1))
+
+    def through(turn, x, shape):
+        def kernel(x_ref, o_ref):
+            o_ref[...] = turn(x_ref[...])
+        return pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct(shape, jnp.float32), interpret=True)(x)
+
+    row = through(flash_module._as_row, column, (1, rows))
+    back = through(flash_module._as_column, row, (rows, 1))
+    as_bits = lambda x: np.asarray(x).view(np.uint32).ravel()  # noqa: E731
+    assert np.array_equal(as_bits(row), values.view(np.uint32))
+    assert np.array_equal(as_bits(back), values.view(np.uint32))
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("blocks", [(128, 128), (128, 64), (64, 128), (256, 256)], ids=lambda b: f"{b[0]}x{b[1]}")
+def test_lse_matches_the_dense_oracles_logsumexp_where_a_block_is_whole_lane_tiles(blocks, causal):
+    """``flash_attention_with_lse``'s ``lse`` against the float32 oracle's
+    logsumexp at blocks of one and two lane tiles and of the whole row, within
+    the tolerance the file holds it to at the small blocks."""
+    q, k, v = _qkv(T=256, B=1, H=2)
+    out, lse = flash_attention_with_lse(q, k, v, causal=causal, block_q=blocks[0], block_k=blocks[1])
+    ref_out, ref_lse = _dense_attention_lse(q, k, v, causal)
+    assert lse.shape == ref_lse.shape == (1, 2, 256)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out), atol=2e-5)

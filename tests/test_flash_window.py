@@ -66,7 +66,10 @@ def program_index(request, monkeypatch):
 @pytest.mark.parametrize("heads", [(4, 2), (2, 2), (4, 1)], ids=lambda h: f"{h[0]}q{h[1]}kv")
 @pytest.mark.parametrize(
     "window,blocks",
-    [(5, (16, 16)), (16, (16, 16)), (24, (16, 16)), (40, (16, 32)), (33, (32, 16)), (64, (16, 16)), (200, (16, 16))],
+    [
+        (5, (16, 16)), (16, (16, 16)), (24, (16, 16)), (40, (16, 32)), (33, (32, 16)), (64, (16, 16)), (200, (16, 16)),
+        (1, (16, 16)),      # all band: every position has the bounds (0, 1, 1, 1) from itself, one body for them all
+    ],
     ids=lambda x: str(x).replace(" ", ""),
 )
 def test_windowed_grouped_attention_matches_the_dense_oracle(window, blocks, heads, program_index):
@@ -83,6 +86,24 @@ def test_windowed_grouped_attention_matches_the_dense_oracle(window, blocks, hea
     for got, ref, name in zip(pull(do), pull_want(do), "qkv"):
         assert got.shape == ref.shape
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4, rtol=1e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("window,blocks", [(1, (16, 16)), (1, (16, 32)), (5, (16, 16)), (24, (16, 16)), (200, (16, 16))],
+                         ids=lambda x: str(x).replace(" ", ""))
+def test_the_forwards_lse_under_a_window_is_the_dense_logsumexp_of_the_band(window, blocks, program_index):
+    """Every query block writes its own lanes of the statistic's row, whoever
+    wrote its body (a window of one key on square tiles: one body at the
+    traced position for all four blocks)."""
+    q, k, v = _qkv(64, 2, 2)
+    B, T, H, D = q.shape
+    heads_first = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, T, D)  # noqa: E731
+    _, lse = flash_module._fwd_call(*map(heads_first, (q, k, v)), 1 / np.sqrt(D), True, *blocks, True, window)
+    att = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") / np.sqrt(D)
+    ahead = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+    att = jnp.where(((ahead >= 0) & (ahead < window))[None, None], att, -jnp.inf)
+    want = jax.nn.logsumexp(att, axis=-1).reshape(B * H, T)
+    assert lse.shape == want.shape
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want), atol=2e-5)
 
 
 def test_bf16_grouped_windowed_gradients_keep_their_dtype_and_stay_close():

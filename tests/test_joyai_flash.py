@@ -379,12 +379,15 @@ def test_the_config_reads_config_json_and_refuses_what_it_does_not_implement():
 #: sha256 of the lowered loss-and-gradient step at ca7fb08 (the parent of PR 34), by ``lowered_digest`` below;
 #: ``kimi-linear-dense`` as PR 43's own tree lowers it, which means to alter it (q's and k's ``l2norm`` rides the
 #: short convolution's two kernels; PR 42 had replaced it for those kernels, PR 40 for the flat layout); the latent
-#: layers alone still lower to ca7fb08's text
+#: layers alone still lower to ca7fb08's text.  **Every digest below that holds a flash kernel (all but Trinity's two,
+#: whose tiny configuration runs XLA's attention) is PR 47's own tree's, which means to alter them**: lse and delta cross
+#: the three kernels' boundary as rows ``[B.H, 1, T]``; with PR 47's ``ops/flash_attention.py`` put back to its parent's
+#: (fd18a10) every one reads what it read, so nothing else in the steps moved
 _PARENT_LOWERED = {
     "trinity-dense": "37a8171073a0b4a3dacc27e8b8545e81591e23a904e1e308408aa025b260041e",
     "trinity-chunked": "a932872d3198be9fdbb2f0ba03f5d0f9a185c0419a89343cf30e19254da6bb44",
-    "kimi-linear-dense": "29b67dd7cd4dae8d53c36a4352943a400ca8b67ed3210220088215ded08ee99b",
-    "kimi-linear-latent-layers-chunked": "7bcb169f9e9314357cf7c76268c4d8396b67fa42046cc894470afee6819f579a",
+    "kimi-linear-dense": "381407bc9ef371da2597e551469826792547c71b39cedc5b31f19d946d7c9d40",
+    "kimi-linear-latent-layers-chunked": "c190f8562dbb1058f1b43073c6a1e7de844e79a0ee06d6bbd343d073dd7d571f",
 }
 
 
@@ -392,12 +395,12 @@ _PARENT_LOWERED = {
 #: ``stateful_loss`` and first ``model_state`` at its ``Config.tiny()``: made on 41e404e (the parent of PR 44, which
 #: moved the loss's dense/chunked fork into ``models/lm.py``), before any model file was touched
 _LOWERED_AT_41E404E = {
-    "joyai-flash-dense": "e5971d94bb5197d9e1994a55b0625a448c5d2004327963ec51a35e028309437b",
-    "joyai-flash-chunked": "7fb918d3996dc3a9fdccdd891edde8631ff1d5a09ede226e49c20d78eec8f591",
-    "granite-hybrid-dense": "255f09cdfe64cb7466e920fe4690d7dd723dc395cbc971f7851351b514f5d875",
-    "granite-hybrid-chunked": "ca5b37138f6a6e5334074f4b4e78fd8ef760f7a9c389eddccc3a843170ec5186",
-    "phi4-flash-dense": "0fe143720abb0b4c5ba9b4ff558a20ffdf26d477ceb21ad9a3a99dd4e7f4cfea",
-    "phi4-flash-chunked": "4890f5850c9c97df475c8e9bd9c1ebd3e826fcb74695e61cac50e41dcbb05171",
+    "joyai-flash-dense": "6efa895b0e5b2e1d4c020a81c005c45b8810c539fc764cf04376aca294700191",
+    "joyai-flash-chunked": "0c3010442611fb40d7560db9a8c55f6fca04a0c4e09b496a20b7e4efa8159d32",
+    "granite-hybrid-dense": "5038e2e12b56794bd9b15bac4ed94906b27919e64cf88f0c1512f47fe46788e2",
+    "granite-hybrid-chunked": "9f4fdd14cbe334ad6899abd63bca33f663af055e095c1e350c6db1fb27425184",
+    "phi4-flash-dense": "26e9609176ad57d1398e97aa9a7e574df3258eb31faf901f5932b03efe934dc2",
+    "phi4-flash-chunked": "7b46afdc92228f253ec57f392b187720354bef433e295c210aa764a04be2d09c",
 }
 
 
