@@ -34,6 +34,41 @@ turn moves values and computes none, so the results are to the bit those of
 any other layout, and nothing outside the kernels pads, broadcasts or slices
 a statistic: the forward's array is the one the backward kernels read.
 
+**Where the operands live.**  The model keeps q, k, v and wants o as
+``[B, T, H, D]``, which is ``[B, T, H·D]`` for free.  Where the heads are
+equal (``H_q == H_kv``), ``D == D_v``, a 128-lane block is whole heads
+(``pair = max(1, 128 // D)``, ``pair·D`` a multiple of 128) and ``H`` divides
+by ``pair``, the kernels read q, k, v, do and write o, dq, dk, dv in that
+array: the grid's first axis runs over ``B · H/pair`` lane blocks, a block
+``(1, rows, pair·D)`` at ``(b // (H/pair), ·, b % (H/pair))``, and no pass of
+XLA's stands between a projection and a kernel; the residuals are the model's
+arrays.  :func:`_bthd_call` decides, by the shapes and nothing else; one
+kernel body serves both ways of addressing.  At ``D = 64`` a program holds
+two heads side by side in its blocks' lanes.  *Masked operands, no lane
+slice*: once a program, outside the walk, the resident operand (q and do; k
+and v in the dk/dv kernel) is formed once for each head with the other head's
+lanes zero (cleared on the 32-bit words, :func:`_heads_of`), and every
+product of a head contracts over all 128 lanes against the other operand as
+loaded, the other head's lanes adding exact zeros to an fp32 sum.  The
+accumulating products come out 128 lanes wide with the head's own 64 valid;
+each head keeps its accumulator and the block is put together once, after the
+walk, at the store (the forward divides once, the normalizers spread over
+their heads' lanes).  A 64-deep contraction and a 64-wide result already take
+a whole pass of the 128 x 128 MXU, so the passes are the ``[B·H, T, D]``
+entry's, and the programs are half as many.  The backward kernels take both
+heads tile by tile (a K/V or q/do tile loaded once for both); the forward
+walks one head's tiles, then the other's.  Measured on a v5e at cell 1's
+shape against the ``[B·H, T, D]`` entry (PERF.md §6, PR 48): forward +4.9% a
+call, dq -3.0%, dk/dv -2.2%, the three together -0.7%; with lane slices
+instead of masks +9.7%, +3.0%, +0.8%.  lse and Δ stay ``f32[B·H, 1, T]``, a block
+``(pair, 1, T)`` a program; Δ is summed over each head's lanes of ``do ∘ o``
+as a product with the heads' 0/1 indicator (:func:`_head_sums`).  Everything
+else keeps the ``[B·H, T, D]`` entry and the transposes around it, unchanged:
+grouped K/V heads (cells 3, 6, 7, 8; an index map alone would do, not
+measured), a head size of its own for v (cells 4, 5, 7), an odd number of
+64-wide heads, and the ring shard, which calls ``_flash_bhtd_lse`` on arrays
+it has already laid out (``parallel/ring_attention.py``).
+
 **Which tiles.**  With ``causal=True`` a kernel visits only the tiles the
 mask leaves: query block ``qi`` takes key blocks ``0 … ((qi+1)·bq − 1) // bk``,
 key block ``kj`` takes query blocks from ``(kj·bk) // bq`` up, and the mask is
@@ -70,9 +105,9 @@ body for each of the 16 positions would be 70.
 (``H_q = G * H_kv``): query head ``h`` reads KV head ``h // G`` through the
 K/V block index, nothing is repeated in HBM, and the dk/dv kernel writes one
 fp32 partial for each query head that the wrapper sums over the group.  Run
-on a chip (PERF.md §4): equal heads at head size 64 and T=1,024 (cells 1-2);
-eight query heads to a K/V head at head size 128 and T=8,192, windowed and
-full (cell 3); equal heads with scores over 192 and values over 128 at
+on a chip (PERF.md §4): equal heads at head size 64 and T=1,024, read in place,
+two heads a program (cells 1-2, PR 48); eight query heads to a K/V head at
+head size 128 and T=8,192, windowed and full (cell 3); equal heads with scores over 192 and values over 128 at
 T=8,192 (cells 4-5); four query heads to a K/V head at head size 64 and
 T=8,192 with a given ``scale`` (cell 6, PR 39).
 
@@ -111,7 +146,7 @@ kernel on its local K/V shard).
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -308,7 +343,7 @@ def _top(n: int) -> int:
     return 1 << (n.bit_length() - 1)
 
 
-def _runs(n: int, span, block_q: int, block_k: int):
+def _runs(n: int, span, block_q: int, block_k: int, pair: int = 1):
     """How ``n`` positions walk their spans from ONE body whose bounds are
     traced: ``(most, run)`` for each of a span's three parts (masked,
     unmasked, masked), ``most`` the tiles any position has there.  ``run`` 0:
@@ -319,10 +354,11 @@ def _runs(n: int, span, block_q: int, block_k: int):
     runs are the largest power of two (at most the largest in ``most``) whose
     code, ``2·run − 1`` tiles for each such part beside the written-out ones,
     stays within ``_TRACED_BASE_ELEMENTS``, and 1 where nothing fits: one tile
-    a turn."""
+    a turn.  A written-out tile is the code of ``pair`` heads' (a program of
+    the in-place entry holds as many), so it counts that many times."""
     spans = [span(i) for i in range(n)]
     counts = [[s[i + 1] - s[i] for s in spans] for i in range(3)]
-    room = _TRACED_BASE_ELEMENTS // (block_q * block_k)
+    room = _TRACED_BASE_ELEMENTS // (block_q * block_k * pair)
 
     def parts(run):
         return tuple((max(c), 0 if min(c) == max(c) else min(run, _top(max(c)))) for c in counts)
@@ -336,33 +372,36 @@ def _runs(n: int, span, block_q: int, block_k: int):
     return parts(run)
 
 
-def _written_out(n: int, span, block_q: int, block_k: int, causal: bool, window):
+def _written_out(n: int, span, block_q: int, block_k: int, causal: bool, window, pair: int = 1):
     """The written-out bodies (:func:`_bodies`) of a kernel over ``n`` grid
-    positions along the block axis, or None where their code would pass
-    ``_STRAIGHT_LINE_ELEMENTS`` (one body then walks by :func:`_runs`)."""
+    positions along the block axis, or None where their code (each tile that
+    of ``pair`` heads) would pass ``_STRAIGHT_LINE_ELEMENTS`` (one body then
+    walks by :func:`_runs`)."""
     if not causal or n == 1:
         return [(0, 0, span(0))]
     bodies = _bodies(n, span, share=window is not None and block_q == block_k)
     written = sum(bounds[3] - bounds[0] for _, _, bounds in bodies)
-    return bodies if written * block_q * block_k <= _STRAIGHT_LINE_ELEMENTS else None
+    return bodies if written * block_q * block_k * pair <= _STRAIGHT_LINE_ELEMENTS else None
 
 
 def looped_tiles(
-    T: int, block_q: int, block_k: int, causal: bool, window: Optional[int] = None, key_side: bool = False
+    T: int, block_q: int, block_k: int, causal: bool, window: Optional[int] = None, key_side: bool = False,
+    pair: int = 1,
 ) -> int:
     """Of :func:`visited_tiles`, those a kernel gridded over query blocks (or
     over key blocks: ``key_side``) reaches from inside a loop with a traced
-    trip count; the others are straight-line code, under a condition or not."""
+    trip count; the others are straight-line code, under a condition or not.
+    ``pair``: the heads a program holds (:func:`_operands`)."""
     n, span = (_key_side if key_side else _query_side)(T, block_q, block_k, causal, window)
-    if _written_out(n, span, block_q, block_k, causal, window) is not None:
+    if _written_out(n, span, block_q, block_k, causal, window, pair) is not None:
         return 0
-    parts = _runs(n, span, block_q, block_k)
+    parts = _runs(n, span, block_q, block_k, pair)
     return sum(
         (s[i + 1] - s[i]) // run * run for s in map(span, range(n)) for i, (_, run) in enumerate(parts) if run
     )
 
 
-def _per_program(body, n: int, span, block_q: int, block_k: int, causal: bool, window) -> None:
+def _per_program(body, n: int, span, block_q: int, block_k: int, causal: bool, window, pair: int) -> None:
     """``body(rel, bounds, base, walk)`` for the grid position ``base + rel``
     along the block axis (of ``n``): ``_at(base, rel)`` is the program's own
     block in every body (a query block finds its lanes of a statistic's row by
@@ -379,14 +418,14 @@ def _per_program(body, n: int, span, block_q: int, block_k: int, causal: bool, w
     (:func:`_runs`).  Without the mask one body serves them all: every
     position has its bounds, counted from 0, and ``rel`` is the traced
     position."""
-    bodies = _written_out(n, span, block_q, block_k, causal, window)
+    bodies = _written_out(n, span, block_q, block_k, causal, window, pair)
     if n == 1:
         return body(0, bodies[0][2], 0, _band)
     position = pl.program_id(1)
     if not causal:
         return body(position, bodies[0][2], 0, _band)
     if bodies is None:
-        return body(position, span(position), 0, functools.partial(_band, parts=_runs(n, span, block_q, block_k)))
+        return body(position, span(position), 0, functools.partial(_band, parts=_runs(n, span, block_q, block_k, pair)))
     for first, last, bounds in bodies:
         if first == last:
             pl.when(position == first)(functools.partial(body, first, bounds, 0, _band))
@@ -464,72 +503,121 @@ def _held(base, r, first, band):
     return _at(base, r) if band is None else r - first
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k, window, seq, band):
+def _heads_of(x, pair: int):
+    """A ``[rows, pair·d]`` block that holds ``pair`` heads side by side in
+    its lanes, once for each head with the other heads' lanes zero: a product
+    that contracts over all the lanes is then that head's alone (exact zeros
+    added to an fp32 sum), and no lane is sliced or shifted.  One head: the
+    block itself.  The lanes are cleared on the block's 32-bit words (two
+    rows of bfloat16 a word, the same lane): a select on bfloat16 itself is
+    unpacked to float32 and packed again on a v5e, which cost every kernel
+    2-3% of a call at cell 1's shape (PERF.md §6, PR 48)."""
+    if pair == 1:
+        return [x]
+    whole_words = (x.shape[0] * x.dtype.itemsize) % 4 == 0
+    words = pltpu.bitcast(x, jnp.uint32) if whole_words else x
+    head = lax.broadcasted_iota(jnp.int32, words.shape, 1) // (words.shape[1] // pair)
+    kept = [jnp.where(head == h, words, jnp.zeros_like(words)) for h in range(pair)]
+    return [pltpu.bitcast(k, x.dtype) for k in kept] if whole_words else kept
+
+
+def _side_by_side(parts, lanes=None):
+    """One ``[rows, pair·d]`` block from a result for each head, each as wide
+    as the block and valid on its own head's lanes (a product against all the
+    lanes of the other operand leaves the other heads' columns beside it); or
+    from a ``[rows, 1]`` column for each head, spread over the head's lanes
+    of ``lanes``."""
+    block = parts[-1]
+    if len(parts) == 1:
+        return block
+    lanes = lanes or block.shape[1]
+    head = lax.broadcasted_iota(jnp.int32, (block.shape[0], lanes), 1) // (lanes // len(parts))
+    for h in reversed(range(len(parts) - 1)):
+        block = jnp.where(head == h, parts[h], block)
+    return block
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_q, block_k, window, seq, band, pair):
     q = q_ref[0]
     bq = q.shape[0]
+    q_heads = _heads_of(q, pair)
 
     def query_block(rel, bounds, base, walk):
-        def tile(r, carry, masked):
-            m, l, acc = carry
-            at = _held(base, r, bounds[0], band)
-            k, v = _rows(k_ref, at, block_k), _rows(v_ref, at, block_k)
-            s = _dot(q, k, (1, 1)) * scale
-            if masked:
-                s = _mask(s, base, rel, r, block_q, block_k, window)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new)
-            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc = acc * alpha + _dot(p.astype(v.dtype), v, (1, 0))
-            return m_new, l, acc
+        # a head's whole walk after the other's (the backward kernels take the heads tile by tile): measured, the
+        # forward is 1-2% of a call faster so at cell 1's shape, within 5% of the [B·H, T, D] entry's (PERF.md §6, PR 48)
+        def tiles_of(q):
+            def tile(r, carry, masked):
+                m, l, acc = carry
+                at = _held(base, r, bounds[0], band)
+                k, v = _rows(k_ref, at, block_k), _rows(v_ref, at, block_k)
+                s = _dot(q, k, (1, 1)) * scale
+                if masked:
+                    s = _mask(s, base, rel, r, block_q, block_k, window)
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new)
+                l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+                acc = acc * alpha + _dot(p.astype(v.dtype), v, (1, 0))
+                return m_new, l, acc
 
-        carry = (
-            jnp.full((bq, 1), _NEG_INF, jnp.float32),
-            jnp.zeros((bq, 1), jnp.float32),
-            jnp.zeros((bq, v_ref.shape[-1]), jnp.float32),
-        )
-        m, l, acc = walk(bounds, tile, carry)
-        o_ref[0] = (acc / l).astype(o_ref.dtype)
-        lse_ref[0, :, _block(_at(base, rel), block_q)] = _as_row(m + jnp.log(l))
+            return tile
 
-    _per_program(query_block, *_query_side(seq, block_q, block_k, causal, window), block_q, block_k, causal, window)
+        def start():
+            return (
+                jnp.full((bq, 1), _NEG_INF, jnp.float32),
+                jnp.zeros((bq, 1), jnp.float32),
+                jnp.zeros((bq, v_ref.shape[-1]), jnp.float32),
+            )
+
+        done = [walk(bounds, tiles_of(q), start()) for q in q_heads]
+        # one division a program: the heads' sums and normalizers side by side first
+        acc = _side_by_side([acc for _, _, acc in done])
+        o_ref[0] = (acc / _side_by_side([l for _, l, _ in done], acc.shape[1])).astype(o_ref.dtype)
+        for h, (m, l, _) in enumerate(done):
+            lse_ref[h, :, _block(_at(base, rel), block_q)] = _as_row(m + jnp.log(l))
+
+    _per_program(query_block, *_query_side(seq, block_q, block_k, causal, window), block_q, block_k, causal, window, pair)
 
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-    *, scale, causal, block_q, block_k, window, seq, band,
+    *, scale, causal, block_q, block_k, window, seq, band, pair,
 ):
     q = q_ref[0]
     do = do_ref[0]
-    bq, d = q.shape
+    heads = list(zip(_heads_of(q, pair), _heads_of(do, pair)))
 
     def query_block(rel, bounds, base, walk):
         own = _block(_at(base, rel), block_q)
-        lse_col = _as_column(lse_ref[0, :, own])
-        delta_col = _as_column(delta_ref[0, :, own])
+        columns = [(_as_column(lse_ref[h, :, own]), _as_column(delta_ref[h, :, own])) for h in range(pair)]
 
-        def tile(r, dq, masked):
+        def tile(r, carry, masked):
             at = _held(base, r, bounds[0], band)
             k, v = _rows(k_ref, at, block_k), _rows(v_ref, at, block_k)
-            s = _dot(q, k, (1, 1)) * scale
-            if masked:
-                s = _mask(s, base, rel, r, block_q, block_k, window)
-            p = jnp.exp(s - lse_col)
-            ds = p * (_dot(do, v, (1, 1)) - delta_col) * scale
-            return dq + _dot(ds.astype(k.dtype), k, (1, 0))
 
-        dq = walk(bounds, tile, jnp.zeros((bq, d), jnp.float32))
-        dq_ref[0] = dq.astype(dq_ref.dtype)
+            def head(q, do, lse_col, delta_col, dq):
+                s = _dot(q, k, (1, 1)) * scale
+                if masked:
+                    s = _mask(s, base, rel, r, block_q, block_k, window)
+                p = jnp.exp(s - lse_col)
+                ds = p * (_dot(do, v, (1, 1)) - delta_col) * scale
+                return dq + _dot(ds.astype(k.dtype), k, (1, 0))
 
-    _per_program(query_block, *_query_side(seq, block_q, block_k, causal, window), block_q, block_k, causal, window)
+            return tuple(head(*operands, *stats, dq) for operands, stats, dq in zip(heads, columns, carry))
+
+        done = walk(bounds, tile, tuple(jnp.zeros(q.shape, jnp.float32) for _ in heads))
+        dq_ref[0] = _side_by_side(done).astype(dq_ref.dtype)
+
+    _per_program(query_block, *_query_side(seq, block_q, block_k, causal, window), block_q, block_k, causal, window, pair)
 
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    *, scale, causal, block_q, block_k, window, seq, band,
+    *, scale, causal, block_q, block_k, window, seq, band, pair,
 ):
     k = k_ref[0]
     v = v_ref[0]
+    heads = list(zip(_heads_of(k, pair), _heads_of(v, pair)))
     n_q = seq // block_q
 
     def key_block(rel, bounds, base, walk):
@@ -540,26 +628,30 @@ def _dkv_kernel(
             # the tile transposed, [bk, bq]: all four products then contract
             # in the MXU's own orientations (no transposed left operand), and
             # lse and delta are rows
-            dk, dv = carry
             i = _held(base, r, first, band)
             q, do = _rows(q_ref, i, block_q), _rows(do_ref, i, block_q)
-            lse_row = lse_ref[0, :, _block(i, block_q)]
-            delta_row = delta_ref[0, :, _block(i, block_q)]
-            s = _dot(k, q, (1, 1)) * scale
-            if masked:
-                s = _mask(s, base, r, rel, block_q, block_k, window, q_axis=1)
-            p = jnp.exp(s - lse_row)
-            dv = dv + _dot(p.astype(do.dtype), do, (1, 0))
-            ds = p * (_dot(v, do, (1, 1)) - delta_row) * scale
-            dk = dk + _dot(ds.astype(q.dtype), q, (1, 0))
-            return dk, dv
+
+            def head(h, k, v, dk, dv):
+                lse_row = lse_ref[h, :, _block(i, block_q)]
+                delta_row = delta_ref[h, :, _block(i, block_q)]
+                s = _dot(k, q, (1, 1)) * scale
+                if masked:
+                    s = _mask(s, base, r, rel, block_q, block_k, window, q_axis=1)
+                p = jnp.exp(s - lse_row)
+                dv = dv + _dot(p.astype(do.dtype), do, (1, 0))
+                ds = p * (_dot(v, do, (1, 1)) - delta_row) * scale
+                dk = dk + _dot(ds.astype(q.dtype), q, (1, 0))
+                return dk, dv
+
+            return tuple(head(h, *operands, *sums) for h, (operands, sums) in enumerate(zip(heads, carry)))
 
         zeros = jnp.zeros(k.shape, jnp.float32)
-        dk, dv = walk(bounds, tile, (zeros, zeros if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32)))
-        dk_ref[0] = dk.astype(dk_ref.dtype)
-        dv_ref[0] = dv.astype(dv_ref.dtype)
+        sums = (zeros, zeros if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32))
+        done = walk(bounds, tile, (sums,) * pair)
+        dk_ref[0] = _side_by_side([dk for dk, _ in done]).astype(dk_ref.dtype)
+        dv_ref[0] = _side_by_side([dv for _, dv in done]).astype(dv_ref.dtype)
 
-    _per_program(key_block, *_key_side(seq, block_q, block_k, causal, window), block_q, block_k, causal, window)
+    _per_program(key_block, *_key_side(seq, block_q, block_k, causal, window), block_q, block_k, causal, window, pair)
 
 
 #: The tile by shape, measured on a TPU v5e (PERF.md §6, PR 25): rows of
@@ -618,19 +710,21 @@ def _block_sizes(T: int, D: int, dtype, block_q: Optional[int], block_k: Optiona
     return bq, bk
 
 
-def _record_tiles(T: int, bq: int, bk: int, causal: bool, kernels: int, window) -> None:
+def _record_tiles(T: int, bq: int, bk: int, causal: bool, kernels: int, window, where: "_Operands") -> None:
     """Trace-time gauges (once per compile, nothing per step): the tiles of
     one ``[T, T]`` score plane the attention call's kernels visit and would
     visit without skipping, summed over ``kernels`` of them (one: the forward;
     three: with dq and dk/dv), of the visited those reached from a loop
-    (:func:`looped_tiles`), and the tile."""
+    (:func:`looped_tiles`), the tile, and whether the kernels read the
+    model's own arrays (``flash.in_place`` 1) or ``[B·H, T, D]`` copies."""
     metrics = default_registry()
     metrics.gauge("flash.tiles_visited", kernels * visited_tiles(T, bq, bk, causal, window))
     metrics.gauge("flash.tiles_total", kernels * (T // bq) * (T // bk))
-    by_query, by_key = (looped_tiles(T, bq, bk, causal, window, key_side=side) for side in (False, True))
+    by_query, by_key = (looped_tiles(T, bq, bk, causal, window, key_side=side, pair=where.pair) for side in (False, True))
     metrics.gauge("flash.tiles_looped", by_query if kernels == 1 else 2 * by_query + by_key)
     metrics.gauge("flash.block_q", bq)
     metrics.gauge("flash.block_k", bk)
+    metrics.gauge("flash.in_place", int(where.in_place))
 
 
 #: Bytes of whole-sequence operands (K and V, or q and do, double-buffered)
@@ -641,6 +735,7 @@ _VMEM_LIMIT = 96 << 20
 
 
 def _compiler_params(T: int, D: int, Dv: int, dtype) -> dict:
+    """``D``, ``Dv``: the lanes of a program's block (a pair's width)."""
     resident = 2 * T * (D + Dv) * jnp.dtype(dtype).itemsize
     if resident <= _VMEM_ASK_OVER:
         return {}
@@ -658,27 +753,107 @@ def _band_blocks(T: int, bq: int, bk: int, window) -> Optional[int]:
     return blocks if blocks < T // bk else None
 
 
-def _resident(T: int, D: int, band: Optional[int], block: int, index, first):
-    """The spec of a ``[·, T, D]`` operand held whole, or (``band`` blocks)
-    from block ``first(i)`` of grid position ``i`` on: element-indexed, the
-    offset a multiple of the block so that Mosaic can prove it aligned."""
-    if band is None:
-        return pl.BlockSpec((1, T, D), lambda b, i: (index(b), 0, 0))
-    return pl.BlockSpec(
-        (pl.Element(1), pl.Element(band * block), pl.Element(D)),
-        lambda b, i: (index(b), first(i) * block, 0),
+class _Operands(NamedTuple):
+    """Where grid row ``b`` of a kernel finds its heads.  On the
+    ``[B·H, T, D]`` entry a row is a head: ``pair`` 1, ``q_at(b) = (b, 0)``,
+    ``kv_at(b) = (b // groups, 0)``.  In place a row is ``pair`` heads that
+    share a lane block of the model's ``[B, T, H·D]``: ``(batch row, lane
+    block) = (b // (H/pair), b % (H/pair))`` for q, o, do, dq and k, v, dk, dv
+    alike.  Either way the rows' heads are rows ``pair·b …`` of the
+    statistics' ``[B·H, 1, T]``."""
+
+    in_place: bool
+    rows: int       # grid positions along axis 0
+    pair: int       # heads a program holds, side by side in its blocks' lanes
+    q_at: Callable
+    kv_at: Callable
+
+
+def _lane_pair(D: int) -> int:
+    """Heads of size ``D`` that fill a 128-lane block (one where a head is
+    that wide or wider)."""
+    return max(1, _LANES // D)
+
+
+def _operands(q_shape, k_shape) -> _Operands:
+    """By the arrays the entry was handed: the model's ``[B, T, H, D]``
+    (:func:`_bthd_call` hands them over only where the rule of "Where the
+    operands live" holds) or ``[B·H, T, D]``."""
+    if len(q_shape) == 4:
+        B, _, H, D = q_shape
+        pair = _lane_pair(D)
+        at = lambda b: (b // (H // pair), b % (H // pair))  # noqa: E731
+        return _Operands(True, B * H // pair, pair, at, at)
+    kv = _kv_index(q_shape[0] // k_shape[0])
+    return _Operands(False, q_shape[0], 1, lambda b: (b, 0), lambda b: (kv(b), 0))
+
+
+def _lanes_shape(shape):
+    """``[B, T, H, D] -> [B, T, H·D]``; a ``[B·H, T, D]`` shape as it is."""
+    return shape if len(shape) == 3 else (*shape[:2], shape[2] * shape[3])
+
+
+def _lanes(x):
+    """The model's ``[B, T, H, D]`` as the ``[B, T, H·D]`` the kernels
+    address (the same bytes); a ``[B·H, T, D]`` array as it is."""
+    return x if x.ndim == 3 else x.reshape(_lanes_shape(x.shape))
+
+
+def _head_sums(x, heads: int):
+    """``f32[B, T, H·D] -> f32[B, H, T]``: each head's ``D`` lanes summed, as a
+    product with the heads' 0/1 indicator.  XLA's own ``sum`` over the
+    ``[B, T, H, D]`` view wants ``T`` on the lanes first and re-lays the whole
+    array for it (a copy of ``f32[12, 1024, 768]`` a layer in cell 1's
+    compiled step); the product's emitter reads the array as it lies, takes
+    the elementwise pass that made it as its own input, and writes the
+    statistics' layout.  At ``HIGHEST`` the float32 operand goes through whole
+    (three bfloat16 pieces against an exact indicator) and the sum is
+    accumulated in float32."""
+    width = x.shape[-1]
+    indicator = jnp.arange(width)[:, None] // (width // heads) == jnp.arange(heads)[None, :]
+    return jnp.einsum(
+        "btc,ch->bht", x, indicator.astype(x.dtype),
+        precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )
 
 
-def _stat_row(T: int):
-    """The spec of a ``[B·H, 1, T]`` statistic: head-row ``b``'s row, whole.
-    As the forward's result it is one block that every program along grid
-    axis 1 revisits, each storing its own ``bq`` lanes; it is written back
-    when ``b`` moves on.  That holds while axis 1 runs in order on one core
+def _own(block: int, width: int, at):
+    """The spec of grid position ``(b, i)``'s own ``block`` rows of an
+    operand, ``width`` lanes at ``at(b)``."""
+    def index(b, i):
+        row, lane = at(b)
+        return row, i, lane
+
+    return pl.BlockSpec((1, block, width), index)
+
+
+def _resident(T: int, width: int, band: Optional[int], block: int, at, first):
+    """The spec of an operand's ``width`` lanes at ``at(b)`` held whole, or
+    (``band`` blocks) from block ``first(i)`` of grid position ``i`` on:
+    element-indexed, the offsets multiples of the block so that Mosaic can
+    prove them aligned."""
+    def whole(b, i):
+        row, lane = at(b)
+        return row, 0, lane
+
+    def banded(b, i):
+        row, lane = at(b)
+        return row, first(i) * block, lane * width
+
+    if band is None:
+        return pl.BlockSpec((1, T, width), whole)
+    return pl.BlockSpec((pl.Element(1), pl.Element(band * block), pl.Element(width)), banded)
+
+
+def _stat_row(T: int, pair: int = 1):
+    """The spec of a ``[B·H, 1, T]`` statistic: the rows of grid row ``b``'s
+    ``pair`` heads, whole.  As the forward's result it is one block that
+    every program along grid axis 1 revisits, each storing its own ``bq``
+    lanes; it is written back when ``b`` moves on.  That holds while axis 1 runs in order on one core
     (Mosaic's default, ``arbitrary``): marked ``parallel`` for a chip with
     two cores, each core would write back a whole row over the other's
     lanes, so that axis takes no such mark while the row is the block."""
-    return pl.BlockSpec((1, 1, T), lambda b, i: (b, 0, 0))
+    return pl.BlockSpec((pair, 1, T), lambda b, i: (b, 0, 0))
 
 
 def _kv_index(groups: int):
@@ -691,14 +866,17 @@ def _kv_index(groups: int):
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
 )
 def _flash_bhtd(q, k, v, scale, causal, block_q, block_k, interpret, window):
+    """``q, k, v`` as ``[B·H, T, D]``, or all three as the model's
+    ``[B, T, H, D]`` where :func:`_bthd_call` found that the kernels can read
+    them in place; ``o`` comes back in the layout ``q`` came in."""
     out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window)
     return out
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window):
-    _, T, D = q.shape
+    T, D = q.shape[1], q.shape[-1]
     bq, bk = _block_sizes(T, D, q.dtype, block_q, block_k)
-    _record_tiles(T, bq, bk, causal, 1, window)
+    _record_tiles(T, bq, bk, causal, 1, window, _operands(q.shape, k.shape))
     interp = resolve_interpret(interpret, "flash_attention")
     out, lse = _fwd_call(q, k, v, scale, causal, bq, bk, interp, window)
     return out, (q, k, v, out, lse)
@@ -710,32 +888,32 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, window):
 # step pays 72 of them, twice (PERF.md §6, PR 25: the step's trace).
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
 def _fwd_call(q, k, v, scale, causal, bq, bk, interp, window):
-    BH, T, D = q.shape
-    Dv = v.shape[-1]
-    kv = _kv_index(BH // k.shape[0])
+    T, D, Dv = q.shape[1], q.shape[-1], v.shape[-1]
+    where = _operands(q.shape, k.shape)
+    pair, heads = where.pair, where.rows * where.pair
     band = _band_blocks(T, bq, bk, window)
     ahead = 0 if band is None else band - 1     # the band starts this many blocks before the diagonal
-    held = lambda d: _resident(T, d, band, bk, kv, lambda i: jnp.maximum(i - ahead, 0))  # noqa: E731
+    held = lambda d: _resident(T, pair * d, band, bk, where.kv_at, lambda i: jnp.maximum(i - ahead, 0))  # noqa: E731
     out, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk, window=window,
-            seq=T, band=band,
+            seq=T, band=band, pair=pair,
         ),
-        grid=(BH, T // bq),
-        in_specs=[pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)), held(D), held(Dv)],
+        grid=(where.rows, T // bq),
+        in_specs=[_own(bq, pair * D, where.q_at), held(D), held(Dv)],
         out_specs=[
-            pl.BlockSpec((1, bq, Dv), lambda b, i: (b, i, 0)),
-            _stat_row(T),   # revisited along axis 1, which therefore stays "arbitrary" (see _stat_row)
+            _own(bq, pair * Dv, where.q_at),
+            _stat_row(T, pair),   # revisited along axis 1, which therefore stays "arbitrary" (see _stat_row)
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, T, Dv), q.dtype),
-            jax.ShapeDtypeStruct((BH, 1, T), jnp.float32),
+            jax.ShapeDtypeStruct(_lanes_shape((*q.shape[:-1], Dv)), q.dtype),
+            jax.ShapeDtypeStruct((heads, 1, T), jnp.float32),
         ],
         interpret=interp,
         name="flash_fwd",
-        **_compiler_params(T if band is None else band * bk, D, Dv, k.dtype),
-    )(q, k, v)
-    return out, lse.reshape(BH, T)
+        **_compiler_params(T if band is None else band * bk, pair * D, pair * Dv, k.dtype),
+    )(_lanes(q), _lanes(k), _lanes(v))
+    return out.reshape(*q.shape[:-1], Dv), lse.reshape(heads, T)
 
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, window, res, do):
@@ -747,10 +925,10 @@ def _flash_bwd_core(scale, causal, block_q, block_k, interpret, window, res, do,
     which folds into the existing kernels as ``delta → delta − dlse`` (the
     bracket is ``p·(dp − delta)``) — no kernel change needed."""
     q, k = res[0], res[1]
-    _, T, D = q.shape
+    T, D = q.shape[1], q.shape[-1]
     bq, bk = _block_sizes(T, D, q.dtype, block_q, block_k)
     # a backward pass closes an attention call: its forward kernel and these two
-    _record_tiles(T, bq, bk, causal, 3, window)
+    _record_tiles(T, bq, bk, causal, 3, window, _operands(q.shape, k.shape))
     interp = resolve_interpret(interpret, "flash_attention")
     return _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window)
 
@@ -758,82 +936,86 @@ def _flash_bwd_core(scale, causal, block_q, block_k, interpret, window, res, do,
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
 def _bwd_call(res, do, dlse, scale, causal, bq, bk, interp, window):
     q, k, v, out, lse = res
-    BH, T, D = q.shape
-    Dv = v.shape[-1]
-    groups = BH // k.shape[0]
-    kv = _kv_index(groups)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    T, D, Dv = q.shape[1], q.shape[-1], v.shape[-1]
+    where = _operands(q.shape, k.shape)
+    pair, heads = where.pair, where.rows * where.pair
+    groups = 1 if where.in_place else heads // k.shape[0]
+    if where.in_place:
+        delta = _head_sums(_lanes(do).astype(jnp.float32) * _lanes(out).astype(jnp.float32), q.shape[2]).reshape(heads, T)
+    else:
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
     stats = lse[:, None, :], delta[:, None, :]
+    operands = tuple(map(_lanes, (q, k, v, do)))
 
     band = _band_blocks(T, bq, bk, window)
     rows = T if band is None else band * bk
     ahead = 0 if band is None else band - 1
-    held = lambda d: _resident(T, d, band, bk, kv, lambda i: jnp.maximum(i - ahead, 0))  # noqa: E731
+    held = lambda d: _resident(T, pair * d, band, bk, where.kv_at, lambda i: jnp.maximum(i - ahead, 0))  # noqa: E731
     dq = pl.pallas_call(
         functools.partial(
             _dq_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk, window=window,
-            seq=T, band=band,
+            seq=T, band=band, pair=pair,
         ),
-        grid=(BH, T // bq),
+        grid=(where.rows, T // bq),
         in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
+            _own(bq, pair * D, where.q_at),
             held(D),
             held(Dv),
-            pl.BlockSpec((1, bq, Dv), lambda b, i: (b, i, 0)),
-            _stat_row(T),
-            _stat_row(T),
+            _own(bq, pair * Dv, where.q_at),
+            _stat_row(T, pair),
+            _stat_row(T, pair),
         ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+        out_specs=_own(bq, pair * D, where.q_at),
+        out_shape=jax.ShapeDtypeStruct(operands[0].shape, q.dtype),
         interpret=interp,
         name="flash_bwd_dq",
-        **_compiler_params(rows, D, Dv, k.dtype),
-    )(q, k, v, do, *stats)
+        **_compiler_params(rows, pair * D, pair * Dv, k.dtype),
+    )(*operands, *stats)
 
     # grouped heads: each query head writes its own fp32 share of dk and dv
     # (a grid program owns its output block), summed over the group below
     part = jnp.float32 if groups > 1 else None
     last = 0 if band is None else T // bq - band    # the last block a band of queries can start at
-    seen = lambda d: _resident(T, d, band, bq, lambda b: b, lambda j: jnp.minimum(j, last))  # noqa: E731
+    seen = lambda d: _resident(T, pair * d, band, bq, where.q_at, lambda j: jnp.minimum(j, last))  # noqa: E731
     if band is None:
-        stat = _stat_row(T)
+        stat = _stat_row(T, pair)
     else:
-        stat = pl.BlockSpec(
-            (pl.Element(1), pl.Element(1), pl.Element(rows)),
-            lambda b, j: (b, 0, jnp.minimum(j, last) * bq),
+        stat = pl.BlockSpec(     # element-indexed: grid row b's heads start at row pair·b
+            (pl.Element(pair), pl.Element(1), pl.Element(rows)),
+            lambda b, j: (b if pair == 1 else b * pair, 0, jnp.minimum(j, last) * bq),
         )
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkv_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk, window=window,
-            seq=T, band=band,
+            seq=T, band=band, pair=pair,
         ),
-        grid=(BH, T // bk),
+        grid=(where.rows, T // bk),
         in_specs=[
             seen(D),
-            pl.BlockSpec((1, bk, D), lambda b, j: (kv(b), j, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, j: (kv(b), j, 0)),
+            _own(bk, pair * D, where.kv_at),
+            _own(bk, pair * Dv, where.kv_at),
             seen(Dv),
             stat,
             stat,
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, j: (b, j, 0)),
+            _own(bk, pair * D, where.q_at),
+            _own(bk, pair * Dv, where.q_at),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), part or k.dtype),
-            jax.ShapeDtypeStruct((BH, T, Dv), part or v.dtype),
+            jax.ShapeDtypeStruct(operands[0].shape, part or k.dtype),
+            jax.ShapeDtypeStruct(operands[3].shape, part or v.dtype),
         ],
         interpret=interp,
         name="flash_bwd_dkv",
-        **_compiler_params(rows, D, Dv, q.dtype),
-    )(q, k, v, do, *stats)
+        **_compiler_params(rows, pair * D, pair * Dv, q.dtype),
+    )(*operands, *stats)
     if groups > 1:
-        dk = dk.reshape(BH // groups, groups, T, D).sum(axis=1).astype(k.dtype)
-        dv = dv.reshape(BH // groups, groups, T, Dv).sum(axis=1).astype(v.dtype)
-    return dq, dk, dv
+        dk = dk.reshape(heads // groups, groups, T, D).sum(axis=1).astype(k.dtype)
+        dv = dv.reshape(heads // groups, groups, T, Dv).sum(axis=1).astype(v.dtype)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 _flash_bhtd.defvjp(_flash_fwd, _flash_bwd)
@@ -864,8 +1046,11 @@ _flash_bhtd_lse.defvjp(_flash_fwd_lse, _flash_bwd_lse)
 
 def _bthd_call(kernel_entry, q, k, v, causal, scale, block_q, block_k, interpret, window=None):
     """Shared model-layout plumbing for the public wrappers: validate,
-    default the scale, run ``kernel_entry`` on ``[B·H, T, D]`` tensors, and
-    return its raw outputs plus the dims needed to restore the layout."""
+    default the scale, and run ``kernel_entry`` on the arrays as they are
+    where the kernels can read them in place (the rule of "Where the operands
+    live": decided here, by the shapes, and nowhere else), else on
+    ``[B·H, T, D]`` copies; returns its raw outputs, ``o`` as ``[B, T, H,
+    D_v]`` either way."""
     B, T, H, D = q.shape
     Hkv = k.shape[2] if k.ndim == 4 else 0
     if k.shape[:-1] != v.shape[:-1] or k.shape != (B, T, Hkv, D) or Hkv == 0 or H % Hkv:
@@ -880,12 +1065,16 @@ def _bthd_call(kernel_entry, q, k, v, causal, scale, block_q, block_k, interpret
         window = None if window >= T else int(window)   # a band over the whole triangle
     if scale is None:
         scale = float(1.0 / np.sqrt(D))
+    pair = _lane_pair(D)
+    if q.shape == k.shape == v.shape and (pair * D) % _LANES == 0 and H % pair == 0:
+        return kernel_entry(q, k, v, scale, causal, block_q, block_k, interpret, window)
     to_bhtd = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, T, x.shape[-1])  # noqa: E731
+    from_bhtd = lambda o: o.reshape(B, H, T, v.shape[-1]).transpose(0, 2, 1, 3)  # noqa: E731
     raw = kernel_entry(
         to_bhtd(q), to_bhtd(k), to_bhtd(v),
         scale, causal, block_q, block_k, interpret, window,
     )
-    return raw, (B, T, H, v.shape[-1])
+    return (from_bhtd(raw[0]), raw[1]) if isinstance(raw, tuple) else from_bhtd(raw)
 
 
 def flash_attention(
@@ -911,10 +1100,7 @@ def flash_attention(
     ``v`` may be ``[B, T, H_kv, D]`` with ``H`` a multiple of ``H_kv``
     (query head ``h`` reads KV head ``h // (H // H_kv)``).
     """
-    out, (B, T, H, D) = _bthd_call(
-        _flash_bhtd, q, k, v, causal, scale, block_q, block_k, interpret, window
-    )
-    return out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    return _bthd_call(_flash_bhtd, q, k, v, causal, scale, block_q, block_k, interpret, window)
 
 
 def flash_attention_with_lse(
@@ -934,10 +1120,5 @@ def flash_attention_with_lse(
     attention over K/V blocks it sees one at a time (ring attention's
     log-sum-exp combine).  Fully differentiable in both outputs.
     """
-    (out, lse), (B, T, H, D) = _bthd_call(
-        _flash_bhtd_lse, q, k, v, causal, scale, block_q, block_k, interpret
-    )
-    return (
-        out.reshape(B, H, T, D).transpose(0, 2, 1, 3),
-        lse.reshape(B, H, T),
-    )
+    out, lse = _bthd_call(_flash_bhtd_lse, q, k, v, causal, scale, block_q, block_k, interpret)
+    return out, lse.reshape(q.shape[0], q.shape[2], q.shape[1])

@@ -318,10 +318,14 @@ def test_the_expert_layer_writes_short_rows_when_the_assignments_fit_them(one_ch
 #: order: tests/test_flash_attention.py holds the two equal to the bit).  **The four ``flash-*`` digests are PR 47's own
 #: tree's (on fd18a10), which means to alter them**: the forward writes lse and the dq kernel reads lse and delta as rows
 #: ``[B.H, 1, T]``, turned to and from the kernels' columns once a program (the values moved, none recomputed: on the chip
-#: o, lse, dq, dk, dv came out the parent's bit for bit at every cell's shape, CHANGES.md PR 47); ``experts-cell3`` is 3f0513b's
+#: o, lse, dq, dk, dv came out the parent's bit for bit at every cell's shape, CHANGES.md PR 47); ``experts-cell3`` is 3f0513b's.
+#: **``flash-cell1`` and ``flash-cell2`` are PR 48's own tree's (on b372cff), which means to alter them and no other**:
+#: equal heads of 64 are read in place (the kernels' operands are the model's ``[B, T, H.D]``, two heads to a 128-lane
+#: block and a program, no ``[B, T, H, D] <-> [B.H, T, D]`` transpose in the text, delta summed by a product with the
+#: heads' indicator); cell 3's grouped heads keep the ``[B.H, T, D]`` entry and its two digests stand as PR 47 left them
 _PARENT_LOWERED = {
-    "flash-cell1": "ef15c7184539b013482d2cbbc39410fc7b00d9f0daf151a14f8420013ad805f1",
-    "flash-cell2": "6a29440be55a87789147446952be2a94a7bf919eef195418ccd4aa45419c9d76",
+    "flash-cell1": "23738d96c5f34b27c351970c109cc682b4985fd496f403414f77146eea46f816",
+    "flash-cell2": "4e33b31c5e103f95c2102423d00aa4229105db84afc0b7c869c043f94584dab5",
     "flash-cell3-window": "eb295fca2c402f9bfa7e63bd2e457c89acccc094d3a2f457a16e062e7ed33565",
     "flash-cell3-full": "5a8a18e94ecd769e4102257f4f52321d1f02e3c4f5dcd32a16b38c0380c1c018",
     "experts-cell3": "c13c04dad2a0fb4e8e8d6c336ea23dec01ad1e8bd5c1c4893c82a43f75e70bf7",
@@ -346,6 +350,25 @@ _STATISTIC_ROWS = {
 }
 
 
+_COMPILED_ATTENTION = {}
+
+
+def _compiled_attention(cell, one_chip) -> str:
+    """The text of a cell's attention call, forward and backward, compiled for
+    the described chip: once a cell, for every test of this file that reads it."""
+    from adapcc_tpu.ops import flash_attention
+
+    if cell not in _COMPILED_ATTENTION:
+        q, k, v, window = _STATISTIC_ROWS[cell]
+
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, causal=True, window=window, interpret=False).astype(jnp.float32))
+
+        shapes = [jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip) for dims in (q, k, v)]
+        _COMPILED_ATTENTION[cell] = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(*shapes).compile().as_text()
+    return _COMPILED_ATTENTION[cell]
+
+
 @pytest.mark.parametrize("cell", sorted(_STATISTIC_ROWS))
 def test_the_statistics_cross_the_compiled_kernels_as_rows_a_sublane_deep(one_chip, cell):
     """PR 47: lse and delta reach the three compiled kernels as
@@ -353,15 +376,8 @@ def test_the_statistics_cross_the_compiled_kernels_as_rows_a_sublane_deep(one_ch
     the forward's is the very array both backward kernels read, and no
     ``f32[B.H, T, 8]`` (tiled to 128 lanes: 16 times its content) is left in
     the program for XLA to slice or broadcast."""
-    from adapcc_tpu.ops import flash_attention
-
-    q, k, v, window = _STATISTIC_ROWS[cell]
-
-    def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True, window=window, interpret=False).astype(jnp.float32))
-
-    shapes = [jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip) for dims in (q, k, v)]
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(*shapes).compile().as_text()
+    q = _STATISTIC_ROWS[cell][0]
+    text = _compiled_attention(cell, one_chip)
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     heads, T = q[0] * q[2], q[1]
     row = rf"f32\[{heads},1,{T}\]\{{2,1,0:T\(1,128\)"
@@ -371,6 +387,24 @@ def test_the_statistics_cross_the_compiled_kernels_as_rows_a_sublane_deep(one_ch
     backward = [line for line in text.splitlines() if "custom-call(" in line and "flash_bwd" in line]
     assert len(backward) == 2 and all(f"%{lse}," in line for line in backward), "a pass of XLA's stands between the kernels"
     assert not re.search(rf"f32\[{heads},{T},8\]", text)
+
+
+def test_cell_1s_compiled_kernels_read_the_models_arrays_and_no_transpose_stands_around_them(one_chip):
+    """PR 48: at equal heads of 64 the three compiled kernels return
+    ``bf16[B, T, H.D]`` (the model's ``[B, T, H, D]``, two heads to a lane
+    block) and the program holds no array of the ``[B, H, T, D]`` or
+    ``[B.H, T, D]`` shape at all, so no ``copy`` or ``transpose`` of XLA's
+    makes or unmakes one around them.  What is left re-tiles this bare call's
+    own 4-D arguments and results, one pass each (a model's projections write
+    and read ``[B, T, H.D]`` themselves: cell 1's layer compiles to none)."""
+    text = _compiled_attention("cell1", one_chip)
+    kernels = [line for line in text.splitlines() if " custom-call(" in line and "tpu_custom_call" in line]
+    assert len(kernels) == 3
+    for line in kernels:
+        assert set(re.findall(r"bf16\[([\d,]+)\]", line.split(" custom-call(", 1)[0])) == {"12,1024,768"}, line
+    assert not re.search(r"\[12,12,1024,64\]|\[144,1024,64\]", text)
+    moved = [line for line in text.splitlines() if re.match(r"\s*(?:ROOT )?%\S+ = \S+ (?:copy|transpose)\(", line)]
+    assert len(moved) <= 6 and all(" = bf16[12,1024,768]{" in line for line in moved), moved
 
 
 def _without_source_lines(lower):

@@ -623,3 +623,91 @@ def test_lse_matches_the_dense_oracles_logsumexp_where_a_block_is_whole_lane_til
     assert lse.shape == ref_lse.shape == (1, 2, 256)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), atol=2e-5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out), atol=2e-5)
+
+
+# --- PR 48: the kernels read the model's [B, T, H·D] where the shapes allow -------
+
+
+#: ``(B, T, H, H_kv, D, D_v, dtype, causal, window, heads a program holds or 0 where the call falls back)``
+_WHERE = {
+    "pair-of-64-causal": (2, 64, 4, 4, 64, 64, jnp.float32, True, None, 2),
+    "pair-of-64-causal-bf16": (1, 64, 2, 2, 64, 64, jnp.bfloat16, True, None, 2),
+    "pair-of-64-band": (1, 128, 2, 2, 64, 64, jnp.float32, True, 40, 2),       # three blocks of four resident
+    "pair-of-64-odd-rows-bf16": (1, 5, 2, 2, 64, 64, jnp.bfloat16, True, None, 2),     # no whole 32-bit words of rows: masked as they are
+    "pair-of-64-shard": (1, 64, 2, 2, 64, 64, jnp.float32, False, None, 2),    # a ring's off-diagonal block
+    "four-of-32": (1, 64, 4, 4, 32, 32, jnp.float32, True, None, 4),
+    "one-of-128": (1, 64, 3, 3, 128, 128, jnp.float32, True, None, 1),
+    "odd-heads-of-64": (1, 64, 3, 3, 64, 64, jnp.float32, True, None, 0),
+    "grouped-kv": (1, 64, 4, 2, 64, 64, jnp.float32, True, None, 0),
+    "v-of-its-own-size": (1, 64, 2, 2, 64, 128, jnp.float32, True, None, 0),
+    "head-of-192": (1, 64, 2, 2, 192, 192, jnp.float32, True, None, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WHERE))
+def test_the_in_place_entry_agrees_with_the_heads_first_entry_and_the_shapes_choose(case):
+    """``_bthd_call`` hands the kernels the model's arrays where heads are
+    equal, ``D == D_v``, a lane block is whole heads and they divide ``H``
+    (gauge ``flash.in_place`` 1), and ``[B.H, T, D]`` copies everywhere else
+    (0).  Either way o and lse are the ``[B.H, T, D]`` entry's to the bit
+    (values addressed, never recomputed: a masked head adds exact zeros) and
+    dq, dk, dv, under a cotangent for o and a random one for lse, agree with
+    it to float32 rounding (in place delta is summed by a product)."""
+    B, T, H, h_kv, D, d_v, dtype, causal, window, pair = _WHERE[case]
+    rng = np.random.default_rng(48)
+    q, k, v, do = (jnp.asarray(rng.normal(size=(B, T, h, d)), dtype) for h, d in ((H, D), (h_kv, D), (h_kv, d_v), (H, d_v)))
+    dlse = jnp.asarray(rng.normal(size=(B * H, T)), jnp.float32)
+    static = (float(1 / np.sqrt(D)), causal, 32, 32, True, window)
+    heads_first = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, T, x.shape[-1])  # noqa: E731
+    back = lambda x: x.reshape(B, -1, T, x.shape[-1]).transpose(0, 2, 1, 3)  # noqa: E731
+
+    def chosen(q, k, v):
+        return flash_module._bthd_call(flash_module._flash_bhtd_lse, q, k, v, causal, None, 32, 32, True, window)
+
+    def copies(q, k, v):
+        out, lse = flash_module._flash_bhtd_lse(*map(heads_first, (q, k, v)), *static)
+        return back(out), lse
+
+    def with_grads(entry):
+        (out, lse), pull = jax.vjp(entry, q, k, v)
+        return (out, lse, *pull((do, dlse)))
+
+    _forget()
+    jaxpr = jax.make_jaxpr(lambda q, k, v: with_grads(chosen))(q, k, v).jaxpr
+    g = default_registry().snapshot()["gauges"]
+    assert g["flash.in_place"] == (1 if pair else 0)
+    calls = _pallas_calls(jaxpr)
+    assert [(c.params["name"], len(c.invars), len(c.outvars)) for c in calls] == [
+        ("flash_fwd", 3, 2), ("flash_bwd_dq", 6, 1), ("flash_bwd_dkv", 6, 2),
+    ]
+    kernel_q = (B, T, H * D) if pair else (B * H, T, D)
+    assert all(c.invars[0].aval.shape == kernel_q for c in calls)
+    assert all(c.params["grid_mapping"].grid[0] == B * H // (pair or 1) for c in calls)
+    # the residuals are the model's arrays: nothing transposed anywhere when the call is in place
+    assert any(e.primitive.name == "transpose" and e.invars[0].aval.ndim == 4 for e in _eqns(jaxpr)) == (not pair)
+
+    got, want = with_grads(chosen), with_grads(copies)
+    for name, a, b in zip(("o", "lse"), got, want):
+        assert a.shape == b.shape and np.array_equal(np.asarray(a), np.asarray(b)), name
+    tol = 5e-2 if dtype == jnp.bfloat16 else 2e-5
+    for name, a, b in zip(("dq", "dk", "dv"), got[2:], want[2:]):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32), atol=tol, err_msg=name)
+    _forget()
+
+
+def test_a_written_out_tile_counts_once_for_each_head_a_program_holds():
+    """The code of a tile is that of every head of the pair: T = 1,024 keeps
+    its straight-line bodies at a pair of two, a T = 2,048 triangle of
+    512-tiles (10 tiles: 2.6 M elements a head) passes to the one traced body,
+    whose runs are half as long as a single head's."""
+    from adapcc_tpu.ops.flash_attention import _query_side, _runs, _written_out
+
+    for T, alone, paired in ((1024, True, True), (2048, True, False), (4096, False, False)):
+        n, span = _query_side(T, 512, 512, True, None)
+        assert (_written_out(n, span, 512, 512, True, None) is not None) == alone
+        assert (_written_out(n, span, 512, 512, True, None, pair=2) is not None) == paired
+    n, span = _query_side(8192, 512, 512, True, None)
+    assert [run for _, run in _runs(n, span, 512, 512)] == [0, 4, 0]
+    assert [run for _, run in _runs(n, span, 512, 512, pair=2)] == [0, 2, 0]
+    assert looped_tiles(2048, 512, 512, True) == 0 and looped_tiles(2048, 512, 512, True, pair=2) > 0
