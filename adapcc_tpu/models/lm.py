@@ -1,8 +1,9 @@
 """What the decoder language models share (Trinity-Mini's, Kimi-Linear's,
-JoyAI-LLM-Flash's, Granite 4.0-H's and Phi-4-mini-flash's blocks): the norm,
-the gated MLP, the bias-free projection, the remat table, the state-space
-mixers' initialisers, and the loss's one fork between float32 logits of the
-whole batch and the head product fused into the loss.
+JoyAI-LLM-Flash's, Granite 4.0-H's, Phi-4-mini-flash's, LFM2-24B-A2B's and
+SmallThinker-21BA3B's blocks): the norm, the gated MLP, the bias-free
+projection, the remat table, the state-space mixers' initialisers, and the
+loss's one fork between float32 logits of the whole batch and the head product
+fused into the loss.
 
 A model file takes these by their public names from here and nothing private
 from another model's file; what is one model's own stays in its file.

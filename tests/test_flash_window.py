@@ -106,6 +106,21 @@ def test_the_forwards_lse_under_a_window_is_the_dense_logsumexp_of_the_band(wind
     np.testing.assert_allclose(np.asarray(lse), np.asarray(want), atol=2e-5)
 
 
+def test_a_group_of_seven_under_a_window_of_half_the_row_matches_the_dense_oracle(program_index):
+    """The first group that is no power of two (SmallThinker's 28 query heads
+    on 4 K/V heads, here 14 on 2) with the window half of T, as its cell runs
+    it (4,096 of 8,192): the K/V block index ``h // 7`` and the seven fp32
+    ``dk`` / ``dv`` partials the wrapper sums, forward and the three gradients."""
+    q, k, v = _qkv(64, 14, 2, seed=7)
+    do = jnp.asarray(np.random.default_rng(5).normal(size=q.shape), jnp.float32)
+    out, pull = jax.vjp(lambda q, k, v: flash_attention(q, k, v, causal=True, window=32, block_q=16, block_k=16), q, k, v)
+    want, pull_want = jax.vjp(lambda q, k, v: dense_oracle(q, k, v, 32), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
+    for got, ref, name in zip(pull(do), pull_want(do), "qkv"):
+        assert got.shape == ref.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4, rtol=1e-4, err_msg=f"d{name}")
+
+
 def test_bf16_grouped_windowed_gradients_keep_their_dtype_and_stay_close():
     q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(64, 4, 2))
     loss = lambda q, k, v: jnp.sum(  # noqa: E731
